@@ -59,7 +59,8 @@ def make_gpt_measure(cfg=None, *, seq_len: int = 64, warmup: int = 1,
         tokens = shard_put(tokens, batch_sharding)
 
         def loss_fn(p, b, rng):
-            return gpt.loss_fn(p, run_cfg, b[:, :-1], b[:, 1:]), {}
+            return gpt.loss_fn(p, run_cfg, b[:, :-1], b[:, 1:],
+                               mesh=mesh), {}
 
         step = make_train_step(loss_fn, tx, mesh=mesh,
                                state_sharding=sharding,

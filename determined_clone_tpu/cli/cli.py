@@ -1189,6 +1189,12 @@ def cmd_serve(args) -> int:
     model_cfg = gpt_model.GPTConfig.tiny()
     import jax
 
+    from determined_clone_tpu.utils.compile_cache import (
+        configure_compile_cache,
+    )
+
+    # a restarted server finds its warm-up ladder already compiled
+    configure_compile_cache()
     params = gpt_model.init(jax.random.PRNGKey(args.seed), model_cfg)
     if args.checkpoint:
         from determined_clone_tpu.core._serialization import load_pytree
@@ -1246,12 +1252,16 @@ def cmd_fleet_up(args) -> int:
         FleetHTTPServer,
         generate_over_http,
     )
+    from determined_clone_tpu.utils.compile_cache import (
+        configure_compile_cache,
+    )
 
     if args.model != "tiny":
         print(f"error: unknown model preset {args.model!r} (have: tiny)",
               file=sys.stderr)
         return 2
     model_cfg = gpt_model.GPTConfig.tiny()
+    configure_compile_cache()
     params = gpt_model.init(jax.random.PRNGKey(args.seed), model_cfg)
     if args.checkpoint:
         from determined_clone_tpu.core._serialization import load_pytree

@@ -179,6 +179,12 @@ def main(argv=None) -> int:
         MasterSearcherSource,
     )
     from determined_clone_tpu.training import JaxTrial, Trainer, TrialContext
+    from determined_clone_tpu.utils.compile_cache import (
+        configure_compile_cache,
+    )
+
+    # a restart leg (or the next trial of the same shape) reuses compiles
+    configure_compile_cache()
 
     # Chaos runs ship their plan through the environment; a no-op when
     # DCT_FAULT_PLAN is unset.

@@ -134,6 +134,9 @@ GPT_PP_SHARDING_RULES = sharding_rules(pipelined=True)
 # Activation specs: batch over (dp, fsdp), sequence over sp, heads/features over tp.
 TOKENS_SPEC = P(("dp", "fsdp"), "sp")
 ACTIVATION_SPEC = P(("dp", "fsdp"), "sp", "tp")
+# q/k/v as the flash kernel sees them, [B, T, H, hd]: batch and heads are
+# independent, the sequence stays whole on every shard.
+FLASH_QKV_SPEC = P(("dp", "fsdp"), None, "tp", None)
 
 
 def init(key: jax.Array, cfg: GPTConfig) -> Params:
@@ -177,9 +180,31 @@ def init(key: jax.Array, cfg: GPTConfig) -> Params:
     return params
 
 
+def _flash(q: jax.Array, k: jax.Array, v: jax.Array, blk: int,
+           mesh: Optional[Any]) -> jax.Array:
+    """Causal flash attention, per shard when ``mesh`` spans devices.
+
+    XLA cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned"), so under a multi-device mesh the kernel
+    runs inside ``shard_map``: each device attends over its own slice of
+    the batch (dp, fsdp) and heads (tp). No collective is needed — rows
+    and heads never interact inside attention."""
+    from determined_clone_tpu.ops.flash_attention import flash_attention
+
+    attend = functools.partial(flash_attention, causal=True, block_q=blk,
+                               block_k=blk)
+    if mesh is None or mesh.size == 1:
+        return attend(q, k, v)
+    return jax.shard_map(
+        attend, mesh=mesh, in_specs=(FLASH_QKV_SPEC,) * 3,
+        out_specs=FLASH_QKV_SPEC, check_vma=False)(q, k, v)
+
+
 def _block(cfg: GPTConfig, block_params: Params, x: jax.Array,
-           positions: jax.Array, dropout_key: Optional[jax.Array]):
+           positions: jax.Array, dropout_key: Optional[jax.Array],
+           mesh: Optional[Any] = None):
     """One pre-LN transformer block. x: [B, T, D] in compute dtype.
+    ``mesh`` is only read by the flash kernel (see ``_flash``).
     Returns (x, aux) — aux is the MoE load-balancing loss (0 for dense)."""
     B, T, D = x.shape
     H, hd = cfg.n_heads, cfg.head_dim
@@ -197,8 +222,6 @@ def _block(cfg: GPTConfig, block_params: Params, x: jax.Array,
     if impl == "blockwise":
         attn = causal_blockwise_attention(q, k, v, block_size=cfg.attention_block_size)
     elif impl == "flash":
-        from determined_clone_tpu.ops.flash_attention import flash_attention
-
         blk = min(cfg.attention_block_size, 128)
         # the kernel tiles T into blk-sized blocks; pad indivisible T (the
         # everyday case: loss_fn slices tokens[:, :-1]) and slice back.
@@ -208,8 +231,7 @@ def _block(cfg: GPTConfig, block_params: Params, x: jax.Array,
         if pad:
             q, k, v = (jnp.pad(t, ((0, 0), (0, pad), (0, 0), (0, 0)))
                        for t in (q, k, v))
-        attn = flash_attention(q, k, v, causal=True, block_q=blk,
-                               block_k=blk)
+        attn = _flash(q, k, v, blk, mesh)
         if pad:
             attn = attn[:, :T]
     else:
@@ -246,6 +268,10 @@ def _forward(params: Params, cfg: GPTConfig, tokens: jax.Array, *,
     sliced over pp, activations rotate the stage ring. (In that mode per-layer
     dropout keys are shared across microbatches — masks repeat across
     microbatches of one step; statistically harmless.)
+
+    A mesh that spans more than one device is also what lets the flash
+    kernel run sharded (``_flash``): a jitted step over such a mesh must
+    pass it, or the TPU compiler refuses the unpartitionable kernel.
     """
     B, T = tokens.shape
     positions = jnp.arange(T)
@@ -256,15 +282,19 @@ def _forward(params: Params, cfg: GPTConfig, tokens: jax.Array, *,
         jax.random.split(dropout_key, cfg.n_layers) if use_dropout else None
     )
 
+    pp = mesh.shape.get("pp", 1) if mesh is not None else 1
+    pipelined = pp > 1 and cfg.pipeline_microbatches > 1
+
     def block_fn(layer_params, x, key):
-        return _block(cfg, layer_params, x, positions, key)
+        # pipeline stages already run inside pipeline_apply's shard_map
+        return _block(cfg, layer_params, x, positions, key,
+                      None if pipelined else mesh)
     if cfg.remat:
         block_fn = jax.checkpoint(
             block_fn, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable
         )
 
-    pp = mesh.shape.get("pp", 1) if mesh is not None else 1
-    if pp > 1 and cfg.pipeline_microbatches > 1:
+    if pipelined:
         from determined_clone_tpu.parallel.pipeline import pipeline_apply
 
         M = cfg.pipeline_microbatches

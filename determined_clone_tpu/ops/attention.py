@@ -144,15 +144,10 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, *, axis_name: str,
     l0 = jnp.zeros((B, H, T), jnp.float32)
     # the zero-init carry is a replicated constant but every loop output
     # varies over the sp axis — mark it varying or shard_map's vma check
-    # rejects the fori_loop carry. pvary is deprecated in favour of pcast
-    # on current JAX; keep the fallback for older versions.
-    if hasattr(jax.lax, "pcast"):
-        acc0, m0, l0 = jax.tree.map(
-            lambda x: jax.lax.pcast(x, axis_name, to="varying"),
-            (acc0, m0, l0))
-    else:
-        acc0, m0, l0 = jax.tree.map(
-            lambda x: jax.lax.pvary(x, axis_name), (acc0, m0, l0))
+    # rejects the fori_loop carry
+    acc0, m0, l0 = jax.tree.map(
+        lambda x: jax.lax.pcast(x, axis_name, to="varying"),
+        (acc0, m0, l0))
     acc, m, l, _, _ = jax.lax.fori_loop(
         0, axis_size, body, (acc0, m0, l0, k, v)
     )

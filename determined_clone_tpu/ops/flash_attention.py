@@ -14,7 +14,13 @@ Backward pass: custom VJP that recomputes attention with the XLA blockwise
 path (ops/attention.py) — fwd gets the fused kernel + no residual scores,
 bwd stays memory-efficient via rematerialization (jax.checkpoint-style).
 
-Falls back to interpret mode off-TPU so tests exercise the same code path.
+Off-TPU (CPU tests) the kernel runs in Pallas interpret mode, so tests
+exercise the same code path. On a TPU backend the compiled Mosaic kernel is
+the only path unless a caller passes ``interpret=True`` explicitly: nothing
+here selects interpret mode on a chip by itself.
+
+XLA cannot partition a Mosaic kernel: under a multi-device mesh the caller
+wraps the call in ``shard_map`` (models/gpt.py ``_flash`` does).
 """
 from __future__ import annotations
 

@@ -90,7 +90,8 @@ def _measure_mesh(mesh: Any, batch_size: int, *, steps: int,
     tokens = shard_put(tokens, batch_sharding)
 
     def loss_fn(p, batch, rng):
-        return gpt.loss_fn(p, cfg, batch[:, :-1], batch[:, 1:]), {}
+        return gpt.loss_fn(p, cfg, batch[:, :-1], batch[:, 1:],
+                           mesh=mesh), {}
 
     step = make_train_step(
         loss_fn, tx, mesh=mesh, state_sharding=sharding,

@@ -307,15 +307,15 @@ def parse_hlo_collectives(hlo_text: str, mesh: Any = None
 
 def comm_compute_fraction(
         summary: CollectiveSummary, flops: Optional[float], *,
-        interconnect_bytes_per_s: float,
-        peak_flops_per_s: float) -> Optional[float]:
+        interconnect_bytes_per_s: Optional[float],
+        peak_flops_per_s: Optional[float]) -> Optional[float]:
     """Analytic comm-vs-compute fraction of one program execution:
     ``comm_s / (comm_s + compute_s)``. None when the program's FLOPs are
-    unknown (no cost analysis) — a fraction with a made-up numerator
-    would be worse than no fraction."""
+    unknown (no cost analysis) or the device has no published peaks — a
+    fraction with a made-up term would be worse than no fraction."""
     if flops is None or flops <= 0:
         return None
-    if interconnect_bytes_per_s <= 0 or peak_flops_per_s <= 0:
+    if (interconnect_bytes_per_s or 0) <= 0 or (peak_flops_per_s or 0) <= 0:
         return None
     comm_s = summary.total_bytes / interconnect_bytes_per_s
     compute_s = flops / peak_flops_per_s

@@ -28,23 +28,41 @@ The widely used ``6 * n_params`` approximation is available as
 from __future__ import annotations
 
 import math
-import os
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
 
 # Training multiplier: forward + backward(2x).
 TRAIN_MULT = 3.0
 
-# Peak bf16 matmul FLOPs per chip. TPU numbers are published per-chip
-# peaks; the CPU number is a deliberately round order-of-magnitude
-# estimate (tens of GFLOPs for a few vector cores) — its job is to make
-# MFU non-null and *comparable across rounds on the same machine*, not
-# to be accurate in absolute terms. The provenance label says which.
+# Published per-chip peaks (Google Cloud TPU documentation, per
+# generation): bf16 matmul FLOP/s, HBM bytes/s, and further down the ICI
+# bytes/s. ONE table — bench.py reads it too. The CPU number is a
+# deliberately round order-of-magnitude estimate (tens of GFLOPs for a
+# few vector cores) — its job is to keep the plumbing exercised in CPU
+# tests, not to be a utilization claim. The provenance label says which.
 TPU_PEAK_BF16_FLOPS: Dict[str, float] = {
     "v4": 275e12,
     "v5e": 197e12,
     "v5p": 459e12,
     "v6e": 918e12,
+}
+TPU_HBM_BYTES_PER_S: Dict[str, float] = {
+    "v4": 1228e9,
+    "v5e": 819e9,
+    "v5p": 2765e9,
+    "v6e": 1640e9,
+}
+# ``jax.devices()[0].device_kind`` -> generation. The kind the runtime
+# reports is the only thing that names the chip; a kind missing here has
+# no peak, and so no MFU (never a default).
+TPU_DEVICE_KINDS: Dict[str, str] = {
+    "TPU v4": "v4",
+    "TPU v5 lite": "v5e",
+    "TPU v5e": "v5e",
+    "TPU v5": "v5p",
+    "TPU v5p": "v5p",
+    "TPU v6 lite": "v6e",
+    "TPU v6e": "v6e",
 }
 CPU_PEAK_EST_FLOPS = 50e9
 
@@ -287,41 +305,50 @@ def dense_train_step_flops(n_params: int, batch_size: int,
                      tokens=tokens, breakdown={"dense_6n": per_token * tokens})
 
 
-def peak_flops_estimate(platform: Optional[str] = None,
-                        tpu_generation: Optional[str] = None,
-                        ) -> Tuple[float, str]:
-    """Best-available peak FLOPs for the current chip.
+def _published(tpu_table: Dict[str, float], cpu_estimate: float,
+               platform: Optional[str], device_kind: Optional[str]
+               ) -> Tuple[Optional[float], str]:
+    """``(value, provenance)`` for the named device, or — for whichever
+    of platform and kind the caller left out — for ``jax.devices()[0]``
+    (imported here, so the module stays importable without jax)."""
+    platform = platform and platform.lower()
+    if platform is None or (platform == "tpu" and device_kind is None):
+        import jax
 
-    Returns ``(peak_flops, provenance)`` where provenance is a label like
-    ``"tpu:v5e"`` (published spec) or ``"cpu:est"`` (order-of-magnitude
-    assumption). MFU consumers must carry the label next to the number so
-    nobody mistakes an assumed-peak MFU for a measured one.
+        dev = jax.devices()[0]
+        platform = platform or dev.platform
+        device_kind = device_kind or dev.device_kind
+    if platform == "cpu":
+        return cpu_estimate, "cpu:est"
+    gen = TPU_DEVICE_KINDS.get(device_kind) if platform == "tpu" else None
+    if gen is None:
+        return None, f"{platform}:unknown-kind:{device_kind}"
+    return tpu_table[gen], f"tpu:{gen}"
+
+
+def peak_flops_estimate(platform: Optional[str] = None,
+                        device_kind: Optional[str] = None,
+                        ) -> Tuple[Optional[float], str]:
+    """Peak bf16 FLOP/s of one chip of the current (or the named) device.
+
+    Returns ``(peak_flops, provenance)``. On TPU the peak is the published
+    one for the ``device_kind`` the runtime reports (label ``"tpu:v5e"``);
+    an accelerator whose kind is not in the table has **no** peak —
+    ``(None, "tpu:unknown-kind:<kind>")`` — and consumers then publish no
+    MFU rather than one against a guessed denominator. ``"cpu:est"`` is
+    the order-of-magnitude CPU stand-in. MFU consumers carry the label
+    next to the number.
     """
-    plat = (platform or "").lower()
-    if not plat:
-        try:  # detect lazily; keep this importable without jax
-            import jax
-            plat = jax.default_backend()
-        except Exception:
-            plat = "cpu"
-    if plat == "tpu":
-        gen = (tpu_generation or os.environ.get("DCT_TPU_GENERATION")
-               or "").lower().lstrip("tpu").strip("-_ ")
-        if gen in TPU_PEAK_BF16_FLOPS:
-            return TPU_PEAK_BF16_FLOPS[gen], f"tpu:{gen}"
-        # Unknown generation: assume the most common fleet chip.
-        return TPU_PEAK_BF16_FLOPS["v5e"], "tpu:v5e:assumed"
-    if plat == "gpu":
-        return 312e12, "gpu:a100:assumed"
-    return CPU_PEAK_EST_FLOPS, "cpu:est"
+    return _published(TPU_PEAK_BF16_FLOPS, CPU_PEAK_EST_FLOPS,
+                      platform, device_kind)
 
 
 # Per-device interconnect bandwidth, bytes/s. TPU ICI numbers are
 # published per-link aggregates; the CPU number stands in for "shared
 # memory on one host" (a simulated --xla_force_host_platform_device_count
 # mesh moves shards through RAM) — like CPU_PEAK_EST_FLOPS it exists to
-# make the comm-vs-compute fraction non-null and comparable across rounds,
-# not to be absolutely accurate, and it carries a provenance label.
+# keep the comm-vs-compute plumbing exercised in CPU tests, and it
+# carries a provenance label.
 TPU_ICI_BYTES_PER_S: Dict[str, float] = {
     "v4": 300e9,
     "v5e": 200e9,
@@ -332,37 +359,21 @@ CPU_INTERCONNECT_EST_BYTES_PER_S = 10e9
 
 
 def interconnect_bandwidth_estimate(platform: Optional[str] = None,
-                                    tpu_generation: Optional[str] = None,
-                                    ) -> Tuple[float, str]:
-    """Best-available per-device interconnect bandwidth (bytes/s).
-
-    Returns ``(bytes_per_s, provenance)`` with the same provenance-label
-    contract as :func:`peak_flops_estimate`; the analytic comm-vs-compute
-    fraction (telemetry/collectives.py) divides collective payload bytes
-    by this to turn the compiled program's structure into seconds.
-    """
-    plat = (platform or "").lower()
-    if not plat:
-        try:
-            import jax
-            plat = jax.default_backend()
-        except Exception:
-            plat = "cpu"
-    if plat == "tpu":
-        gen = (tpu_generation or os.environ.get("DCT_TPU_GENERATION")
-               or "").lower().lstrip("tpu").strip("-_ ")
-        if gen in TPU_ICI_BYTES_PER_S:
-            return TPU_ICI_BYTES_PER_S[gen], f"tpu:{gen}"
-        return TPU_ICI_BYTES_PER_S["v5e"], "tpu:v5e:assumed"
-    if plat == "gpu":
-        return 600e9, "gpu:nvlink:assumed"
-    return CPU_INTERCONNECT_EST_BYTES_PER_S, "cpu:est"
+                                    device_kind: Optional[str] = None,
+                                    ) -> Tuple[Optional[float], str]:
+    """Per-device interconnect bandwidth (bytes/s) with the same
+    ``(value, provenance)`` contract as :func:`peak_flops_estimate` —
+    ``None`` for an accelerator kind that is not in the table. The
+    analytic comm-vs-compute fraction (telemetry/collectives.py) divides
+    collective payload bytes by this."""
+    return _published(TPU_ICI_BYTES_PER_S, CPU_INTERCONNECT_EST_BYTES_PER_S,
+                      platform, device_kind)
 
 
-def mfu(flops_per_sec: float, peak_flops: float,
-        n_devices: int = 1) -> float:
-    """Model FLOPs utilization against ``n_devices`` chips of peak."""
-    denom = peak_flops * max(1, n_devices)
-    if denom <= 0:
-        return 0.0
-    return flops_per_sec / denom
+def mfu(flops_per_sec: float, peak_flops: Optional[float],
+        n_devices: int = 1) -> Optional[float]:
+    """Model FLOPs utilization against ``n_devices`` chips of peak; None
+    when the chip has no known peak."""
+    if not peak_flops or peak_flops <= 0:
+        return None
+    return flops_per_sec / (peak_flops * max(1, n_devices))

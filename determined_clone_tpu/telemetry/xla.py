@@ -292,8 +292,10 @@ def aot_compile(
             record.cache_load_seconds = hit_meta.get("load_seconds")
             record.compile_time_saved_s = hit_meta.get("compile_seconds")
     except Exception as exc:  # noqa: BLE001 - capture must never fail training
-        logger.debug("aot compile capture unavailable for %s: %r",
-                     program, exc)
+        # training goes on with the implicit compile, but a lost capture
+        # must be seen: no compile record, no measured-FLOPs MFU
+        logger.warning("aot compile capture failed for %s: %r",
+                       program, exc)
         return fn, None
 
     if mesh is not None:
@@ -306,16 +308,10 @@ def aot_compile(
             summary = coll_mod.parse_hlo_collectives(
                 compiled.as_text(), mesh=mesh)
             record.collectives = summary
-            platform = None
-            try:
-                import jax
-
-                platform = jax.devices()[0].platform
-            except Exception:
-                platform = "cpu"
-            bw, _bw_label = flops_mod.interconnect_bandwidth_estimate(
-                platform)
-            peak, _peak_label = flops_mod.peak_flops_estimate(platform)
+            # both read the device the process actually has; a kind with
+            # no published numbers yields None and so no fraction
+            bw, _bw_label = flops_mod.interconnect_bandwidth_estimate()
+            peak, _peak_label = flops_mod.peak_flops_estimate()
             # cost_analysis() describes the per-device partitioned module
             # and the parser's byte volumes are per-shard payloads, so
             # both sides of the fraction are per-device quantities

@@ -333,14 +333,20 @@ class Trainer:
             n_devices = (int(mesh.devices.size) if mesh is not None
                          else jax.device_count())
             peak, peak_label = flops_mod.peak_flops_estimate()
-            peak_total = peak * max(1, n_devices)
+            if peak is None:
+                # an accelerator that is not in the peak table: FLOP/s are
+                # still reported, MFU is not (never a guessed denominator)
+                logger.warning(
+                    "no published peak for this device (%s): MFU will not "
+                    "be reported", peak_label)
+            peak_total = (peak or 0.0) * max(1, n_devices)
             # measured MFU: the compiled program's own cost_analysis FLOPs
             # (per single-step batch — the fused program covers k batches)
             if fused_record is not None and fused_record.flops:
                 measured_step_flops = fused_record.flops / k
             elif step_record is not None and step_record.flops:
                 measured_step_flops = step_record.flops
-            if measured_step_flops:
+            if measured_step_flops and peak_total:
                 mfu_cmp = MfuComparator(tel.registry,
                                         peak_flops_total=peak_total)
 
@@ -526,28 +532,30 @@ class Trainer:
                         (batches_trained - n0) * trial.global_batch_size / dt
                     )
                     if tel is not None and step_flops:
-                        # FLOPs throughput + MFU against the (measured or
-                        # assumed) peak; the provenance labels travel with
-                        # the number so an assumed-peak MFU can't pass as
-                        # a measured one (docs/observability.md)
+                        # FLOPs throughput + MFU against the published
+                        # peak of the device kind the runtime reports; the
+                        # provenance label travels with the number, and an
+                        # unknown kind publishes no MFU at all
+                        # (docs/observability.md)
                         fps = step_flops * train_metrics["batches_per_second"]
                         mfu_val = flops_mod.mfu(fps, peak_total)
                         train_metrics["flops_per_sec"] = fps
-                        train_metrics["mfu"] = mfu_val
                         reg = tel.registry
                         reg.gauge("samples_per_sec",
                                   "training throughput").set(
                             train_metrics["samples_per_second"])
                         reg.gauge("flops_per_sec",
                                   "analytic model FLOPs per second").set(fps)
-                        reg.gauge("mfu",
-                                  "model FLOPs utilization vs peak "
-                                  "(provenance: mfu_peak_info labels)").set(
-                            mfu_val)
-                        reg.gauge("mfu_peak_flops",
-                                  "peak FLOPs the MFU denominator assumes "
-                                  "(all participating devices)").set(
-                            peak_total)
+                        if mfu_val is not None:
+                            train_metrics["mfu"] = mfu_val
+                            reg.gauge("mfu",
+                                      "model FLOPs utilization vs peak "
+                                      "(provenance: mfu_peak_info labels)"
+                                      ).set(mfu_val)
+                            reg.gauge("mfu_peak_flops",
+                                      "peak FLOPs the MFU denominator uses "
+                                      "(all participating devices)").set(
+                                peak_total)
                         reg.gauge(
                             "mfu_peak_info",
                             "constant 1; labels carry the peak provenance "

@@ -40,8 +40,13 @@ class TrialContext:
             mesh_hp = hparams.get("mesh")
             spec = MeshSpec.from_dict(mesh_hp) if mesh_hp else MeshSpec()
             n = config.resources.slots_per_trial or 1
-            devices = jax.devices()[:n] if n <= len(jax.devices()) else jax.devices()
-            mesh = make_mesh(spec.resolve(len(devices)), devices)
+            if n > len(jax.devices()):
+                # never a quietly smaller mesh: a job asked for n chips
+                raise RuntimeError(
+                    f"resources.slots_per_trial={n} but only "
+                    f"{len(jax.devices())} devices are present")
+            devices = jax.devices()[:n]
+            mesh = make_mesh(spec.resolve(n), devices)
         self.mesh = mesh
 
     @property

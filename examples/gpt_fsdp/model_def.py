@@ -16,6 +16,7 @@ import numpy as np
 import optax
 
 from determined_clone_tpu.models import gpt
+from determined_clone_tpu.telemetry.flops import gpt_train_step_flops
 from determined_clone_tpu.training import JaxTrial
 
 
@@ -60,10 +61,17 @@ class GPTTrial(JaxTrial):
         )
 
     def loss(self, params, batch, rng):
-        return gpt.loss_fn(params, self.cfg, batch[:, :-1], batch[:, 1:]), {}
+        return gpt.loss_fn(params, self.cfg, batch[:, :-1], batch[:, 1:],
+                           mesh=self.context.mesh), {}
 
     def sharding_rules(self):
         return gpt.GPT_SHARDING_RULES
+
+    def train_step_flops(self):
+        # without this the trainer's MFU falls back to 6N x one token per
+        # sample — low by a factor of seq_len
+        return gpt_train_step_flops(self.cfg, self.global_batch_size,
+                                    self.seq_len)
 
     def training_data(self):
         bs, T = self.global_batch_size, self.seq_len

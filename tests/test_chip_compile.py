@@ -1,0 +1,254 @@
+"""What the chip's compiler says, asked without the chip — and chip_smoke.py
+rehearsed on the CPU.
+
+The TPU compiler is installed wherever JAX is; it compiles for a v5e that
+is *described* (``topologies.get_topology_desc``), not attached. These
+tests compile the main path's kernels and programs at GPT-2-small width,
+so that a kernel the chip would refuse (tiling, VMEM, partitioning) fails
+here, at no chip time. A compile that passes is not a chip run: nothing
+executes, nothing is timed. Skipped where the topology cannot be described.
+
+Code that asks ``jax.default_backend()`` sees the CPU under such a compile
+and would take its CPU branch, so the tests steer it themselves
+(``attention_impl="flash"``, ``interpret=False`` / monkeypatch).
+"""
+import functools
+import os
+import random
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import chip_smoke  # noqa: E402
+
+from determined_clone_tpu.models import gpt  # noqa: E402
+from determined_clone_tpu.ops import flash_attention as flash_mod  # noqa: E402
+from determined_clone_tpu.parallel import MeshSpec, make_mesh  # noqa: E402
+from determined_clone_tpu.utils import compile_cache  # noqa: E402
+
+GPT2_SMALL = gpt.GPTConfig(max_seq_len=1024)  # serving's view of fsdp.yaml
+V5E_HBM_BYTES = 16e9
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The four described chips of a v5e 2x2 host."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        from jax.experimental import topologies
+
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "not here"
+        pytest.skip(f"cannot describe a v5e topology here: {e!r}")
+    # such a compile is written to the persistent cache but cannot be read
+    # back without a chip (the next one would warn and compile again)
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield list(topo.devices)
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _shapes(tree, sharding):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
+def test_described_chip_is_in_the_peak_table(v5e):
+    """The device_kind the smoke will meet has a published peak."""
+    from determined_clone_tpu.telemetry import flops
+
+    kind = v5e[0].device_kind
+    assert flops.peak_flops_estimate("tpu", kind) == (197e12, "tpu:v5e")
+    assert flops.TPU_HBM_BYTES_PER_S[flops.TPU_DEVICE_KINDS[kind]] == 819e9
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_flash_kernel_compiles_at_gpt2_width(v5e, grad):
+    one = SingleDeviceSharding(v5e[0])
+    x = jax.ShapeDtypeStruct((8, 1024, 12, 64), jnp.bfloat16, sharding=one)
+    fn = functools.partial(flash_mod.flash_attention, causal=True,
+                           interpret=False)
+    if grad:
+        fn = jax.grad(lambda q, k, v, f=fn: f(q, k, v).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2))
+    compiled = jax.jit(fn).lower(x, x, x).compile()
+    # the backward pass is the XLA blockwise path (flash_attention.py's
+    # custom VJP), and a bare grad leaves the forward kernel dead
+    assert ("tpu_custom_call" in compiled.as_text()) == (not grad)
+
+
+def test_flash_kernel_runs_per_shard_under_a_mesh(v5e, monkeypatch):
+    """XLA refuses to partition a Mosaic kernel; gpt._flash shard_maps it
+    over batch (dp, fsdp) and heads (tp) — the fault the four-chip compile
+    found before any chip time was spent."""
+    from jax.sharding import NamedSharding
+
+    monkeypatch.setattr(flash_mod, "_should_interpret", lambda: False)
+    mesh = make_mesh(MeshSpec(fsdp=2, tp=2), v5e)
+    x = jax.ShapeDtypeStruct((8, 1024, 12, 64), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, gpt.FLASH_QKV_SPEC))
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        jax.jit(lambda q, k, v: gpt._flash(q, k, v, 128, None)
+                ).lower(x, x, x).compile()
+    compiled = jax.jit(lambda q, k, v: gpt._flash(q, k, v, 128, mesh)
+                       ).lower(x, x, x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("batch,t", [(8, 1), (8, 128)],
+                         ids=["decode", "prefill"])
+def test_paged_forward_compiles_at_gpt2_small(v5e, batch, t):
+    """The engine's one jitted entry point, at the shapes its default
+    ServingConfig warms up (pool of 512 blocks x 16 positions)."""
+    from determined_clone_tpu.serving.engine import make_paged_forward
+
+    one = SingleDeviceSharding(v5e[0])
+    cfg = GPT2_SMALL
+    params = jax.eval_shape(lambda k: gpt.init(k, cfg), jax.random.PRNGKey(0))
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    pool = arr((cfg.n_layers, 512, 16, cfg.n_heads, cfg.head_dim),
+               cfg.compute_dtype)
+    compiled = make_paged_forward(exec_cache=False).lower(
+        _shapes(params, one), cfg, arr((batch, t), jnp.int32),
+        arr((batch, t), jnp.int32), arr((batch, t), jnp.bool_),
+        arr((batch,), jnp.int32), pool, pool,
+        arr((batch, cfg.max_seq_len // 16), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_BYTES
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("n_chips", [1, 4])
+def test_gpt2_small_train_step_compiles(v5e, monkeypatch, n_chips):
+    """The whole fsdp.yaml train step (batch 8 x 1025 tokens, AdamW + clip,
+    flash attention), built the way the trainer builds it: the kernel is in
+    the program, the program fits the chip, and on four chips the FSDP
+    collectives are there.
+
+    Marked slow (12 s a case): the tier-1 lane already runs into its 870 s
+    limit, so it keeps the kernels and the paged step above and leaves this
+    to ``pytest tests/test_chip_compile.py`` before a chip call."""
+    from determined_clone_tpu.telemetry import collectives
+
+    monkeypatch.setattr(flash_mod, "_should_interpret", lambda: False)
+    config = chip_smoke.experiment_config(
+        n_chips, widths={"attention_impl": "flash"}, max_batches=1)
+    hparams = config.hyperparameters.sample(random.Random(0))
+    mesh = make_mesh(MeshSpec(fsdp=n_chips), v5e[:n_chips])
+    compiled = chip_smoke.compile_train_step(config, hparams, mesh)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    mem = compiled.memory_analysis()  # per device
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < V5E_HBM_BYTES)
+    coll = collectives.parse_hlo_collectives(text, mesh=mesh)
+    if n_chips == 1:
+        assert coll.total_ops == 0
+    else:
+        assert coll.count("all-gather", "fsdp") > 0
+        assert (coll.count("reduce-scatter", "fsdp")
+                + coll.count("all-reduce", "fsdp")) > 0
+
+
+# ---------------------------------------------------------------------------
+# chip_smoke.py on the CPU: the phase functions at GPTConfig.tiny() widths
+# ---------------------------------------------------------------------------
+
+_TINY = gpt.GPTConfig.tiny()
+TINY_WIDTHS = dict(vocab_size=_TINY.vocab_size, n_layers=_TINY.n_layers,
+                   d_model=_TINY.d_model, n_heads=_TINY.n_heads,
+                   d_ff=_TINY.d_ff, seq_len=_TINY.max_seq_len // 2,
+                   n_train_tokens=20_000)
+
+
+@pytest.mark.parametrize("phase", [
+    "train", "serve", pytest.param("sharded", marks=pytest.mark.slow)])
+def test_smoke_phase_rehearsal_on_cpu(tmp_path, phase):
+    if phase == "train":
+        out = chip_smoke.train_phase(str(tmp_path), widths=TINY_WIDTHS,
+                                     units=2)
+        assert out["reports_at"] == [20, 40, 41]
+        assert out["checkpoint_restored"] and out["last_loss"] < out[
+            "first_loss"]
+        assert out["mfu_peak_label"] == "cpu:est"
+        # off the chip "auto" resolves to plain XLA attention
+        assert out["attention_impl"] == "mha"
+        assert not out["pallas_custom_call_in_step"]
+    elif phase == "serve":
+        from determined_clone_tpu.serving.engine import make_paged_forward
+
+        # jit caches belong to the function, not the wrapper: every engine
+        # in the process shares forward_paged's. Count from zero, and leave
+        # nothing behind for the serving tests' program budgets.
+        shared = make_paged_forward(exec_cache=False)
+        shared.clear_cache()
+        try:
+            out = chip_smoke.serve_phase(_TINY)
+        finally:
+            shared.clear_cache()
+        assert out["requests"] == len(chip_smoke.SERVE_REQUESTS)
+        assert out["tokens_match_reference"]  # bit-identical on the CPU
+        assert out["leaked_kv_blocks"] == 0 and out["peak_active"] >= 2
+    else:
+        if len(jax.devices()) < 4:
+            pytest.skip("needs four (virtual) devices")
+        out = chip_smoke.sharded_phase(str(tmp_path), n_chips=4,
+                                       widths=TINY_WIDTHS, steps=4)
+        assert out["sharded"]["mesh"] == {"fsdp": 4}
+        assert out["sharded"]["leaves_misplaced"] == 0
+        assert out["max_relative_loss_gap"] < 1e-3
+
+
+def test_smoke_refuses_to_run_without_a_tpu():
+    """No accelerator: non-zero exit and no result line."""
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")],
+        env={**os.environ, "JAX_PLATFORMS": "cpu"}, cwd=REPO,
+        capture_output=True, text=True, timeout=120)
+    assert proc.returncode != 0
+    assert '"ok": true' not in proc.stdout
+    assert "no TPU" in proc.stderr
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path,
+                                              from_env):
+    """JAX_COMPILATION_CACHE_DIR stands where it is set (and no other
+    directory is set in code); unset, the cache is <repo>/.jax_cache."""
+    was = jax.config.jax_compilation_cache_dir
+    was_min = jax.config.jax_persistent_cache_min_compile_time_secs
+    monkeypatch.delenv("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
+                       raising=False)
+    try:
+        if from_env:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            assert compile_cache.configure_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == was
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            path = compile_cache.configure_compile_cache()
+            assert path == os.path.join(REPO, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == path
+        # sub-second programs are kept too (the warm-up ladder is made of
+        # them), unless the threshold was set from outside
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          was_min)

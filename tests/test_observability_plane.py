@@ -127,15 +127,18 @@ class TestFlops:
         assert flops_mod.mfu(peak / 2, peak) == pytest.approx(0.5)
         assert flops_mod.mfu(peak, peak, n_devices=4) == pytest.approx(0.25)
 
-    def test_tpu_generation_from_env_and_unknown_fallback(self, monkeypatch):
-        monkeypatch.setenv("DCT_TPU_GENERATION", "v5p")
-        peak, label = flops_mod.peak_flops_estimate("tpu")
-        assert peak == flops_mod.TPU_PEAK_BF16_FLOPS["v5p"]
-        assert label == "tpu:v5p"
-        # unknown generation: fleet-default peak, labeled as assumed
-        monkeypatch.delenv("DCT_TPU_GENERATION")
-        peak, label = flops_mod.peak_flops_estimate("tpu")
-        assert label == "tpu:v5e:assumed"
+    def test_tpu_peak_from_device_kind_and_unknown_has_none(self):
+        peak, label = flops_mod.peak_flops_estimate("tpu", "TPU v5 lite")
+        assert peak == flops_mod.TPU_PEAK_BF16_FLOPS["v5e"] == 197e12
+        assert label == "tpu:v5e"
+        # a kind that is not in the table: no peak, no MFU — never a
+        # default, and the label says which kind it was
+        peak, label = flops_mod.peak_flops_estimate("tpu", "TPU v9 mega")
+        assert peak is None and label == "tpu:unknown-kind:TPU v9 mega"
+        assert flops_mod.mfu(1e12, peak) is None
+        bw, label = flops_mod.interconnect_bandwidth_estimate(
+            "tpu", "TPU v9 mega")
+        assert bw is None and label == "tpu:unknown-kind:TPU v9 mega"
 
 
 # ---------------------------------------------------------------------------
@@ -432,7 +435,13 @@ class TestBenchGate:
         assert any("platform changed" in line for line in report)
 
     def test_cli_against_real_rounds(self, tmp_path):
-        # the repo's own previous round vs a synthetic new one
+        # a previous round as the driver writes it (wrapper around the
+        # bench's last line, mfu still null) vs a synthetic new one
+        old = tmp_path / "BENCH_r05.json"
+        old.write_text(json.dumps({
+            "n": 5, "cmd": "python bench.py", "rc": 0,
+            "tail": json.dumps(_bench_result(40.589, mfu=None)) + "\n",
+        }))
         new = tmp_path / "new.json"
         new.write_text(json.dumps(_bench_result(41.0)))
-        assert bench_gate.main(["BENCH_r05.json", str(new)]) == 0
+        assert bench_gate.main([str(old), str(new)]) == 0
