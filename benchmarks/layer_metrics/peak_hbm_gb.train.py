@@ -1,0 +1,13 @@
+"""Peak bytes in use on the fullest chip after the window, as the
+allocator reports (``memory_stats()["peak_bytes_in_use"]``), before the
+reference runs (train cells)."""
+LAYER = "device"
+UNIT = "GB"
+SOURCE = "program_counter"
+MOVES = "train_tokens_per_s_per_chip"
+
+
+def read(ctx):
+    if ctx["kind"] != "train" or not ctx["memory_peak_bytes"]:
+        return None
+    return ctx["memory_peak_bytes"] / 1e9
