@@ -212,45 +212,52 @@ def _block(cfg: GPTConfig, block_params: Params, x: jax.Array,
     if dropout_key is not None:
         k_attn, k_mlp = jax.random.split(dropout_key)
 
-    h = layernorm(block_params["ln1"], x)
-    qkv = dense(block_params["attn_qkv"], h, compute_dtype=cfg.compute_dtype)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    q = rotary_embedding(q.reshape(B, T, H, hd), positions)
-    k = rotary_embedding(k.reshape(B, T, H, hd), positions)
-    v = v.reshape(B, T, H, hd)
-    impl = resolved_attention_impl(cfg)
-    if impl == "blockwise":
-        attn = causal_blockwise_attention(q, k, v, block_size=cfg.attention_block_size)
-    elif impl == "flash":
-        blk = min(cfg.attention_block_size, 128)
-        # the kernel tiles T into blk-sized blocks; pad indivisible T (the
-        # everyday case: loss_fn slices tokens[:, :-1]) and slice back.
-        # Safe because attention is causal: real queries only ever see
-        # real keys (i < T), and padded rows are discarded.
-        pad = -T % blk
-        if pad:
-            q, k, v = (jnp.pad(t, ((0, 0), (0, pad), (0, 0), (0, 0)))
-                       for t in (q, k, v))
-        attn = _flash(q, k, v, blk, mesh)
-        if pad:
-            attn = attn[:, :T]
-    else:
-        attn = mha(q, k, v, causal=True)
-    attn = dense(block_params["attn_out"], attn.reshape(B, T, D),
-                 compute_dtype=cfg.compute_dtype)
-    x = x + dropout(k_attn, attn, cfg.dropout, training=k_attn is not None)
+    with jax.named_scope("attn"):
+        h = layernorm(block_params["ln1"], x)
+        qkv = dense(block_params["attn_qkv"], h,
+                    compute_dtype=cfg.compute_dtype)
+        q, k, v = jnp.split(qkv, 3, axis=-1)
+        q = rotary_embedding(q.reshape(B, T, H, hd), positions)
+        k = rotary_embedding(k.reshape(B, T, H, hd), positions)
+        v = v.reshape(B, T, H, hd)
+        impl = resolved_attention_impl(cfg)
+        if impl == "blockwise":
+            attn = causal_blockwise_attention(
+                q, k, v, block_size=cfg.attention_block_size)
+        elif impl == "flash":
+            blk = min(cfg.attention_block_size, 128)
+            # the kernel tiles T into blk-sized blocks; pad indivisible T
+            # (the everyday case: loss_fn slices tokens[:, :-1]) and slice
+            # back. Safe because attention is causal: real queries only
+            # ever see real keys (i < T), and padded rows are discarded.
+            pad = -T % blk
+            if pad:
+                q, k, v = (jnp.pad(t, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                           for t in (q, k, v))
+            attn = _flash(q, k, v, blk, mesh)
+            if pad:
+                attn = attn[:, :T]
+        else:
+            attn = mha(q, k, v, causal=True)
+        attn = dense(block_params["attn_out"], attn.reshape(B, T, D),
+                     compute_dtype=cfg.compute_dtype)
+        x = x + dropout(k_attn, attn, cfg.dropout,
+                        training=k_attn is not None)
 
-    h = layernorm(block_params["ln2"], x)
-    if cfg.moe_experts > 0:
-        h, aux = moe_ffn(block_params["moe"], h, k=cfg.moe_k,
-                         capacity_factor=cfg.moe_capacity_factor,
-                         compute_dtype=cfg.compute_dtype)
-    else:
-        h = dense(block_params["mlp_up"], h, compute_dtype=cfg.compute_dtype)
-        h = jax.nn.gelu(h, approximate=True)
-        h = dense(block_params["mlp_down"], h, compute_dtype=cfg.compute_dtype)
-        aux = jnp.zeros((), jnp.float32)
-    x = x + dropout(k_mlp, h, cfg.dropout, training=k_mlp is not None)
+    with jax.named_scope("mlp"):
+        h = layernorm(block_params["ln2"], x)
+        if cfg.moe_experts > 0:
+            h, aux = moe_ffn(block_params["moe"], h, k=cfg.moe_k,
+                             capacity_factor=cfg.moe_capacity_factor,
+                             compute_dtype=cfg.compute_dtype)
+        else:
+            h = dense(block_params["mlp_up"], h,
+                      compute_dtype=cfg.compute_dtype)
+            h = jax.nn.gelu(h, approximate=True)
+            h = dense(block_params["mlp_down"], h,
+                      compute_dtype=cfg.compute_dtype)
+            aux = jnp.zeros((), jnp.float32)
+        x = x + dropout(k_mlp, h, cfg.dropout, training=k_mlp is not None)
     return x, aux
 
 
@@ -275,7 +282,9 @@ def _forward(params: Params, cfg: GPTConfig, tokens: jax.Array, *,
     """
     B, T = tokens.shape
     positions = jnp.arange(T)
-    x = jnp.take(params["embed"]["table"], tokens, axis=0).astype(cfg.compute_dtype)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"]["table"], tokens,
+                     axis=0).astype(cfg.compute_dtype)
 
     use_dropout = training and dropout_key is not None and cfg.dropout > 0.0
     layer_keys = (
@@ -336,12 +345,15 @@ def _forward(params: Params, cfg: GPTConfig, tokens: jax.Array, *,
         x, aux_stack = jax.lax.scan(scan_body, x, params["blocks"])
         aux_total = jnp.sum(aux_stack)
 
-    x = layernorm(params["final_norm"], x)
-    if cfg.tie_embeddings:
-        logits = x.astype(jnp.float32) @ params["embed"]["table"].astype(jnp.float32).T
-    else:
-        logits = dense(params["lm_head"], x, compute_dtype=jnp.float32)
-    return logits.astype(jnp.float32), aux_total
+    with jax.named_scope("logits"):
+        x = layernorm(params["final_norm"], x)
+        if cfg.tie_embeddings:
+            logits = (x.astype(jnp.float32)
+                      @ params["embed"]["table"].astype(jnp.float32).T)
+        else:
+            logits = dense(params["lm_head"], x, compute_dtype=jnp.float32)
+        logits = logits.astype(jnp.float32)
+    return logits, aux_total
 
 
 def apply(params: Params, cfg: GPTConfig, tokens: jax.Array, *,
@@ -362,12 +374,13 @@ def loss_fn(params: Params, cfg: GPTConfig, tokens: jax.Array,
     """Mean next-token cross-entropy (+ MoE aux loss). targets/mask: [B, T]."""
     logits, aux = _forward(params, cfg, tokens, training=training,
                            dropout_key=dropout_key, mesh=mesh)
-    per_tok = softmax_cross_entropy(logits, targets)
-    if mask is not None:
-        maskf = mask.astype(jnp.float32)
-        ce = jnp.sum(per_tok * maskf) / jnp.maximum(jnp.sum(maskf), 1.0)
-    else:
-        ce = jnp.mean(per_tok)
+    with jax.named_scope("logits"):
+        per_tok = softmax_cross_entropy(logits, targets)
+        if mask is not None:
+            maskf = mask.astype(jnp.float32)
+            ce = jnp.sum(per_tok * maskf) / jnp.maximum(jnp.sum(maskf), 1.0)
+        else:
+            ce = jnp.mean(per_tok)
     if cfg.moe_experts > 0:
         ce = ce + cfg.moe_aux_weight * aux
     return ce
@@ -395,36 +408,45 @@ def _block_paged(cfg: GPTConfig, block_params: Params, x: jax.Array,
     H, hd = cfg.n_heads, cfg.head_dim
     N, bs = k_pool_l.shape[0], k_pool_l.shape[1]
 
-    h = layernorm(block_params["ln1"], x)
-    qkv = dense(block_params["attn_qkv"], h, compute_dtype=cfg.compute_dtype)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    q = rotary_embedding(q.reshape(B, T, H, hd), positions)
-    k = rotary_embedding(k.reshape(B, T, H, hd), positions)
-    v = v.reshape(B, T, H, hd)
+    with jax.named_scope("attn"):
+        h = layernorm(block_params["ln1"], x)
+        qkv = dense(block_params["attn_qkv"], h,
+                    compute_dtype=cfg.compute_dtype)
+        q, k, v = jnp.split(qkv, 3, axis=-1)
+        q = rotary_embedding(q.reshape(B, T, H, hd), positions)
+        k = rotary_embedding(k.reshape(B, T, H, hd), positions)
+        v = v.reshape(B, T, H, hd)
 
-    k_flat = k_pool_l.reshape(N * bs, H, hd)
-    v_flat = v_pool_l.reshape(N * bs, H, hd)
-    k_flat = k_flat.at[scatter_idx].set(k.reshape(B * T, H, hd), mode="drop")
-    v_flat = v_flat.at[scatter_idx].set(v.reshape(B * T, H, hd), mode="drop")
-    # gather the whole paged context: [B, S, H, hd]; slot j of the gathered
-    # context is sequence position j (block tables map contiguously)
-    ctx_k = k_flat[gather_idx]
-    ctx_v = v_flat[gather_idx]
-    attn = mha(q, ctx_k, ctx_v, causal=False, mask=attn_mask)
-    attn = dense(block_params["attn_out"], attn.reshape(B, T, D),
-                 compute_dtype=cfg.compute_dtype)
-    x = x + attn
+        with jax.named_scope("kv_cache"):
+            k_flat = k_pool_l.reshape(N * bs, H, hd)
+            v_flat = v_pool_l.reshape(N * bs, H, hd)
+            k_flat = k_flat.at[scatter_idx].set(k.reshape(B * T, H, hd),
+                                                mode="drop")
+            v_flat = v_flat.at[scatter_idx].set(v.reshape(B * T, H, hd),
+                                                mode="drop")
+            # gather the whole paged context: [B, S, H, hd]; slot j of the
+            # gathered context is sequence position j (block tables map
+            # contiguously)
+            ctx_k = k_flat[gather_idx]
+            ctx_v = v_flat[gather_idx]
+        attn = mha(q, ctx_k, ctx_v, causal=False, mask=attn_mask)
+        attn = dense(block_params["attn_out"], attn.reshape(B, T, D),
+                     compute_dtype=cfg.compute_dtype)
+        x = x + attn
 
-    h = layernorm(block_params["ln2"], x)
-    if cfg.moe_experts > 0:
-        h, _ = moe_ffn(block_params["moe"], h, k=cfg.moe_k,
-                       capacity_factor=cfg.moe_capacity_factor,
-                       compute_dtype=cfg.compute_dtype)
-    else:
-        h = dense(block_params["mlp_up"], h, compute_dtype=cfg.compute_dtype)
-        h = jax.nn.gelu(h, approximate=True)
-        h = dense(block_params["mlp_down"], h, compute_dtype=cfg.compute_dtype)
-    x = x + h
+    with jax.named_scope("mlp"):
+        h = layernorm(block_params["ln2"], x)
+        if cfg.moe_experts > 0:
+            h, _ = moe_ffn(block_params["moe"], h, k=cfg.moe_k,
+                           capacity_factor=cfg.moe_capacity_factor,
+                           compute_dtype=cfg.compute_dtype)
+        else:
+            h = dense(block_params["mlp_up"], h,
+                      compute_dtype=cfg.compute_dtype)
+            h = jax.nn.gelu(h, approximate=True)
+            h = dense(block_params["mlp_down"], h,
+                      compute_dtype=cfg.compute_dtype)
+        x = x + h
     return x, k_flat.reshape(N, bs, H, hd), v_flat.reshape(N, bs, H, hd)
 
 
@@ -457,8 +479,9 @@ def _paged_backbone(params: Params, cfg: GPTConfig, tokens: jax.Array,
                  ) & token_mask[:, :, None]
     attn_mask = attn_mask[:, None]  # [B, 1, T, S] broadcast over heads
 
-    x = jnp.take(params["embed"]["table"], tokens,
-                 axis=0).astype(cfg.compute_dtype)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"]["table"], tokens,
+                     axis=0).astype(cfg.compute_dtype)
 
     def scan_body(x, layer_in):
         layer_params, k_l, v_l = layer_in
@@ -469,7 +492,9 @@ def _paged_backbone(params: Params, cfg: GPTConfig, tokens: jax.Array,
     x, (k_pool, v_pool) = jax.lax.scan(
         scan_body, x, (params["blocks"], k_pool, v_pool))
 
-    return layernorm(params["final_norm"], x), k_pool, v_pool
+    with jax.named_scope("logits"):
+        x = layernorm(params["final_norm"], x)
+    return x, k_pool, v_pool
 
 
 def forward_paged(params: Params, cfg: GPTConfig, tokens: jax.Array,
@@ -511,14 +536,17 @@ def forward_paged(params: Params, cfg: GPTConfig, tokens: jax.Array,
     x, k_pool, v_pool = _paged_backbone(params, cfg, tokens, positions,
                                         token_mask, k_pool, v_pool,
                                         block_tables)
-    h_last = jnp.take_along_axis(
-        x, last_index[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    if cfg.tie_embeddings:
-        logits = (h_last.astype(jnp.float32)
-                  @ params["embed"]["table"].astype(jnp.float32).T)
-    else:
-        logits = dense(params["lm_head"], h_last, compute_dtype=jnp.float32)
-    return logits.astype(jnp.float32), k_pool, v_pool
+    with jax.named_scope("logits"):
+        h_last = jnp.take_along_axis(
+            x, last_index[:, None, None].astype(jnp.int32), axis=1)[:, 0]
+        if cfg.tie_embeddings:
+            logits = (h_last.astype(jnp.float32)
+                      @ params["embed"]["table"].astype(jnp.float32).T)
+        else:
+            logits = dense(params["lm_head"], h_last,
+                           compute_dtype=jnp.float32)
+        logits = logits.astype(jnp.float32)
+    return logits, k_pool, v_pool
 
 
 def forward_paged_logits(params: Params, cfg: GPTConfig, tokens: jax.Array,
@@ -545,12 +573,14 @@ def forward_paged_logits(params: Params, cfg: GPTConfig, tokens: jax.Array,
     x, k_pool, v_pool = _paged_backbone(params, cfg, tokens, positions,
                                         token_mask, k_pool, v_pool,
                                         block_tables)
-    if cfg.tie_embeddings:
-        logits = (x.astype(jnp.float32)
-                  @ params["embed"]["table"].astype(jnp.float32).T)
-    else:
-        logits = dense(params["lm_head"], x, compute_dtype=jnp.float32)
-    return logits.astype(jnp.float32), k_pool, v_pool
+    with jax.named_scope("logits"):
+        if cfg.tie_embeddings:
+            logits = (x.astype(jnp.float32)
+                      @ params["embed"]["table"].astype(jnp.float32).T)
+        else:
+            logits = dense(params["lm_head"], x, compute_dtype=jnp.float32)
+        logits = logits.astype(jnp.float32)
+    return logits, k_pool, v_pool
 
 
 def param_count(params: Params) -> int:

@@ -136,6 +136,7 @@ def _flash_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool,
             pltpu.VMEM((block_q, D), jnp.float32),   # acc (unnormalized out)
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qf, kf, vf)
     return out.reshape(B, H, Tq, D).transpose(0, 2, 1, 3)
 

@@ -50,7 +50,6 @@ optional and all preserving the bit-identical-greedy-parity pin
 from __future__ import annotations
 
 import collections
-import contextlib
 import dataclasses
 import math
 import os
@@ -76,6 +75,7 @@ from determined_clone_tpu.serving.kv_store import (
     params_fingerprint,
 )
 from determined_clone_tpu.telemetry import MetricsRegistry
+from determined_clone_tpu.telemetry.spans import null_span
 from determined_clone_tpu.utils.retry import RetryPolicy, retry_call
 
 
@@ -479,8 +479,7 @@ class InferenceEngine:
             registry if isinstance(registry, MetricsRegistry)
             else MetricsRegistry())
         tracer = getattr(telemetry, "tracer", None)
-        self._span = (tracer.span if tracer is not None
-                      else lambda name, **kw: contextlib.nullcontext())
+        self._span = tracer.span if tracer is not None else null_span
         # per-request event recording (queue admission, prefill chunks,
         # speculative rounds, COW forks, retirement): None when telemetry
         # is off, so the disabled path pays one `is not None` per step and
@@ -1106,7 +1105,9 @@ class InferenceEngine:
                             # fingerprint (rollback warms), never here
                             self._params_fp = params_fingerprint(
                                 self._params)
-                    admitted = self._admit_locked()
+                    # spans the admission alone, never the wait above
+                    with self._span("admit"):
+                        admitted = self._admit_locked()
                     self._busy = True
                 # fault points fire OUTSIDE the condition (a delay rule
                 # must wedge only this scheduler, never a lock every
@@ -1121,20 +1122,21 @@ class InferenceEngine:
                     if self._fault_scope:
                         faults.point("engine.step." + self._fault_scope)
                 iter_t0 = time.monotonic()
-                worked = self._reap_expired()
-                if self._pending_writes:
-                    self._do_writes()
-                    worked = True
-                if self._prefilling:
-                    self._prefill_step()
-                    worked = True
-                if self._active:
-                    if self._spec_k:
-                        self._spec_step()
-                    else:
-                        self._decode_step()
-                    worked = True
-                self._beat_t = time.monotonic()
+                with self._span("engine_iteration"):
+                    worked = self._reap_expired()
+                    if self._pending_writes:
+                        self._do_writes()
+                        worked = True
+                    if self._prefilling:
+                        self._prefill_step()
+                        worked = True
+                    if self._active:
+                        if self._spec_k:
+                            self._spec_step()
+                        else:
+                            self._decode_step()
+                        worked = True
+                    self._beat_t = time.monotonic()
                 if worked and self.iteration_floor_s > 0.0:
                     pad = self.iteration_floor_s \
                         - (time.monotonic() - iter_t0)
@@ -1513,28 +1515,29 @@ class InferenceEngine:
         and their completed prompts are registered for future sharing.
         """
         rows = list(self._prefilling)
-        self._do_copies(rows)
-        cnt = []
-        for a in rows:
-            remaining = a.prompt_len - a.prefill_pos
-            if self.chunk_prefill_len:
-                remaining = min(remaining, self.chunk_prefill_len)
-            cnt.append(remaining)
-        b = bucket_for(len(rows), self.buckets.batch_buckets)
-        t = bucket_for(max(cnt), self.buckets.prefill_len_buckets)
-        tok = np.zeros((b, t), np.int32)
-        pos = np.zeros((b, t), np.int32)
-        msk = np.zeros((b, t), bool)
-        last = np.zeros((b,), np.int32)
-        for i, a in enumerate(rows):
-            lo, n = a.prefill_pos, cnt[i]
-            tok[i, :n] = a.handle.req.prompt[lo:lo + n]
-            pos[i, :n] = np.arange(lo, lo + n)
-            msk[i, :n] = True
-            last[i] = n - 1
-        jt = (jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(msk),
-              jnp.asarray(last))
-        tables = self._tables_for(rows, b)
+        with self._span("prefill_prepare", rows=len(rows)):
+            self._do_copies(rows)
+            cnt = []
+            for a in rows:
+                remaining = a.prompt_len - a.prefill_pos
+                if self.chunk_prefill_len:
+                    remaining = min(remaining, self.chunk_prefill_len)
+                cnt.append(remaining)
+            b = bucket_for(len(rows), self.buckets.batch_buckets)
+            t = bucket_for(max(cnt), self.buckets.prefill_len_buckets)
+            tok = np.zeros((b, t), np.int32)
+            pos = np.zeros((b, t), np.int32)
+            msk = np.zeros((b, t), bool)
+            last = np.zeros((b,), np.int32)
+            for i, a in enumerate(rows):
+                lo, n = a.prefill_pos, cnt[i]
+                tok[i, :n] = a.handle.req.prompt[lo:lo + n]
+                pos[i, :n] = np.arange(lo, lo + n)
+                msk[i, :n] = True
+                last[i] = n - 1
+            jt = (jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(msk),
+                  jnp.asarray(last))
+            tables = self._tables_for(rows, b)
         t0 = time.monotonic()
         pt0 = time.perf_counter() if self._tracer is not None else 0.0
         with self._span("serving_prefill", batch=b, length=t):
@@ -1585,32 +1588,41 @@ class InferenceEngine:
         row's last sampled token to the pool, sample the next."""
         rows = list(self._active)
         b = bucket_for(len(rows), self.buckets.batch_buckets)
-        tok = np.zeros((b, 1), np.int32)
-        pos = np.zeros((b, 1), np.int32)
-        msk = np.zeros((b, 1), bool)
-        for i, a in enumerate(rows):
-            tok[i, 0] = a.last_token
-            pos[i, 0] = a.prompt_len + len(a.out) - 1
-            msk[i, 0] = True
+        # the host's phases of the step, each a span with the same args
+        # (docs/observability.md "An engine iteration")
+        size = {"batch": b, "rows": len(rows)}
+        with self._span("decode_prepare", **size):
+            tok = np.zeros((b, 1), np.int32)
+            pos = np.zeros((b, 1), np.int32)
+            msk = np.zeros((b, 1), bool)
+            for i, a in enumerate(rows):
+                tok[i, 0] = a.last_token
+                pos[i, 0] = a.prompt_len + len(a.out) - 1
+                msk[i, 0] = True
+            tables = self._tables_for(rows, b)
         t0 = time.monotonic()
-        with self._span("serving_decode_step", batch=b, rows=len(rows)):
-            logits, self._k_pool, self._v_pool = self._fwd(
-                self._params, self.model_cfg, jnp.asarray(tok),
-                jnp.asarray(pos), jnp.asarray(msk),
-                jnp.zeros((b,), jnp.int32),
-                self._k_pool, self._v_pool, self._tables_for(rows, b))
-            nxt = np.asarray(jnp.argmax(logits, axis=-1))
+        with self._span("serving_decode_step", **size):
+            with self._span("decode_dispatch", **size):
+                logits, self._k_pool, self._v_pool = self._fwd(
+                    self._params, self.model_cfg, jnp.asarray(tok),
+                    jnp.asarray(pos), jnp.asarray(msk),
+                    jnp.zeros((b,), jnp.int32),
+                    self._k_pool, self._v_pool, tables)
+            with self._span("decode_readback", **size):
+                nxt = np.asarray(jnp.argmax(logits, axis=-1))
         self._h_decode.observe(time.monotonic() - t0)
-        survivors: List[_Active] = []
-        for i, a in enumerate(rows):
-            a.out.append(int(nxt[i]))
-            a.last_token = int(nxt[i])
-            if not self._maybe_finish(a):
-                survivors.append(a)
-        with self._cond:
-            self._active = survivors
-            self._g_active.set(len(self._active) + len(self._prefilling))
-            self._g_free_blocks.set(self._allocator.free_blocks())
+        with self._span("decode_commit", **size):
+            survivors: List[_Active] = []
+            for i, a in enumerate(rows):
+                a.out.append(int(nxt[i]))
+                a.last_token = int(nxt[i])
+                if not self._maybe_finish(a):
+                    survivors.append(a)
+            with self._cond:
+                self._active = survivors
+                self._g_active.set(len(self._active)
+                                   + len(self._prefilling))
+                self._g_free_blocks.set(self._allocator.free_blocks())
 
     def _spec_step(self) -> None:
         """One speculative iteration for every active sequence: the
@@ -1631,15 +1643,16 @@ class InferenceEngine:
         rows = list(self._active)
         k = self._spec_k
         b = bucket_for(len(rows), self.buckets.batch_buckets)
-        tables = self._tables_for(rows, b)
-        n0 = np.array([a.prompt_len + len(a.out) for a in rows])
-        allow = np.array([min(k + 1,
-                              a.handle.req.max_new_tokens - len(a.out))
-                          for a in rows])
+        size = {"batch": b, "rows": len(rows)}
+        with self._span("decode_prepare", **size):
+            tables = self._tables_for(rows, b)
+            n0 = np.array([a.prompt_len + len(a.out) for a in rows])
+            allow = np.array([min(k + 1,
+                                  a.handle.req.max_new_tokens - len(a.out))
+                              for a in rows])
         t0 = time.monotonic()
         pt0 = time.perf_counter() if self._tracer is not None else 0.0
-        with self._span("serving_spec_step", batch=b, rows=len(rows),
-                        k=k):
+        with self._span("serving_spec_step", k=k, **size):
             drafts = np.zeros((len(rows), k), np.int64)
             cur = np.array([a.last_token for a in rows])
             zero_last = jnp.zeros((b,), jnp.int32)
@@ -1650,11 +1663,14 @@ class InferenceEngine:
                 tok[:len(rows), 0] = cur
                 pos[:len(rows), 0] = n0 - 1 + j
                 msk[:len(rows), 0] = j < allow
-                dl, self._dk_pool, self._dv_pool = self._draft_fwd(
-                    self._draft_params, self.draft_cfg, jnp.asarray(tok),
-                    jnp.asarray(pos), jnp.asarray(msk), zero_last,
-                    self._dk_pool, self._dv_pool, tables)
-                cur = np.asarray(jnp.argmax(dl, axis=-1))[:len(rows)]
+                with self._span("decode_dispatch", **size):
+                    dl, self._dk_pool, self._dv_pool = self._draft_fwd(
+                        self._draft_params, self.draft_cfg,
+                        jnp.asarray(tok), jnp.asarray(pos),
+                        jnp.asarray(msk), zero_last,
+                        self._dk_pool, self._dv_pool, tables)
+                with self._span("decode_readback", **size):
+                    cur = np.asarray(jnp.argmax(dl, axis=-1))[:len(rows)]
                 drafts[:, j] = cur
             tok = np.zeros((b, k + 1), np.int32)
             pos = np.zeros((b, k + 1), np.int32)
@@ -1664,53 +1680,56 @@ class InferenceEngine:
                 tok[i, 1:] = drafts[i]
                 pos[i] = np.arange(n0[i] - 1, n0[i] + k)
                 msk[i] = np.arange(k + 1) < allow[i]
-            logits, self._k_pool, self._v_pool = self._verify_fwd(
-                self._params, self.model_cfg, jnp.asarray(tok),
-                jnp.asarray(pos), jnp.asarray(msk),
-                self._k_pool, self._v_pool, tables)
-            target = np.asarray(jnp.argmax(logits, axis=-1))
+            with self._span("decode_dispatch", **size):
+                logits, self._k_pool, self._v_pool = self._verify_fwd(
+                    self._params, self.model_cfg, jnp.asarray(tok),
+                    jnp.asarray(pos), jnp.asarray(msk),
+                    self._k_pool, self._v_pool, tables)
+            with self._span("decode_readback", **size):
+                target = np.asarray(jnp.argmax(logits, axis=-1))
         step_dt = time.monotonic() - t0
         self._h_decode.observe(step_dt)
-        survivors: List[_Active] = []
-        step_proposed = step_accepted = 0
-        for i, a in enumerate(rows):
-            # accept while the draft echoes the target's own greedy pick;
-            # target[i, j] is trustworthy for j < allow[i] because all of
-            # its conditioning tokens are committed-or-accepted by then
-            emitted = [int(target[i, 0])]
-            j = 0
-            while (j < allow[i] - 1 and j < k
-                   and int(drafts[i, j]) == int(target[i, j])):
-                j += 1
-                emitted.append(int(target[i, j]))
-            usable = int(min(k, allow[i] - 1))
-            a.spec_proposed += usable
-            a.spec_accepted += len(emitted) - 1
-            step_proposed += usable
-            step_accepted += len(emitted) - 1
-            if self._tracer is not None:
-                self._tracer.record_span(
-                    "request_spec_round", pt0, step_dt, **self._req_args(
-                        a.handle.req, proposed=usable,
-                        accepted=len(emitted) - 1, emitted=len(emitted)))
-            for tk in emitted:
-                a.out.append(tk)
-                a.last_token = tk
-                if (a.handle.req.eos_token_id is not None
-                        and tk == a.handle.req.eos_token_id):
-                    break
-            if not self._maybe_finish(a):
-                survivors.append(a)
-        self._c_spec_proposed.inc(step_proposed)
-        self._c_spec_accepted.inc(step_accepted)
-        proposed = self._c_spec_proposed.value
-        if proposed:
-            self._g_spec_rate.set(
-                self._c_spec_accepted.value / proposed)
-        with self._cond:
-            self._active = survivors
-            self._g_active.set(len(self._active) + len(self._prefilling))
-            self._g_free_blocks.set(self._allocator.free_blocks())
+        with self._span("decode_commit", **size):
+            survivors: List[_Active] = []
+            step_proposed = step_accepted = 0
+            for i, a in enumerate(rows):
+                # accept while the draft echoes the target's own greedy pick;
+                # target[i, j] is trustworthy for j < allow[i] because all of
+                # its conditioning tokens are committed-or-accepted by then
+                emitted = [int(target[i, 0])]
+                j = 0
+                while (j < allow[i] - 1 and j < k
+                       and int(drafts[i, j]) == int(target[i, j])):
+                    j += 1
+                    emitted.append(int(target[i, j]))
+                usable = int(min(k, allow[i] - 1))
+                a.spec_proposed += usable
+                a.spec_accepted += len(emitted) - 1
+                step_proposed += usable
+                step_accepted += len(emitted) - 1
+                if self._tracer is not None:
+                    self._tracer.record_span(
+                        "request_spec_round", pt0, step_dt, **self._req_args(
+                            a.handle.req, proposed=usable,
+                            accepted=len(emitted) - 1, emitted=len(emitted)))
+                for tk in emitted:
+                    a.out.append(tk)
+                    a.last_token = tk
+                    if (a.handle.req.eos_token_id is not None
+                            and tk == a.handle.req.eos_token_id):
+                        break
+                if not self._maybe_finish(a):
+                    survivors.append(a)
+            self._c_spec_proposed.inc(step_proposed)
+            self._c_spec_accepted.inc(step_accepted)
+            proposed = self._c_spec_proposed.value
+            if proposed:
+                self._g_spec_rate.set(
+                    self._c_spec_accepted.value / proposed)
+            with self._cond:
+                self._active = survivors
+                self._g_active.set(len(self._active) + len(self._prefilling))
+                self._g_free_blocks.set(self._allocator.free_blocks())
 
     def _maybe_finish(self, a: _Active) -> bool:
         req = a.handle.req

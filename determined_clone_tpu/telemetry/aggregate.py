@@ -856,7 +856,6 @@ class ClusterMetricsAggregator:
         with self._lock:
             n_trials = len(self._trials)
             mfu = gauge_per_trial("mfu")
-            mfu_measured = gauge_per_trial("mfu_measured")
         ingest = {
             "batches": self._batches.value,
             "samples": self._samples.value,
@@ -876,7 +875,6 @@ class ClusterMetricsAggregator:
             "top_trials_by_throughput": top,
             "throughput_total": sum(throughput.values()),
             "mfu_by_trial": mfu,
-            "mfu_measured_by_trial": mfu_measured,
             "straggler": straggler,
             "goodput": self.goodput_rollup(fams),
             "serving_fleet": self.serving_fleet_rollup(fams),
@@ -908,9 +906,6 @@ def format_summary(summary: Dict[str, Any]) -> str:
         for tid, sps in summary["top_trials_by_throughput"]:
             mfu = summary["mfu_by_trial"].get(tid)
             mfu_s = f"  mfu={mfu:.4f}" if mfu is not None else ""
-            mmfu = summary.get("mfu_measured_by_trial", {}).get(tid)
-            if mmfu is not None:
-                mfu_s += f"  mfu_measured={mmfu:.4f}"
             out.append(f"  trial {tid}: {sps:.2f} samples/sec{mfu_s}")
     straggler = summary.get("straggler")
     if straggler:

@@ -21,9 +21,17 @@ Design constraints (docs/observability.md has the taxonomy):
 - **Bounded**: at ``max_events`` the tracer stops recording (keeping the
   head — startup and compile spans are the irreplaceable part) and counts
   drops, so a long run cannot OOM the host.
+- **On the profiler's clock too**: an enabled tracer enters a
+  ``jax.profiler.TraceAnnotation(name)`` around every span, so a
+  ``jax.profiler`` trace shows the program's spans on the ``/host:CPU``
+  plane above the device's operations, on the trace's own clock. Outside
+  a profiler session the annotation is a flag check; a process that has
+  not imported jax (the CLI, the master's helpers) gets none and imports
+  nothing.
 """
 from __future__ import annotations
 
+import sys
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
@@ -52,6 +60,15 @@ def null_span(name: str, **args: Any) -> _NullSpan:
     return NULL_SPAN
 
 
+def _trace_annotation() -> Any:
+    """``jax.profiler.TraceAnnotation`` where this process has imported
+    jax, else None (no profiler session can exist without it, and
+    telemetry never pulls jax into a process that does not use it)."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)
+    return getattr(profiler, "TraceAnnotation", None)
+
+
 class Span:
     """One live span; records itself into the tracer on ``__exit__``.
 
@@ -60,7 +77,8 @@ class Span:
     thread lanes in the exported trace, not by parent links).
     """
 
-    __slots__ = ("_tracer", "name", "args", "_start", "_depth")
+    __slots__ = ("_tracer", "name", "args", "_start", "_depth",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str,
                  args: Optional[Dict[str, Any]]) -> None:
@@ -69,6 +87,7 @@ class Span:
         self.args = args
         self._start = 0.0
         self._depth = 0
+        self._annotation: Any = None
 
     def set(self, **args: Any) -> None:
         """Attach/override args after entry (e.g. compile detection only
@@ -81,11 +100,18 @@ class Span:
         stack = self._tracer._stack()
         self._depth = len(stack)
         stack.append(self)
+        annotate = _trace_annotation()
+        if annotate is not None:
+            self._annotation = annotate(self.name)
+            self._annotation.__enter__()
         self._start = time.perf_counter()
         return self
 
     def __exit__(self, *exc: Any) -> None:
         end = time.perf_counter()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+            self._annotation = None
         stack = self._tracer._stack()
         # tolerate exception-path misnesting: pop to (and including) self
         while stack:

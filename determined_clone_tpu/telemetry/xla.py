@@ -1,12 +1,12 @@
-"""XLA-level telemetry: explicit compile capture, measured MFU, anomalies.
+"""XLA-level telemetry: explicit compile capture and step-time anomalies.
 
 The telemetry stack so far watches the *Python* side of the hot loop —
 spans time dispatches, ``xla_compiles_total`` counts cache growth — but
 the compiled program itself stayed a black box: compile time was invisible
 (ROADMAP item 4's persistent executable cache needs it to prove
-``compile_time_saved``) and MFU was analytic-only (a formula about the
-architecture, not the program XLA actually emitted). This module opens the
-box via JAX's AOT path:
+``compile_time_saved``). This module opens the box via JAX's AOT path
+(``cost_analysis()`` FLOPs are recorded per program but feed no MFU: on
+TPU they leave the scan body and custom calls out, docs/observability.md):
 
 - :func:`aot_compile` replaces a jitted callable's first-call implicit
   compile with an explicit ``lower()`` / ``compile()`` whose wall time is
@@ -15,10 +15,6 @@ box via JAX's AOT path:
   ``cost_analysis()`` FLOPs/bytes become per-program metrics. The returned
   callable runs the AOT executable (no double compile) and falls back to
   the original jit wrapper on argument-shape mismatch.
-- :class:`MfuComparator` turns the compiled program's *measured* FLOPs
-  into a second MFU gauge next to PR 6's analytic one, and warns —
-  rate-limited — when the two diverge more than 20%: either the analytic
-  formula drifted from the model, or XLA emitted something unexpected.
 - :class:`StepTimeAnomalyDetector` — a rolling median/MAD detector over
   dispatch durations. MAD (median absolute deviation) is robust to the
   very outliers it hunts: a straggler step moves a mean-based z-score's
@@ -42,11 +38,6 @@ import time
 from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
 logger = logging.getLogger(__name__)
-
-# Measured-vs-analytic MFU divergence: warn past this ratio, at most once
-# per WARN_PERIOD (per comparator) so a long run can't spam the log.
-MFU_DIVERGENCE_RATIO = 1.2
-MFU_WARN_PERIOD_SEC = 300.0
 
 # 1.4826 * MAD estimates the standard deviation for normal data; the
 # detector's threshold is expressed in these robust sigmas.
@@ -555,64 +546,6 @@ class AotDispatcher:
         }
 
 
-class MfuComparator:
-    """Measured MFU (cost_analysis FLOPs) next to the analytic gauge.
-
-    The analytic number says what the *architecture* costs; the measured
-    number says what the *compiled program* costs. They legitimately
-    differ a little (rematerialization recomputes the forward pass,
-    fusion eliminates ops the formula counts), so the warn threshold is
-    20% — past that either the analytic formula no longer matches the
-    model (e.g. a new block type not in flops.py) or XLA emitted
-    something pathological. The warning is rate-limited; gauges update
-    every chunk regardless.
-    """
-
-    def __init__(self, registry: Any, *, peak_flops_total: float,
-                 warn_period_s: float = MFU_WARN_PERIOD_SEC) -> None:
-        self._registry = registry
-        self._peak = float(peak_flops_total)
-        self._warn_period = warn_period_s
-        self._last_warn = -warn_period_s  # first divergence warns
-        self._warned = 0
-
-    def report(self, *, measured_flops_per_batch: float,
-               batches_per_second: float,
-               analytic_mfu: Optional[float] = None) -> float:
-        """Update the measured gauges; compare against the analytic MFU.
-
-        Returns the measured MFU. Call at the chunk boundary (never per
-        step).
-        """
-        fps = measured_flops_per_batch * batches_per_second
-        measured = fps / self._peak if self._peak > 0 else 0.0
-        reg = self._registry
-        reg.gauge("measured_flops_per_sec",
-                  "throughput x per-program FLOPs from cost_analysis()"
-                  ).set(fps)
-        reg.gauge("mfu_measured",
-                  "MFU from the compiled program's measured FLOPs "
-                  "(vs the analytic `mfu` gauge)").set(measured)
-        if analytic_mfu and measured > 0:
-            ratio = max(measured / analytic_mfu, analytic_mfu / measured)
-            if ratio > MFU_DIVERGENCE_RATIO:
-                now = time.monotonic()
-                if now - self._last_warn >= self._warn_period:
-                    self._last_warn = now
-                    self._warned += 1
-                    logger.warning(
-                        "measured MFU %.4f vs analytic MFU %.4f diverge "
-                        "%.0f%% (>20%%): the analytic FLOPs formula and the "
-                        "compiled program disagree — check flops.py against "
-                        "the model, or a recompile changed the program",
-                        measured, analytic_mfu, (ratio - 1.0) * 100.0)
-                reg.counter(
-                    "mfu_divergence_total",
-                    "chunks where measured and analytic MFU diverged >20%"
-                ).inc()
-        return measured
-
-
 class StepTimeAnomalyDetector:
     """Rolling median/MAD detector over dispatch durations.
 
@@ -689,7 +622,6 @@ class StepTimeAnomalyDetector:
 __all__ = [
     "AotDispatcher",
     "CompileRecord",
-    "MfuComparator",
     "StepTimeAnomalyDetector",
     "aot_compile",
     "export_compile_record",

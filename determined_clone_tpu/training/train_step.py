@@ -125,12 +125,15 @@ def make_train_step(
         (loss, metrics), grads = jax.value_and_grad(wrapped, has_aux=True)(
             state.params
         )
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = tx.update(grads, state.opt_state,
+                                           state.params)
+            params = optax.apply_updates(state.params, updates)
         new_state = TrainState(
             params=params, opt_state=opt_state, step=state.step + 1, rng=rng
         )
-        gnorm = optax.global_norm(grads)
+        with jax.named_scope("optimizer"):
+            gnorm = optax.global_norm(grads)
         out_metrics = {"loss": loss.astype(jnp.float32),
                        "grad_norm": gnorm.astype(jnp.float32), **metrics}
         return new_state, out_metrics

@@ -38,10 +38,7 @@ from determined_clone_tpu.core._serialization import load_pytree, save_pytree
 from determined_clone_tpu.telemetry import flops as flops_mod
 from determined_clone_tpu.telemetry.device import DeviceMemoryMonitor
 from determined_clone_tpu.telemetry.spans import null_span
-from determined_clone_tpu.telemetry.xla import (
-    MfuComparator,
-    StepTimeAnomalyDetector,
-)
+from determined_clone_tpu.telemetry.xla import StepTimeAnomalyDetector
 from determined_clone_tpu.training.metrics import MetricAccumulator
 from determined_clone_tpu.training.train_step import (
     TrainState,
@@ -285,7 +282,6 @@ class Trainer:
         # train_dispatch span cover device completion, not just enqueue.
         tel = self._telemetry
         span = tel.tracer.span if tel is not None else null_span
-        step_record = fused_record = None
         anomaly = None
         memmon = None
         if tel is not None:
@@ -293,12 +289,12 @@ class Trainer:
             # compile that runs is the compile that was measured, and the
             # program fingerprint + cost_analysis FLOPs land in the
             # registry before the first step dispatches
-            train_step, step_record = capture_compile(
+            train_step, _ = capture_compile(
                 train_step, (state, first_batch),
                 program="train_step",
                 registry=tel.registry, tracer=tel.tracer)
             if fused_step is not None:
-                fused_step, fused_record = capture_compile(
+                fused_step, _ = capture_compile(
                     fused_step, (state,) + (first_batch,) * k,
                     program=f"train_step_fused_k{k}",
                     registry=tel.registry, tracer=tel.tracer)
@@ -326,8 +322,6 @@ class Trainer:
         step_flops = 0.0
         flops_source = peak_label = ""
         peak_total = 0.0
-        mfu_cmp = None
-        measured_step_flops = 0.0
         if tel is not None:
             step_flops, flops_source = self._resolve_step_flops(trial, state)
             n_devices = (int(mesh.devices.size) if mesh is not None
@@ -340,15 +334,6 @@ class Trainer:
                     "no published peak for this device (%s): MFU will not "
                     "be reported", peak_label)
             peak_total = (peak or 0.0) * max(1, n_devices)
-            # measured MFU: the compiled program's own cost_analysis FLOPs
-            # (per single-step batch — the fused program covers k batches)
-            if fused_record is not None and fused_record.flops:
-                measured_step_flops = fused_record.flops / k
-            elif step_record is not None and step_record.flops:
-                measured_step_flops = step_record.flops
-            if measured_step_flops and peak_total:
-                mfu_cmp = MfuComparator(tel.registry,
-                                        peak_flops_total=peak_total)
 
         sched_unit = config.scheduling_unit
         val_period = self._to_batches(config.min_validation_period, 0)
@@ -562,12 +547,6 @@ class Trainer:
                             "and FLOPs-count source",
                             labels={"assumed": peak_label,
                                     "flops_source": flops_source}).set(1)
-                        if mfu_cmp is not None:
-                            train_metrics["mfu_measured"] = mfu_cmp.report(
-                                measured_flops_per_batch=measured_step_flops,
-                                batches_per_second=train_metrics[
-                                    "batches_per_second"],
-                                analytic_mfu=mfu_val)
                     if memmon is not None:
                         # per-device gauges + the between-boundary peak
                         # watermark (profiler's sampler thread feeds the

@@ -90,6 +90,20 @@ def test_flash_kernel_compiles_at_gpt2_width(v5e, grad):
     assert ("tpu_custom_call" in compiled.as_text()) == (not grad)
 
 
+def test_flash_kernel_is_named_in_the_compiled_program(v5e):
+    """The custom call is ``flash_fwd`` in the optimised HLO, which is the
+    name its events carry in a device trace (PERF.md section 3)."""
+    import re
+
+    one = SingleDeviceSharding(v5e[0])
+    x = jax.ShapeDtypeStruct((8, 1024, 12, 64), jnp.bfloat16, sharding=one)
+    fn = functools.partial(flash_mod.flash_attention, causal=True,
+                           interpret=False)
+    text = jax.jit(fn).lower(x, x, x).compile().as_text()
+    assert re.search(r"%flash_fwd[.\d]* = [^\n]*custom-call\([^\n]*"
+                     r'custom_call_target="tpu_custom_call"', text)
+
+
 def test_flash_kernel_runs_per_shard_under_a_mesh(v5e, monkeypatch):
     """XLA refuses to partition a Mosaic kernel; gpt._flash shard_maps it
     over batch (dp, fsdp) and heads (tp) — the fault the four-chip compile

@@ -48,9 +48,18 @@ def test_admin_flag_is_cluster_admin(master):
     assert me["enforced"] is True
 
 
+def _nobody_id(admin):
+    """The unassigned user's id; made here where an earlier test of this
+    module has not (xdist's load distribution may split the module)."""
+    for u in admin.list_users():
+        if u["username"] == "nobody":
+            return u["id"]
+    return admin.create_user("nobody", "pw")["id"]
+
+
 def test_unassigned_user_cannot_mutate(master):
     admin = master["session"]
-    admin.create_user("nobody", "pw")
+    _nobody_id(admin)
     nobody = login_as(master, "nobody", "pw")
     assert nobody.my_permissions()["rank"] == 0
     with pytest.raises(MasterError) as err:
@@ -168,8 +177,7 @@ def test_role_granted_cluster_admin_manages_users(master):
 def test_member_add_is_atomic(master):
     admin = master["session"]
     g = admin.create_group("atomic")
-    uid = next(u["id"] for u in admin.list_users()
-               if u["username"] == "nobody")
+    uid = _nobody_id(admin)
     with pytest.raises(MasterError) as err:
         admin.update_group_members(g["id"], add=[uid, 999999])
     assert err.value.status == 400
@@ -227,6 +235,10 @@ def test_workspace_delete_revokes_scoped_assignments(master):
 
 def test_rbac_state_survives_restart(master):
     admin = master["session"]
+    _nobody_id(admin)
+    if not admin.list_groups():  # run apart from the tests that make some
+        kept = admin.create_group("kept-over-restart")
+        admin.assign_role("Viewer", group_id=kept["id"])
     assignments_before = admin.list_role_assignments()
     groups_before = admin.list_groups()
     assert assignments_before and groups_before

@@ -1,0 +1,250 @@
+"""Names in the device program and the host's phases of a decode step
+(docs/observability.md "Scopes in the device program", "An engine
+iteration"): every scope of the vocabulary reaches the HLO of the train
+step and of the paged forward, scopes change no arithmetic, the engine's
+tracer records the span tree of an iteration, and an enabled tracer's spans
+appear in a profiler trace as ``TraceAnnotation``s on the trace's clock."""
+import contextlib
+import dataclasses
+import glob
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from determined_clone_tpu.models import gpt
+from determined_clone_tpu.serving import (
+    BucketSpec,
+    InferenceEngine,
+    KVCacheConfig,
+)
+from determined_clone_tpu.telemetry import Telemetry, spans
+from determined_clone_tpu.training.train_step import (
+    create_train_state,
+    make_train_step,
+)
+
+TINY = dataclasses.replace(gpt.GPTConfig.tiny(), attention_impl="flash",
+                           remat=True)
+TRAIN_SCOPES = ("embed", "attn", "mlp", "logits", "optimizer")
+PAGED_SCOPES = ("embed", "attn", "kv_cache", "mlp", "logits")
+
+
+def _train_step(cfg=TINY):
+    """(a fresh jitted step, state, batch) of the tiny model. A fresh jit
+    every call: a patched ``named_scope`` must be traced, not cached."""
+    tx = optax.chain(optax.clip_by_global_norm(1.0), optax.adamw(1e-3))
+    params = gpt.init(jax.random.PRNGKey(0), cfg)
+    state = create_train_state(params, tx, jax.random.PRNGKey(1))
+
+    def loss(p, batch, rng):
+        return gpt.loss_fn(p, cfg, batch["tokens"], batch["targets"])
+
+    tokens = jax.random.randint(jax.random.PRNGKey(2), (2, 65), 0,
+                                cfg.vocab_size)
+    batch = {"tokens": tokens[:, :-1], "targets": tokens[:, 1:]}
+    return make_train_step(loss, tx, donate=False), state, batch
+
+
+@pytest.fixture(scope="module")
+def train_hlo():
+    step, state, batch = _train_step()
+    return step.lower(state, batch).as_text(debug_info=True)
+
+
+@pytest.fixture(scope="module")
+def paged_hlo():
+    cfg = dataclasses.replace(gpt.GPTConfig.tiny(), attention_impl="mha")
+    params = gpt.init(jax.random.PRNGKey(0), cfg)
+    pool = jnp.zeros((cfg.n_layers, 8, 8, cfg.n_heads, cfg.head_dim),
+                     cfg.compute_dtype)
+    b, t = 2, 1
+    fwd = jax.jit(gpt.forward_paged, static_argnums=(1,))
+    return fwd.lower(
+        params, cfg, jnp.zeros((b, t), jnp.int32),
+        jnp.zeros((b, t), jnp.int32), jnp.ones((b, t), bool),
+        jnp.zeros((b,), jnp.int32), pool, pool,
+        jnp.zeros((b, 4), jnp.int32)).as_text(debug_info=True)
+
+
+def _has_scope(hlo: str, name: str) -> bool:
+    """``name`` as one component of some operation's scope path, bare or
+    wrapped by a transform (``jvp(attn)``, ``transpose(jvp(attn))``)."""
+    return any(f"{a}{name}{b}" in hlo
+               for a in ("/", "(", '"') for b in ("/", ")"))
+
+
+@pytest.mark.parametrize("scope", TRAIN_SCOPES)
+def test_train_step_hlo_carries_every_scope(train_hlo, scope):
+    assert _has_scope(train_hlo, scope), scope
+
+
+def test_train_step_hlo_names_the_flash_kernel(train_hlo):
+    # forward and remat's second forward both run the named kernel
+    assert _has_scope(train_hlo, "flash_fwd")
+    assert "rematted_computation/attn/flash_fwd" in train_hlo
+
+
+def test_backward_of_a_scope_carries_its_name(train_hlo):
+    assert "transpose(jvp(logits))" in train_hlo
+
+
+@pytest.mark.parametrize("scope", PAGED_SCOPES)
+def test_forward_paged_hlo_carries_every_scope(paged_hlo, scope):
+    assert _has_scope(paged_hlo, scope), scope
+
+
+def test_kv_cache_lies_inside_attn(paged_hlo):
+    assert "attn/kv_cache/" in paged_hlo
+
+
+def test_scopes_change_no_arithmetic(monkeypatch):
+    """Losses, gradients (through Adam's first moment) and updated
+    parameters of three steps are bit-equal with the scopes on and with
+    ``jax.named_scope`` patched to a no-op."""
+    def three_steps():
+        step, state, batch = _train_step()
+        losses = []
+        for _ in range(3):
+            state, metrics = step(state, batch)
+            losses.append(np.asarray(metrics["loss"]))
+        return losses, jax.tree.map(np.asarray,
+                                    (state.params, state.opt_state))
+
+    named_losses, named_state = three_steps()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    step, state, batch = _train_step()
+    assert not _has_scope(step.lower(state, batch).as_text(debug_info=True),
+                          "optimizer")
+    plain_losses, plain_state = three_steps()
+    for a, b in zip(named_losses, plain_losses):
+        assert a.tobytes() == b.tobytes()
+    for a, b in zip(jax.tree.leaves(named_state),
+                    jax.tree.leaves(plain_state)):
+        assert a.tobytes() == b.tobytes()
+
+
+# -- the host's phases ------------------------------------------------------
+
+SERVE_CFG = gpt.GPTConfig(vocab_size=97, n_layers=2, d_model=32, n_heads=4,
+                          d_ff=64, max_seq_len=48, remat=False,
+                          attention_impl="mha")
+PHASES = ("decode_prepare", "serving_decode_step", "decode_dispatch",
+          "decode_readback", "decode_commit")
+
+
+def _serve(telemetry):
+    params = gpt.init(jax.random.PRNGKey(0), SERVE_CFG)
+    engine = InferenceEngine(
+        params, SERVE_CFG, buckets=BucketSpec.build(4, 16),
+        cache=KVCacheConfig(num_blocks=16, block_size=8), telemetry=telemetry)
+    try:
+        handles = [engine.submit(p, max_new_tokens=4)
+                   for p in ([5, 17, 3], [9] * 7)]
+        return [h.result(timeout=120.0).tokens for h in handles]
+    finally:
+        engine.close()
+
+
+def _inside(inner, outer):
+    return (inner["tid"] == outer["tid"]
+            and outer["ts_us"] <= inner["ts_us"]
+            and inner["ts_us"] + inner["dur_us"]
+            <= outer["ts_us"] + outer["dur_us"] + 0.2)
+
+
+def test_engine_records_the_span_tree_of_every_decode_step():
+    tel = Telemetry(enabled=True)
+    tokens = _serve(tel)
+    events = [e for e in tel.tracer.events() if e.get("ph") != "i"]
+    by_name = {}
+    for e in events:
+        by_name.setdefault(e["name"], []).append(e)
+    steps = by_name["serving_decode_step"]
+    assert len(steps) >= 3  # 4 tokens a request, the first from prefill
+    for name in PHASES:
+        assert len(by_name[name]) == len(steps), name
+    iterations = by_name["engine_iteration"]
+    for i, step in enumerate(steps):
+        (iteration,) = [it for it in iterations if _inside(step, it)]
+        prepare, dispatch, readback, commit = (
+            by_name[n][i] for n in ("decode_prepare", "decode_dispatch",
+                                    "decode_readback", "decode_commit"))
+        # in that order inside the iteration; dispatch then read-back
+        # inside the step
+        for phase in (prepare, step, commit):
+            assert _inside(phase, iteration)
+        assert _inside(dispatch, step) and _inside(readback, step)
+        order = [prepare, dispatch, readback, commit]
+        assert [e["ts_us"] for e in order] == sorted(
+            e["ts_us"] for e in order)
+        assert prepare["ts_us"] + prepare["dur_us"] <= step["ts_us"] + 0.2
+        assert commit["ts_us"] >= step["ts_us"] + step["dur_us"] - 0.2
+        assert dispatch["depth"] == step["depth"] + 1 == iteration["depth"] + 2
+        for e in (prepare, step, dispatch, readback, commit):
+            assert set(e["args"]) == {"rows", "batch"}
+            assert 1 <= e["args"]["rows"] <= e["args"]["batch"]
+    # admission is spanned alone (the wait on the condition never is), and
+    # a prefill has its prepare phase
+    assert by_name["admit"] and by_name["prefill_prepare"]
+    assert all(not _inside(a, it) for a in by_name["admit"]
+               for it in iterations)
+    assert all(len(t) == 4 for t in tokens)
+
+
+def test_engine_without_tracer_records_nothing_and_serves_the_same():
+    traced = Telemetry(enabled=True)
+    off = Telemetry(enabled=False)
+    assert _serve(off) == _serve(traced)
+    assert off.tracer.events() == []
+    assert _serve(None) == _serve(off)
+
+
+# -- spans on the profiler's clock ------------------------------------------
+
+def test_enabled_span_is_a_trace_annotation_in_a_profiler_trace(tmp_path):
+    tracer = spans.Tracer(enabled=True)
+    quiet = spans.Tracer(enabled=False)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with tracer.span("scopes_probe_outer", step=1):
+            with tracer.span("scopes_probe_inner"):
+                jnp.ones((8, 8)).sum().block_until_ready()
+        with quiet.span("scopes_probe_quiet"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    found = {}
+    for plane in data.planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("scopes_probe"):
+                    found[e.name] = (e.start_ns, e.start_ns + e.duration_ns)
+    assert set(found) == {"scopes_probe_outer", "scopes_probe_inner"}
+    outer, inner = found["scopes_probe_outer"], found["scopes_probe_inner"]
+    assert outer[0] <= inner[0] and inner[1] <= outer[1]
+    # the tracer's own record and the annotation time the same span
+    (rec,) = [e for e in tracer.events() if e["name"] == "scopes_probe_outer"]
+    assert abs((outer[1] - outer[0]) / 1e3 - rec["dur_us"]) < 500.0
+
+
+def test_spans_pull_no_jax_into_a_process_without_it(monkeypatch):
+    monkeypatch.setitem(sys.modules, "jax", None)
+    assert spans._trace_annotation() is None
+    tracer = spans.Tracer(enabled=True)
+    with tracer.span("no_jax_here"):
+        pass
+    assert [e["name"] for e in tracer.events()] == ["no_jax_here"]

@@ -1,12 +1,10 @@
 """XLA-level telemetry tests (telemetry/xla.py): explicit compile capture,
-fingerprint stability, measured-vs-analytic MFU, and the median/MAD
-step-time anomaly detector — plus the Prometheus round-trip of every new
+fingerprint stability, and the median/MAD step-time anomaly
+detector — plus the Prometheus round-trip of every new
 metric family."""
-import logging
 
 import jax
 import jax.numpy as jnp
-import pytest
 
 from determined_clone_tpu.telemetry import (
     MetricsRegistry,
@@ -14,7 +12,6 @@ from determined_clone_tpu.telemetry import (
     parse_prometheus_text,
 )
 from determined_clone_tpu.telemetry.xla import (
-    MfuComparator,
     StepTimeAnomalyDetector,
     aot_compile,
     fingerprint_stablehlo,
@@ -155,38 +152,6 @@ class TestAnomalyDetector:
 
 
 # ---------------------------------------------------------------------------
-# Measured-vs-analytic MFU comparator
-# ---------------------------------------------------------------------------
-
-class TestMfuComparator:
-    def test_measured_gauges_and_value(self):
-        reg = MetricsRegistry()
-        cmp_ = MfuComparator(reg, peak_flops_total=1e9)
-        measured = cmp_.report(measured_flops_per_batch=1e6,
-                               batches_per_second=100.0,
-                               analytic_mfu=0.1)
-        assert measured == pytest.approx(0.1)
-        assert reg.gauge("measured_flops_per_sec").value == 1e8
-        assert reg.gauge("mfu_measured").value == pytest.approx(0.1)
-        # within 20% of analytic: no divergence counted
-        assert reg.counter("mfu_divergence_total").value == 0
-
-    def test_divergence_counts_and_warn_is_rate_limited(self, caplog):
-        reg = MetricsRegistry()
-        cmp_ = MfuComparator(reg, peak_flops_total=1e9,
-                             warn_period_s=3600.0)
-        with caplog.at_level(logging.WARNING,
-                             logger="determined_clone_tpu.telemetry.xla"):
-            for _ in range(5):  # 2x divergence, five chunks in a row
-                cmp_.report(measured_flops_per_batch=2e6,
-                            batches_per_second=100.0, analytic_mfu=0.1)
-        # every divergent chunk counts; the log line fires once per period
-        assert reg.counter("mfu_divergence_total").value == 5
-        warns = [r for r in caplog.records if "diverge" in r.message]
-        assert len(warns) == 1
-
-
-# ---------------------------------------------------------------------------
 # Prometheus round-trip: every new family survives dump -> parse
 # ---------------------------------------------------------------------------
 
@@ -199,8 +164,6 @@ def test_new_families_round_trip_through_prometheus_text():
     for _ in range(10):
         det.observe(0.01)
     det.observe(1.0)
-    MfuComparator(reg, peak_flops_total=1e9).report(
-        measured_flops_per_batch=1e6, batches_per_second=10.0)
     reg.counter("flight_records_dropped",
                 "flight-recorder records lost to write errors").inc(2)
 
@@ -210,8 +173,7 @@ def test_new_families_round_trip_through_prometheus_text():
         by_name.setdefault(name, []).append((labels, value))
     for family in ("xla_compiles_total", "xla_compile_seconds",
                    "xla_program_flops", "xla_program_bytes_accessed",
-                   "step_time_anomalies_total", "measured_flops_per_sec",
-                   "mfu_measured", "flight_records_dropped"):
+                   "step_time_anomalies_total", "flight_records_dropped"):
         assert family in by_name, f"{family} missing from exposition"
     assert by_name["step_time_anomalies_total"][0][1] == 1
     assert by_name["flight_records_dropped"][0][1] == 2
