@@ -1,8 +1,11 @@
 """Attention ops, including the sequence-parallel paths the reference lacks.
 
-Three implementations, one semantic:
+Four implementations, one semantic:
  - ``mha``: plain XLA attention (einsum + softmax). XLA fuses this well on
    TPU; correct reference implementation for tests.
+ - ``decode_attention_rows``: ``mha`` for one query position over a
+   context kept as rows of all heads side by side (the paged KV pool's
+   layout), as two matmuls that read the rows as they lie.
  - ``causal_blockwise_attention``: lax.scan over key/value blocks with a
    streaming (online-softmax) accumulator — the memory-efficient form that
    long sequences need; the basis for ring attention.
@@ -42,6 +45,42 @@ def mha(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = True,
         scores = jnp.where(mask, scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+def decode_attention_rows(q: jax.Array, k: jax.Array, v: jax.Array,
+                          mask: jax.Array) -> jax.Array:
+    """``mha`` for a single query position over row-major context.
+
+    q: [B, 1, H, D]; k, v: [B, S, R] — position s's K (V) for all heads
+    side by side in columns ``0..H*D``, any further columns ignored;
+    mask: [B, 1, 1, S]. Returns [B, 1, H, D], the same numbers as
+    ``mha(q, k[..., :H*D].reshape(B, S, H, D), ..., causal=False,
+    mask=mask)`` (bf16 products summed in fp32, fp32 softmax).
+
+    ``mha``'s per-head contraction over D = 64 makes XLA on TPU transpose
+    the whole context (S into the lanes) before it multiplies. Here q is
+    spread block-diagonally over [R, H], so the scores are one
+    [H, R] x [R, S] matmul per row that reads k as it lies, and the
+    output is [H, S] x [S, R] with each head's own columns picked out
+    afterwards. The zeros cost (H - 1) / H of the MXU's work, which at
+    one query position is far below the time to read the context.
+    """
+    B, _, H, D = q.shape
+    R = k.shape[-1]
+    # own[h, r]: column r of a row belongs to head h
+    own = jnp.arange(R)[None, :] // D == jnp.arange(H)[:, None]
+    q_rows = jnp.pad(q.reshape(B, 1, H * D), ((0, 0), (0, 0), (0, R - H * D)))
+    q_diag = jnp.where(own[None], q_rows, 0)                        # [B, H, R]
+    scores = jnp.einsum("bhr,bsr->bhs", q_diag, k,
+                        preferred_element_type=jnp.float32)
+    scores = scores.astype(q.dtype).astype(jnp.float32)  # as mha rounds them
+    scores = scores / jnp.sqrt(jnp.float32(D))
+    scores = jnp.where(mask[:, 0], scores, NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)         # [B, H, S]
+    out = jnp.einsum("bhs,bsr->bhr", probs, v,
+                     preferred_element_type=jnp.float32)
+    out = jnp.sum(jnp.where(own[None], out, 0.0), axis=1)           # [B, R]
+    return out[:, :H * D].astype(q.dtype).reshape(B, 1, H, D)
 
 
 def _online_softmax_block(carry, qkv_block, *, scale):

@@ -1,10 +1,10 @@
 """Paged KV cache: a preallocated block pool plus per-sequence block tables.
 
 vLLM's PagedAttention memory model, sized for the engine at startup and
-never reallocated: the pools are ``[L, num_blocks, block_size, H, hd]``
-device arrays (compute dtype — the exact values ``mha`` would see, which
-is what makes paged decode token-identical to the uncached forward), and
-each admitted sequence owns a list of block ids covering
+never reallocated: the pools are ``[L, num_blocks, block_size, R]`` device
+arrays, one row of all heads' values per position (compute dtype — the
+exact values ``mha`` would see, which is what makes paged decode
+token-identical to the uncached forward), and each admitted sequence owns a list of block ids covering
 ``ceil((prompt_len + max_new_tokens) / block_size)`` slots. The
 :class:`BlockAllocator` is plain host-side bookkeeping — per-block
 refcounts over a free list — because block assignment happens at
@@ -36,6 +36,9 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax.numpy as jnp
 
 
+_LANES = 128  # a TPU tile's minor dimension
+
+
 @dataclasses.dataclass(frozen=True)
 class KVCacheConfig:
     num_blocks: int
@@ -53,21 +56,38 @@ class KVCacheConfig:
 
     def pool_bytes(self, n_layers: int, n_heads: int, head_dim: int,
                    dtype_bytes: int = 2) -> int:
-        """K + V pool footprint, for docs/serving.md-style sizing."""
+        """K + V pool footprint, for docs/serving.md-style sizing: the
+        bytes :func:`init_kv_pools` allocates, row padding included."""
         return (2 * n_layers * self.num_blocks * self.block_size
-                * n_heads * head_dim * dtype_bytes)
+                * kv_row_width(n_heads, head_dim) * dtype_bytes)
+
+
+def kv_row_width(n_heads: int, head_dim: int) -> int:
+    """Width R of one pool row: a position's ``H * head_dim`` K (or V)
+    values, rounded up to a whole number of the TPU's 128-lane tiles
+    (1024 for 16 x 64; 1664 for 25 x 64, 4 % padding)."""
+    return -(-n_heads * head_dim // _LANES) * _LANES
 
 
 def init_kv_pools(cfg: Any, cache: KVCacheConfig) -> Tuple[jnp.ndarray,
                                                            jnp.ndarray]:
-    """Zero K/V pools [L, N, block, H, hd] in the model's compute dtype.
+    """Zero K/V pools [L, N, block, R] in the model's compute dtype,
+    ``R = kv_row_width(H, hd)``.
+
+    One row per position, all heads side by side, because that is the
+    layout the paged forward scatters into and gathers from: with a
+    128-multiple minor dimension the TPU keeps the array in HBM row-major
+    and a donated pool is updated in place. (A ``[..., H, 64]`` pool was
+    kept block-minor-most and copied to row-major and back, whole, by
+    every program that touched it.) Columns ``H*hd..R`` are padding:
+    never read by attention, always zero.
 
     Zeros (not garbage) so never-written slots contribute exactly
     0-probability * 0-value under the attention mask — see
     models/gpt.py:forward_paged's parity contract.
     """
     shape = (cfg.n_layers, cache.num_blocks, cache.block_size,
-             cfg.n_heads, cfg.head_dim)
+             kv_row_width(cfg.n_heads, cfg.head_dim))
     return (jnp.zeros(shape, cfg.compute_dtype),
             jnp.zeros(shape, cfg.compute_dtype))
 

@@ -7,6 +7,7 @@ jax.sharding.Mesh code paths are exercised exactly as they would be on a v5e-8.
 The steering itself (env + jax.config) lives in
 determined_clone_tpu.utils.host_steering, shared with __graft_entry__.
 """
+import dataclasses
 import os
 import sys
 import threading
@@ -76,3 +77,47 @@ def no_leaked_nondaemon_threads():
     assert not remaining, (
         f"test leaked threads: "
         f"{[(t.name, 'daemon' if t.daemon else 'non-daemon') for t in remaining]}")
+
+
+# -- serving: the two pool-row geometries ------------------------------------
+
+@pytest.fixture(params=["padded_rows", "aligned_rows"])
+def geometry(request):
+    """``(cfg, params)`` for the two cases of a KV pool row
+    (serving/kv_cache.py:kv_row_width): the requesting module's ``CFG``
+    and ``params`` (H * head_dim = 32, padded to a 128-wide row) and the
+    same model at d_model = 128, which fills the row exactly."""
+    cfg = request.module.CFG
+    if request.param == "padded_rows":
+        yield cfg, request.getfixturevalue("params")
+        return
+    import jax
+
+    from determined_clone_tpu.models import gpt
+    from determined_clone_tpu.serving.engine import make_paged_forward
+
+    # the jit cache belongs to forward_paged, not to an engine, and those
+    # modules' program-budget assertions count it: one geometry at a time
+    shared = make_paged_forward(exec_cache=False)
+    shared.clear_cache()
+    aligned = dataclasses.replace(cfg, d_model=128)
+    yield aligned, gpt.init(jax.random.PRNGKey(0), aligned)
+    shared.clear_cache()
+
+
+@pytest.fixture
+def assert_pool_rows():
+    """Check an idle engine's pools: [L, N, block, R] with R a multiple of
+    128, something written, the padding columns past H * head_dim still
+    zero, and ``pool_bytes`` counting exactly what is allocated."""
+    def check(eng, cfg):
+        D = cfg.n_heads * cfg.head_dim
+        for pool in (eng._k_pool, eng._v_pool):
+            assert pool.shape == (cfg.n_layers, eng.cache.num_blocks,
+                                  eng.cache.block_size, -(-D // 128) * 128)
+            assert bool((pool[..., :D] != 0).any())
+            assert not bool((pool[..., D:] != 0).any())
+        assert eng.cache.pool_bytes(
+            cfg.n_layers, cfg.n_heads, cfg.head_dim,
+            eng._k_pool.dtype.itemsize) == 2 * eng._k_pool.nbytes
+    return check

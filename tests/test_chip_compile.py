@@ -13,8 +13,10 @@ and would take its CPU branch, so the tests steer it themselves
 (``attention_impl="flash"``, ``interpret=False`` / monkeypatch).
 """
 import functools
+import math
 import os
 import random
+import re
 import subprocess
 import sys
 
@@ -122,29 +124,104 @@ def test_flash_kernel_runs_per_shard_under_a_mesh(v5e, monkeypatch):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _compile_paged_forward(cfg, device, *, blocks, block, batch, t):
+    """The engine's jitted forward for a described chip, pools as
+    ``init_kv_pools`` shapes them. Returns (compiled, one pool's shape)."""
+    from determined_clone_tpu.serving.engine import make_paged_forward
+    from determined_clone_tpu.serving.kv_cache import (
+        KVCacheConfig,
+        init_kv_pools,
+    )
+
+    one = SingleDeviceSharding(device)
+    params = jax.eval_shape(lambda k: gpt.init(k, cfg), jax.random.PRNGKey(0))
+    k_pool, v_pool = _shapes(jax.eval_shape(
+        lambda: init_kv_pools(cfg, KVCacheConfig(blocks, block))), one)
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    compiled = make_paged_forward(exec_cache=False).lower(
+        _shapes(params, one), cfg, arr((batch, t), jnp.int32),
+        arr((batch, t), jnp.int32), arr((batch, t), jnp.bool_),
+        arr((batch,), jnp.int32), k_pool, v_pool,
+        arr((batch, cfg.max_seq_len // block), jnp.int32)).compile()
+    return compiled, k_pool.shape
+
+
 @pytest.mark.parametrize("batch,t", [(8, 1), (8, 128)],
                          ids=["decode", "prefill"])
 def test_paged_forward_compiles_at_gpt2_small(v5e, batch, t):
     """The engine's one jitted entry point, at the shapes its default
     ServingConfig warms up (pool of 512 blocks x 16 positions)."""
-    from determined_clone_tpu.serving.engine import make_paged_forward
-
-    one = SingleDeviceSharding(v5e[0])
-    cfg = GPT2_SMALL
-    params = jax.eval_shape(lambda k: gpt.init(k, cfg), jax.random.PRNGKey(0))
-
-    def arr(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
-
-    pool = arr((cfg.n_layers, 512, 16, cfg.n_heads, cfg.head_dim),
-               cfg.compute_dtype)
-    compiled = make_paged_forward(exec_cache=False).lower(
-        _shapes(params, one), cfg, arr((batch, t), jnp.int32),
-        arr((batch, t), jnp.int32), arr((batch, t), jnp.bool_),
-        arr((batch,), jnp.int32), pool, pool,
-        arr((batch, cfg.max_seq_len // 16), jnp.int32)).compile()
+    compiled, _ = _compile_paged_forward(GPT2_SMALL, v5e[0], blocks=512,
+                                         block=16, batch=batch, t=t)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_BYTES
+
+
+_COPY_OPCODES = ("copy", "copy-start", "copy-done", "dynamic-slice",
+                 "dynamic-update-slice", "slice", "transpose")
+_HLO_LINE = re.compile(
+    r"^\s*(?:ROOT )?%?(?P<name>[\w.\-]+) = (?P<result>\(?\w+\[[^=]*?) "
+    r"(?P<opcode>[\w\-]+)\(")
+
+
+def _pool_sized_copies(hlo_text, layer_elements):
+    """Instructions outside fused computations that copy or slice (by
+    opcode, or a fusion named after one) into a result with at least
+    ``layer_elements`` elements: one layer's share of a pool."""
+    found, fused = [], False
+    for line in hlo_text.splitlines():
+        if line.endswith("{"):  # a computation's header
+            fused = line.lstrip("%").startswith("fused_computation")
+            continue
+        m = None if fused else _HLO_LINE.match(line)
+        if not m:
+            continue
+        opcode, name = m["opcode"], m["name"]
+        if not (opcode in _COPY_OPCODES or (opcode == "fusion" and any(
+                w in name for w in ("copy", "slice", "transpose")))):
+            continue
+        largest = max(
+            (math.prod(map(int, dims.split(",")))
+             for dims in re.findall(r"\w+\[([\d,]+)\]", m["result"])),
+            default=0)
+        if largest >= layer_elements:
+            found.append(f"{name} = {m['result']} {opcode}")
+    return found
+
+
+@pytest.mark.parametrize("t", [1, 128], ids=["decode", "prefill"])
+@pytest.mark.parametrize("cell", ["gpt2-medium.serve-closed",
+                                  "gpt2-xl.serve-closed"])
+def test_paged_forward_copies_no_pool(v5e, cell, t):
+    """At the two serve cells' geometries (16 heads x 64: a 1024-wide pool
+    row; 25 x 64 = 1600, padded to 1664) the compiled program updates the
+    donated pools in place: nothing as large as one layer's share of a
+    pool is copied, sliced out or stacked back, and the program's
+    temporaries stay under one pool plus the bf16 copies of the fp32
+    weights (hoisted out of the layer scan: ROADMAP A10).
+
+    At the cells' real depths, which compile in seconds: two layers of the
+    xl pool are small enough for the compiler to stage them whole in fast
+    memory, which is another program than the one the chip runs."""
+    widths = {"gpt2-medium.serve-closed": (24, 1024, 16, 2048, 32),
+              "gpt2-xl.serve-closed": (48, 1600, 25, 512, 8)}
+    n_layers, d_model, n_heads, blocks, batch = widths[cell]
+    # a small vocabulary: the real table is larger than a layer of xl's pool
+    cfg = gpt.GPTConfig(vocab_size=2048, n_layers=n_layers,
+                        d_model=d_model, n_heads=n_heads, d_ff=4 * d_model,
+                        max_seq_len=1024)
+    compiled, pool_shape = _compile_paged_forward(
+        cfg, v5e[0], blocks=blocks, block=16, batch=batch, t=t)
+    assert pool_shape[-1] % 128 == 0 and pool_shape[-1] >= d_model
+    pool_elements = math.prod(pool_shape)
+    assert _pool_sized_copies(compiled.as_text(),
+                              pool_elements // n_layers) == []
+    block_weights = 12 * d_model * d_model * n_layers  # qkv, out, up, down
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < 2 * pool_elements + 2 * block_weights)
 
 
 @pytest.mark.slow

@@ -70,12 +70,12 @@ def payload(seed: int, nbytes: int = 1024) -> dict:
             "v": rng.standard_normal(half // 8).astype(np.float64)}
 
 
-def make_fleet(params, **kw):
+def make_fleet(params, cfg=CFG, **kw):
     kw.setdefault("buckets", BUCKETS)
     kw.setdefault("cache", CACHE)
     kw.setdefault("warmup", False)
     kw.setdefault("prefix_cache", True)
-    return ServingFleet(params, CFG, **kw)
+    return ServingFleet(params, cfg, **kw)
 
 
 # -- chain keys + inventory (pure units) ------------------------------------
@@ -332,15 +332,20 @@ def test_fleet_kv_store_requires_prefix_cache(params):
                      prefix_cache=False, kv_store=True)
 
 
-def test_warm_handoff_promotes_bit_identical(params, tmp_path):
+def test_warm_handoff_promotes_bit_identical(geometry, assert_pool_rows,
+                                             tmp_path):
     """The acceptance path in miniature: fleet A serves a prompt and
     flushes to a CAS-backed tier; a brand-new fleet B sharing the tier
     serves the same prompt by PROMOTING the shared blocks (zero misses
-    on the shared prefix) and emits bit-identical greedy tokens."""
+    on the shared prefix) and emits bit-identical greedy tokens. A tier
+    payload is a block's pool rows, [L, block, R], padding included, and
+    the padding is still zero after the round trip."""
+    cfg, params = geometry
+    D = cfg.n_heads * cfg.head_dim
     blobs = KVBlobStore(SharedFSStorageManager(str(tmp_path)))
     store = KVBlockStore(budget_bytes=32 << 20, blob_store=blobs)
 
-    fleet_a = make_fleet(params, name="kv-a", kv_store=store)
+    fleet_a = make_fleet(params, cfg, name="kv-a", kv_store=store)
     try:
         fleet_a.scale_up(1)
         ref, _ = fleet_a.handle_request(PROMPT, MAX_NEW, timeout=60.0)
@@ -349,15 +354,23 @@ def test_warm_handoff_promotes_bit_identical(params, tmp_path):
         fleet_a.close()  # close() flushes resident blocks to the tier
     assert store.stats()["puts"] >= 2  # both full prompt blocks landed
 
-    fleet_b = make_fleet(params, name="kv-b", kv_store=store)
+    fleet_b = make_fleet(params, cfg, name="kv-b", kv_store=store)
     try:
         fleet_b.scale_up(1)
         res, _ = fleet_b.handle_request(PROMPT, MAX_NEW, timeout=60.0)
         assert list(res.tokens) == ref_tokens
-        st = fleet_b.replicas()[0].engine.stats()
+        engine = fleet_b.replicas()[0].engine
+        st = engine.stats()
         assert st.kv_promoted_blocks >= 2
         assert st.kv_miss_blocks == 0
         assert st.kv_host_hit_blocks + st.kv_cas_hit_blocks >= 2
+        assert_pool_rows(engine, cfg)
+        key = prompt_chain_keys(PROMPT, CACHE.block_size, 1)[0]
+        spilled = store.get(engine._params_fp, key)
+        for rows in (spilled["k"], spilled["v"]):
+            assert rows.shape == (cfg.n_layers, CACHE.block_size, 128)
+            assert np.any(rows[..., :D] != 0)
+            assert not np.any(rows[..., D:] != 0)
         rollup_src = fleet_b.stats()
     finally:
         fleet_b.close()

@@ -21,6 +21,7 @@ from determined_clone_tpu.serving import (
     BucketSpec,
     InferenceEngine,
     KVCacheConfig,
+    init_kv_pools,
 )
 from determined_clone_tpu.telemetry import Telemetry, spans
 from determined_clone_tpu.training.train_step import (
@@ -60,8 +61,7 @@ def train_hlo():
 def paged_hlo():
     cfg = dataclasses.replace(gpt.GPTConfig.tiny(), attention_impl="mha")
     params = gpt.init(jax.random.PRNGKey(0), cfg)
-    pool = jnp.zeros((cfg.n_layers, 8, 8, cfg.n_heads, cfg.head_dim),
-                     cfg.compute_dtype)
+    pool, _ = init_kv_pools(cfg, KVCacheConfig(num_blocks=8, block_size=8))
     b, t = 2, 1
     fwd = jax.jit(gpt.forward_paged, static_argnums=(1,))
     return fwd.lower(

@@ -51,11 +51,11 @@ def params():
     return gpt.init(jax.random.PRNGKey(0), CFG)
 
 
-def naive_greedy(params, prompt, max_new):
+def naive_greedy(params, prompt, max_new, cfg=CFG):
     """Reference decode: full-context uncached forward every step."""
     toks = list(prompt)
     for _ in range(max_new):
-        logits = gpt.apply(params, CFG, jnp.asarray([toks], jnp.int32))
+        logits = gpt.apply(params, cfg, jnp.asarray([toks], jnp.int32))
         toks.append(int(jnp.argmax(logits[0, -1])))
     return toks[len(prompt):]
 
@@ -110,14 +110,18 @@ def test_block_allocator():
 
 # -- the tier-1 contract: parity + compile discipline ------------------------
 
-def test_paged_decode_token_identical_and_compile_budget(params):
+def test_paged_decode_token_identical_and_compile_budget(geometry,
+                                                         assert_pool_rows):
     """Mixed-length requests through the continuous scheduler produce
     EXACTLY the tokens of the naive uncached forward (greedy), and the
     shared jitted forward never compiles more programs than the bucket
-    budget — the two acceptance properties of the serving tentpole."""
-    expected = {i: naive_greedy(params, p, 12)
+    budget — the two acceptance properties of the serving tentpole. Both
+    hold whether or not a pool row is padded, and prefill and decode
+    leave the padding zero."""
+    cfg, params = geometry
+    expected = {i: naive_greedy(params, p, 12, cfg)
                 for i, p in enumerate(PROMPTS)}
-    with make_engine(params) as eng:
+    with InferenceEngine(params, cfg, buckets=BUCKETS, cache=CACHE) as eng:
         handles = [eng.submit(p, 12, request_id=str(i))
                    for i, p in enumerate(PROMPTS)]
         results = {int(h.result(timeout=120.0).request_id):
@@ -129,6 +133,7 @@ def test_paged_decode_token_identical_and_compile_budget(params):
         compiled = eng.programs_compiled()
         budget = eng.buckets.program_budget
         stats = eng.stats()
+        assert_pool_rows(eng, cfg)
     for i in range(len(PROMPTS)):
         assert results[i].tokens == expected[i], f"request {i} diverged"
         assert results[i].finish_reason == "length"

@@ -73,10 +73,10 @@ def naive_greedy(params, prompt, max_new, cfg=CFG):
     return toks[len(prompt):]
 
 
-def make_engine(params, **kw):
+def make_engine(params, cfg=CFG, **kw):
     kw.setdefault("buckets", BUCKETS)
     kw.setdefault("cache", CACHE)
-    return InferenceEngine(params, CFG, **kw)
+    return InferenceEngine(params, cfg, **kw)
 
 
 def assert_pool_accounted(eng):
@@ -163,15 +163,17 @@ def test_prefix_cache_match_register_evict():
 
 # -- COW prefix sharing through the engine ------------------------------------
 
-def test_prefix_sharing_parity_counters_and_cow(params):
+def test_prefix_sharing_parity_counters_and_cow(geometry, assert_pool_rows):
     """Repeat and prefix-sharing prompts alias cached blocks (hit/miss
     counters prove it) and still decode bit-identically — the COW fork
-    of the written block is what keeps the aliased copy immutable."""
+    of the written block is what keeps the aliased copy immutable. A fork
+    copies whole pool rows, so a row's padding stays zero through it."""
+    cfg, params = geometry
     base = list(range(1, 12))            # 1 full block + 3-token tail
     fork = base[:8] + [61, 62, 63]       # shares the full block only
-    expected = {tuple(p): naive_greedy(params, p, 8)
+    expected = {tuple(p): naive_greedy(params, p, 8, cfg)
                 for p in (base, fork)}
-    with make_engine(params, prefix_cache=True) as eng:
+    with make_engine(params, cfg, prefix_cache=True) as eng:
         r1 = eng.generate(base, 8)       # cold: everything misses
         assert r1.tokens == expected[tuple(base)]
         assert (r1.prefix_hit_blocks, r1.prefix_miss_blocks) == (0, 2)
@@ -189,6 +191,7 @@ def test_prefix_sharing_parity_counters_and_cow(params):
         assert stats.prefix_miss_blocks == 3
         assert stats.prefix_cached_entries > 0
         assert_pool_accounted(eng)
+        assert_pool_rows(eng, cfg)
         dump = eng.registry.dump()   # Prometheus text exposition
     assert "prefix_cache_hit_blocks_total 3" in dump
     assert "prefix_cache_miss_blocks_total 3" in dump
