@@ -1,4 +1,12 @@
 """Built-in model families (≈ the reference's examples/ + model_hub coverage)."""
-from determined_clone_tpu.models import bert, gpt, mlp, mnist_cnn, resnet, vit
+from determined_clone_tpu.models import (
+    bert,
+    evabyte,
+    gpt,
+    mlp,
+    mnist_cnn,
+    resnet,
+    vit,
+)
 
-__all__ = ["bert", "gpt", "mlp", "mnist_cnn", "resnet", "vit"]
+__all__ = ["bert", "evabyte", "gpt", "mlp", "mnist_cnn", "resnet", "vit"]

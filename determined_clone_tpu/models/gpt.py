@@ -39,6 +39,7 @@ from determined_clone_tpu.ops.layers import (
     softmax_cross_entropy,
     trunc_normal,
 )
+from determined_clone_tpu.models.paged import PagedModel
 from determined_clone_tpu.ops.moe import moe_ffn
 from determined_clone_tpu.parallel.sharding import ShardingRules
 
@@ -85,6 +86,10 @@ class GPTConfig:
     def tiny() -> "GPTConfig":
         return GPTConfig(vocab_size=256, n_layers=2, d_model=64, n_heads=4,
                          d_ff=128, max_seq_len=128, remat=False)
+
+    def paged_model(self) -> PagedModel:
+        """The family on the serving path (``models/paged.py``)."""
+        return PAGED
 
 
 def resolved_attention_impl(cfg: GPTConfig) -> str:
@@ -601,6 +606,19 @@ def forward_paged_logits(params: Params, cfg: GPTConfig, tokens: jax.Array,
             logits = dense(params["lm_head"], x, compute_dtype=jnp.float32)
         logits = logits.astype(jnp.float32)
     return logits, k_pool, v_pool
+
+
+def _cache_layout(cfg: GPTConfig, cache: Any) -> Any:
+    # imported here: serving/ imports this module at import time
+    from determined_clone_tpu.serving.kv_cache import CacheLayout
+
+    return CacheLayout(cache, cfg.max_seq_len)
+
+
+PAGED = PagedModel(family="gpt", forward_paged=forward_paged,
+                   forward_paged_logits=forward_paged_logits,
+                   init=init,
+                   cache_layout=_cache_layout)
 
 
 def param_count(params: Params) -> int:

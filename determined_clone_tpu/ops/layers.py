@@ -84,11 +84,16 @@ def layernorm(params: Params, x: jax.Array, eps: float = 1e-5) -> jax.Array:
 def rmsnorm_init(dim: int, dtype=jnp.float32) -> Params:
     return {"scale": jnp.ones((dim,), dtype)}
 
-def rmsnorm(params: Params, x: jax.Array, eps: float = 1e-6) -> jax.Array:
+def rmsnorm(params: Params, x: jax.Array, eps: float = 1e-6, *,
+            unit_offset: bool = False, dtype=None) -> jax.Array:
+    """``unit_offset``: the stored scale is w and the norm multiplies by
+    1 + w (a zero-initialised w is the identity). ``dtype``: of the
+    result, x's by default; statistics are fp32 either way."""
     xf = x.astype(jnp.float32)
     ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-    y = xf * jax.lax.rsqrt(ms + eps) * params["scale"].astype(jnp.float32)
-    return y.astype(x.dtype)
+    scale = params["scale"].astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(ms + eps) * (1.0 + scale if unit_offset else scale)
+    return y.astype(dtype or x.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -11,6 +11,12 @@ makes continuous batching beat run-to-completion batching on tokens/sec
 under load (bench.py's ``serving`` section measures exactly that, with
 :meth:`InferenceEngine.run_static` as the same-program baseline).
 
+The model is whatever family the given model config belongs to: the
+engine imports none and asks the config for its
+:class:`~determined_clone_tpu.models.paged.PagedModel` (the paged forward,
+the layout of a sequence's cache in pool blocks, the features that
+family's cache cannot serve yet, which are refused at construction).
+
 Compile discipline: all device work funnels through ONE jitted
 ``forward_paged`` whose shapes are padded to :class:`BucketSpec` buckets,
 so the XLA program count is bounded by ``buckets.program_budget`` for
@@ -51,7 +57,6 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-import math
 import os
 import threading
 import time
@@ -62,7 +67,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from determined_clone_tpu import faults
-from determined_clone_tpu.models import gpt
 from determined_clone_tpu.serving.bucketing import BucketSpec, bucket_for
 from determined_clone_tpu.serving.kv_cache import (
     BlockAllocator,
@@ -152,8 +156,32 @@ def _sum_cache_summaries(dispatchers: Sequence[Any]) -> Optional[
     return totals
 
 
+def forward_paged(params: Any, cfg: Any, tokens: jax.Array,
+                  positions: jax.Array, token_mask: jax.Array,
+                  last_index: jax.Array, k_pool: jax.Array,
+                  v_pool: jax.Array, block_tables: jax.Array) -> Any:
+    """The paged forward of ``cfg``'s family: a prefill slice or a decode
+    step (``models/gpt.py:forward_paged`` states the contract). Argument
+    for argument the family's own, so the compiled program is too."""
+    return cfg.paged_model().forward_paged(
+        params, cfg, tokens, positions, token_mask, last_index, k_pool,
+        v_pool, block_tables)
+
+
+def forward_paged_logits(params: Any, cfg: Any, tokens: jax.Array,
+                         positions: jax.Array, token_mask: jax.Array,
+                         k_pool: jax.Array, v_pool: jax.Array,
+                         block_tables: jax.Array) -> Any:
+    """The family's paged forward with logits at every position."""
+    return cfg.paged_model().forward_paged_logits(
+        params, cfg, tokens, positions, token_mask, k_pool, v_pool,
+        block_tables)
+
+
 def make_paged_forward(exec_cache: Any = None) -> Any:
-    """The jitted paged forward an engine runs everything through.
+    """The jitted paged forward an engine runs everything through, for
+    whichever family the (static) model config it is called with belongs
+    to.
     Replica fleets pass ONE of these to every engine (``fwd=``) so the
     whole fleet shares a single XLA program cache: replica N>1 warms up
     for free, and scale-up never pays a compile (all replicas serve the
@@ -165,7 +193,7 @@ def make_paged_forward(exec_cache: Any = None) -> Any:
     loads previously-compiled programs from the CAS ``cas/exec/``
     namespace instead of compiling, so even the FIRST process of a
     restart leg starts warm. ``exec_cache=False`` opts out."""
-    fwd = jax.jit(gpt.forward_paged, static_argnums=(1,),
+    fwd = jax.jit(forward_paged, static_argnums=(1,),
                   donate_argnums=(6, 7))
     return _maybe_dispatch(fwd, exec_cache, "serving_forward_paged")
 
@@ -174,7 +202,7 @@ def make_paged_verify(exec_cache: Any = None) -> Any:
     """The jitted multi-logit forward the speculative verify step runs
     through: one [B, k+1] call scores the last committed token plus all
     k drafts; compiles one program per batch bucket."""
-    fwd = jax.jit(gpt.forward_paged_logits, static_argnums=(1,),
+    fwd = jax.jit(forward_paged_logits, static_argnums=(1,),
                   donate_argnums=(5, 6))
     return _maybe_dispatch(fwd, exec_cache, "serving_verify")
 
@@ -328,14 +356,16 @@ class _Handle:
 class _Active:
     """Scheduler-private state of one running sequence."""
 
-    __slots__ = ("handle", "blocks", "prompt_len", "out", "last_token",
-                 "prefill_pos", "pending_copy", "hit_blocks", "miss_blocks",
-                 "spec_proposed", "spec_accepted")
+    __slots__ = ("handle", "blocks", "by_kind", "prompt_len", "out",
+                 "last_token", "prefill_pos", "pending_copy", "hit_blocks",
+                 "miss_blocks", "spec_proposed", "spec_accepted")
 
     def __init__(self, handle: _Handle, blocks: List[int],
                  prompt_len: int) -> None:
         self.handle = handle
         self.blocks = blocks
+        # blocks per kind of the cache layout (gauged while it runs)
+        self.by_kind: Tuple[int, ...] = ()
         self.prompt_len = prompt_len
         self.out: List[int] = []
         self.last_token = -1
@@ -352,7 +382,7 @@ class _Active:
 
 
 class InferenceEngine:
-    """Continuous-batching GPT server over a paged KV cache.
+    """Continuous-batching decoder server over a paged KV cache.
 
     One scheduler thread (named ``serving-engine`` — the conftest
     thread-leak fixture knows it) owns all device work; request threads
@@ -360,7 +390,7 @@ class InferenceEngine:
     call :meth:`close` — the thread must be joined.
     """
 
-    def __init__(self, params: gpt.Params, model_cfg: gpt.GPTConfig, *,
+    def __init__(self, params: Any, model_cfg: Any, *,
                  buckets: Optional[BucketSpec] = None,
                  cache: Optional[KVCacheConfig] = None,
                  max_queue_depth: int = 64,
@@ -370,11 +400,21 @@ class InferenceEngine:
                  prefix_cache: bool = False,
                  chunk_prefill_len: int = 0,
                  speculative_k: int = 0,
-                 draft_params: Optional[gpt.Params] = None,
-                 draft_cfg: Optional[gpt.GPTConfig] = None,
+                 draft_params: Any = None,
+                 draft_cfg: Any = None,
                  kv_store: Any = None,
                  fault_scope: str = "") -> None:
         self.model_cfg = model_cfg
+        # the family: its forward, its cache's layout, what it cannot serve
+        self._model = model_cfg.paged_model()
+        asked = {"prefix_cache": prefix_cache,
+                 "kv_store": kv_store is not None,
+                 "speculative": bool(speculative_k)}
+        refused = [f for f in self._model.unsupported if asked[f]]
+        if refused:
+            raise ValueError(
+                f"the {self._model.family} family's cache cannot serve "
+                f"{', '.join(refused)} yet (models/paged.py)")
         # chaos targeting: with a scope (the fleet passes the replica
         # id) the scheduler also hits "engine.step.<scope>" /
         # "engine.admit.<request_id>" so a seeded FaultPlan can kill ONE
@@ -389,28 +429,28 @@ class InferenceEngine:
                 f"prefill bucket {self.buckets.max_prefill_len} exceeds "
                 f"model max_seq_len {model_cfg.max_seq_len}")
         if cache is None:
-            block = 16
             cache = KVCacheConfig(
                 num_blocks=self.buckets.max_batch
-                * max(1, math.ceil(model_cfg.max_seq_len / block)),
-                block_size=block)
+                * self.blocks_per_sequence(model_cfg, 16), block_size=16)
         self.cache = cache
+        self._layout = self._model.cache_layout(model_cfg, cache)
         self.max_queue_depth = int(max_queue_depth)
 
         self._params = params
-        self._pending_params: Optional[gpt.Params] = None
+        self._pending_params: Any = None
         self._allocator = BlockAllocator(cache)
         self._k_pool, self._v_pool = init_kv_pools(model_cfg, cache)
         # fixed block-table width: every call sees the same W, so table
         # shape never causes a retrace
-        self._table_width = max(
-            1, math.ceil(model_cfg.max_seq_len / cache.block_size))
+        self._table_width = self._layout.table_width
         self._fwd = fwd if fwd is not None else make_paged_forward()
 
         # -- optional raw-speed features (module docstring) --------------
         self.chunk_prefill_len = int(chunk_prefill_len)
         if self.chunk_prefill_len:
             self.buckets.validate_chunk_len(self.chunk_prefill_len)
+        self._layout.check_prefill(self.buckets.max_prefill_len,
+                                   self.chunk_prefill_len)
         self._spec_k = int(speculative_k)
         if self._spec_k < 0:
             raise ValueError(f"speculative_k must be >= 0, got {speculative_k}")
@@ -520,6 +560,18 @@ class InferenceEngine:
         self._g_free_blocks = m.gauge(
             "serving_free_kv_blocks", "unallocated KV pool blocks")
         self._g_free_blocks.set(self._allocator.free_blocks())
+        # a cache of several kinds: blocks in use and rows attended, by kind
+        kinds = self._layout.kinds if len(self._layout.kinds) > 1 else ()
+        self._g_kind_blocks = [
+            m.gauge("serving_kv_blocks_in_use",
+                    "KV pool blocks held by running sequences, by kind",
+                    labels={"kind": kind}) for kind in kinds]
+        self._kind_blocks = [0] * len(kinds)
+        self._row_args = tuple(f"{kind}_rows" for kind in kinds)
+        self._c_rows = [
+            m.counter(name, f"{kind} cache rows attended by decode steps "
+                            f"(at the rows' real lengths)")
+            for name, kind in zip(self._model.row_counters, kinds)]
         self._c_prefix_hit = m.counter(
             "prefix_cache_hit_blocks_total",
             "prompt blocks aliased from the prefix cache (prefill skipped)")
@@ -583,24 +635,30 @@ class InferenceEngine:
                                         name="serving-engine", daemon=True)
         self._thread.start()
 
+    @staticmethod
+    def blocks_per_sequence(model_cfg: Any, block_size: int) -> int:
+        """Pool blocks a sequence of the model's full length holds."""
+        return model_cfg.paged_model().cache_layout(
+            model_cfg, KVCacheConfig(1, block_size)
+        ).blocks_needed(model_cfg.max_seq_len)
+
     @classmethod
-    def from_serving_config(cls, params: gpt.Params,
-                            model_cfg: gpt.GPTConfig, scfg: Any, *,
+    def from_serving_config(cls, params: Any, model_cfg: Any, scfg: Any, *,
                             telemetry: Any = None, fwd: Any = None,
                             iteration_floor_s: float = 0.0,
-                            draft_params: Optional[gpt.Params] = None
+                            draft_params: Any = None
                             ) -> "InferenceEngine":
         """Build an engine from a config/experiment.py ServingConfig
         (the `serving:` block of an experiment YAML). When the
-        ``speculative:`` block is enabled the draft GPT shares the
+        ``speculative:`` block is enabled the draft model shares the
         tokenizer/vocab and max_seq_len with the target; its weights
         come from ``draft_params`` or, absent one (no distilled draft
         checkpoint yet), a seeded random init — correct but slow, since
         the accept rule never trusts the draft."""
         buckets = BucketSpec.build(
             scfg.max_batch, min(scfg.max_prefill_len, model_cfg.max_seq_len))
-        blocks = scfg.kv_blocks or scfg.max_batch * max(
-            1, math.ceil(model_cfg.max_seq_len / scfg.kv_block_size))
+        blocks = scfg.kv_blocks or scfg.max_batch * cls.blocks_per_sequence(
+            model_cfg, scfg.kv_block_size)
         spec = getattr(scfg, "speculative", None)
         spec_k = 0
         draft_cfg = None
@@ -611,7 +669,8 @@ class InferenceEngine:
                 d_model=spec.draft_d_model, n_heads=spec.draft_n_heads,
                 d_ff=spec.draft_d_ff, remat=False)
             if draft_params is None:
-                draft_params = gpt.init(jax.random.PRNGKey(0), draft_cfg)
+                draft_params = model_cfg.paged_model().init(
+                    jax.random.PRNGKey(0), draft_cfg)
         return cls(params, model_cfg, buckets=buckets,
                    cache=KVCacheConfig(num_blocks=blocks,
                                        block_size=scfg.kv_block_size),
@@ -748,11 +807,17 @@ class InferenceEngine:
 
     # -- model hot-swap ----------------------------------------------------
 
-    def hot_swap(self, params: gpt.Params) -> None:
+    def hot_swap(self, params: Any) -> None:
         """Queue a new parameter pytree; the scheduler installs it at the
         next iteration boundary (never mid-step), so in-flight sequences
         finish under whichever params their next step sees — the standard
-        online-swap semantics."""
+        online-swap semantics. Another family's tree (or another depth's)
+        is refused: the engine's model config, pools and programs stay."""
+        if jax.tree.structure(params) != jax.tree.structure(self._params):
+            raise ValueError(
+                f"hot_swap across model families is not served: the "
+                f"{self._model.family} engine was given a tree of another "
+                f"structure")
         with self._cond:
             self._pending_params = params
             self._cond.notify_all()
@@ -999,7 +1064,7 @@ class InferenceEngine:
             if f is None:
                 continue
             # jax keys the jit cache on the underlying function: _fwd
-            # and _draft_fwd both wrap gpt.forward_paged, so they SHARE
+            # and _draft_fwd both wrap forward_paged, so they SHARE
             # one cache (that is what lets the draft ladder ride the
             # fleet-shared forward) — count each distinct cache once or
             # the draft programs get double-counted
@@ -1183,6 +1248,7 @@ class InferenceEngine:
                 self._allocator.release([a.pending_copy[0]])
                 a.pending_copy = None
             self._allocator.release(a.blocks)
+            self._gauge_kinds(a.by_kind, -1)
             pairs.append((a.handle, True))
         self._active.clear()
         self._prefilling.clear()
@@ -1226,7 +1292,8 @@ class InferenceEngine:
                 continue
             plen = len(head.req.prompt)
             total = plen + head.req.max_new_tokens
-            need_total = self.cache.blocks_needed(total)
+            by_kind = self._layout.blocks_by_kind(total)
+            need_total = sum(by_kind)
             shared: List[int] = []
             fork_src: Optional[int] = None
             if self._prefix is not None:
@@ -1276,6 +1343,8 @@ class InferenceEngine:
                 self._h_queue_wait.observe(now - head.submit_t)
             fresh = self._allocator.allocate_blocks(need)
             a = _Active(head, shared + fresh, plen)
+            a.by_kind = by_kind
+            self._gauge_kinds(by_kind, +1)
             a.prefill_pos = skip
             if fork_src is not None:
                 # fresh[0] backs the forked block's position range
@@ -1483,13 +1552,18 @@ class InferenceEngine:
                     "request_cow_fork", **self._req_args(
                         a.handle.req, src_block=src, dst_block=dst))
 
-    def _pools_for(self, cfg: gpt.GPTConfig) -> Tuple[jnp.ndarray,
-                                                      jnp.ndarray]:
+    def _gauge_kinds(self, by_kind: Sequence[int], sign: int) -> None:
+        """Blocks in use by kind; nothing for a cache of one kind."""
+        for i, g in enumerate(self._g_kind_blocks):
+            self._kind_blocks[i] += sign * by_kind[i]
+            g.set(self._kind_blocks[i])
+
+    def _pools_for(self, cfg: Any) -> Tuple[jnp.ndarray, jnp.ndarray]:
         if cfg is self.model_cfg:
             return self._k_pool, self._v_pool
         return self._dk_pool, self._dv_pool
 
-    def _set_pools_for(self, cfg: gpt.GPTConfig, k_pool: jnp.ndarray,
+    def _set_pools_for(self, cfg: Any, k_pool: jnp.ndarray,
                        v_pool: jnp.ndarray) -> None:
         if cfg is self.model_cfg:
             self._k_pool, self._v_pool = k_pool, v_pool
@@ -1500,7 +1574,7 @@ class InferenceEngine:
                     ) -> jnp.ndarray:
         tables = np.zeros((padded_b, self._table_width), np.int32)
         for i, a in enumerate(rows):
-            tables[i, :len(a.blocks)] = a.blocks
+            self._layout.lay_table(tables[i], a.blocks)
         return jnp.asarray(tables)
 
     def _prefill_step(self) -> None:
@@ -1591,6 +1665,13 @@ class InferenceEngine:
         # the host's phases of the step, each a span with the same args
         # (docs/observability.md "An engine iteration")
         size = {"batch": b, "rows": len(rows)}
+        attended = [0] * len(self._row_args)
+        if attended:  # a cache of several kinds: rows attended, by kind
+            for a in rows:
+                for i, n in enumerate(self._layout.attended_rows(
+                        a.prompt_len + len(a.out))):
+                    attended[i] += n
+            size.update(zip(self._row_args, attended))
         with self._span("decode_prepare", **size):
             tok = np.zeros((b, 1), np.int32)
             pos = np.zeros((b, 1), np.int32)
@@ -1611,6 +1692,8 @@ class InferenceEngine:
             with self._span("decode_readback", **size):
                 nxt = np.asarray(jnp.argmax(logits, axis=-1))
         self._h_decode.observe(time.monotonic() - t0)
+        for counter, n in zip(self._c_rows, attended):
+            counter.inc(n)
         with self._span("decode_commit", **size):
             survivors: List[_Active] = []
             for i, a in enumerate(rows):
@@ -1746,6 +1829,7 @@ class InferenceEngine:
     def _retire(self, a: _Active, reason: str) -> None:
         now = time.monotonic()
         self._allocator.release(a.blocks)
+        self._gauge_kinds(a.by_kind, -1)
         h = a.handle
         result = RequestResult(
             request_id=h.req.request_id,
@@ -1828,8 +1912,9 @@ class InferenceEngine:
                 h = _Handle(Request(prompt, max_new, None, f"static-{i}"))
                 h.submit_t = t0 + arr
                 h.admit_t = time.monotonic()
-                rows.append(_Active(h, self._allocator.allocate(
-                    len(prompt) + max_new), len(prompt)))
+                rows.append(_Active(h, self._allocator.allocate_blocks(
+                    self._layout.blocks_needed(len(prompt) + max_new)),
+                    len(prompt)))
             self._static_group(rows)
             for (arr, i, _, _), a in zip(group, rows):
                 end = time.monotonic()

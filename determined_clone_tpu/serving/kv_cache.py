@@ -34,6 +34,7 @@ import threading
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
+import numpy as np
 
 
 _LANES = 128  # a TPU tile's minor dimension
@@ -90,6 +91,122 @@ def init_kv_pools(cfg: Any, cache: KVCacheConfig) -> Tuple[jnp.ndarray,
              kv_row_width(cfg.n_heads, cfg.head_dim))
     return (jnp.zeros(shape, cfg.compute_dtype),
             jnp.zeros(shape, cfg.compute_dtype))
+
+
+class CacheLayout:
+    """How one sequence's cache lies in pool blocks: which kinds of block
+    it owns, how many of each a request reserves, and where each stands in
+    the row of block ids the paged forward is given. This one is the
+    uniform cache (one exact K/V row per position, block ``w`` of the
+    table backing positions ``[w * block, (w + 1) * block)``); a model
+    family whose cache has other kinds brings a subclass
+    (``models/paged.py``). All kinds are blocks of the one pool and come
+    from the one :class:`BlockAllocator`.
+    """
+
+    kinds: Tuple[str, ...] = ("kv",)
+
+    def __init__(self, cache: KVCacheConfig, max_seq_len: int) -> None:
+        self.cache = cache
+        self.max_seq_len = int(max_seq_len)
+
+    @property
+    def table_width(self) -> int:
+        """Entries in a row of the block table (fixed: no retrace)."""
+        return self.cache.blocks_needed(self.max_seq_len)
+
+    def blocks_by_kind(self, total_len: int) -> Tuple[int, ...]:
+        """Blocks of each kind a request of ``total_len`` positions
+        (prompt + new tokens) reserves at admission."""
+        return (self.cache.blocks_needed(total_len),)
+
+    def blocks_needed(self, total_len: int) -> int:
+        return sum(self.blocks_by_kind(total_len))
+
+    def lay_table(self, row: np.ndarray, blocks: Sequence[int]) -> None:
+        """Write a sequence's blocks (as ``blocks_needed`` reserved them,
+        kind after kind) into its row of the table."""
+        row[:len(blocks)] = blocks
+
+    def attended_rows(self, length: int) -> Tuple[int, ...]:
+        """Cache rows of each kind that the query at position
+        ``length - 1`` attends."""
+        return (length,)
+
+    def check_prefill(self, max_prefill_len: int,
+                      chunk_prefill_len: int) -> None:
+        """Raise ValueError for prefill slice lengths this cache cannot
+        take. The uniform cache takes any."""
+
+
+class WindowSummaryLayout(CacheLayout):
+    """A cache of two kinds (EVA attention, ``models/evabyte.py``): exact
+    K/V rows of the current ``window`` positions, and one summary row per
+    ``chunk`` positions of every finished window.
+
+    The row of the table is two tables side by side. Entries
+    ``[0, window / block)`` are a ring of window blocks: position ``p``
+    lives in entry ``(p % window) // block``, and a new window overwrites
+    the last one's rows in place. The entries after them are summary
+    blocks: chunk ``c`` lives in entry ``c // block``, row ``c % block``.
+    Entries a request did not reserve (the summaries of its last,
+    unfinished window; the rest of the ring of a request shorter than a
+    window) hold -1, which the forward never writes through. So a
+    sequence holds, and a step reads, ``window + T / chunk`` rows, not
+    ``T``. The chunk is the block (one gather of a block is a chunk).
+    """
+
+    kinds = ("window", "summary")
+
+    def __init__(self, cache: KVCacheConfig, max_seq_len: int, *,
+                 window: int, chunk: int) -> None:
+        super().__init__(cache, max_seq_len)
+        if chunk != cache.block_size:
+            raise ValueError(
+                f"the cache block ({cache.block_size}) must be the model's "
+                f"chunk ({chunk}): a summary is pooled from one block")
+        if window % chunk:
+            raise ValueError(f"window {window} is not whole chunks of {chunk}")
+        self.window, self.chunk = int(window), int(chunk)
+        self.window_blocks = window // chunk
+        # summaries of every window that can finish inside max_seq_len
+        self.summary_blocks = -(-(self.max_seq_len // window)
+                                * self.window_blocks // chunk)
+
+    @property
+    def table_width(self) -> int:
+        return self.window_blocks + self.summary_blocks
+
+    def blocks_by_kind(self, total_len: int) -> Tuple[int, int]:
+        finished = total_len // self.window
+        return (min(self.cache.blocks_needed(total_len), self.window_blocks),
+                -(-finished * self.window_blocks // self.chunk))
+
+    def lay_table(self, row: np.ndarray, blocks: Sequence[int]) -> None:
+        # summaries are reserved only once the ring is whole; -1 = none
+        n_window = min(len(blocks), self.window_blocks)
+        row[:] = -1
+        row[:n_window] = blocks[:n_window]
+        row[self.window_blocks:self.window_blocks + len(blocks) - n_window] \
+            = blocks[n_window:]
+
+    def attended_rows(self, length: int) -> Tuple[int, int]:
+        t = length - 1
+        return (t % self.window + 1,
+                t // self.window * self.window_blocks)
+
+    def check_prefill(self, max_prefill_len: int,
+                      chunk_prefill_len: int) -> None:
+        if max_prefill_len > self.window:
+            raise ValueError(
+                f"max_prefill_len {max_prefill_len} exceeds the attention "
+                f"window {self.window}: a prefill slice lies in one window")
+        if chunk_prefill_len and (self.window % chunk_prefill_len
+                                  or chunk_prefill_len % self.chunk):
+            raise ValueError(
+                f"chunk_prefill_len {chunk_prefill_len} must divide the "
+                f"attention window {self.window} and be whole chunks of "
+                f"{self.chunk}, so that no slice straddles a window")
 
 
 class BlockAllocator:

@@ -224,6 +224,55 @@ def test_paged_forward_copies_no_pool(v5e, cell, t):
             < 2 * pool_elements + 2 * block_weights)
 
 
+@pytest.mark.parametrize("batch,t", [(8, 1), (1, 2048), (8, 2048)],
+                         ids=["decode", "slice", "slices-of-8-rows"])
+def test_evabyte_paged_forward_compiles_at_the_cell_sizes(v5e, batch, t):
+    """``evabyte-6.5b.serve-doc-closed``: 16 layers at the published widths
+    (6.5 GB of bfloat16 weights), a pool of 8 x (128 window + 64 summary)
+    blocks (6.4 GB), the decode step and the 2048-token prefill slice. They
+    fit the 15.75 GB a v5e offers a program, the donated pools are updated
+    in place, and no program casts a weight: a matrix is read as it lies."""
+    from determined_clone_tpu.models import evabyte
+    from determined_clone_tpu.serving.engine import make_paged_forward
+    from determined_clone_tpu.serving.kv_cache import (
+        KVCacheConfig,
+        init_kv_pools,
+    )
+
+    cfg = evabyte.EvaByteConfig(n_layers=16, max_seq_len=16384)
+    cache = KVCacheConfig(8 * 192, 16)
+    layout = cfg.paged_model().cache_layout(cfg, cache)
+    assert layout.blocks_needed(cfg.max_seq_len) == layout.table_width == 192
+    one = SingleDeviceSharding(v5e[0])
+    params = _shapes(jax.eval_shape(lambda k: evabyte.init(k, cfg),
+                                    jax.random.PRNGKey(0)), one)
+    k_pool, v_pool = _shapes(jax.eval_shape(
+        lambda: init_kv_pools(cfg, cache)), one)
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    compiled = make_paged_forward(exec_cache=False).lower(
+        params, cfg, arr((batch, t), jnp.int32), arr((batch, t), jnp.int32),
+        arr((batch, t), jnp.bool_), arr((batch,), jnp.int32), k_pool, v_pool,
+        arr((batch, layout.table_width), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = 2 * math.prod(k_pool.shape)
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes  # both pools, in place
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 15.75 * 2 ** 30)
+    text = compiled.as_text()
+    # of the pool, that is: the 8-row prefill also lays one [16, D, D]
+    # weight out anew, once a call (a third of a millisecond)
+    L, N, bs, R = k_pool.shape
+    pool_dims = (f"{N},{bs},{R}]", f"[{L * N * bs},{R}]", f"[{N * bs},{R}]")
+    assert [c for c in _pool_sized_copies(text, N * bs * R)
+            if any(d in c for d in pool_dims)] == []
+    weight = re.compile(r"= \w+\[(?:16,)?(?:4096|11008),(?:4096|11008)\]"
+                        r"[^ ]* convert\(")
+    assert [ln for ln in text.splitlines() if weight.search(ln)] == []
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("n_chips", [1, 4])
 def test_gpt2_small_train_step_compiles(v5e, monkeypatch, n_chips):
