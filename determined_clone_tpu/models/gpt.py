@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import re
 from typing import Any, Dict, Optional
 
 import jax
@@ -39,7 +40,7 @@ from determined_clone_tpu.ops.layers import (
     softmax_cross_entropy,
     trunc_normal,
 )
-from determined_clone_tpu.models.paged import PagedModel
+from determined_clone_tpu.models.paged import PagedModel, cast_leaves
 from determined_clone_tpu.ops.moe import moe_ffn
 from determined_clone_tpu.parallel.sharding import ShardingRules
 
@@ -615,10 +616,29 @@ def _cache_layout(cfg: GPTConfig, cache: Any) -> Any:
     return CacheLayout(cache, cfg.max_seq_len)
 
 
+# what _block_paged hands to dense / moe_ffn with compute_dtype=: kernel
+# and bias alike (dense adds the bias in the product's type)
+_BLOCK_MATRICES = re.compile(
+    r"^blocks/(attn_qkv|attn_out|mlp_up|mlp_down|moe/(up|down))/")
+
+
+def serving_params(params: Params, cfg: GPTConfig) -> Params:
+    """The tree :func:`forward_paged` reads without converting a leaf: the
+    block matrices in ``cfg.compute_dtype``, rounded once, as ``dense``
+    would round them in every call (the same bits). The norms, the MoE
+    router, the table and the head stay as they are: the norms compute
+    in float32 and the logits multiply the table in float32."""
+    return cast_leaves(
+        params,
+        lambda path: cfg.compute_dtype if _BLOCK_MATRICES.match(path)
+        else None)
+
+
 PAGED = PagedModel(family="gpt", forward_paged=forward_paged,
                    forward_paged_logits=forward_paged_logits,
                    init=init,
-                   cache_layout=_cache_layout)
+                   cache_layout=_cache_layout,
+                   serving_params=serving_params)
 
 
 def param_count(params: Params) -> int:

@@ -10,10 +10,45 @@ the engine refuses at construction. ``models/gpt.py`` and
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Tuple
+import functools
+from typing import Any, Callable, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from determined_clone_tpu.parallel.sharding import tree_paths_and_leaves
 
 # engine features a family may name in ``unsupported``
 ENGINE_FEATURES = ("prefix_cache", "kv_store", "speculative")
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _cast(leaves: Tuple[Any, ...], dtypes: Tuple[Any, ...]) -> Tuple[Any, ...]:
+    return tuple(x.astype(d) for x, d in zip(leaves, dtypes))
+
+
+def cast_leaves(params: Any,
+                read_as: Callable[[str], Optional[Any]]) -> Any:
+    """``params`` with every leaf in the type ``read_as("a/b/c")`` names
+    for its path (None: as it is). A leaf that already has its type comes
+    back as the same object, so a tree in its serving form costs nothing
+    and calling this twice is calling it once; the others are cast in one
+    jitted call, on the device where they are device arrays."""
+    leaves, treedef = jax.tree_util.tree_flatten(params)
+    cast = {}
+    for i, (path, leaf) in enumerate(tree_paths_and_leaves(params)):
+        dtype = read_as(path)
+        if dtype is not None and leaf.dtype != jnp.dtype(dtype):
+            cast[i] = jnp.dtype(dtype)
+    if cast:
+        done = _cast(tuple(leaves[i] for i in cast), tuple(cast.values()))
+        for i, leaf in zip(cast, done):
+            leaves[i] = leaf
+    return jax.tree_util.tree_unflatten(treedef, leaves)
+
+
+def _as_given(params: Any, cfg: Any) -> Any:
+    return params
 
 
 @dataclasses.dataclass(frozen=True)
@@ -27,6 +62,10 @@ class PagedModel:
     forward_paged_logits(the same minus last_index) -> (logits at every
         position, k_pool, v_pool): the speculative verify step.
     init(key, cfg) -> params.
+    serving_params(params, cfg) -> params: the tree the engine serves
+        from, every leaf in the type the paged forward reads it in
+        (:func:`cast_leaves`), so that no serving program converts a
+        weight. Same structure, idempotent; the default keeps the tree.
     cache_layout(cfg, cache) -> serving/kv_cache.py:CacheLayout: block
         kinds, reservation, table rows. Pools are
         ``kv_cache.init_kv_pools(cfg, cache)`` for every family.
@@ -39,6 +78,7 @@ class PagedModel:
     forward_paged_logits: Callable[..., Any]
     init: Callable[..., Any]
     cache_layout: Callable[[Any, Any], Any]
+    serving_params: Callable[[Any, Any], Any] = _as_given
     unsupported: Tuple[str, ...] = ()
     row_counters: Tuple[str, ...] = ()
 

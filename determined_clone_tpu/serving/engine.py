@@ -156,6 +156,24 @@ def _sum_cache_summaries(dispatchers: Sequence[Any]) -> Optional[
     return totals
 
 
+def serving_form(params: Any, model_cfg: Any, span: Any = null_span) -> Any:
+    """``params`` as the family's paged forward reads them
+    (``PagedModel.serving_params``) and on the device: what an engine, and
+    a fleet for all its replicas, holds and serves from, so that no
+    serving program converts a weight and no call uploads one. Made once
+    when a tree is handed over, on the caller's thread; a device array
+    that already has its type comes back as the same buffer. The span
+    records the bytes that were cast and the bytes kept in their type."""
+    with span("serving_params_prepare") as sp:
+        served = jax.block_until_ready(jax.device_put(
+            model_cfg.paged_model().serving_params(params, model_cfg)))
+        sizes = [(was.nbytes, now.dtype == was.dtype) for was, now in zip(
+            jax.tree.leaves(params), jax.tree.leaves(served))]
+        sp.set(cast_bytes=sum(n for n, same in sizes if not same),
+               kept_bytes=sum(n for n, same in sizes if same))
+    return served
+
+
 def forward_paged(params: Any, cfg: Any, tokens: jax.Array,
                   positions: jax.Array, token_mask: jax.Array,
                   last_index: jax.Array, k_pool: jax.Array,
@@ -436,7 +454,22 @@ class InferenceEngine:
         self._layout = self._model.cache_layout(model_cfg, cache)
         self.max_queue_depth = int(max_queue_depth)
 
-        self._params = params
+        registry = getattr(telemetry, "registry", telemetry)
+        self.registry: MetricsRegistry = (
+            registry if isinstance(registry, MetricsRegistry)
+            else MetricsRegistry())
+        tracer = getattr(telemetry, "tracer", None)
+        self._span = tracer.span if tracer is not None else null_span
+        # per-request event recording (queue admission, prefill chunks,
+        # speculative rounds, COW forks, retirement): None when telemetry
+        # is off, so the disabled path pays one `is not None` per step and
+        # nothing per request
+        self._tracer = (tracer if tracer is not None
+                        and getattr(tracer, "enabled", False) else None)
+
+        # the engine holds the serving form and not the caller's tree
+        self._params = serving_form(params, model_cfg, self._span)
+        self._note_weight_bytes()
         self._pending_params: Any = None
         self._allocator = BlockAllocator(cache)
         self._k_pool, self._v_pool = init_kv_pools(model_cfg, cache)
@@ -462,7 +495,8 @@ class InferenceEngine:
                 raise ValueError(
                     f"draft vocab {draft_cfg.vocab_size} != target vocab "
                     f"{model_cfg.vocab_size} (the tokenizer is shared)")
-            self._draft_params = draft_params
+            self._draft_params = serving_form(draft_params, draft_cfg,
+                                              self._span)
             self.draft_cfg = draft_cfg
             # the draft's pools share block ids (and hence block tables
             # and the allocator) with the target's — only the per-block
@@ -499,7 +533,7 @@ class InferenceEngine:
         # tier-key scope: cached K/V is a function of the params, so a
         # weight change (hot_swap/rollout) switches fingerprints and can
         # never be served another set of weights' blocks
-        self._params_fp = (params_fingerprint(params)
+        self._params_fp = (params_fingerprint(self._params)
                            if kv_store is not None else "")
         # host→pool promotion writes queued at admission; each dst block
         # carries an extra allocator reference until the write lands in
@@ -514,18 +548,6 @@ class InferenceEngine:
         # loadgen's simulated agents (see docs/serving.md).
         self.iteration_floor_s = float(iteration_floor_s)
 
-        registry = getattr(telemetry, "registry", telemetry)
-        self.registry: MetricsRegistry = (
-            registry if isinstance(registry, MetricsRegistry)
-            else MetricsRegistry())
-        tracer = getattr(telemetry, "tracer", None)
-        self._span = tracer.span if tracer is not None else null_span
-        # per-request event recording (queue admission, prefill chunks,
-        # speculative rounds, COW forks, retirement): None when telemetry
-        # is off, so the disabled path pays one `is not None` per step and
-        # nothing per request
-        self._tracer = (tracer if tracer is not None
-                        and getattr(tracer, "enabled", False) else None)
         # exec-cache-backed dispatchers export their compile records
         # (xla_compile spans, xla_exec_cache_* counters) through this
         # replica's registry/tracer; a fleet-shared dispatcher rebinds to
@@ -634,6 +656,16 @@ class InferenceEngine:
         self._thread = threading.Thread(target=self._run,
                                         name="serving-engine", daemon=True)
         self._thread.start()
+
+    def _note_weight_bytes(self) -> None:
+        by_dtype: Dict[str, int] = collections.Counter()
+        for leaf in jax.tree.leaves(self._params):
+            by_dtype[str(leaf.dtype)] += leaf.nbytes
+        for dtype, nbytes in by_dtype.items():
+            self.registry.gauge(
+                "serving_weight_bytes",
+                "bytes of the tree the engine serves from, by leaf type",
+                labels={"dtype": dtype}).set(nbytes)
 
     @staticmethod
     def blocks_per_sequence(model_cfg: Any, block_size: int) -> int:
@@ -812,12 +844,15 @@ class InferenceEngine:
         next iteration boundary (never mid-step), so in-flight sequences
         finish under whichever params their next step sees — the standard
         online-swap semantics. Another family's tree (or another depth's)
-        is refused: the engine's model config, pools and programs stay."""
+        is refused: the engine's model config, pools and programs stay.
+        The tree is brought into its serving form here, on the caller's
+        thread (:func:`serving_form`); the scheduler only installs it."""
         if jax.tree.structure(params) != jax.tree.structure(self._params):
             raise ValueError(
                 f"hot_swap across model families is not served: the "
                 f"{self._model.family} engine was given a tree of another "
                 f"structure")
+        params = serving_form(params, self.model_cfg, self._span)
         with self._cond:
             self._pending_params = params
             self._cond.notify_all()
@@ -1159,6 +1194,7 @@ class InferenceEngine:
                     if self._pending_params is not None:
                         self._params = self._pending_params
                         self._pending_params = None
+                        self._note_weight_bytes()
                         # cached KV is a function of the params
                         if self._prefix is not None:
                             self._prefix.flush()

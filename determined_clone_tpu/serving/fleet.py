@@ -66,6 +66,7 @@ from determined_clone_tpu.serving.engine import (
     InferenceEngine,
     ReplicaFailed,
     make_paged_forward,
+    serving_form,
 )
 from determined_clone_tpu.serving.kv_cache import KVCacheConfig
 from determined_clone_tpu.serving.kv_store import KVBlockStore
@@ -76,6 +77,7 @@ from determined_clone_tpu.telemetry import (
     SLOEngine,
     Tracer,
 )
+from determined_clone_tpu.telemetry.spans import null_span
 
 # Replica lifecycle. STARTING replicas exist but take no traffic (engine
 # warming up); DRAINING replicas finish what they accepted but get
@@ -350,7 +352,9 @@ class ServingFleet:
         # namespace instead of compiling, so even replica 1 of a restart
         # leg warms in milliseconds (``exec_cache=False`` opts out)
         self._fwd = make_paged_forward(exec_cache)
-        self._params = params
+        # held in the serving form: every replica's engine takes these
+        # leaves as they are, so the replicas share one set of buffers
+        self._params = self._serving_form(params)
         self._lock = threading.RLock()   # membership + rollout serialization
         self._replicas: Dict[str, Replica] = {}
         self._next_seq = 1
@@ -396,6 +400,10 @@ class ServingFleet:
         self._incidents: List[Dict[str, Any]] = []
         # optional FleetSupervisor, attached by start_supervisor()
         self.supervisor: Any = None
+
+    def _serving_form(self, params: Any) -> Any:
+        return serving_form(params, self.model_cfg,
+                            getattr(self.frontdoor_tracer, "span", null_span))
 
     def _make_tracer(self, process_name: str) -> Optional[Tracer]:
         """One tracer lane of the stitched request trace; None (and zero
@@ -803,6 +811,7 @@ class ServingFleet:
             reps = [self._replicas[r] for r in order]
         if not reps:
             raise RuntimeError("rollout on an empty fleet")
+        new_params = self._serving_form(new_params)  # once for all replicas
         probe_output: List[int] = []
         drain_s: Dict[str, float] = {}
         for i, rep in enumerate(reps):

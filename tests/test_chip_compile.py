@@ -125,7 +125,8 @@ def test_flash_kernel_runs_per_shard_under_a_mesh(v5e, monkeypatch):
 
 
 def _compile_paged_forward(cfg, device, *, blocks, block, batch, t):
-    """The engine's jitted forward for a described chip, pools as
+    """The engine's jitted forward for a described chip, the weights in
+    the form the engine holds them in (``serving_params``), pools as
     ``init_kv_pools`` shapes them. Returns (compiled, one pool's shape)."""
     from determined_clone_tpu.serving.engine import make_paged_forward
     from determined_clone_tpu.serving.kv_cache import (
@@ -134,7 +135,9 @@ def _compile_paged_forward(cfg, device, *, blocks, block, batch, t):
     )
 
     one = SingleDeviceSharding(device)
-    params = jax.eval_shape(lambda k: gpt.init(k, cfg), jax.random.PRNGKey(0))
+    params = jax.eval_shape(
+        lambda k: gpt.serving_params(gpt.init(k, cfg), cfg),
+        jax.random.PRNGKey(0))
     k_pool, v_pool = _shapes(jax.eval_shape(
         lambda: init_kv_pools(cfg, KVCacheConfig(blocks, block))), one)
 
@@ -192,36 +195,72 @@ def _pool_sized_copies(hlo_text, layer_elements):
     return found
 
 
-@pytest.mark.parametrize("t", [1, 128], ids=["decode", "prefill"])
-@pytest.mark.parametrize("cell", ["gpt2-medium.serve-closed",
-                                  "gpt2-xl.serve-closed"])
+_SERVE_CELLS = {"gpt2-medium.serve-closed": (24, 1024, 16, 2048, 32),
+                "gpt2-xl.serve-closed": (48, 1600, 25, 512, 8)}
+_serve_cell_programs = {}
+
+
+def _compile_serve_cell(device, cell, t):
+    """A GPT serve cell's decode step (t = 1) or prefill call, at the
+    cell's real depth, which compiles in seconds: two layers of the xl pool
+    are small enough for the compiler to stage them whole in fast memory,
+    which is another program than the one the chip runs. Compiled once for
+    the tests that read it. Returns (cfg, compiled, one pool's shape)."""
+    if (cell, t) not in _serve_cell_programs:
+        n_layers, d_model, n_heads, blocks, batch = _SERVE_CELLS[cell]
+        # a small vocabulary: the real table is larger than a layer of
+        # xl's pool
+        cfg = gpt.GPTConfig(vocab_size=2048, n_layers=n_layers,
+                            d_model=d_model, n_heads=n_heads,
+                            d_ff=4 * d_model, max_seq_len=1024)
+        _serve_cell_programs[cell, t] = (cfg, *_compile_paged_forward(
+            cfg, device, blocks=blocks, block=16, batch=batch, t=t))
+    return _serve_cell_programs[cell, t]
+
+
+_each_serve_cell_program = pytest.mark.parametrize(
+    "cell,t", [(cell, t) for cell in _SERVE_CELLS for t in (1, 128)],
+    ids=[f"{cell}-{kind}" for cell in _SERVE_CELLS
+         for kind in ("decode", "prefill")])
+
+
+@_each_serve_cell_program
 def test_paged_forward_copies_no_pool(v5e, cell, t):
     """At the two serve cells' geometries (16 heads x 64: a 1024-wide pool
     row; 25 x 64 = 1600, padded to 1664) the compiled program updates the
     donated pools in place: nothing as large as one layer's share of a
     pool is copied, sliced out or stacked back, and the program's
-    temporaries stay under one pool plus the bf16 copies of the fp32
-    weights (hoisted out of the layer scan: ROADMAP A10).
-
-    At the cells' real depths, which compile in seconds: two layers of the
-    xl pool are small enough for the compiler to stage them whole in fast
-    memory, which is another program than the one the chip runs."""
-    widths = {"gpt2-medium.serve-closed": (24, 1024, 16, 2048, 32),
-              "gpt2-xl.serve-closed": (48, 1600, 25, 512, 8)}
-    n_layers, d_model, n_heads, blocks, batch = widths[cell]
-    # a small vocabulary: the real table is larger than a layer of xl's pool
-    cfg = gpt.GPTConfig(vocab_size=2048, n_layers=n_layers,
-                        d_model=d_model, n_heads=n_heads, d_ff=4 * d_model,
-                        max_seq_len=1024)
-    compiled, pool_shape = _compile_paged_forward(
-        cfg, v5e[0], blocks=blocks, block=16, batch=batch, t=t)
-    assert pool_shape[-1] % 128 == 0 and pool_shape[-1] >= d_model
+    temporaries stay under one pool (the weights come in the type they
+    are multiplied in, so no copy of them is among the temporaries)."""
+    cfg, compiled, pool_shape = _compile_serve_cell(v5e[0], cell, t)
+    assert pool_shape[-1] % 128 == 0 and pool_shape[-1] >= cfg.d_model
     pool_elements = math.prod(pool_shape)
     assert _pool_sized_copies(compiled.as_text(),
-                              pool_elements // n_layers) == []
-    block_weights = 12 * d_model * d_model * n_layers  # qkv, out, up, down
-    assert (compiled.memory_analysis().temp_size_in_bytes
-            < 2 * pool_elements + 2 * block_weights)
+                              pool_elements // cfg.n_layers) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * pool_elements
+
+
+@_each_serve_cell_program
+def test_paged_forward_converts_and_copies_no_weight_stack(v5e, cell, t):
+    """The block matrices arrive as ``[L, ...]`` stacks of the compute
+    type, in the layout the chip holds such an array in. No instruction
+    converts one or lays one out anew (xl's 1600 and 4800 are no
+    multiples of 128: a layout copy of a stack in every program would be
+    the casts under another name), and nothing of a stack's size is
+    copied or sliced: a layer's matrix is read where it lies."""
+    cfg, compiled, _ = _compile_serve_cell(v5e[0], cell, t)
+    L, D = cfg.n_layers, cfg.d_model
+    stack = re.compile(rf"\w+\[{L},(?:{D}|{3 * D}|{4 * D}),"
+                       rf"(?:{D}|{3 * D}|{4 * D})\]")
+    text = compiled.as_text()
+    made = [m for m in map(_HLO_LINE.match, text.splitlines())
+            if m and stack.search(m["result"])]
+    # what makes a stack-shaped value: reading a parameter or the scan's
+    # carry, never an operation on the data
+    assert {m["opcode"] for m in made} <= {
+        "parameter", "get-tuple-element", "bitcast"}, sorted(
+            {(m["name"], m["opcode"]) for m in made})
+    assert _pool_sized_copies(text, L * D * D) == []
 
 
 @pytest.mark.parametrize("batch,t", [(8, 1), (1, 2048), (8, 2048)],

@@ -363,6 +363,23 @@ def test_hot_swap_across_families_is_refused(params):
         eng.hot_swap(jax.tree.map(lambda x: x, params))  # its own: taken
 
 
+def test_the_engine_serves_the_very_buffers_it_was_given(params):
+    """EvaByte's paged forward reads every leaf in the type it is held in,
+    so its serving form is the tree itself: nothing is cast or copied, by
+    the family or by the engine, at construction or at a swap."""
+    cfg = _config(jnp.float32)
+
+    def given(tree):
+        return all(a is b for a, b in zip(jax.tree.leaves(tree),
+                                          jax.tree.leaves(params)))
+
+    assert given(cfg.paged_model().serving_params(params, cfg))
+    with _engine(cfg, params) as eng:
+        assert given(eng._params)
+        eng.hot_swap(params)
+        assert given(eng._pending_params or eng._params)
+
+
 def test_gpt_through_the_interface_is_token_identical_to_uncached():
     """The first implementation of the interface, unchanged in arithmetic:
     greedy decode through the engine equals re-running ``gpt.apply`` on the

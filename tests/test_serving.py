@@ -3,6 +3,7 @@ parity against the uncached forward, compile discipline under the bucket
 budget, admission control/backpressure, checkpoint hot-load, the static
 run-to-completion baseline, the HTTP front-end, and the KV-cached decode
 FLOPs accounting that makes serving MFU honest."""
+import dataclasses
 import json
 import urllib.error
 import urllib.request
@@ -58,6 +59,11 @@ def naive_greedy(params, prompt, max_new, cfg=CFG):
         logits = gpt.apply(params, cfg, jnp.asarray([toks], jnp.int32))
         toks.append(int(jnp.argmax(logits[0, -1])))
     return toks[len(prompt):]
+
+
+def same_leaves(x, y):
+    """Leaf for leaf the same objects: nothing was cast or copied."""
+    return all(a is b for a, b in zip(jax.tree.leaves(x), jax.tree.leaves(y)))
 
 
 def make_engine(params, **kw):
@@ -277,8 +283,125 @@ def test_hot_load_from_cas_swaps_params(params, tmp_path):
     leaves_b = jax.tree.leaves(params_b)
     assert any(not jnp.array_equal(a, b)
                for a, b in zip(leaves_a, leaves_b))
-    assert all(jnp.array_equal(s, b) for s, b in zip(swapped, leaves_b))
+    # installed in its serving form: the block matrices rounded once
+    served_b = jax.tree.leaves(gpt.serving_params(params_b, CFG))
+    assert [s.dtype for s in swapped] == [b.dtype for b in served_b]
+    assert all(jnp.array_equal(s, b) for s, b in zip(swapped, served_b))
+    assert not all(s.dtype == b.dtype for s, b in zip(swapped, leaves_b))
     assert compiled <= BUCKETS.program_budget
+
+
+# -- the serving form of the weights ------------------------------------------
+
+def _paged_call(params, t):
+    """One ``forward_paged`` call on a pool that holds an 8-token context
+    for each of 2 rows: a decode step (t = 1) or a prefill slice."""
+    from determined_clone_tpu.serving.kv_cache import init_kv_pools
+
+    key = jax.random.PRNGKey(3)
+    k_pool, v_pool = (jax.random.normal(k, pool.shape, pool.dtype)
+                      for k, pool in zip(jax.random.split(key),
+                                         init_kv_pools(CFG, CACHE)))
+    tokens = jax.random.randint(key, (2, t), 0, CFG.vocab_size)
+    positions = 8 + jnp.tile(jnp.arange(t, dtype=jnp.int32), (2, 1))
+    tables = jnp.arange(2 * 6, dtype=jnp.int32).reshape(2, 6)
+    return gpt.forward_paged(
+        params, CFG, tokens, positions, jnp.ones((2, t), bool),
+        jnp.full((2,), t - 1, jnp.int32), k_pool, v_pool, tables)
+
+
+@pytest.mark.parametrize("t", [1, 8], ids=["decode", "prefill-slice"])
+def test_serving_form_gives_the_float32_trees_results_bitwise(params, t):
+    """Rounding a matrix once is rounding it in every call: logits and
+    both pools are the same bits."""
+    served = gpt.serving_params(params, CFG)
+    for got, want in zip(_paged_call(served, t), _paged_call(params, t)):
+        assert got.dtype == want.dtype
+        assert jnp.array_equal(got, want)
+
+
+def test_serving_form_casts_the_block_matrices_only_and_only_once(params):
+    served = gpt.PAGED.serving_params(params, CFG)
+    assert jax.tree.structure(served) == jax.tree.structure(params)
+    blocks = served["blocks"]
+    for name in ("attn_qkv", "attn_out", "mlp_up", "mlp_down"):
+        assert blocks[name]["kernel"].dtype == CFG.compute_dtype
+        assert blocks[name]["bias"].dtype == CFG.compute_dtype
+    # norms, table (and with it the head): the very buffers that came in
+    for kept in (("blocks", "ln1"), ("blocks", "ln2"), ("final_norm",),
+                 ("embed",)):
+        was, now = params, served
+        for k in kept:
+            was, now = was[k], now[k]
+        assert same_leaves(was, now)
+        assert all(b.dtype == jnp.float32 for b in jax.tree.leaves(now))
+    assert same_leaves(served, gpt.PAGED.serving_params(served, CFG))
+    # a tree whose type is the compute type already is served as it lies
+    cfg32 = dataclasses.replace(CFG, compute_dtype=jnp.float32)
+    assert same_leaves(params, gpt.PAGED.serving_params(params, cfg32))
+
+
+def test_serving_form_of_an_moe_tree_leaves_the_router_float32():
+    cfg = dataclasses.replace(CFG, moe_experts=2, moe_k=1)
+    served = gpt.serving_params(gpt.init(jax.random.PRNGKey(0), cfg), cfg)
+    moe = served["blocks"]["moe"]
+    assert moe["router"]["kernel"].dtype == jnp.float32
+    assert {leaf.dtype for part in ("up", "down")
+            for leaf in jax.tree.leaves(moe[part])} == {
+                jnp.dtype(cfg.compute_dtype)}
+
+
+def test_engine_records_what_it_cast_and_what_it_holds(params):
+    """``serving_params_prepare`` carries the bytes cast and kept, the
+    gauge the served tree's bytes by type. A tree already in its serving
+    form is installed as it lies, prepared when the swap is queued."""
+    from determined_clone_tpu.telemetry import Tracer
+
+    class Telemetry:
+        registry = None
+        tracer = Tracer(enabled=True, process_name="t")
+
+    served = gpt.serving_params(params, CFG)
+    with make_engine(params, telemetry=Telemetry()) as eng:
+        assert not same_leaves(eng._params, params)  # not the caller's
+        eng.hot_swap(served)
+        eng.generate(PROMPTS[2], 2)
+        assert same_leaves(eng._params, served)
+        gauges = {m.labels["dtype"]: m.value for m in eng.registry.metrics()
+                  if m.name == "serving_weight_bytes"}
+    cast = [e["args"] for e in Telemetry.tracer.events()
+            if e["name"] == "serving_params_prepare"]
+    blocks32 = sum(x.nbytes for name in ("attn_qkv", "attn_out", "mlp_up",
+                                         "mlp_down")
+                   for x in jax.tree.leaves(params["blocks"][name]))
+    rest32 = sum(x.nbytes for x in jax.tree.leaves(params)) - blocks32
+    assert cast == [
+        {"cast_bytes": blocks32, "kept_bytes": rest32},
+        {"cast_bytes": 0, "kept_bytes": rest32 + blocks32 // 2}]
+    assert gauges == {"bfloat16": blocks32 // 2, "float32": rest32}
+
+
+def test_replicas_of_one_fleet_share_the_weight_buffers(params):
+    """The fleet converts once and hands that tree to every replica, at
+    construction and at a rollout: no replica holds weights of its own."""
+    from determined_clone_tpu.serving.fleet import ServingFleet
+
+    def buffers(tree):
+        return [x.unsafe_buffer_pointer() for x in jax.tree.leaves(tree)]
+
+    fleet = ServingFleet(params, CFG, buckets=BUCKETS, cache=CACHE,
+                         warmup=False)
+    try:
+        a, b = (fleet._replicas[r].engine for r in fleet.scale_up(2))
+        assert a._params["blocks"]["mlp_up"]["kernel"].dtype == jnp.bfloat16
+        assert buffers(a._params) == buffers(b._params) == buffers(
+            fleet._params)
+        fleet.rollout(gpt.init(jax.random.PRNGKey(7), CFG))
+        assert a._params["blocks"]["mlp_up"]["kernel"].dtype == jnp.bfloat16
+        assert buffers(a._params) == buffers(b._params) == buffers(
+            fleet._params)
+    finally:
+        fleet.close()
 
 
 # -- HTTP surface -------------------------------------------------------------
