@@ -1,8 +1,8 @@
 """In-process master — the control-plane surface without the C++ binary.
 
 The cluster e2e path runs trials against the compiled ``dct-master``; the
-*observability* plane also needs a master that test harnesses, the
-LocalExperimentRunner, and ``bench.py`` can embed in-process: something
+*observability* plane also needs a master that test harnesses and the
+LocalExperimentRunner can embed in-process: something
 that speaks the same ``/api/v1/trials/{id}/profiler`` ingestion route and
 serves the aggregated cluster view (`GET /metrics`, experiment traces)
 without a build step. :class:`InProcessMaster` is that surface, built on
@@ -41,8 +41,8 @@ class InProcessMaster:
     layer: a :class:`~determined_clone_tpu.telemetry.tsdb.TimeSeriesDB`
     scraped from the aggregator plus a
     :class:`~determined_clone_tpu.telemetry.rules.RuleEngine`, exposed
-    as ``GET /api/v1/timeseries`` and ``GET /api/v1/alerts``. Tests and
-    the bench drive :meth:`scrape_tick` deterministically; production
+    as ``GET /api/v1/timeseries`` and ``GET /api/v1/alerts``. Tests
+    drive :meth:`scrape_tick` deterministically; production
     callers start the ``dct-tsdb-scrape`` loop.
     """
 

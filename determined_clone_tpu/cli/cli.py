@@ -328,54 +328,8 @@ def cmd_checkpoint_stats(args) -> int:
               "content-addressed; stats need `type: cas`", file=sys.stderr)
         return 2
     # storage_stats() includes the per-namespace split (checkpoint
-    # chunks vs cached executables) under "namespaces"
+    # chunks vs spilled KV blocks) under "namespaces"
     print_json(manager.storage_stats())
-    return 0
-
-
-def cmd_exec_cache_stats(args) -> int:
-    """Persistent executable cache readout: entries, bytes, per-program
-    breakdown, session hit rate (docs/checkpoint_storage.md, "Executable
-    cache").
-
-    Accepts the same storage addressing as `checkpoint stats` (--config /
-    --host-path with a cas block) or --dir, a bare shared_fs root — the
-    DCT_EXEC_CACHE_DIR convention the serving warm-start harness uses.
-    """
-    from determined_clone_tpu.config.experiment import (
-        CheckpointStorageConfig,
-    )
-    from determined_clone_tpu.storage import (
-        CASStorageManager,
-        ExecutableCache,
-        SharedFSStorageManager,
-        build,
-    )
-
-    if args.dir:
-        cache = ExecutableCache(SharedFSStorageManager(args.dir))
-    else:
-        if args.config:
-            import yaml
-
-            with open(args.config) as f:
-                doc = yaml.safe_load(f) or {}
-            raw = doc.get("checkpoint_storage") or doc
-        elif args.host_path:
-            raw = {"type": "cas", "inner": {
-                "type": "shared_fs", "host_path": args.host_path}}
-        else:
-            print("exec-cache stats needs --config, --host-path or --dir",
-                  file=sys.stderr)
-            return 2
-        manager = build(CheckpointStorageConfig.from_dict(raw))
-        if not isinstance(manager, CASStorageManager):
-            print(f"checkpoint_storage type {raw.get('type')!r} is not "
-                  "content-addressed; the executable cache lives on "
-                  "`type: cas`", file=sys.stderr)
-            return 2
-        cache = manager.exec_cache()
-    print_json(cache.stats())
     return 0
 
 
@@ -384,7 +338,7 @@ def cmd_kv_stats(args) -> int:
     hierarchy"): from a live fleet front door (--url → the ``kv_tier``
     block of GET /v1/fleet — host tier counters plus nested CAS stats)
     or straight off a CAS store's ``cas/kv/`` namespace (--config /
-    --host-path, same addressing as `exec-cache stats`)."""
+    --host-path, same addressing as `checkpoint stats`)."""
     if args.url:
         import urllib.request
 
@@ -859,9 +813,7 @@ def _top_frame(args, session) -> str:
                   for _, v in s.get("samples") or []]
     lines.append(f"tokens/s  {_sparkline(tps_points)}")
     goodput = one("dct_goodput_cluster_fraction")
-    hit = one("dct_exec_cache_hit_rate")
-    lines.append(f"goodput {fmt(goodput, '.1%')}   "
-                 f"exec-cache hit {fmt(hit, '.1%')}")
+    lines.append(f"goodput {fmt(goodput, '.1%')}")
     queues = {(s.get("labels") or {}).get("component"): s.get("value")
               for s in query("serving_queue_depth")
               if (s.get("labels") or {}).get("component")}
@@ -1059,100 +1011,38 @@ def cmd_goodput(args) -> int:
 def cmd_mesh(args) -> int:
     """Mesh observability readout (docs/parallelism.md): collective
     op/byte counts per (kind, axis), straggler events, and the worst
-    comm-vs-compute fraction from the cluster metrics plane; or a
-    MULTICHIP scaling artifact rendered from a file (``--file``) or
-    measured fresh on a simulated mesh (``--run N``)."""
-    from determined_clone_tpu.telemetry.mesh import (
-        format_multichip,
-        validate_multichip,
+    comm-vs-compute fraction from the cluster metrics plane."""
+    from determined_clone_tpu.telemetry.aggregate import (
+        ClusterMetricsAggregator,
     )
+    import urllib.request
 
-    if args.run is not None:
-        # device count is fixed at backend init — measure in a subprocess
-        # that steers itself to a forced-device-count CPU mesh
-        import subprocess
-        proc = subprocess.run(
-            [sys.executable, "-m",
-             "determined_clone_tpu.parallel.scaling_bench",
-             "--devices", str(args.run), "--json"],
-            capture_output=True, text=True, timeout=600)
-        artifact = None
-        for line in proc.stdout.splitlines():
-            line = line.strip()
-            if line.startswith("{"):
-                try:
-                    artifact = json.loads(line)
-                except ValueError:
-                    continue
-        if proc.returncode != 0 or not isinstance(artifact, dict):
-            print(f"scaling bench failed (rc={proc.returncode}): "
-                  f"{proc.stderr.strip()[-400:]}", file=sys.stderr)
-            return 1
-    elif args.file:
-        with open(args.file) as f:
-            obj = json.load(f)
-        artifact = obj
-        if isinstance(obj, dict) and "tail" in obj and "meshes" not in obj:
-            # driver MULTICHIP_rN.json wrapper: the artifact is the last
-            # JSON line of the round's stdout tail
-            artifact = None
-            for line in str(obj["tail"]).splitlines():
-                line = line.strip()
-                if line.startswith("{"):
-                    try:
-                        artifact = json.loads(line)
-                    except ValueError:
-                        continue
-            if artifact is None:
-                print(f"{args.file}: no artifact line in wrapper tail",
-                      file=sys.stderr)
-                return 1
-    else:
-        # cluster plane: fold the master's /metrics exposition through the
-        # aggregator and print the mesh rollup
-        from determined_clone_tpu.telemetry.aggregate import (
-            ClusterMetricsAggregator,
-        )
-        import urllib.request
-
-        session = make_session(args)
-        url = f"http://{session.host}:{session.port}/metrics"
-        with urllib.request.urlopen(url, timeout=10) as resp:
-            text = resp.read().decode("utf-8")
-        agg = ClusterMetricsAggregator()
-        agg.ingest_prometheus_text("master", text)
-        roll = agg.mesh_rollup()
-        if roll is None:
-            print("no mesh metrics reported (no sharded program has "
-                  "exported collective accounting yet)", file=sys.stderr)
-            return 1
-        if args.json:
-            print(json.dumps(roll, indent=2, default=str))
-            return 0
-        for kind, axes in sorted((roll.get("collective_ops") or {}).items()):
-            for ax, cnt in sorted(axes.items()):
-                b = (roll.get("collective_bytes") or {}).get(
-                    kind, {}).get(ax)
-                b_s = f", {b:.0f} B/exec" if isinstance(b, (int, float)) \
-                    else ""
-                print(f"collective {kind}[{ax}]: {cnt:.0f} ops{b_s}")
-        for dev, cnt in sorted((roll.get("straggler_events") or {}).items()):
-            print(f"straggler events {dev}: {cnt:.0f}")
-        worst = roll.get("worst_comm_fraction")
-        if isinstance(worst, dict):
-            print(f"worst comm/compute fraction: "
-                  f"{worst.get('fraction'):.1%} ({worst.get('program')})")
-        return 0
-
-    problems = validate_multichip(artifact)
-    if args.json:
-        print(json.dumps(artifact, indent=2, default=str))
-    else:
-        print(format_multichip(artifact))
-    if problems:
-        print("schema problems: " + "; ".join(problems[:5]),
-              file=sys.stderr)
+    session = make_session(args)
+    url = f"http://{session.host}:{session.port}/metrics"
+    with urllib.request.urlopen(url, timeout=10) as resp:
+        text = resp.read().decode("utf-8")
+    agg = ClusterMetricsAggregator()
+    agg.ingest_prometheus_text("master", text)
+    roll = agg.mesh_rollup()
+    if roll is None:
+        print("no mesh metrics reported (no sharded program has "
+              "exported collective accounting yet)", file=sys.stderr)
         return 1
+    if args.json:
+        print(json.dumps(roll, indent=2, default=str))
+        return 0
+    for kind, axes in sorted((roll.get("collective_ops") or {}).items()):
+        for ax, cnt in sorted(axes.items()):
+            b = (roll.get("collective_bytes") or {}).get(
+                kind, {}).get(ax)
+            b_s = f", {b:.0f} B/exec" if isinstance(b, (int, float)) else ""
+            print(f"collective {kind}[{ax}]: {cnt:.0f} ops{b_s}")
+    for dev, cnt in sorted((roll.get("straggler_events") or {}).items()):
+        print(f"straggler events {dev}: {cnt:.0f}")
+    worst = roll.get("worst_comm_fraction")
+    if isinstance(worst, dict):
+        print(f"worst comm/compute fraction: "
+              f"{worst.get('fraction'):.1%} ({worst.get('program')})")
     return 0
 
 
@@ -1842,24 +1732,6 @@ def build_parser() -> argparse.ArgumentParser:
                    help="local chunk-cache dir (with --host-path)")
     c.set_defaults(func=cmd_checkpoint_stats)
 
-    # exec-cache (persistent compiled-executable cache on the CAS store)
-    p_exec = sub.add_parser(
-        "exec-cache",
-        help="persistent AOT executable cache on the CAS blob store")
-    se = p_exec.add_subparsers(dest="subcommand", required=True)
-    c = se.add_parser("stats",
-                      help="entries, bytes, per-program breakdown, "
-                           "session hit rate")
-    c.add_argument("--config", default=None,
-                   help="experiment config yaml with a checkpoint_storage "
-                        "cas block")
-    c.add_argument("--host-path", default=None,
-                   help="shared_fs storage root (shortcut for a config)")
-    c.add_argument("--dir", default=None,
-                   help="bare exec-cache root (the DCT_EXEC_CACHE_DIR "
-                        "convention)")
-    c.set_defaults(func=cmd_exec_cache_stats)
-
     # kv (fleet-wide KV memory hierarchy — docs/serving.md)
     p_kv = sub.add_parser(
         "kv", help="fleet-wide KV memory hierarchy (host tier + "
@@ -2196,21 +2068,13 @@ def build_parser() -> argparse.ArgumentParser:
                    help="query lookback window in seconds (default 300)")
     c.set_defaults(func=cmd_top)
 
-    # mesh (collective accounting + straggler + scaling readout —
+    # mesh (collective accounting + straggler readout —
     # docs/parallelism.md)
     c = sub.add_parser("mesh",
                        help="mesh observability: collective op/byte "
-                            "counts, straggler events, multichip scaling "
-                            "artifacts")
-    c.add_argument("--file", default=None,
-                   help="render a MULTICHIP artifact (raw or driver "
-                        "MULTICHIP_rN.json wrapper) instead of asking "
-                        "the master")
-    c.add_argument("--run", type=int, default=None, metavar="N",
-                   help="measure fresh on an N-device simulated mesh "
-                        "(runs parallel/scaling_bench in a subprocess)")
+                            "counts, straggler events")
     c.add_argument("--json", action="store_true",
-                   help="print the artifact/rollup as JSON")
+                   help="print the rollup as JSON")
     c.set_defaults(func=cmd_mesh)
 
     # serve (online inference: continuous batching + paged KV cache —
@@ -2299,7 +2163,7 @@ def build_parser() -> argparse.ArgumentParser:
                             "the source tree")
     c.add_argument("paths", nargs="*", default=[],
                    help="files/directories (default: the tier-1 set: "
-                        "determined_clone_tpu tools bench.py)")
+                        "determined_clone_tpu tools)")
     c.add_argument("--select", default=None,
                    help="comma-separated rule ids (e.g. JAX001,TIME001)")
     c.add_argument("--no-baseline", action="store_true")

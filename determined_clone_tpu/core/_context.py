@@ -136,21 +136,6 @@ def init(
     if telemetry is not None and hasattr(storage, "set_telemetry"):
         storage.set_telemetry(telemetry.registry, telemetry.tracer)
 
-    # DCT_EXEC_CACHE=1 + CAS storage: install the checkpoint store's
-    # executable-cache client as the process default, so the trainer's
-    # AOT step capture (and any engine built in-process) loads compiled
-    # executables from cas/exec/ on restart legs instead of recompiling.
-    # Opt-in: without the flag the compile path is byte-identical to the
-    # uncached behavior.
-    if os.environ.get("DCT_EXEC_CACHE") == "1" and hasattr(
-            storage, "exec_cache"):
-        from determined_clone_tpu.storage import exec_cache as exec_mod
-
-        try:
-            exec_mod.set_default_cache(storage.exec_cache())
-        except Exception:  # noqa: BLE001 - cache is an observer
-            pass
-
     registry = checkpoint_registry or LocalCheckpointRegistry(
         os.path.join(registry_base, "checkpoints.jsonl")
     )

@@ -34,11 +34,10 @@ def sweep_uncommitted(manager) -> int:
         if sid == "cas":
             # the content-addressed namespace (storage/cas.py) is not a
             # checkpoint and never has a COMMIT marker: it holds the chunk
-            # store AND the persistent executable cache (cas/exec/ blobs +
-            # index, storage/exec_cache.py), neither of which may ever be
-            # swept as "uncommitted". A CAS manager already hides it, but
-            # guard here too for legacy GC configs pointing directly at
-            # the inner store
+            # store AND the spilled KV blocks (cas/kv/ blobs + index),
+            # neither of which may ever be swept as "uncommitted". A CAS
+            # manager already hides it, but guard here too for legacy GC
+            # configs pointing directly at the inner store
             continue
         try:
             if manager.is_committed(sid):
@@ -67,9 +66,9 @@ def main() -> int:
         return 0
     # when DCT_GC_STORAGE is a `type: cas` block, delete() below also runs
     # the ref-counted chunk GC: chunks still referenced by any surviving
-    # checkpoint are kept, and the exec/ executable-cache namespace is
-    # outside the chunk walk entirely — cached executables are never
-    # reclaimed here (storage/cas.py, docs/checkpoint_storage.md)
+    # checkpoint are kept, and the kv/ namespace is outside the chunk
+    # walk entirely — spilled KV blocks are never reclaimed here
+    # (storage/cas.py, docs/checkpoint_storage.md)
     manager = build(CheckpointStorageConfig.from_dict(json.loads(storage_raw)))
     uuids = [u for u in uuids_raw.split(",") if u]
     failed = 0

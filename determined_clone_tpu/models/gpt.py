@@ -657,9 +657,9 @@ def extend_with_identity_layers(params: Params, cfg: GPTConfig,
     attention still run — only the final adds vanish). That makes the
     pair (original, extended) a controlled speculative-decoding
     testbed: the original IS a perfectly-distilled draft of the
-    extended target, so greedy acceptance is exactly 1.0. bench.py and
-    tests/test_serving_speed.py use it to measure the spec-decode
-    ceiling without training a real draft.
+    extended target, so greedy acceptance is exactly 1.0.
+    tests/test_serving_speed.py uses it to test speculative decoding
+    without training a real draft.
 
     Returns ``(params, cfg)`` for the deepened model. Stacked-block
     layout means extension is a leading-axis concat; MoE blocks are not

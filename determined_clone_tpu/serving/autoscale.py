@@ -33,7 +33,7 @@ HOLD = "hold"
 
 @dataclasses.dataclass(frozen=True)
 class AutoscalePolicy:
-    """Thresholds for one fleet. The defaults suit the bench's paced
+    """Thresholds for one fleet. The defaults suit the tests' paced
     tiny-GPT replicas; real deployments tune per model."""
     min_replicas: int = 1
     max_replicas: int = 4

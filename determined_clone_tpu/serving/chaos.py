@@ -325,51 +325,6 @@ class ChaosRunner:
             fleet.close()
         return self._finish("wedged_scheduler", t0, checks, fleet)
 
-    def torn_warmstart(self) -> ScenarioResult:
-        """Torn CAS blob during warm-start: every executable-cache load
-        fails mid-read while a replica is also killed. The invariant is
-        graceful degradation — loads fall back to compile, recovery
-        still completes, nothing is lost."""
-        import tempfile
-
-        from determined_clone_tpu.storage.base import SharedFSStorageManager
-        from determined_clone_tpu.storage.exec_cache import ExecutableCache
-
-        t0 = time.monotonic()
-        checks: List[Check] = []
-        torn_rule = {"point": "exec_cache.load", "action": "error",
-                     "exc": "io", "times": 0}
-        with tempfile.TemporaryDirectory(prefix="dct-chaos-exec-") as tmp:
-            cache = ExecutableCache(SharedFSStorageManager(tmp))
-            # blobs are torn from the very first load: the fleet's own
-            # warm-up must already degrade to compiling
-            build_plan = faults.activate(faults.plan_from_dict(
-                {"seed": self.seed, "rules": [dict(torn_rule)]}))
-            fleet = self._fleet(exec_cache=cache, warmup=True)
-            plan = None
-            try:
-                fleet.scale_up(2)
-                prompts = self._prompts(self.requests)
-                ref = self._reference(fleet, prompts)
-                fleet.start_supervisor(interval_s=0.05, stale_after_s=2.0)
-                plan = faults.activate(faults.plan_from_dict({
-                    "seed": self.seed,
-                    "rules": [dict(torn_rule),
-                              {"point": "engine.step.chaos-1",
-                               "action": "error", "nth": 2, "times": 1}],
-                }), fleet.registry)
-                results = self._run_workload(fleet, prompts)
-                self._audit(fleet, checks, ref, results,
-                            expect_replicas=2, expect_min_incidents=1)
-                fired = build_plan.rules[0].fires + plan.rules[0].fires
-                checks.append(Check("torn_loads_degraded", fired > 0,
-                                    f"exec_cache.load faults fired={fired}"))
-            finally:
-                faults.deactivate(plan)
-                faults.deactivate(build_plan)
-                fleet.close()
-        return self._finish("torn_warmstart", t0, checks, fleet)
-
     def double_fault(self) -> ScenarioResult:
         """Supervisor + replica double fault: the probe pass itself
         raises (twice) while a replica is dead. Supervision absorbs its
@@ -579,7 +534,6 @@ class ChaosRunner:
 SCENARIOS: Dict[str, Callable[[ChaosRunner], ScenarioResult]] = {
     "kill_replica_mid_decode": ChaosRunner.kill_replica_mid_decode,
     "wedged_scheduler": ChaosRunner.wedged_scheduler,
-    "torn_warmstart": ChaosRunner.torn_warmstart,
     "double_fault": ChaosRunner.double_fault,
     "poison_pill": ChaosRunner.poison_pill,
     "kv_warm_failover": ChaosRunner.kv_warm_failover,

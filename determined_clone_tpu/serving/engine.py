@@ -8,8 +8,7 @@ for the newcomers), then runs ONE decode step for every active sequence
 their pool blocks and batch slots free up for the next iteration. No
 sequence ever waits for a stranger's completion — the property that
 makes continuous batching beat run-to-completion batching on tokens/sec
-under load (bench.py's ``serving`` section measures exactly that, with
-:meth:`InferenceEngine.run_static` as the same-program baseline).
+under load.
 
 The model is whatever family the given model config belongs to: the
 engine imports none and asks the config for its
@@ -107,55 +106,6 @@ ADMISSION_RETRY = RetryPolicy(
     multiplier=2.0, max_delay_s=2.0, retryable=(ServerOverloaded,))
 
 
-def _ambient_exec_cache() -> Any:
-    """The process-default persistent executable cache (storage/
-    exec_cache.py), or None. Resolution must never fail engine
-    construction."""
-    try:
-        from determined_clone_tpu.storage import exec_cache as exec_mod
-
-        return exec_mod.default_cache()
-    except Exception:  # pragma: no cover - defensive
-        return None
-
-
-def _maybe_dispatch(fn: Any, exec_cache: Any, program: str) -> Any:
-    """Wrap a jitted entry point in an AotDispatcher when a persistent
-    executable cache is in play (explicit ``exec_cache``, or the ambient
-    default). ``exec_cache=False`` forces the plain jit wrapper; with no
-    cache anywhere the jit wrapper comes back unchanged — the seed
-    behavior, byte-for-byte."""
-    if exec_cache is False:
-        return fn
-    cache = exec_cache if exec_cache is not None else _ambient_exec_cache()
-    if cache is None:
-        return fn
-    from determined_clone_tpu.telemetry.xla import AotDispatcher
-
-    return AotDispatcher(fn, program=program, exec_cache=cache)
-
-
-def _sum_cache_summaries(dispatchers: Sequence[Any]) -> Optional[
-        Dict[str, Any]]:
-    """Merge ``AotDispatcher.cache_summary()`` dicts (None with no
-    dispatchers — plain jit everywhere, nothing to report).
-    ``compile_time_saved_s`` stays None until at least one hit so "no
-    cache traffic" and "cache saved 0s" read differently downstream."""
-    totals: Optional[Dict[str, Any]] = None
-    for d in dispatchers:
-        s = d.cache_summary()
-        if totals is None:
-            totals = dict(s)
-            continue
-        for k, v in s.items():
-            if v is None:
-                continue
-            totals[k] = (totals.get(k) or 0) + v
-    if totals is not None and not totals.get("exec_cache_hits"):
-        totals["compile_time_saved_s"] = None
-    return totals
-
-
 def serving_form(params: Any, model_cfg: Any, span: Any = null_span) -> Any:
     """``params`` as the family's paged forward reads them
     (``PagedModel.serving_params``) and on the device: what an engine, and
@@ -196,33 +146,24 @@ def forward_paged_logits(params: Any, cfg: Any, tokens: jax.Array,
         block_tables)
 
 
-def make_paged_forward(exec_cache: Any = None) -> Any:
+def make_paged_forward() -> Any:
     """The jitted paged forward an engine runs everything through, for
     whichever family the (static) model config it is called with belongs
     to.
     Replica fleets pass ONE of these to every engine (``fwd=``) so the
     whole fleet shares a single XLA program cache: replica N>1 warms up
     for free, and scale-up never pays a compile (all replicas serve the
-    same model config and bucket ladder, so the shapes are identical).
-
-    With a persistent executable cache (``exec_cache=``, or the ambient
-    default from storage/exec_cache.py) the wrapper is an
-    :class:`~determined_clone_tpu.telemetry.xla.AotDispatcher`: warmup
-    loads previously-compiled programs from the CAS ``cas/exec/``
-    namespace instead of compiling, so even the FIRST process of a
-    restart leg starts warm. ``exec_cache=False`` opts out."""
-    fwd = jax.jit(forward_paged, static_argnums=(1,),
-                  donate_argnums=(6, 7))
-    return _maybe_dispatch(fwd, exec_cache, "serving_forward_paged")
+    same model config and bucket ladder, so the shapes are identical)."""
+    return jax.jit(forward_paged, static_argnums=(1,),
+                   donate_argnums=(6, 7))
 
 
-def make_paged_verify(exec_cache: Any = None) -> Any:
+def make_paged_verify() -> Any:
     """The jitted multi-logit forward the speculative verify step runs
     through: one [B, k+1] call scores the last committed token plus all
     k drafts; compiles one program per batch bucket."""
-    fwd = jax.jit(forward_paged_logits, static_argnums=(1,),
-                  donate_argnums=(5, 6))
-    return _maybe_dispatch(fwd, exec_cache, "serving_verify")
+    return jax.jit(forward_paged_logits, static_argnums=(1,),
+                   donate_argnums=(5, 6))
 
 
 def _block_copy(k_pool: jax.Array, v_pool: jax.Array,
@@ -232,11 +173,10 @@ def _block_copy(k_pool: jax.Array, v_pool: jax.Array,
             v_pool.at[:, dst].set(v_pool[:, src]))
 
 
-def make_block_copy(exec_cache: Any = None) -> Any:
+def make_block_copy() -> Any:
     """Jitted :func:`_block_copy` — src/dst are dynamic scalars, so the
     whole COW protocol costs exactly one XLA program per pool pair."""
-    fwd = jax.jit(_block_copy, donate_argnums=(0, 1))
-    return _maybe_dispatch(fwd, exec_cache, "serving_block_copy")
+    return jax.jit(_block_copy, donate_argnums=(0, 1))
 
 
 def _block_write(k_pool: jax.Array, v_pool: jax.Array, dst: jax.Array,
@@ -246,11 +186,10 @@ def _block_write(k_pool: jax.Array, v_pool: jax.Array, dst: jax.Array,
     return (k_pool.at[:, dst].set(k_blk), v_pool.at[:, dst].set(v_blk))
 
 
-def make_block_write(exec_cache: Any = None) -> Any:
+def make_block_write() -> Any:
     """Jitted :func:`_block_write` — dst is a dynamic scalar, so tier
     promotion costs exactly one XLA program per pool pair."""
-    fwd = jax.jit(_block_write, donate_argnums=(0, 1))
-    return _maybe_dispatch(fwd, exec_cache, "serving_block_write")
+    return jax.jit(_block_write, donate_argnums=(0, 1))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -503,12 +442,7 @@ class InferenceEngine:
             # payload shape differs — so prefix sharing and COW cover
             # the draft KV with zero extra bookkeeping
             self._dk_pool, self._dv_pool = init_kv_pools(draft_cfg, cache)
-            # an AotDispatcher keys on (cfg, shapes), so target and draft
-            # lanes SHARE one dispatcher — their executables land in one
-            # table and programs_compiled() counts them once, exactly as
-            # the shared jit cache always did
-            self._draft_fwd = (self._fwd if hasattr(self._fwd, "warm")
-                               else make_paged_forward(exec_cache=False))
+            self._draft_fwd = make_paged_forward()
             self._verify_fwd = make_paged_verify()
         else:
             self._draft_params = None
@@ -542,21 +476,12 @@ class InferenceEngine:
 
         # simulated device-step floor: pad every scheduler iteration that
         # did device work up to this many seconds. 0.0 (the default) is a
-        # no-op. Fleet benches on a single host set it so per-replica
+        # no-op. Fleet tests on a single host set it so per-replica
         # capacity is bounded by the floor rather than by the one CPU the
         # replicas share — the same stand-in-for-hardware idiom as
         # loadgen's simulated agents (see docs/serving.md).
         self.iteration_floor_s = float(iteration_floor_s)
 
-        # exec-cache-backed dispatchers export their compile records
-        # (xla_compile spans, xla_exec_cache_* counters) through this
-        # replica's registry/tracer; a fleet-shared dispatcher rebinds to
-        # whichever replica is currently warming
-        for entry in (self._fwd, self._draft_fwd, self._verify_fwd,
-                      self._copy, self._write):
-            bind = getattr(entry, "bind_telemetry", None)
-            if callable(bind):
-                bind(self.registry, self._tracer)
         m = self.registry
         self._h_queue_wait = m.histogram(
             "serving_queue_wait_seconds", "submit → admitted into the batch")
@@ -739,8 +664,7 @@ class InferenceEngine:
     def attach_tracer(self, tracer: Any) -> None:
         """Late-bind (or detach, with None) the per-request event tracer.
         A plain attribute swap is atomic, so flipping it while the
-        scheduler runs is safe — the bench uses this to measure the same
-        warm engine traced vs untraced (tracing_overhead)."""
+        scheduler runs is safe."""
         self._tracer = (tracer if tracer is not None
                         and getattr(tracer, "enabled", False) else None)
 
@@ -904,13 +828,6 @@ class InferenceEngine:
             self._warming = True
         t0 = time.monotonic()
 
-        def call(f: Any, *args: Any) -> Any:
-            # exec-cache-backed dispatchers take the cache-first AOT path
-            # (load the serialized executable, compile only on a miss);
-            # plain jit wrappers compile implicitly as they always did
-            warm = getattr(f, "warm", None)
-            return warm(*args) if callable(warm) else f(*args)
-
         try:
             with self._span("serving_warmup"):
                 lanes = [(self._fwd, self._params, self.model_cfg)]
@@ -921,8 +838,8 @@ class InferenceEngine:
                     tables = jnp.zeros((b, self._table_width), jnp.int32)
                     for fwd, params, cfg in lanes:
                         for t in (*self.buckets.prefill_len_buckets, 1):
-                            logits, kp, vp = call(
-                                fwd, params, cfg,
+                            logits, kp, vp = fwd(
+                                params, cfg,
                                 jnp.zeros((b, t), jnp.int32),
                                 jnp.zeros((b, t), jnp.int32),
                                 jnp.zeros((b, t), bool),
@@ -935,8 +852,7 @@ class InferenceEngine:
                             jnp.argmax(logits, axis=-1).block_until_ready()
                     if self._spec_k:
                         t = self._spec_k + 1
-                        logits, self._k_pool, self._v_pool = call(
-                            self._verify_fwd,
+                        logits, self._k_pool, self._v_pool = self._verify_fwd(
                             self._params, self.model_cfg,
                             jnp.zeros((b, t), jnp.int32),
                             jnp.zeros((b, t), jnp.int32),
@@ -944,11 +860,11 @@ class InferenceEngine:
                             self._k_pool, self._v_pool, tables)
                         logits.block_until_ready()
                 if self._copy is not None:
-                    self._k_pool, self._v_pool = call(
-                        self._copy, self._k_pool, self._v_pool, 0, 0)
+                    self._k_pool, self._v_pool = self._copy(
+                        self._k_pool, self._v_pool, 0, 0)
                     if self._spec_k:
-                        self._dk_pool, self._dv_pool = call(
-                            self._copy, self._dk_pool, self._dv_pool, 0, 0)
+                        self._dk_pool, self._dv_pool = self._copy(
+                            self._dk_pool, self._dv_pool, 0, 0)
                     jax.block_until_ready(self._k_pool)
                 if self._write is not None:
                     # warmed by writing block 0's own contents back:
@@ -956,14 +872,13 @@ class InferenceEngine:
                     # the write is bit-identical (all zeros at warmup)
                     kb = jnp.array(self._k_pool[:, 0])
                     vb = jnp.array(self._v_pool[:, 0])
-                    self._k_pool, self._v_pool = call(
-                        self._write, self._k_pool, self._v_pool, 0, kb, vb)
+                    self._k_pool, self._v_pool = self._write(
+                        self._k_pool, self._v_pool, 0, kb, vb)
                     if self._spec_k:
                         dkb = jnp.array(self._dk_pool[:, 0])
                         dvb = jnp.array(self._dv_pool[:, 0])
-                        self._dk_pool, self._dv_pool = call(
-                            self._write, self._dk_pool, self._dv_pool, 0,
-                            dkb, dvb)
+                        self._dk_pool, self._dv_pool = self._write(
+                            self._dk_pool, self._dv_pool, 0, dkb, dvb)
                     jax.block_until_ready(self._k_pool)
         finally:
             with self._cond:
@@ -1120,26 +1035,6 @@ class InferenceEngine:
             speculative=self._spec_k > 0,
             prefix_cache=self._prefix is not None,
             kv_store=self._kv_store is not None)
-
-    def exec_dispatchers(self) -> List[Any]:
-        """The engine's distinct AOT dispatchers (empty when the engine
-        runs plain jit — the persistent executable cache is not in play).
-        The fleet dedups these across replicas: the shared forward is ONE
-        dispatcher no matter how many engines run through it."""
-        out: List[Any] = []
-        for f in (self._fwd, self._draft_fwd, self._verify_fwd,
-                  self._copy, self._write):
-            if callable(getattr(f, "cache_summary", None)) and not any(
-                    f is s for s in out):
-                out.append(f)
-        return out
-
-    def exec_cache_summary(self) -> Optional[Dict[str, Any]]:
-        """Aggregated persistent-executable-cache accounting across the
-        engine's dispatchers (None when the engine runs plain jit — the
-        cache is not in play). ``fallback_compiles`` > 0 on a supposedly
-        warm engine means some program was compiled instead of loaded."""
-        return _sum_cache_summaries(self.exec_dispatchers())
 
     def stats(self) -> EngineStats:
         with self._cond:
@@ -1902,136 +1797,3 @@ class InferenceEngine:
             self._completed += 1
             self._total_tokens += len(a.out)
         h._finish(result)
-
-    # -- static (run-to-completion) baseline -------------------------------
-
-    def run_static(self, requests: Sequence[Tuple[Sequence[int], int]], *,
-                   arrivals: Optional[Sequence[float]] = None,
-                   timeout: Optional[float] = 300.0
-                   ) -> List[RequestResult]:
-        """Serve ``requests`` [(prompt, max_new_tokens), ...] the
-        pre-continuous-batching way: FIFO groups of up to ``max_batch``,
-        each run to completion (every decode step runs until the LAST
-        member of the group finishes — early finishers burn batch slots),
-        and no one joins a running group. Uses the very same jitted
-        programs and pool as the continuous path, so bench comparisons
-        isolate the *scheduling* policy. ``arrivals`` (seconds from call
-        start, ascending) simulates offered load; latency for each
-        request counts from its arrival instant.
-
-        The engine must be idle (nothing queued or running) — this is a
-        benchmarking harness, not a second serving mode.
-        """
-        with self._cond:
-            self._await_idle_locked("run_static")
-        arrivals = list(arrivals) if arrivals is not None \
-            else [0.0] * len(requests)
-        if len(arrivals) != len(requests):
-            raise ValueError("arrivals must match requests")
-        pending = sorted(
-            ((arr, i, tuple(int(t) for t in p), int(mx))
-             for i, ((p, mx), arr) in enumerate(zip(requests, arrivals))),
-            key=lambda x: (x[0], x[1]))
-        results: List[Optional[RequestResult]] = [None] * len(requests)
-        t0 = time.monotonic()
-        while pending:
-            now = time.monotonic() - t0
-            if pending[0][0] > now:
-                time.sleep(min(pending[0][0] - now, 0.05))
-                continue
-            group = []
-            while (pending and len(group) < self.buckets.max_batch
-                   and pending[0][0] <= now):
-                group.append(pending.pop(0))
-            rows = []
-            for arr, i, prompt, max_new in group:
-                h = _Handle(Request(prompt, max_new, None, f"static-{i}"))
-                h.submit_t = t0 + arr
-                h.admit_t = time.monotonic()
-                rows.append(_Active(h, self._allocator.allocate_blocks(
-                    self._layout.blocks_needed(len(prompt) + max_new)),
-                    len(prompt)))
-            self._static_group(rows)
-            for (arr, i, _, _), a in zip(group, rows):
-                end = time.monotonic()
-                self._allocator.release(a.blocks)
-                results[i] = RequestResult(
-                    request_id=f"static-{i}", prompt_len=a.prompt_len,
-                    tokens=list(a.out), finish_reason="length",
-                    queue_wait_s=a.handle.admit_t - a.handle.submit_t,
-                    prefill_s=a.handle.prefill_s,
-                    decode_s=end - a.handle.prefill_done_t,
-                    total_s=end - a.handle.submit_t)
-            if timeout is not None and time.monotonic() - t0 > timeout:
-                raise TimeoutError("run_static exceeded its timeout")
-        return [r for r in results if r is not None]
-
-    def _static_group(self, rows: List[_Active]) -> None:
-        """Prefill + decode one group run-to-completion: every step runs
-        at the full group batch until the slowest member finishes;
-        finished rows are masked (no pool writes) but keep burning their
-        slot — the static-batching cost the continuous scheduler
-        eliminates."""
-        b = bucket_for(len(rows), self.buckets.batch_buckets)
-        # chunked prefill applies to the static path too (same programs;
-        # without it a chunked-engine workload could not be replayed) —
-        # run-to-completion means chunks of ONE group interleave with
-        # nothing, so the whole prompt still lands before any decode
-        chunk = self.chunk_prefill_len or self.buckets.prefill_len_buckets[-1]
-        tables = self._tables_for(rows, b)
-        t0 = time.monotonic()
-        offs = [0] * len(rows)
-        first = np.zeros((b,), np.int64)
-        while True:
-            cnts = [min(chunk, a.prompt_len - offs[i])
-                    for i, a in enumerate(rows)]
-            t = bucket_for(max(cnts), self.buckets.prefill_len_buckets)
-            tok = np.zeros((b, t), np.int32)
-            pos = np.zeros((b, t), np.int32)
-            msk = np.zeros((b, t), bool)
-            last = np.zeros((b,), np.int32)
-            for i, a in enumerate(rows):
-                n = cnts[i]
-                if n > 0:
-                    tok[i, :n] = a.handle.req.prompt[offs[i]:offs[i] + n]
-                    pos[i, :n] = np.arange(offs[i], offs[i] + n)
-                    msk[i, :n] = True
-                    last[i] = n - 1
-            logits, self._k_pool, self._v_pool = self._fwd(
-                self._params, self.model_cfg, jnp.asarray(tok),
-                jnp.asarray(pos), jnp.asarray(msk), jnp.asarray(last),
-                self._k_pool, self._v_pool, tables)
-            picks = np.asarray(jnp.argmax(logits, axis=-1))
-            for i, a in enumerate(rows):
-                offs[i] += cnts[i]
-                if cnts[i] > 0 and offs[i] >= a.prompt_len:
-                    first[i] = picks[i]
-            if all(offs[i] >= a.prompt_len for i, a in enumerate(rows)):
-                break
-        dt = time.monotonic() - t0
-        done_t = time.monotonic()
-        for i, a in enumerate(rows):
-            a.handle.prefill_s = dt
-            a.handle.prefill_done_t = done_t
-            a.out.append(int(first[i]))
-            a.last_token = int(first[i])
-        group_max = max(a.handle.req.max_new_tokens for a in rows)
-        for _ in range(group_max - 1):
-            tok1 = np.zeros((b, 1), np.int32)
-            pos1 = np.zeros((b, 1), np.int32)
-            msk1 = np.zeros((b, 1), bool)
-            for i, a in enumerate(rows):
-                running = len(a.out) < a.handle.req.max_new_tokens
-                tok1[i, 0] = a.last_token
-                pos1[i, 0] = a.prompt_len + len(a.out) - 1
-                msk1[i, 0] = running
-            logits, self._k_pool, self._v_pool = self._fwd(
-                self._params, self.model_cfg, jnp.asarray(tok1),
-                jnp.asarray(pos1), jnp.asarray(msk1),
-                jnp.zeros((b,), jnp.int32),
-                self._k_pool, self._v_pool, tables)
-            nxt = np.asarray(jnp.argmax(logits, axis=-1))
-            for i, a in enumerate(rows):
-                if len(a.out) < a.handle.req.max_new_tokens:
-                    a.out.append(int(nxt[i]))
-                    a.last_token = int(nxt[i])

@@ -272,7 +272,7 @@ class ServingFleet:
     """N engine replicas + router + drain/rollout orchestration.
 
     ``iteration_floor_s`` is forwarded to every engine; single-host
-    benches set it so per-replica capacity is floor-bound rather than
+    tests set it so per-replica capacity is floor-bound rather than
     bound by the one CPU all replicas share (docs/serving.md). The first
     replica's warmup compiles the shared bucket ladder; later replicas
     warm up against a hot cache for free.
@@ -292,7 +292,6 @@ class ServingFleet:
                  tracing: Optional[bool] = None,
                  archive_dir: Optional[str] = None,
                  slo: Any = None,
-                 exec_cache: Any = None,
                  max_request_crashes: int = 3) -> None:
         self.name = name
         # poison-pill strike budget: a request that was RUNNING on this
@@ -346,12 +345,8 @@ class ServingFleet:
         self._router_tracer = self._make_tracer("router")
         self.router = LeastLoadedRouter(self.registry,
                                         tracer=self._router_tracer)
-        # the fleet-shared forward: one jit cache — and, with a persistent
-        # executable cache (``exec_cache=`` or the ambient default), one
-        # AOT dispatcher whose ladder loads from the CAS ``exec/``
-        # namespace instead of compiling, so even replica 1 of a restart
-        # leg warms in milliseconds (``exec_cache=False`` opts out)
-        self._fwd = make_paged_forward(exec_cache)
+        # the fleet-shared forward: one jit cache for every replica
+        self._fwd = make_paged_forward()
         # held in the serving form: every replica's engine takes these
         # leaves as they are, so the replicas share one set of buffers
         self._params = self._serving_form(params)
@@ -372,9 +367,6 @@ class ServingFleet:
         self._h_scale_up = self.registry.histogram(
             "fleet_scale_up_seconds",
             "per-replica scale-up wall-time (engine build + warmup)")
-        # per-replica scale-up latencies in arrival order — the bench's
-        # cold-vs-warm replica-start A/B reads this directly
-        self.scale_up_latencies_s: List[float] = []
 
         # -- self-healing state (docs/serving.md "Self-healing") ----------
         self._c_replacements = self.registry.counter(
@@ -473,10 +465,7 @@ class ServingFleet:
                 self._g_replicas.set(len(self._replicas))
             self.router.add(rep)
             added.append(rid)
-            dt = time.monotonic() - t0
-            self._h_scale_up.observe(dt)
-            with self._lock:
-                self.scale_up_latencies_s.append(dt)
+            self._h_scale_up.observe(time.monotonic() - t0)
         return added
 
     def stop_replica(self, replica_id: str, timeout: float = 60.0) -> float:
@@ -860,26 +849,6 @@ class ServingFleet:
         return self.rollout(new_params, **kw)
 
     # -- telemetry ---------------------------------------------------------
-
-    def exec_cache_summary(self) -> Optional[Dict[str, Any]]:
-        """Fleet-wide persistent-executable-cache accounting (None when
-        every entry point runs plain jit). Dispatchers are deduped by
-        identity across replicas — the fleet-shared forward is ONE
-        dispatcher no matter how many engines run through it, so its
-        hits/misses count once."""
-        from determined_clone_tpu.serving.engine import _sum_cache_summaries
-
-        seen: List[Any] = []
-        if callable(getattr(self._fwd, "cache_summary", None)):
-            seen.append(self._fwd)
-        for rep in self.replicas():
-            lister = getattr(rep.engine, "exec_dispatchers", None)
-            if not callable(lister):
-                continue
-            for d in lister():
-                if not any(d is s for s in seen):
-                    seen.append(d)
-        return _sum_cache_summaries(seen)
 
     def kv_stats(self) -> Optional[Dict[str, Any]]:
         """Shared KV-tier accounting (None when the hierarchy is off):

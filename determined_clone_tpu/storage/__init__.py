@@ -14,10 +14,6 @@ from determined_clone_tpu.storage.cas import (
     CASStorageManager,
     ChunkCache,
 )
-from determined_clone_tpu.storage.exec_cache import (
-    ExecKey,
-    ExecutableCache,
-)
 from determined_clone_tpu.storage.transfer import (
     TransferPool,
     get_pool,
@@ -31,8 +27,6 @@ __all__ = [
     "CASStorageManager",
     "ChunkCache",
     "DirectoryStorageManager",
-    "ExecKey",
-    "ExecutableCache",
     "GCSStorageManager",
     "S3StorageManager",
     "SharedFSStorageManager",

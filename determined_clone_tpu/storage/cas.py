@@ -13,21 +13,16 @@ store** with two clients:
   root) re-upload only the chunks that actually changed — the
   incremental-checkpoint result of Check-N-Run (NSDI '22) / CheckFreq
   (FAST '21), see docs/checkpoint_storage.md.
-- **compiled executables** (``cas/exec/``): the persistent AOT
-  executable cache (storage/exec_cache.py) stores serialized XLA
-  executables as content-addressed blobs plus a key index, so replica
-  fleets and restart legs skip recompiling programs another process
-  already built.
 - **spilled KV blocks** (``cas/kv/``): :class:`KVBlobStore` is the
   durable tier of the fleet KV memory hierarchy (serving/kv_store.py)
   — exact K/V block payloads keyed by the prefix cache's chained
   content hash, so a restarted or replacement replica warms shared
   prefixes by *fetching* instead of re-prefilling (docs/serving.md).
 
-All three ride the same :class:`BlobService` transport — digest-keyed object
+Both ride the same :class:`BlobService` transport — digest-keyed object
 paths, sha256 verification on every read, local :class:`ChunkCache`
 read-through, fault-point injection — so the integrity and chaos
-machinery proven on checkpoints applies to executables unchanged.
+machinery proven on checkpoints applies to spilled blocks unchanged.
 
 Protocol extension: a checkpoint is restorable iff its COMMIT marker
 exists (unchanged from PR 4) AND every chunk its manifests reference
@@ -73,18 +68,16 @@ from determined_clone_tpu.storage.base import (
 logger = logging.getLogger(__name__)
 
 # Reserved storage_id holding the shared blob objects (checkpoint chunks
-# AND cached executables); never a checkpoint. GC sweeps and
+# AND spilled KV blocks); never a checkpoint. GC sweeps and
 # list_storage_ids() must skip it.
 CHUNK_NAMESPACE = "cas"
 
 # Blob namespaces inside the reserved storage_id. Chunk GC only ever
 # deletes ``chunks/...`` rels (structurally — see BlobService.rel), so
-# ``exec/...`` and ``kv/...`` entries can never be swept as orphan
-# chunks; their lifecycle is the per-namespace budget sweep
-# (:func:`sweep_namespace`) instead.
+# ``kv/...`` entries can never be swept as orphan chunks; their
+# lifecycle is the namespace's budget sweep (:func:`sweep_namespace`)
+# instead.
 CHUNK_PREFIX = "chunks"
-EXEC_BLOB_PREFIX = "exec/blobs"
-EXEC_INDEX_PREFIX = "exec/index"
 KV_BLOB_PREFIX = "kv/blobs"
 KV_INDEX_PREFIX = "kv/index"
 
@@ -300,7 +293,7 @@ class BlobService:
     """Digest-keyed blob transport over the reserved ``cas`` storage_id.
 
     One instance per namespace — checkpoint chunks under ``chunks/``,
-    serialized executables under ``exec/blobs/`` — each with its own
+    spilled KV blocks under ``kv/blobs/`` — each with its own
     fault-point names so chaos tests can tear or drop either object kind
     independently. Shared guarantees:
 
@@ -308,7 +301,7 @@ class BlobService:
       shared_fs directories stay enumerable);
     - every read is sha256-verified against its key before it is served
       (:class:`BlobIntegrityError` on mismatch — a torn object can never
-      launder bad bytes into a restore or a deserialized executable);
+      launder bad bytes into a restore or a promoted KV block);
     - an optional local :class:`ChunkCache` serves repeat reads without
       touching the backend (itself digest-verified per hit);
     - ``fault_store`` / ``fault_drop`` / ``fault_load`` name the
@@ -429,7 +422,7 @@ class BlobService:
 
 def namespace_usage(inner: StorageManager, namespace: str) -> Dict[str, int]:
     """rel -> size for every object (blobs AND index files) under one
-    blob namespace (``exec``/``kv``) of the reserved ``cas`` storage_id."""
+    blob namespace (``kv``) of the reserved ``cas`` storage_id."""
     head = namespace.rstrip("/") + "/"
     try:
         listing = inner.list_files(CHUNK_NAMESPACE)
@@ -442,18 +435,18 @@ def namespace_usage(inner: StorageManager, namespace: str) -> Dict[str, int]:
 def sweep_namespace(inner: StorageManager, namespace: str,
                     budget_bytes: int) -> Dict[str, Any]:
     """LRU-by-mtime byte-budget sweep for one blob namespace; the
-    shared eviction path for ``cas/exec/`` and ``cas/kv/``.
+    eviction path of ``cas/kv/``.
 
     Deletes the oldest objects (by backend mtime, via the optional
     ``file_mtimes`` capability) until the namespace fits its budget.
     Objects are evicted individually — an index whose blob got swept
-    (or vice versa) is harmless, because both namespace clients
-    (storage/exec_cache.py, :class:`KVBlobStore`) treat ANY load
-    failure as a plain miss and re-create the pair on the next store.
+    (or vice versa) is harmless, because the namespace's client
+    (:class:`KVBlobStore`) treats ANY load failure as a plain miss and
+    re-creates the pair on the next store.
     Backends that cannot stat mtimes or delete per-object skip the
-    sweep gracefully (``swept: False``). Chunk GC never touches these
-    namespaces (structurally — see the CHUNK_PREFIX note), so this
-    sweep is their only eviction path.
+    sweep gracefully (``swept: False``). Chunk GC never touches the
+    namespace (structurally — see the CHUNK_PREFIX note), so this
+    sweep is its only eviction path.
     """
     usage = namespace_usage(inner, namespace)
     total = sum(usage.values())
@@ -499,7 +492,7 @@ class KVBlobStore:
     Third (durable, cross-process) level of the device → host → CAS
     hierarchy: exact K/V block payloads spilled by any replica land
     under ``cas/kv/`` and can warm a restarted or replacement replica
-    in another process. The layout mirrors the executable cache — a
+    in another process. The layout is a
     content-addressed pickle blob under ``kv/blobs/`` plus one small
     JSON index record per chain key under ``kv/index/`` — so the same
     integrity machinery applies: every blob read is sha256-verified,
@@ -703,24 +696,23 @@ class CASStorageManager(StorageManager):
             "chunks_uploaded": 0, "chunks_deduped": 0, "chunks_dropped": 0,
             "cache_hits": 0, "cache_misses": 0,
         }
-        # chunk-namespace client of the shared blob transport; the
-        # executable cache (exec_cache()) is the second client
+        # chunk-namespace client of the shared blob transport; the KV
+        # spill tier (kv_store()) is the second client
         self._chunks = BlobService(
             inner, CHUNK_PREFIX, cache=cache,
             fault_store="cas.chunk_upload", fault_drop="cas.chunk_drop",
             fault_load="cas.chunk_download", counter=self._count)
-        self._exec_cache: Optional[Any] = None
         self._kv_store: Optional[KVBlobStore] = None
-        # per-namespace byte budgets ("exec"/"kv") enforced by
+        # per-namespace byte budgets ("kv") enforced by
         # sweep_namespaces(); chunk GC keys on checkpoint references,
         # not bytes, so "chunks" is not budgetable here
         self._ns_budgets: Dict[str, int] = dict(namespace_budgets or {})
-        bad = set(self._ns_budgets) - {"exec", "kv"}
+        bad = set(self._ns_budgets) - {"kv"}
         if bad:
             raise ValueError(
                 f"unknown namespace budget(s): {sorted(bad)} "
-                "(budgetable namespaces: exec, kv)")
-        self._ns_evictions: Dict[str, int] = {"exec": 0, "kv": 0}
+                "(budgetable namespaces: kv)")
+        self._ns_evictions: Dict[str, int] = {"kv": 0}
 
     # -- telemetry ----------------------------------------------------------
 
@@ -764,8 +756,8 @@ class CASStorageManager(StorageManager):
     def _list_backend_chunks(self) -> Set[str]:
         """Digests present in the chunk namespace RIGHT NOW (fresh listing,
         no session memo) — what dedup re-verification checks against.
-        Executable-cache blobs (``exec/...``) are a different namespace
-        and never appear here."""
+        Spilled KV blobs (``kv/...``) are a different namespace and
+        never appear here."""
         return set(self._chunks.list_blobs())
 
     def _refresh_known_chunks(self) -> Set[str]:
@@ -1088,8 +1080,8 @@ class CASStorageManager(StorageManager):
         if not garbage:
             return
         try:
-            # only ever the chunk namespace: executable-cache entries
-            # (cas/exec/...) are referenced via their own index, live in a
+            # only ever the chunk namespace: spilled KV entries
+            # (cas/kv/...) are referenced via their own index, live in a
             # different BlobService prefix, and are structurally invisible
             # to this ref-count walk — never swept as orphan chunks
             self._chunks.delete(garbage)
@@ -1105,25 +1097,6 @@ class CASStorageManager(StorageManager):
                     len(garbage), storage_id, len(referenced & doomed))
 
     # -- stats (dct checkpoint stats) ----------------------------------------
-
-    def exec_cache(self) -> Any:
-        """The executable cache sharing this manager's backend: cached
-        XLA programs land in ``cas/exec/`` next to (but namespaced away
-        from) the checkpoint chunks. Built lazily — a trainer that never
-        AOT-compiles pays nothing. When the manager has a local chunk
-        cache, the executable blobs get their own LRU sibling under
-        ``<cache_path>/exec``."""
-        from determined_clone_tpu.storage import exec_cache as exec_mod
-
-        with self._lock:
-            if self._exec_cache is None:
-                local = None
-                if self._cache is not None:
-                    local = ChunkCache(os.path.join(self._cache.path, "exec"),
-                                       max_bytes=self._cache.max_bytes)
-                self._exec_cache = exec_mod.ExecutableCache(
-                    self._inner, cache=local)
-            return self._exec_cache
 
     def kv_store(self) -> KVBlobStore:
         """The KV spill tier sharing this manager's backend: spilled
@@ -1153,9 +1126,9 @@ class CASStorageManager(StorageManager):
 
     def storage_stats(self) -> Dict[str, Any]:
         """Durable store-wide dedup accounting + cache hit rate, broken
-        out per blob namespace (checkpoint chunks vs cached executables
-        — one aggregate would let a growing executable cache masquerade
-        as checkpoint growth).
+        out per blob namespace (checkpoint chunks vs spilled KV blocks —
+        one aggregate would let a growing KV tier masquerade as
+        checkpoint growth).
 
         dedup_ratio = logical chunked bytes across every checkpoint's
         manifests / physical bytes in the chunk namespace — >1 means
@@ -1165,13 +1138,6 @@ class CASStorageManager(StorageManager):
         listing = self._inner.list_files(CHUNK_NAMESPACE)
         physical = {rel: size for rel, size in listing.items()
                     if self._chunks.digest_of_rel(rel) is not None}
-        exec_blob_bytes = sum(
-            size for rel, size in listing.items()
-            if rel.startswith(EXEC_BLOB_PREFIX + "/"))
-        exec_blob_count = sum(
-            1 for rel in listing if rel.startswith(EXEC_BLOB_PREFIX + "/"))
-        exec_index_count = sum(
-            1 for rel in listing if rel.startswith(EXEC_INDEX_PREFIX + "/"))
         kv_bytes = sum(size for rel, size in listing.items()
                        if rel.startswith("kv/"))
         kv_objects = sum(1 for rel in listing if rel.startswith("kv/"))
@@ -1209,11 +1175,6 @@ class CASStorageManager(StorageManager):
             "namespaces": {
                 "chunks": {"objects": len(physical),
                            "bytes": chunk_bytes},
-                "exec": {"objects": exec_blob_count,
-                         "bytes": exec_blob_bytes,
-                         "executables": exec_index_count,
-                         "budget_bytes": self._ns_budgets.get("exec"),
-                         "evictions": self._ns_evictions.get("exec", 0)},
                 "kv": {"objects": kv_objects,
                        "bytes": kv_bytes,
                        "entries": kv_entries,

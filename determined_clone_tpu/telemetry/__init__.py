@@ -59,12 +59,9 @@ from determined_clone_tpu.telemetry.goodput import (
     read_goodput,
 )
 from determined_clone_tpu.telemetry.mesh import (
-    MULTICHIP_SCHEMA_VERSION,
     MeshStragglerDetector,
     device_lane_records,
-    format_multichip,
     per_device_completion_seconds,
-    validate_multichip,
 )
 from determined_clone_tpu.telemetry.metrics import (
     Counter,
@@ -97,20 +94,20 @@ from determined_clone_tpu.telemetry.tsdb import (
 __all__ = [
     "AlertRule", "CollectiveSummary", "Counter", "FlightRecorder",
     "GOODPUT_CATEGORIES", "Gauge", "GoodputJournal", "GoodputLedger",
-    "Histogram", "MULTICHIP_SCHEMA_VERSION", "MeshStragglerDetector",
+    "Histogram", "MeshStragglerDetector",
     "MetricsRegistry", "NULL_SPAN", "RequestArchive", "RuleEngine",
     "SLOEngine", "Span", "TSDBScraper", "Telemetry", "TimeSeriesDB",
     "Tracer", "check_conservation", "chrome_trace_events",
     "comm_compute_fraction", "device_lane_records", "export_collectives",
     "flight_summary", "flight_to_chrome_trace", "format_alerts",
-    "format_goodput",
-    "format_multichip", "format_slo", "merge_goodput", "null_span", "parse_hlo_collectives",
+    "format_goodput", "format_slo", "merge_goodput", "null_span",
+    "parse_hlo_collectives",
     "parse_prometheus_text", "per_device_completion_seconds",
     "read_flight", "read_goodput", "read_request_archive",
     "request_archive_summary", "request_chrome_trace", "request_records",
     "spans_from_profiler_samples", "stitch_chrome_trace", "stock_slo_rules",
     "telemetry_from_config", "to_chrome_trace", "validate_chrome_trace",
-    "validate_multichip", "write_chrome_trace",
+    "write_chrome_trace",
 ]
 
 
@@ -314,7 +311,7 @@ class Telemetry:
             self.goodput.publish_metrics()
         if self.flight is not None:
             # the black box gets a snapshot even when no profiler channel
-            # is wired (bench runs, unit tests, stripped-down subprocesses)
+            # is wired (unit tests, stripped-down subprocesses)
             self.flight.record_metrics(self.registry.snapshot(),
                                        batches_trained=batches_trained)
         if profiler is None:

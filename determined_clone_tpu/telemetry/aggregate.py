@@ -222,8 +222,8 @@ class ClusterMetricsAggregator:
                 st.experiment_id = int(experiment_id)
 
     def ingest_component(self, component: str, registry: Any) -> None:
-        """Fold a non-trial component's registry (runner, master, bench
-        parent) into the cluster view. Accepts a MetricsRegistry or a
+        """Fold a non-trial component's registry (runner, master,
+        fleet) into the cluster view. Accepts a MetricsRegistry or a
         ``snapshot()``-shaped dict; latest-wins per component."""
         snap = (registry.snapshot() if hasattr(registry, "snapshot")
                 else dict(registry))
@@ -411,7 +411,6 @@ class ClusterMetricsAggregator:
         lines.extend(self._goodput_lines(fams))
         lines.extend(self._serving_fleet_lines(fams))
         lines.extend(self._mesh_lines(fams))
-        lines.extend(self._exec_cache_lines(fams))
         lines.extend(self._staleness_lines())
         text = "\n".join(ln for ln in lines if ln)
         return text + ("\n" if text else "")
@@ -546,38 +545,6 @@ class ClusterMetricsAggregator:
             "slowest_request": slowest,
         }
 
-    def exec_cache_rollup(self, fams: Optional[Dict[str, Any]] = None
-                          ) -> Optional[Dict[str, Any]]:
-        """Cluster view of the persistent executable cache
-        (``xla_exec_cache_*`` from telemetry/xla.py + storage/
-        exec_cache.py) summed across every reporter — trainers and
-        serving replicas publish into the same ``cas/exec/`` namespace,
-        so the interesting number is fleet-wide: how many compiles were
-        skipped and how much compile wall-time that saved. None when no
-        reporter has touched the cache (caching off — the default)."""
-        fams = fams if fams is not None else self._families()
-
-        def total(name: str, key: str = "value") -> float:
-            return sum(float(s.get(key, 0))
-                       for _, s in fams.get(name, {}).get("children", []))
-
-        hits = total("xla_exec_cache_hits_total")
-        misses = total("xla_exec_cache_misses_total")
-        if not hits and not misses:
-            return None
-        load_count = total("xla_exec_cache_load_seconds", "count")
-        load_sum = total("xla_exec_cache_load_seconds", "sum")
-        return {
-            "hits": int(hits),
-            "misses": int(misses),
-            "hit_rate": (hits / (hits + misses)) if hits + misses else None,
-            "compile_time_saved_s": round(
-                total("xla_exec_cache_saved_seconds_total"), 4),
-            "load_seconds_total": round(load_sum, 4),
-            "mean_load_s": (round(load_sum / load_count, 4)
-                            if load_count else None),
-        }
-
     def mesh_rollup(self, fams: Optional[Dict[str, Any]] = None
                     ) -> Optional[Dict[str, Any]]:
         """Mesh view over the collective-accounting and straggler families
@@ -693,27 +660,6 @@ class ClusterMetricsAggregator:
                 '# EXEMPLAR dct_fleet_slowest_request'
                 f'{{request_id="{slowest["request_id"]}"}} '
                 f'{_fmt(slowest["latency_s"])}')
-        return lines
-
-    def _exec_cache_lines(self, fams: Dict[str, Any]) -> List[str]:
-        """``dct_exec_cache_*`` gauges for ``dump()`` — the scrapeable
-        shape of :meth:`exec_cache_rollup`."""
-        roll = self.exec_cache_rollup(fams)
-        if roll is None:
-            return []
-        lines = []
-        for name, key in (("dct_exec_cache_hits", "hits"),
-                          ("dct_exec_cache_misses", "misses"),
-                          ("dct_exec_cache_hit_rate", "hit_rate"),
-                          ("dct_exec_cache_saved_seconds",
-                           "compile_time_saved_s"),
-                          ("dct_exec_cache_mean_load_seconds",
-                           "mean_load_s")):
-            v = roll.get(key)
-            if v is None:
-                continue
-            lines.append(f"# TYPE {name} gauge")
-            lines.append(f"{name} {_fmt(v)}")
         return lines
 
     def _goodput_lines(self, fams: Dict[str, Any]) -> List[str]:
@@ -879,7 +825,6 @@ class ClusterMetricsAggregator:
             "goodput": self.goodput_rollup(fams),
             "serving_fleet": self.serving_fleet_rollup(fams),
             "mesh": self.mesh_rollup(fams),
-            "exec_cache": self.exec_cache_rollup(fams),
             "slo": self.slo_rollup(),
             "quantiles": quantiles,
             "counters": dict(sorted(counters.items())),
@@ -987,18 +932,6 @@ def format_summary(summary: Dict[str, Any]) -> str:
             out.append(
                 f"mesh comm fraction (worst program): "
                 f"{worst['fraction']:.1%} ({worst['program']})")
-    exec_cache = summary.get("exec_cache")
-    if exec_cache:
-        rate = exec_cache.get("hit_rate")
-        rate_s = f"{rate:.1%}" if rate is not None else "n/a"
-        mean_load = exec_cache.get("mean_load_s")
-        load_s = (f", mean load {mean_load:.4f}s"
-                  if mean_load is not None else "")
-        out.append(
-            f"exec cache: {exec_cache['hits']} hits / "
-            f"{exec_cache['misses']} misses ({rate_s}), "
-            f"saved {exec_cache['compile_time_saved_s']:.2f}s of "
-            f"compile{load_s}")
     slo = summary.get("slo")
     if slo:
         parts = []

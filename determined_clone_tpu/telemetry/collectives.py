@@ -20,8 +20,7 @@ partitioning, so they only appear in the **compiled** HLO
 - the sorted (kind, axis, count, bytes) tuples hash into a
   **collective-structure fingerprint**: two rounds that compiled the same
   communication pattern share it, and drift on an unchanged program
-  fingerprint means the partitioner changed its mind — the advisory
-  signal tools/bench_gate.py watches;
+  fingerprint means the partitioner changed its mind;
 - :func:`comm_compute_fraction` turns total collective bytes plus the
   program's cost-analysis FLOPs into an analytic comm-vs-compute
   fraction: ``comm_s / (comm_s + compute_s)`` with

@@ -36,7 +36,7 @@ TRAIN_MULT = 3.0
 
 # Published per-chip peaks (Google Cloud TPU documentation, per
 # generation): bf16 matmul FLOP/s, HBM bytes/s, and further down the ICI
-# bytes/s. ONE table — bench.py reads it too. The CPU number is a
+# bytes/s. ONE table. The CPU number is a
 # deliberately round order-of-magnitude estimate (tens of GFLOPs for a
 # few vector cores) — its job is to keep the plumbing exercised in CPU
 # tests, not to be a utilization claim. The provenance label says which.
@@ -238,8 +238,8 @@ def gpt_generation_flops(cfg: Any, prompt_len: int, new_tokens: int, *,
     """Total forward FLOPs to serve one request: one prefill of
     ``prompt_len`` plus ``new_tokens - 1`` incremental decode steps (the
     first generated token falls out of the prefill logits; decode step j
-    runs at context ``prompt_len + j``). The serving bench divides the
-    sum of this over all completed requests by wall-clock for a real
+    runs at context ``prompt_len + j``). Dividing the
+    sum of this over all completed requests by wall-clock gives a
     tokens-level MFU.
 
     ``prefill_from`` accounts for prefix sharing: positions before it
@@ -282,8 +282,7 @@ def gpt_speculative_step_flops(cfg: Any, draft_cfg: Any, context_len: int,
     sequence: k single-token draft proposals (each an incremental decode
     step of the draft model at its growing context) plus the target's
     k+1-token verify call. Returns ``{"draft", "verify", "total"}`` —
-    the per-emitted-token cost is ``total / (accepted + 1)``, which is
-    the quantity the acceptance-rate gate in tools/bench_gate.py guards.
+    the per-emitted-token cost is ``total / (accepted + 1)``.
     """
     c = int(context_len)
     draft = sum(gpt_decode_flops_per_token(draft_cfg, c + i)["total"]

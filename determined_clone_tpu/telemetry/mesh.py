@@ -22,10 +22,6 @@ gang time. This module gives each device its own observable identity:
   one device holding the gang back does. At most ONE device is flagged
   per window (the slowest), incrementing
   ``mesh_straggler_events_total{device=...}``.
-
-Also home to the versioned MULTICHIP artifact schema (the structured
-replacement for the dryrun's stdout tail): :func:`validate_multichip`
-is the round-trip contract tests and tools/bench_gate.py share.
 """
 from __future__ import annotations
 
@@ -35,10 +31,6 @@ import time
 from typing import Any, Deque, Dict, List, Optional
 
 from determined_clone_tpu.telemetry.xla import MAD_SIGMA_SCALE
-
-# Versioned structured MULTICHIP artifact (satellite of ISSUE 15): bump on
-# any breaking key change and teach validate_multichip both shapes.
-MULTICHIP_SCHEMA_VERSION = 1
 
 
 def per_device_completion_seconds(outputs: Any, t0: float
@@ -182,106 +174,8 @@ class MeshStragglerDetector:
         }
 
 
-def validate_multichip(obj: Any) -> List[str]:
-    """Structural check of a MULTICHIP artifact / bench multichip run
-    (schema_version 1). Returns problems; empty when valid."""
-    errors: List[str] = []
-    if not isinstance(obj, dict):
-        return ["multichip artifact must be a JSON object"]
-    ver = obj.get("schema_version")
-    if ver != MULTICHIP_SCHEMA_VERSION:
-        errors.append(f"schema_version must be {MULTICHIP_SCHEMA_VERSION}, "
-                      f"got {ver!r}")
-    n = obj.get("n_devices")
-    if not isinstance(n, int) or n < 1:
-        errors.append(f"n_devices must be a positive int, got {n!r}")
-    meshes = obj.get("meshes")
-    if not isinstance(meshes, dict) or not meshes:
-        errors.append("meshes must be a non-empty object keyed by axis")
-        meshes = {}
-    for axis, run in meshes.items():
-        where = f"meshes[{axis!r}]"
-        if not isinstance(run, dict):
-            errors.append(f"{where}: not an object")
-            continue
-        shape = run.get("mesh_shape")
-        if not isinstance(shape, dict) or not all(
-                isinstance(v, int) for v in shape.values()):
-            errors.append(f"{where}: mesh_shape must map axes to int sizes")
-        for key in ("scaling_efficiency", "throughput_samples_per_sec",
-                    "mfu_measured", "mfu_analytic"):
-            v = run.get(key)
-            if v is not None and not isinstance(v, (int, float)):
-                errors.append(f"{where}: {key} must be numeric or null")
-        coll = run.get("collectives")
-        if coll is not None and not isinstance(coll, dict):
-            errors.append(f"{where}: collectives must be an object")
-    peaks = obj.get("per_device_peak_bytes")
-    if peaks is not None:
-        if not isinstance(peaks, dict) or not all(
-                isinstance(v, (int, float)) for v in peaks.values()):
-            errors.append(
-                "per_device_peak_bytes must map device -> bytes")
-    return errors
-
-
-def format_multichip(artifact: Dict[str, Any]) -> str:
-    """Human rendering of one MULTICHIP artifact (``dct mesh --file``)."""
-    lines: List[str] = []
-    n = artifact.get("n_devices")
-    lines.append(f"multichip scaling: {n} x {artifact.get('platform', '?')} "
-                 f"devices (schema v{artifact.get('schema_version')})")
-    base = artifact.get("baseline") or {}
-    thr1 = base.get("throughput_samples_per_sec")
-    if isinstance(thr1, (int, float)):
-        lines.append(f"  baseline (1 device): {thr1:.2f} samples/s, "
-                     f"mfu {_pct(base.get('mfu_measured'))} measured / "
-                     f"{_pct(base.get('mfu_analytic'))} analytic")
-    for axis, run in sorted((artifact.get("meshes") or {}).items()):
-        if not isinstance(run, dict):
-            continue
-        eff = run.get("scaling_efficiency")
-        eff_s = f"{eff:.1%}" if isinstance(eff, (int, float)) else "n/a"
-        thr = run.get("throughput_samples_per_sec")
-        thr_s = f"{thr:.2f}" if isinstance(thr, (int, float)) else "n/a"
-        lines.append(
-            f"  {axis}: shape {run.get('mesh_shape')}, efficiency {eff_s}, "
-            f"{thr_s} samples/s, mfu {_pct(run.get('mfu_measured'))} "
-            f"measured / {_pct(run.get('mfu_analytic'))} analytic")
-        coll = run.get("collectives") or {}
-        ops = coll.get("ops") or {}
-        if ops:
-            parts = []
-            for kind, axes in sorted(ops.items()):
-                for ax, stats in sorted(axes.items()):
-                    parts.append(f"{kind}[{ax}]={stats.get('count')}")
-            lines.append(f"      collectives: {' '.join(parts)} "
-                         f"(fingerprint {coll.get('fingerprint', '?')[:12]})")
-        frac = run.get("comm_compute_fraction")
-        if isinstance(frac, (int, float)):
-            lines.append(f"      comm/compute fraction: {frac:.1%}")
-        strag = run.get("straggler") or {}
-        if strag.get("stragglers"):
-            lines.append(f"      stragglers: {strag['stragglers']} over "
-                         f"{strag.get('windows')} windows "
-                         f"{strag.get('by_device')}")
-    peaks = artifact.get("per_device_peak_bytes") or {}
-    if peaks:
-        worst = max(peaks, key=lambda d: peaks[d])
-        lines.append(f"  per-device peak bytes: {len(peaks)} devices, "
-                     f"max {peaks[worst]:.0f} on {worst}")
-    return "\n".join(lines)
-
-
-def _pct(v: Any) -> str:
-    return f"{v:.2%}" if isinstance(v, (int, float)) else "n/a"
-
-
 __all__ = [
-    "MULTICHIP_SCHEMA_VERSION",
     "MeshStragglerDetector",
     "device_lane_records",
-    "format_multichip",
     "per_device_completion_seconds",
-    "validate_multichip",
 ]

@@ -18,7 +18,7 @@ both fast to fire and fast to clear — the short window gates on "is it
 still happening", the long window on "does it matter".
 
 Requests land in coarse time buckets keyed off an injectable clock, so
-tests (and the bench) drive days of simulated traffic in microseconds.
+tests drive days of simulated traffic in microseconds.
 Everything is stdlib-only, thread-safe, and spawns no threads.
 """
 from __future__ import annotations
@@ -49,7 +49,7 @@ class SLOEngine:
     """Time-bucketed SLI accounting + burn-rate evaluation.
 
     ``record_request`` is the single ingest point — the fleet front door
-    calls it once per finished request, the bench and loadgen feed it
+    calls it once per finished request, loadgen feeds it
     directly. Buckets of ``bucket_s`` seconds hold ``[total, errors,
     latency_total, latency_slow]``; anything older than the longest
     window is pruned on write.

@@ -232,8 +232,8 @@ class Tracer:
     def span_summary(self) -> Dict[str, Dict[str, float]]:
         """Aggregate per span name: count / total_s / mean_ms / max_ms.
 
-        The table bench.py emits into the BENCH json, and the quick
-        "where did the time go" answer without loading the full trace.
+        The quick "where did the time go" answer without loading the
+        full trace.
         """
         out: Dict[str, Dict[str, float]] = {}
         for rec in self.events():

@@ -2,16 +2,14 @@
 
 The telemetry stack so far watches the *Python* side of the hot loop —
 spans time dispatches, ``xla_compiles_total`` counts cache growth — but
-the compiled program itself stayed a black box: compile time was invisible
-(ROADMAP item 4's persistent executable cache needs it to prove
-``compile_time_saved``). This module opens the box via JAX's AOT path
+the compiled program itself stayed a black box: compile time was
+invisible. This module opens the box via JAX's AOT path
 (``cost_analysis()`` FLOPs are recorded per program but feed no MFU: on
 TPU they leave the scan body and custom calls out, docs/observability.md):
 
 - :func:`aot_compile` replaces a jitted callable's first-call implicit
   compile with an explicit ``lower()`` / ``compile()`` whose wall time is
-  measured, whose lowered StableHLO text is fingerprinted (sha256 — the
-  keying groundwork for the content-addressed executable cache), and whose
+  measured, whose lowered StableHLO text is fingerprinted (sha256), and whose
   ``cost_analysis()`` FLOPs/bytes become per-program metrics. The returned
   callable runs the AOT executable (no double compile) and falls back to
   the original jit wrapper on argument-shape mismatch.
@@ -33,9 +31,8 @@ import dataclasses
 import hashlib
 import logging
 import statistics
-import threading
 import time
-from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
+from typing import Any, Callable, Deque, Dict, Optional, Tuple
 
 logger = logging.getLogger(__name__)
 
@@ -62,13 +59,6 @@ class CompileRecord:
     # when the compiled HLO text was unavailable or mesh-less
     collectives: Optional[Any] = None
     comm_fraction: Optional[float] = None
-    # persistent executable cache (storage/exec_cache.py): on a hit,
-    # compile_seconds above is the *load* time — the real compile
-    # happened in whichever process populated the cache and its wall
-    # time comes back as compile_time_saved_s
-    cache_hit: bool = False
-    cache_load_seconds: Optional[float] = None
-    compile_time_saved_s: Optional[float] = None
 
     def as_dict(self) -> Dict[str, Any]:
         out = {k: v for k, v in dataclasses.asdict(self).items()
@@ -115,21 +105,9 @@ def _memory_analysis(compiled: Any) -> Dict[str, float]:
 
 
 def fingerprint_stablehlo(text: str) -> str:
-    """sha256 of the lowered program text — the stable identity a
-    persistent executable cache would key on (with mesh + jaxlib version
-    alongside; see ROADMAP item 4)."""
+    """sha256 of the lowered program text: the program's identity in
+    the ``{program, fingerprint}`` labels of the compile metrics."""
     return hashlib.sha256(text.encode("utf-8")).hexdigest()
-
-
-def _default_exec_cache() -> Optional[Any]:
-    """The ambient persistent executable cache (storage/exec_cache.py),
-    or None — resolution must never fail the compile path."""
-    try:
-        from determined_clone_tpu.storage import exec_cache as exec_mod
-
-        return exec_mod.default_cache()
-    except Exception:  # pragma: no cover - defensive
-        return None
 
 
 def _dynamic_positions(example_args: Tuple[Any, ...],
@@ -206,7 +184,6 @@ def aot_compile(
     registry: Optional[Any] = None,
     tracer: Optional[Any] = None,
     mesh: Optional[Any] = None,
-    exec_cache: Optional[Any] = None,
 ) -> Tuple[Callable[..., Any], Optional[CompileRecord]]:
     """Explicitly lower + compile a jitted callable, capturing telemetry.
 
@@ -230,58 +207,24 @@ def aot_compile(
     ``xla_collective_*`` gauges plus an analytic comm-vs-compute fraction.
     The lowered StableHLO has none of this (collectives are *inserted* by
     partitioning), which is why the capture reads ``compiled.as_text()``.
-
-    With ``exec_cache`` (an :class:`~determined_clone_tpu.storage.
-    exec_cache.ExecutableCache`, or the ambient default when one is
-    installed) the compile is **cache-first**: the lowered program's
-    fingerprint keys a load attempt, a hit skips ``compile()`` entirely
-    (``record.cache_hit`` + ``compile_time_saved_s`` say so — and the
-    ``xla_compile`` span/goodput ``compile`` category shrink to the load
-    time), and a miss compiles then publishes for the next process. Any
-    deserialization mismatch degrades to the plain compile — the cache
-    can slow a cold start marginally, never break it.
     """
     try:
         t0 = time.perf_counter()
         lowered = fn.lower(*example_args)
         text = lowered.as_text()
         t1 = time.perf_counter()
-        cache = exec_cache if exec_cache is not None else _default_exec_cache()
-        compiled = None
-        key = None
-        hit_meta: Optional[Dict[str, Any]] = None
-        fingerprint = fingerprint_stablehlo(text)
-        if cache is not None:
-            try:
-                key = cache.key_for(fingerprint, mesh=mesh)
-                loaded = cache.load(key, registry=registry)
-                if loaded is not None:
-                    compiled, hit_meta = loaded
-            except Exception as exc:  # noqa: BLE001 - cache is an observer
-                logger.debug("exec cache unavailable for %s: %r",
-                             program, exc)
-        if compiled is None:
-            compiled = lowered.compile()
-            t2 = time.perf_counter()
-            if cache is not None and key is not None:
-                cache.store(key, compiled, program=program,
-                            compile_seconds=t2 - t1, registry=registry)
-        else:
-            t2 = time.perf_counter()
+        compiled = lowered.compile()
+        t2 = time.perf_counter()
         flops, bytes_accessed = _cost_analysis(compiled)
         record = CompileRecord(
             program=program,
-            fingerprint=fingerprint,
+            fingerprint=fingerprint_stablehlo(text),
             lower_seconds=t1 - t0,
             compile_seconds=t2 - t1,
             flops=flops,
             bytes_accessed=bytes_accessed,
             **_memory_analysis(compiled),
         )
-        if hit_meta is not None:
-            record.cache_hit = True
-            record.cache_load_seconds = hit_meta.get("load_seconds")
-            record.compile_time_saved_s = hit_meta.get("compile_seconds")
     except Exception as exc:  # noqa: BLE001 - capture must never fail training
         # training goes on with the implicit compile, but a lost capture
         # must be seen: no compile record, no measured-FLOPs MFU
@@ -391,159 +334,13 @@ def export_compile_record(record: CompileRecord, *,
                 "xla_program_temp_bytes",
                 "executable scratch memory from memory_analysis()",
                 labels=labels).set(record.temp_bytes)
-        if record.cache_hit and record.compile_time_saved_s:
-            registry.counter(
-                "xla_exec_cache_saved_seconds_total",
-                "compile wall-time skipped via the persistent executable "
-                "cache (the populating process's measured compile time)"
-            ).inc(float(record.compile_time_saved_s))
     if tracer is not None:
         tracer.record_span(
             "xla_compile",
             start if start is not None else time.perf_counter(),
             record.lower_seconds + record.compile_seconds,
             program=record.program, fingerprint=record.fingerprint[:16],
-            explicit=True, cache_hit=record.cache_hit)
-
-
-class AotDispatcher:
-    """Multi-shape AOT front end over ONE jitted callable, backed by the
-    persistent executable cache.
-
-    ``jax.jit``'s internal cache cannot be populated from outside, so a
-    deserialized executable (storage/exec_cache.py) needs its own
-    dispatch: this wrapper keys AOT-compiled (or cache-loaded)
-    executables by argument *shape signature* — mirroring jit's own
-    specialization rule: arrays by ``(shape, dtype)``, Python scalars by
-    type (jit specializes them on weak dtype, not value), static
-    arguments (hashable configs) by value — and falls back to the
-    underlying jit wrapper for any signature it has not warmed (where
-    ``wrap_jit`` counts the retrace, exactly as before).
-
-    :meth:`warm` is the warmup-ladder entry point: cache-first
-    load-or-compile for the given argument signature, then *execute* (the
-    serving warmup relies on execution for its donation/pool round-trip
-    semantics). A fully warmed dispatcher never touches the jit cache —
-    which is how a second process achieves zero compiles.
-
-    The ``_cache_size`` probe counts resident executables PLUS the
-    underlying jit cache (fallback compiles), so the engine's
-    compile-discipline budget (``programs_compiled() <=
-    program_budget()``) keeps meaning what it meant.
-    """
-
-    def __init__(self, fn: Callable[..., Any], *, program: str,
-                 exec_cache: Optional[Any] = None,
-                 registry: Optional[Any] = None,
-                 tracer: Optional[Any] = None,
-                 mesh: Optional[Any] = None) -> None:
-        self._fn = fn
-        self.program = program
-        self._exec_cache = exec_cache
-        self._registry = registry
-        self._tracer = tracer
-        self._mesh = mesh
-        self._execs: Dict[Any, Callable[..., Any]] = {}
-        self._records: List[CompileRecord] = []
-        self._lock = threading.Lock()
-        # the engine's programs_compiled() dedups entry points by
-        # __wrapped__ identity (two jit wrappers over one function share
-        # a cache); keep that contract
-        self.__wrapped__ = getattr(fn, "__wrapped__", fn)
-        self.__name__ = f"aot_dispatch_{program}"
-
-    def bind_telemetry(self, registry: Optional[Any] = None,
-                       tracer: Optional[Any] = None) -> None:
-        """Late-bind the registry/tracer compile records export to (the
-        engine owns them, but the dispatcher is built first — and a
-        fleet-shared dispatcher rebinds to each new replica)."""
-        self._registry = registry
-        self._tracer = tracer
-
-    @staticmethod
-    def _keyify(x: Any) -> Any:
-        if hasattr(x, "shape") and hasattr(x, "dtype"):
-            return ("arr", tuple(x.shape), str(x.dtype))
-        if isinstance(x, (bool, int, float, complex)):
-            return ("py", type(x).__name__)
-        return x  # static hashable (frozen configs, strings)
-
-    def _shape_key(self, args: Tuple[Any, ...]) -> Any:
-        import jax
-
-        leaves, treedef = jax.tree_util.tree_flatten(args)
-        return (treedef, tuple(self._keyify(leaf) for leaf in leaves))
-
-    def warm(self, *args: Any) -> Any:
-        """Make the executable for this argument signature resident —
-        cache-first load, compile-and-publish on miss — then run it."""
-        try:
-            key = self._shape_key(args)
-        except Exception:  # unhashable static arg: jit handles it
-            return self._fn(*args)
-        with self._lock:
-            exec_ = self._execs.get(key)
-        if exec_ is None:
-            call, record = aot_compile(
-                self._fn, args, program=self.program,
-                registry=self._registry, tracer=self._tracer,
-                mesh=self._mesh, exec_cache=self._exec_cache)
-            if record is None:
-                # AOT unavailable (backend quirk): plain jit path
-                return self._fn(*args)
-            with self._lock:
-                exec_ = self._execs.setdefault(key, call)
-                if exec_ is call:
-                    self._records.append(record)
-        return exec_(*args)
-
-    def __call__(self, *args: Any, **kwargs: Any) -> Any:
-        if kwargs:
-            return self._fn(*args, **kwargs)
-        try:
-            key = self._shape_key(args)
-        except Exception:
-            return self._fn(*args)
-        with self._lock:
-            exec_ = self._execs.get(key)
-        if exec_ is not None:
-            # aot_compile's wrapper falls back to the jit cache itself on
-            # an argument mismatch, so this can't strand a request
-            return exec_(*args)
-        return self._fn(*args)
-
-    def _cache_size(self) -> int:
-        return len(self._execs) + self.fallback_compiles()
-
-    def fallback_compiles(self) -> int:
-        """Programs that went through the underlying jit cache instead of
-        an AOT executable — a warm process should report 0."""
-        probe = getattr(self._fn, "_cache_size", None)
-        if not callable(probe):
-            return 0
-        try:
-            return int(probe())
-        except Exception:
-            return 0
-
-    def records(self) -> List[CompileRecord]:
-        return list(self._records)
-
-    def cache_summary(self) -> Dict[str, Any]:
-        """Hit/miss/saved-seconds accounting across this dispatcher's
-        compile captures (bench + warm-start harness read this)."""
-        recs = self.records()
-        hits = sum(1 for r in recs if r.cache_hit)
-        saved = sum(r.compile_time_saved_s or 0.0 for r in recs)
-        spent = sum(r.compile_seconds for r in recs if not r.cache_hit)
-        return {
-            "programs": len(recs),
-            "exec_cache_hits": hits,
-            "exec_cache_misses": len(recs) - hits,
-            "compile_time_saved_s": round(saved, 4) if hits else None,
-            "compile_seconds": round(spent, 4),
-            "fallback_compiles": self.fallback_compiles(),
-        }
+            explicit=True)
 
 
 class StepTimeAnomalyDetector:
@@ -620,7 +417,6 @@ class StepTimeAnomalyDetector:
 
 
 __all__ = [
-    "AotDispatcher",
     "CompileRecord",
     "StepTimeAnomalyDetector",
     "aot_compile",

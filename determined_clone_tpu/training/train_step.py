@@ -180,7 +180,6 @@ def capture_compile(
     registry: Optional[Any] = None,
     tracer: Optional[Any] = None,
     mesh: Optional[Mesh] = None,
-    exec_cache: Optional[Any] = None,
 ) -> Tuple[Callable[..., Any], Optional[Any]]:
     """Explicit ``lower()``/``compile()`` capture for a built step.
 
@@ -195,20 +194,12 @@ def capture_compile(
     jit cache on a shape mismatch (remainder batches). ``example_args``
     contribute shapes only; nothing runs during lowering. On any failure
     the original ``step`` comes back with a ``None`` record.
-
-    With a persistent executable cache — explicit ``exec_cache``, or the
-    ambient default a ``DCT_EXEC_CACHE=1`` CAS-backed run installs
-    (core/_context.py) — the capture is cache-first: a restart leg loads
-    the serialized train-step executable from ``cas/exec/`` instead of
-    recompiling, and the goodput ``compile`` category collapses to the
-    load time (``record.cache_hit``/``compile_time_saved_s`` say so).
     """
     from determined_clone_tpu.telemetry import xla as xla_telemetry
 
     return xla_telemetry.aot_compile(
         step, example_args, program=program,
-        registry=registry, tracer=tracer, mesh=mesh,
-        exec_cache=exec_cache)
+        registry=registry, tracer=tracer, mesh=mesh)
 
 
 def param_count(tree: Any) -> int:
@@ -222,7 +213,7 @@ def program_cache_size(fn: Any) -> Optional[int]:
     """Best-effort size of a jitted callable's compilation cache, or None
     when this jax version doesn't expose it. Growth between two reads means
     a (re)trace+compile happened — ``telemetry.Telemetry.wrap_jit`` and
-    ``bench.py`` use this to count XLA compiles; traced wrappers propagate
+    tests use this to count XLA compiles; traced wrappers propagate
     the probe so the count survives instrumentation."""
     probe = getattr(fn, "_cache_size", None)
     if probe is None:

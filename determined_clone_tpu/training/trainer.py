@@ -378,8 +378,23 @@ class Trainer:
 
         batch_gen = batches()
 
+        one_process = jax.process_count() == 1
+
         def to_device(batch: Any) -> Any:
-            return jax.device_put(batch, batch_sharding)
+            if one_process:
+                return jax.device_put(batch, batch_sharding)
+            # across processes device_put first checks, with an all-gather,
+            # that every process passed the same value. Issued from the
+            # prefetch thread, that collective and the step's are launched
+            # in an order that differs between processes, and the gang
+            # deadlocks. Every process holds the whole batch: each places
+            # the shards its own devices hold and asks no other.
+            def place(x: Any, sharding: NamedSharding) -> jax.Array:
+                x = np.asarray(x)
+                return jax.make_array_from_callback(
+                    x.shape, sharding, x.__getitem__)
+
+            return jax.tree.map(place, batch, batch_sharding)
 
         # async device prefetch: a producer thread overlaps host input +
         # device_put with XLA compute (depth 0 = the old synchronous path);

@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives.
 
 One rule for every entry point that compiles (``chip_smoke.py``,
-``exec/trial.py``, ``dct serve`` / ``dct fleet up``, ``bench.py``): where
+``exec/trial.py``, ``dct serve`` / ``dct fleet up``): where
 ``JAX_COMPILATION_CACHE_DIR`` is set, JAX's own handling of it stands and
 nothing here sets another directory; where it is not, the cache goes to
 ``<repo>/.jax_cache`` (git-ignored). The directory is part of the cache
@@ -9,9 +9,6 @@ key's lookup, so it is a fixed path — never a temporary name, a process id
 or a timestamp, which could not hit twice. Programs of any compile time are
 kept (JAX's default keeps only those that took a second), unless
 ``JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS`` says otherwise.
-
-``storage/exec_cache.py`` (``DCT_EXEC_CACHE_DIR``) is a separate mechanism
-and is not touched here.
 """
 from __future__ import annotations
 

@@ -1,7 +1,7 @@
 """Steer JAX onto a virtual multi-device CPU host platform.
 
-Used by tests/conftest.py, __graft_entry__.dryrun_multichip and
-parallel/scaling_bench.py: code that wants an n-"chip" mesh on one box
+Used by tests/conftest.py and __graft_entry__.dryrun_multichip: code
+that wants an n-"chip" mesh on one box
 (the reference's "artificial slots" trick,
 agent/internal/detect/detect.go:39-56, recast as XLA host devices) sets the
 platform and the forced device count here.

@@ -10,7 +10,7 @@
 # `./run_tests.sh --observability` runs just the telemetry + profiler
 # surface (docs/observability.md): the telemetry core, profiler/tensorboard
 # shipping, the observability config round-trip, the XLA/device lane +
-# flight recorder + goodput ledger + bench result schema, and the static
+# flight recorder + goodput ledger, and the static
 # checks. The goodput suite skips cleanly under DCT_TELEMETRY_DISABLED=1.
 #
 # `./run_tests.sh --lint` runs the dctlint static-analysis suite over the
@@ -26,8 +26,7 @@
 #
 # `./run_tests.sh --storage` runs the checkpoint-storage surface
 # (docs/checkpoint_storage.md): backends, the content-addressed store +
-# transfer pool, the persistent executable cache, and the storage-facing
-# fault-tolerance paths.
+# transfer pool, and the storage-facing fault-tolerance paths.
 #
 # `./run_tests.sh --control-plane` runs the control-plane observability
 # surface (docs/observability.md): scheduler lifecycle telemetry,
@@ -52,22 +51,14 @@
 #
 # `./run_tests.sh --multichip` runs the mesh-observability surface
 # (docs/parallelism.md) on the simulated 8-device mesh: collective
-# accounting, straggler detection, per-device lanes, the MULTICHIP
-# artifact schema, plus the sharding/mesh suites the lane builds on.
+# accounting, straggler detection, per-device lanes, plus the
+# sharding/mesh suites the lane builds on.
 # The live-mesh tests skip cleanly when device forcing is unavailable
 # (they check len(jax.devices()) themselves).
-#
-# `./run_tests.sh --bench-gate` compares the two newest BENCH_r*.json
-# rounds via tools/bench_gate.py (default -5% samples/sec tolerance; the
-# new round must carry a non-null mfu — docs/observability.md).
-if [ "$1" = "--bench-gate" ]; then
+if [ "$1" = "--lint" ]; then
     shift
     exec env JAX_PLATFORMS=cpu \
-        python tools/bench_gate.py "$@"
-elif [ "$1" = "--lint" ]; then
-    shift
-    exec env JAX_PLATFORMS=cpu \
-        python -m tools.dctlint determined_clone_tpu tools bench.py "$@"
+        python -m tools.dctlint determined_clone_tpu tools "$@"
 elif [ "$1" = "--tier1" ]; then
     shift
     set -- tests/ -m "not slow" "$@"
@@ -78,7 +69,6 @@ elif [ "$1" = "--chaos" ]; then
 elif [ "$1" = "--storage" ]; then
     shift
     set -- tests/test_storage_backends.py tests/test_cas_store.py \
-        tests/test_exec_cache.py \
         tests/test_fault_tolerance.py -m "not slow" "$@"
 elif [ "$1" = "--control-plane" ]; then
     shift
@@ -108,7 +98,7 @@ elif [ "$1" = "--observability" ]; then
         tests/test_flight_recorder.py tests/test_goodput.py \
         tests/test_request_tracing.py tests/test_slo.py \
         tests/test_tsdb_rules.py \
-        tests/test_bench_schema.py tests/test_static_checks.py \
+        tests/test_static_checks.py \
         -m "not slow" "$@"
 fi
 exec env JAX_PLATFORMS=cpu \

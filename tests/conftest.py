@@ -98,7 +98,7 @@ def geometry(request):
 
     # the jit cache belongs to forward_paged, not to an engine, and those
     # modules' program-budget assertions count it: one geometry at a time
-    shared = make_paged_forward(exec_cache=False)
+    shared = make_paged_forward()
     shared.clear_cache()
     aligned = dataclasses.replace(cfg, d_model=128)
     yield aligned, gpt.init(jax.random.PRNGKey(0), aligned)

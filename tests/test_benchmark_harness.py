@@ -1,0 +1,66 @@
+"""The benchmark's own tests (``benchmarks/tests/``), as tier-1 runs them.
+
+They guard what the ledger's numbers are computed from: that every
+per-layer metric of ``BENCHMARK.json`` has a reader that agrees, the window
+arithmetic of the end-to-end rates, the reduction of a recorded v5e trace
+into scopes, idle gaps and collectives, the traffic's seeding, the float32
+references. Collected here, by name, is every one that writes to no
+directory another process shares. The rest are run by hand
+(``JAX_PLATFORMS=cpu python -m pytest benchmarks/tests -q``): whatever calls
+``train.run``, which empties ``benchmarks/.work``; a traced run, which
+writes ``benchmarks/.trace/<cell>``; and the untraced serve rehearsals,
+whose paged programs would count against the serving tests' program budgets
+in a worker they share (``jax.jit``'s cache is per function, not per test).
+"""
+import pytest
+
+pytest.register_assert_rewrite(
+    "benchmarks.tests.test_reference", "benchmarks.tests.test_scopes",
+    "benchmarks.tests.test_spec", "benchmarks.tests.test_stats",
+    "benchmarks.tests.test_trace", "benchmarks.tests.test_traffic")
+
+from benchmarks.tests.test_reference import (  # noqa: E402,F401
+    setup,
+    test_adamw_and_clip_match_optax,
+    test_forward_matches_gpt_apply_in_float32,
+    test_loss_and_gradients_match_the_programs_loss,
+    test_lower_precisions_move_the_logits,
+)
+from benchmarks.tests.test_scopes import (  # noqa: E402,F401
+    parsed,
+    recorded_spans,
+    test_annotation_and_sync_mark_place_a_span_alike,
+    test_buckets_partition_the_busy_time_of_the_recorded_trace,
+    test_decode_step_of_the_recorded_trace,
+    test_nothing_to_read_is_none_not_an_error,
+    test_pool_shapes_and_copies,
+    test_probe_is_small_and_parsed_once,
+    test_reduce_scopes_on_made_up_events,
+    test_scope_names_on_a_path,
+    test_self_times_add_up_to_the_union,
+    test_train_step_of_the_recorded_trace,
+)
+from benchmarks.tests.test_spec import (  # noqa: E402,F401
+    manifest,
+    test_every_cell_config_and_mix_has_its_file,
+    test_every_layer_metric_has_a_reader_that_agrees,
+    test_run_refuses_to_run_off_the_chip,
+)
+from benchmarks.tests.test_stats import (  # noqa: E402,F401
+    test_percentile_matches_numpy,
+    test_quartile_spread_is_the_contracts,
+    test_tokens_are_apportioned_to_the_window_by_time,
+    test_whole_units_lie_inside_the_window,
+)
+from benchmarks.tests.test_trace import (  # noqa: E402,F401
+    recorded,
+    test_collectives_are_told_by_opcode_not_by_operands,
+    test_merge,
+    test_program_spans_name_gaps_through_the_sync_mark,
+    test_recorded_trace_has_the_planes_the_reduction_reads,
+    test_reduction_of_the_recorded_trace,
+)
+from benchmarks.tests.test_traffic import (  # noqa: E402,F401
+    test_serve_requests_offer_every_seed_the_same_sizes,
+    test_train_batches_repeat_per_seed_and_rows_differ,
+)

@@ -144,7 +144,7 @@ def _compile_paged_forward(cfg, device, *, blocks, block, batch, t):
     def arr(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
-    compiled = make_paged_forward(exec_cache=False).lower(
+    compiled = make_paged_forward().lower(
         _shapes(params, one), cfg, arr((batch, t), jnp.int32),
         arr((batch, t), jnp.int32), arr((batch, t), jnp.bool_),
         arr((batch,), jnp.int32), k_pool, v_pool,
@@ -291,7 +291,7 @@ def test_evabyte_paged_forward_compiles_at_the_cell_sizes(v5e, batch, t):
     def arr(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
-    compiled = make_paged_forward(exec_cache=False).lower(
+    compiled = make_paged_forward().lower(
         params, cfg, arr((batch, t), jnp.int32), arr((batch, t), jnp.int32),
         arr((batch, t), jnp.bool_), arr((batch,), jnp.int32), k_pool, v_pool,
         arr((batch, layout.table_width), jnp.int32)).compile()
@@ -375,7 +375,7 @@ def test_smoke_phase_rehearsal_on_cpu(tmp_path, phase):
         # jit caches belong to the function, not the wrapper: every engine
         # in the process shares forward_paged's. Count from zero, and leave
         # nothing behind for the serving tests' program budgets.
-        shared = make_paged_forward(exec_cache=False)
+        shared = make_paged_forward()
         shared.clear_cache()
         try:
             out = chip_smoke.serve_phase(_TINY)
@@ -431,3 +431,69 @@ def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path,
         jax.config.update("jax_compilation_cache_dir", was)
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           was_min)
+
+
+# What a process compiles, run as a child so that its jit caches start
+# empty: the last line says what JAX's persistent cache was asked and gave.
+_CACHE_CHILD = """
+import collections, json, sys
+import jax
+from jax import monitoring
+from determined_clone_tpu.utils.compile_cache import configure_compile_cache
+
+events = collections.Counter()
+monitoring.register_event_listener(lambda name, **kw: events.update([name]))
+configure_compile_cache()
+from determined_clone_tpu.models import gpt
+cfg = gpt.GPTConfig.tiny()
+params = gpt.init(jax.random.PRNGKey(0), cfg)
+if sys.argv[1] == "serving_ladder":
+    from determined_clone_tpu.serving import (
+        BucketSpec, InferenceEngine, KVCacheConfig)
+    with InferenceEngine(params, cfg, buckets=BucketSpec.build(2, 8),
+                         cache=KVCacheConfig(8, 8)) as eng:
+        programs = eng.warmup()
+        assert programs == eng.program_budget()
+else:
+    import optax
+    from determined_clone_tpu.training.train_step import (
+        capture_compile, create_train_state, make_train_step)
+    tx = optax.adamw(1e-3)
+    state = create_train_state(params, tx, jax.random.PRNGKey(1))
+    tokens = jax.numpy.zeros((2, 17), jax.numpy.int32)
+    step = make_train_step(
+        lambda p, b, rng: gpt.loss_fn(p, cfg, b[:, :-1], b[:, 1:]), tx)
+    step, record = capture_compile(step, (state, tokens))
+    assert record is not None
+    jax.block_until_ready(step(state, tokens))
+    programs = 1
+prefix = "/jax/compilation_cache/"
+print(json.dumps({"programs": programs, **{
+    k: events[prefix + k] for k in (
+        "compile_requests_use_cache", "cache_hits", "cache_misses")}}))
+"""
+
+
+@pytest.mark.parametrize("what", ["serving_ladder", "trainer_step"])
+def test_second_process_compiles_from_the_persistent_cache(tmp_path, what):
+    """Two processes with one JAX_COMPILATION_CACHE_DIR: the first
+    compiles the engine's whole bucket ladder (or the trainer's captured
+    step) and writes it; the second is given every program by the cache,
+    by JAX's own count of hits and misses."""
+    import json
+
+    def child():
+        proc = subprocess.run(
+            [sys.executable, "-c", _CACHE_CHILD, what], cwd=REPO,
+            env={**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": REPO,
+                 "JAX_COMPILATION_CACHE_DIR": str(tmp_path)},
+            capture_output=True, text=True, timeout=300)
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        return json.loads(proc.stdout.splitlines()[-1])
+
+    cold, warm = child(), child()
+    assert cold["cache_hits"] == 0
+    assert cold["cache_misses"] >= cold["programs"] > 0
+    assert warm["cache_misses"] == 0
+    assert (warm["cache_hits"] == warm["compile_requests_use_cache"]
+            == cold["compile_requests_use_cache"])

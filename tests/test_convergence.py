@@ -5,12 +5,7 @@ best validation accuracy > 0.97). The build environment has no egress, so
 the real data is sklearn's bundled handwritten-digits scans
 (utils/data.py digits_dataset — genuine held-out split, same task family);
 the gate value carries over unchanged.
-
-Also pins the flagship GPT config's loss band: bench.py asserts measured
-loss against tests/data/loss_bands.json, so the bench catches regression,
-not just catastrophe (VERDICT r4 weak #5).
 """
-import json
 import sys
 from pathlib import Path
 
@@ -52,38 +47,3 @@ def test_digits_cnn_beats_097(tmp_path):
         best = max(r["metrics"]["accuracy"] for r in val)
         print(f"\n[convergence] digits best val accuracy: {best:.4f}")
         assert best > 0.97, f"accuracy {best:.4f} below the 0.97 gate"
-
-
-def test_loss_bands_file_well_formed():
-    bands = json.loads(
-        (REPO / "tests" / "data" / "loss_bands.json").read_text())
-    assert "gpt-tiny-cpu" in bands
-    for name, band in bands.items():
-        assert 0 < band["min"] < band["max"], (name, band)
-        assert band["max"] < 12, (name, band)  # sanity: ln(vocab) scale
-
-
-def test_bench_asserts_against_band():
-    """bench.py's loss gate must use the recorded band when one exists for
-    the config (regression detection), falling back to the uniform-entropy
-    catastrophe bound otherwise."""
-    sys.path.insert(0, str(REPO))
-    try:
-        import bench
-    finally:
-        sys.path.pop(0)
-    band = json.loads(
-        (REPO / "tests" / "data" / "loss_bands.json").read_text())[
-            "gpt-tiny-cpu"]
-    mid = (band["min"] + band["max"]) / 2
-    assert bench.loss_ok_for("gpt-tiny-cpu", mid, vocab=512)
-    # outside the band is a REGRESSION even though it beats ln(512)*1.05
-    above = band["max"] + 0.05
-    assert above < 1.05 * 6.24
-    assert not bench.loss_ok_for("gpt-tiny-cpu", above, vocab=512)
-    assert not bench.loss_ok_for("gpt-tiny-cpu", band["min"] - 0.3,
-                                 vocab=512)
-    # configs without a recorded band keep the catastrophe bound
-    assert bench.loss_ok_for("gpt-unbanded", 6.0, vocab=512)
-    assert not bench.loss_ok_for("gpt-unbanded", 7.0, vocab=512)
-    assert not bench.loss_ok_for("gpt-unbanded", float("nan"), vocab=512)

@@ -21,7 +21,7 @@ from tools.dctlint.core import _analyze_source  # noqa: E402
 from tools.dctlint.project import (  # noqa: E402
     ProjectIndex, module_name_for)
 
-TIER1_LINT_PATHS = ["determined_clone_tpu", "tools", "bench.py"]
+TIER1_LINT_PATHS = ["determined_clone_tpu", "tools"]
 PERF_BUDGET_S = 10.0
 
 

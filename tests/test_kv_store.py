@@ -35,6 +35,7 @@ from determined_clone_tpu.storage.cas import (
     namespace_usage,
     sweep_namespace,
 )
+from determined_clone_tpu.storage.transfer import TransferPool
 
 CFG = gpt.GPTConfig(vocab_size=97, n_layers=2, d_model=32, n_heads=4,
                     d_ff=64, max_seq_len=48, remat=False,
@@ -263,6 +264,70 @@ def test_manager_namespace_budgets_and_stats(tmp_path):
     assert ns["evictions"] == swept["kv"]["evicted"]
     # chunk GC / checkpoint accounting never counts kv objects
     assert stats["chunk_count"] == 0
+
+
+# -- the kv/ namespace beside the checkpoint chunks ---------------------------
+
+KV_KEY = {"fingerprint": "fp", "chain": "ab" * 32}
+
+
+def cas_with_kv_entry(tmp_path):
+    """A chunking manager whose store already holds one spilled block."""
+    inner = SharedFSStorageManager(str(tmp_path / "store"))
+    mgr = CASStorageManager(inner, chunk_size=1024,
+                            pool=TransferPool(workers=0))
+    assert mgr.kv_store().store(KV_KEY, payload(1)) is True
+    rels = set(namespace_usage(inner, "kv"))
+    assert len(rels) == 2  # one blob + one index entry
+    return mgr, inner, rels
+
+
+def upload_checkpoint(mgr, tmp_path, sid, nbytes):
+    src = tmp_path / "src"
+    src.mkdir(exist_ok=True)
+    (src / "weights.bin").write_bytes(os.urandom(nbytes))
+    mgr.upload(str(src), sid)
+
+
+def test_chunk_gc_never_sweeps_kv_entries(tmp_path):
+    mgr, inner, before = cas_with_kv_entry(tmp_path)
+    upload_checkpoint(mgr, tmp_path, "ck-1", 3 * 1024)
+    upload_checkpoint(mgr, tmp_path, "ck-2", 3 * 1024)
+    # ref-count GC runs on every delete; kv entries are structurally
+    # outside the chunk namespace it walks
+    mgr.delete("ck-2")
+    assert set(namespace_usage(inner, "kv")) == before
+    mgr.delete("ck-1")  # last checkpoint gone: chunks empty, kv intact
+    assert set(namespace_usage(inner, "kv")) == before
+    assert mgr.kv_store().load(KV_KEY) is not None
+
+
+def test_uncommitted_sweep_skips_the_cas_namespace(tmp_path, monkeypatch):
+    from determined_clone_tpu.exec.gc_checkpoints import sweep_uncommitted
+
+    _, inner, before = cas_with_kv_entry(tmp_path)
+    # age floor 0: everything uncommitted is sweepable — including the
+    # "cas" storage_id (never committed, no COMMIT marker) if the sweep
+    # failed to skip it
+    monkeypatch.setenv("DCT_GC_UNCOMMITTED_AGE_S", "0")
+    assert sweep_uncommitted(inner) == 0
+    assert set(namespace_usage(inner, "kv")) == before
+
+
+def test_storage_stats_splits_chunks_from_kv(tmp_path):
+    mgr, _, _ = cas_with_kv_entry(tmp_path)
+    upload_checkpoint(mgr, tmp_path, "ck-1", 4 * 1024)
+    stats = mgr.storage_stats()
+    ns = stats["namespaces"]
+    assert set(ns) == {"chunks", "kv"}
+    assert ns["chunks"]["objects"] == 4
+    assert ns["chunks"]["bytes"] == 4 * 1024
+    assert ns["kv"]["entries"] == 1
+    assert ns["kv"]["objects"] == 2  # blob + index
+    assert ns["kv"]["bytes"] > 0
+    # the top-level chunk accounting ignores kv blobs entirely
+    assert stats["chunk_count"] == 4
+    assert stats["chunk_bytes"] == 4 * 1024
 
 
 # -- router affinity (fake ports) -------------------------------------------

@@ -1,6 +1,6 @@
 """Mesh observability tests (ISSUE 15): collective accounting from
 post-SPMD HLO text, cross-device straggler detection, per-device Chrome
-trace lanes, the MULTICHIP artifact schema, and the cluster rollup.
+trace lanes, and the cluster rollup.
 
 HLO fixtures use both replica-group syntaxes the parser understands
 (explicit lists and the iota form) on a {dp: 4, tp: 2} logical mesh:
@@ -8,7 +8,6 @@ flattened partition ids arange(8).reshape(4, 2), so the dp groups are
 {{0,2,4,6},{1,3,5,7}} (vary dp, hold tp) and the tp groups are
 {{0,1},{2,3},{4,5},{6,7}}.
 """
-import json
 
 import jax
 import jax.numpy as jnp
@@ -28,12 +27,9 @@ from determined_clone_tpu.telemetry.collectives import (
     parse_replica_groups,
 )
 from determined_clone_tpu.telemetry.mesh import (
-    MULTICHIP_SCHEMA_VERSION,
     MeshStragglerDetector,
     device_lane_records,
-    format_multichip,
     per_device_completion_seconds,
-    validate_multichip,
 )
 
 MESH = {"dp": 4, "tp": 2}
@@ -300,84 +296,6 @@ class TestLiveMesh:
         durations = per_device_completion_seconds(y, t0)
         assert set(durations) == {f"cpu:{i}" for i in range(8)}
         assert all(d >= 0 for d in durations.values())
-
-
-def _artifact():
-    return {
-        "schema_version": MULTICHIP_SCHEMA_VERSION,
-        "n_devices": 8,
-        "platform": "cpu",
-        "baseline": {"throughput_samples_per_sec": 80.0,
-                     "mfu_measured": 0.06, "mfu_analytic": 0.08},
-        "meshes": {
-            "dp": {"mesh_shape": {"dp": 8, "tp": 1},
-                   "scaling_efficiency": 0.15,
-                   "throughput_samples_per_sec": 95.0,
-                   "mfu_measured": 0.009, "mfu_analytic": 0.011,
-                   "program_fingerprint": "aaaa",
-                   "comm_compute_fraction": 0.01,
-                   "straggler": {"windows": 2, "stragglers": 0,
-                                 "by_device": {}},
-                   "collectives": {"fingerprint": "ffff",
-                                   "ops": {"all-reduce": {
-                                       "dp": {"count": 17,
-                                              "bytes": 1.0}}}}},
-        },
-        "per_device_peak_bytes": {f"cpu:{i}": 1000.0 for i in range(8)},
-    }
-
-
-class TestMultichipSchema:
-    def test_round_trip_valid(self):
-        art = _artifact()
-        assert validate_multichip(art) == []
-        assert validate_multichip(json.loads(json.dumps(art))) == []
-
-    def test_rejects_bad_shapes(self):
-        assert validate_multichip([]) != []
-        art = _artifact()
-        art["schema_version"] = 99
-        assert any("schema_version" in e for e in validate_multichip(art))
-        art = _artifact()
-        art["meshes"] = {}
-        assert any("meshes" in e for e in validate_multichip(art))
-        art = _artifact()
-        art["meshes"]["dp"]["scaling_efficiency"] = "fast"
-        assert any("scaling_efficiency" in e
-                   for e in validate_multichip(art))
-        art = _artifact()
-        art["per_device_peak_bytes"] = {"cpu:0": "big"}
-        assert any("per_device_peak_bytes" in e
-                   for e in validate_multichip(art))
-
-    def test_format_renders_key_numbers(self):
-        text = format_multichip(_artifact())
-        assert "8 x cpu devices" in text
-        assert "efficiency 15.0%" in text
-        assert "all-reduce[dp]=17" in text
-        assert "per-device peak bytes: 8 devices" in text
-
-    def test_bench_gate_enforces_efficiency_regression(self):
-        import os
-        import sys
-        sys.path.insert(0, os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "tools"))
-        try:
-            from bench_gate import gate
-        finally:
-            sys.path.pop(0)
-        mc = {"runs": {"8": _artifact()}}
-        base = {"metric": "m", "value": 1.0,
-                "detail": {"platform": "cpu", "mfu": 0.1, "multichip": mc}}
-        worse = json.loads(json.dumps(base))
-        worse["detail"]["multichip"]["runs"]["8"]["meshes"]["dp"][
-            "scaling_efficiency"] = 0.05
-        ok, report = gate(base, worse)
-        assert not ok
-        assert any("FAIL: multichip" in ln for ln in report)
-        ok2, _ = gate(base, json.loads(json.dumps(base)))
-        assert ok2
 
 
 class TestClusterRollup:

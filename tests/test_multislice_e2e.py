@@ -139,14 +139,15 @@ def cluster(tmp_path_factory):
     master.wait(timeout=10)
 
 
-def wait_for(predicate, timeout=300, interval=1.0, desc="condition"):
+def wait_for(predicate, timeout=120, interval=1.0, desc="condition",
+             context=lambda: ""):
     deadline = time.time() + timeout
     while time.time() < deadline:
         result = predicate()
         if result:
             return result
         time.sleep(interval)
-    raise AssertionError(f"timed out waiting for {desc}")
+    raise AssertionError(f"timed out waiting for {desc}{context()}")
 
 
 def test_two_slice_gang_builds_ici_dcn_mesh(cluster):
@@ -169,18 +170,25 @@ def test_two_slice_gang_builds_ici_dcn_mesh(cluster):
         "max_restarts": 0,
     })
 
+    def log_tail():
+        # the agents' own files: the master holds a rank's lines only
+        # once the rank has exited
+        tails = [f"\n{path.parent.name}/{path.name}, last lines:\n"
+                 + "\n".join(line[:200] for line in path.read_text(
+                     errors="replace").splitlines()[-20:])
+                 for path in sorted(
+                     cluster["tmp"].glob("slice-*/task-*.log"))]
+        return "".join(tails) or "\n(no rank wrote a log)"
+
     def done():
         d = session.get_experiment(exp["id"])
         state = d["experiment"]["state"]
         if state == "ERRORED":
-            trial = d["trials"][0]
-            logs = session.task_logs(f"trial-{trial['id']}.0", limit=200)
             raise AssertionError(
-                "multislice experiment ERRORED:\n" +
-                "\n".join(l.get("log", "") for l in logs[-40:]))
+                "multislice experiment ERRORED:" + log_tail())
         return d if state == "COMPLETED" else None
 
-    detail = wait_for(done, desc="multislice completion")
+    detail = wait_for(done, desc="multislice completion", context=log_tail)
     trial = detail["trials"][0]
     assert trial["state"] == "COMPLETED"
 

@@ -1,7 +1,7 @@
 """Cluster observability plane (docs/observability.md): the analytic FLOPs
 engine, master-side aggregation (ingest gates, dedup, Prometheus rollups),
 the in-process master's HTTP front-end, cross-component trace stitching
-through a real experiment, and the bench regression gate."""
+through a real experiment."""
 import json
 import urllib.request
 
@@ -31,8 +31,6 @@ from determined_clone_tpu.telemetry.aggregate import (
 )
 from determined_clone_tpu.training import JaxTrial
 from determined_clone_tpu.utils.retry import RetryPolicy
-
-from tools import bench_gate
 
 
 # ---------------------------------------------------------------------------
@@ -386,62 +384,3 @@ class TestExperimentE2E:
                     if s[0] != "dct_master_source_age_seconds"]
 
         assert stable(raw) == stable(master.metrics_text())
-
-
-# ---------------------------------------------------------------------------
-# Bench regression gate
-# ---------------------------------------------------------------------------
-
-def _bench_result(value, platform="cpu", mfu=0.3):
-    return {"metric": "gpt_train_throughput", "value": value,
-            "detail": {"platform": platform, "mfu": mfu,
-                       "mfu_peak_assumed": "cpu:est" if mfu else None}}
-
-
-class TestBenchGate:
-    def test_wrapper_tail_parses(self, tmp_path):
-        wrapped = tmp_path / "BENCH_r01.json"
-        wrapped.write_text(json.dumps({
-            "n": 1, "cmd": "bench", "rc": 0,
-            "tail": "noise\n" + json.dumps(_bench_result(10.0)) + "\n",
-        }))
-        assert bench_gate.load_bench(str(wrapped))["value"] == 10.0
-
-    def test_within_tolerance_passes(self):
-        ok, _ = bench_gate.gate(_bench_result(100.0), _bench_result(96.0))
-        assert ok
-
-    def test_regression_fails(self):
-        ok, report = bench_gate.gate(_bench_result(100.0),
-                                     _bench_result(90.0))
-        assert not ok
-        assert any("FAIL" in line for line in report)
-
-    def test_null_mfu_fails_even_when_faster(self):
-        ok, _ = bench_gate.gate(_bench_result(100.0),
-                                _bench_result(200.0, mfu=None))
-        assert not ok
-        ok, _ = bench_gate.gate(_bench_result(100.0),
-                                _bench_result(200.0, mfu=None),
-                                allow_null_mfu=True)
-        assert ok
-
-    def test_platform_change_skips_throughput(self):
-        # TPU round vs CPU round: 10x slower but not a regression
-        ok, report = bench_gate.gate(
-            _bench_result(400.0, platform="tpu"),
-            _bench_result(40.0, platform="cpu"))
-        assert ok
-        assert any("platform changed" in line for line in report)
-
-    def test_cli_against_real_rounds(self, tmp_path):
-        # a previous round as the driver writes it (wrapper around the
-        # bench's last line, mfu still null) vs a synthetic new one
-        old = tmp_path / "BENCH_r05.json"
-        old.write_text(json.dumps({
-            "n": 5, "cmd": "python bench.py", "rc": 0,
-            "tail": json.dumps(_bench_result(40.589, mfu=None)) + "\n",
-        }))
-        new = tmp_path / "new.json"
-        new.write_text(json.dumps(_bench_result(41.0)))
-        assert bench_gate.main([str(old), str(new)]) == 0

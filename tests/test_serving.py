@@ -195,19 +195,6 @@ def test_eos_stops_early(params):
     assert r.tokens == ref[:stop]
 
 
-def test_static_baseline_matches_and_shares_programs(params):
-    """run_static (run-to-completion groups) must emit the same tokens —
-    same params, same greedy rule, same jitted programs — so the bench
-    comparison isolates scheduling policy alone."""
-    expected = [naive_greedy(params, p, n)
-                for p, n in zip(PROMPTS, (4, 9, 2))]
-    with make_engine(params) as eng:
-        out = eng.run_static(list(zip(PROMPTS, (4, 9, 2))), timeout=120.0)
-        compiled = eng.programs_compiled()
-    assert [r.tokens for r in out] == expected
-    assert 0 < compiled <= BUCKETS.program_budget
-
-
 def test_telemetry_spans_and_metrics(params):
     with make_engine(params) as eng:
         eng.generate(PROMPTS[0], 4)

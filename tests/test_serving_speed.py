@@ -281,20 +281,6 @@ def test_chunked_prefill_long_prompt_parity(params):
         assert eng.stats().free_blocks == CACHE.num_blocks
 
 
-def test_run_static_chunked_replay(params):
-    """run_static shares the chunked prefill path, so a chunked-engine
-    workload (long prompts included) replays under the static policy
-    with identical tokens — the bench's A/B depends on this."""
-    long_prompt = [i % 90 + 1 for i in range(20)]
-    reqs = [(long_prompt, 6), (PROMPTS[0], 6), (PROMPTS[2], 6)]
-    with make_engine(params, chunk_prefill_len=8) as eng:
-        cont = [eng.generate(p, mx) for p, mx in reqs]
-        static = eng.run_static(reqs, timeout=120.0)
-    for c, s in zip(cont, static):
-        assert s.tokens == c.tokens
-        assert s.finish_reason == "length"
-
-
 # -- all three at once: budgeted warmup, no mid-traffic compiles --------------
 
 def test_all_features_warmup_budget_and_parity():

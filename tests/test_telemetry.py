@@ -121,8 +121,12 @@ class TestThreadSafety:
     def test_spans_from_many_threads(self):
         tr = Tracer()
         n_threads, n_spans = 4, 200
+        # all four alive at once: the ident of a thread that has exited
+        # is handed to the next one started
+        alive = threading.Barrier(n_threads)
 
         def work(tid):
+            alive.wait()
             for i in range(n_spans):
                 with tr.span("w", i=i):
                     if i % 50 == 0:

@@ -76,9 +76,11 @@ def test_assets_with_content_types(master):
 
 
 def test_traversal_blocked(master):
-    # encoded and raw traversal must 404, never escape webui/
-    for path in ("/ui/..%2F..%2Fbench.py", "/ui/%2e%2e/secrets",
-                 "/ui/x/%2e%2e/%2e%2e/bench.py"):
+    # encoded and raw traversal must 404, never escape webui/; the target
+    # is a real file beside webui/, so a traversal that worked would serve it
+    assert b"import" in (WEBUI_DIR / ".." / "chip_smoke.py").read_bytes()
+    for path in ("/ui/..%2Fchip_smoke.py", "/ui/%2e%2e/secrets",
+                 "/ui/%2e%2e/chip_smoke.py"):
         try:
             status, _, body = fetch(master, path)
         except urllib.error.HTTPError as e:

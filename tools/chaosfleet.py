@@ -2,8 +2,8 @@
 """Chaos conductor CLI for the self-healing serving fleet.
 
 Drives the seeded scenario catalog in serving/chaos.py — kill -9
-mid-decode, wedged scheduler, torn warm-start blob, supervisor+replica
-double fault, poison pill, deadline storm — and reports the invariant
+mid-decode, wedged scheduler, supervisor+replica double fault, poison
+pill, KV-warm failover, deadline storm — and reports the invariant
 audit for each: zero lost accepted requests, bit-identical recovered
 outputs, zero leaked KV blocks, bounded MTTR. Exit 0 iff every scenario
 passed (docs/serving.md "Self-healing" for the catalog).
@@ -16,8 +16,7 @@ Usage:
     python tools/chaosfleet.py --selftest            # tier-1 smoke
 
 Importable: ``main(argv) -> int`` (tests/test_self_healing.py calls it);
-``run()`` in serving/chaos.py for in-process use (bench.py's advisory
-``recovery`` section rides the same runner).
+``run_scenarios()`` in serving/chaos.py for in-process use.
 """
 from __future__ import annotations
 

@@ -2,7 +2,7 @@
 pitfalls (ISSUE 3; catalog + workflow in docs/static_analysis.md).
 
 Run as ``python -m tools.dctlint [paths...]`` or ``dct lint``. Tier-1
-runs it over ``determined_clone_tpu/``, ``tools/`` and ``bench.py`` via
+runs it over ``determined_clone_tpu/`` and ``tools/`` via
 tests/test_static_checks.py, so new violations fail CI.
 """
 from tools.dctlint import checkers  # noqa: F401  (registers all checkers)
@@ -25,4 +25,4 @@ from tools.dctlint.project import (  # noqa: F401
     extract_facts,
 )
 
-DEFAULT_PATHS = ("determined_clone_tpu", "tools", "bench.py")
+DEFAULT_PATHS = ("determined_clone_tpu", "tools")

@@ -25,7 +25,7 @@ Usage:
     python tools/loadgen.py --trials 1000 --agents 8 --slots 8
     python tools/loadgen.py --trials 10000 --budget 300   # the 10k run
 
-Importable: ``run_load(trials=1000, ...) -> dict`` (bench.py calls this).
+Importable: ``run_load(trials=1000, ...) -> dict``.
 Never raises on an unavailable master build — returns ``{"error": ...}``.
 """
 from __future__ import annotations
@@ -660,7 +660,7 @@ def run_zipf_load(requests: int = 160, replicas: int = 4,
     over a prompt-template pool whose heads share a system prefix.
     ``kv_store=False`` is the per-replica prefix-cache baseline;
     ``kv_store=True`` (or a ``KVBlockStore``) turns on the shared
-    host/CAS tier plus router prefix affinity — the A/B bench.py runs.
+    host/CAS tier plus router prefix affinity.
 
     ``restart_at`` (a fraction of the burst) restarts one replica
     mid-burst through the drain protocol: the departing replica flushes
