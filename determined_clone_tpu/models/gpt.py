@@ -67,6 +67,7 @@ class GPTConfig:
     # The legacy blockwise_attention flag still selects "blockwise".
     attention_impl: str = "auto"
     blockwise_attention: bool = False
+    # K/V block of "blockwise" only; the flash kernel sizes its own blocks
     attention_block_size: int = 512
     tie_embeddings: bool = True
     # MoE (expert parallel over the ep mesh axis; 0 = dense FFN).
@@ -187,7 +188,7 @@ def init(key: jax.Array, cfg: GPTConfig) -> Params:
     return params
 
 
-def _flash(q: jax.Array, k: jax.Array, v: jax.Array, blk: int,
+def _flash(q: jax.Array, k: jax.Array, v: jax.Array,
            mesh: Optional[Any]) -> jax.Array:
     """Causal flash attention, per shard when ``mesh`` spans devices.
 
@@ -195,11 +196,13 @@ def _flash(q: jax.Array, k: jax.Array, v: jax.Array, blk: int,
     automatically partitioned"), so under a multi-device mesh the kernel
     runs inside ``shard_map``: each device attends over its own slice of
     the batch (dp, fsdp) and heads (tp). No collective is needed — rows
-    and heads never interact inside attention."""
+    and heads never interact inside attention. The kernel's custom VJP is
+    differentiated inside the ``shard_map``, so the backward kernels run
+    per shard too. Blocks are the kernel's own choice, from the shard's
+    shapes."""
     from determined_clone_tpu.ops.flash_attention import flash_attention
 
-    attend = functools.partial(flash_attention, causal=True, block_q=blk,
-                               block_k=blk)
+    attend = functools.partial(flash_attention, causal=True)
     if mesh is None or mesh.size == 1:
         return attend(q, k, v)
     return jax.shard_map(
@@ -232,16 +235,18 @@ def _block(cfg: GPTConfig, block_params: Params, x: jax.Array,
             attn = causal_blockwise_attention(
                 q, k, v, block_size=cfg.attention_block_size)
         elif impl == "flash":
-            blk = min(cfg.attention_block_size, 128)
-            # the kernel tiles T into blk-sized blocks; pad indivisible T
-            # (the everyday case: loss_fn slices tokens[:, :-1]) and slice
-            # back. Safe because attention is causal: real queries only
-            # ever see real keys (i < T), and padded rows are discarded.
-            pad = -T % blk
+            # the kernel tiles T into blocks of its own choosing; pad T to
+            # the multiple it asks for (the everyday case: loss_fn slices
+            # tokens[:, :-1], so 1023 -> 1024) and slice back. Safe because
+            # attention is causal: real queries only ever see real keys
+            # (i < T), and padded rows are discarded.
+            from determined_clone_tpu.ops.flash_attention import seq_multiple
+
+            pad = -T % seq_multiple(T)
             if pad:
                 q, k, v = (jnp.pad(t, ((0, 0), (0, pad), (0, 0), (0, 0)))
                            for t in (q, k, v))
-            attn = _flash(q, k, v, blk, mesh)
+            attn = _flash(q, k, v, mesh)
             if pad:
                 attn = attn[:, :T]
         else:

@@ -77,6 +77,19 @@ def test_described_chip_is_in_the_peak_table(v5e):
     assert flops.TPU_HBM_BYTES_PER_S[flops.TPU_DEVICE_KINDS[kind]] == 819e9
 
 
+def _flash_calls(hlo: str, bare: bool = True):
+    """The flash kernels among the Mosaic custom calls of an optimised HLO
+    text: their kernel names (``bare``; a grad taken outside a scan
+    prefixes the instruction with its transform, ``jvp_flash_fwd_``) or the
+    instructions' names as a device trace shows them."""
+    names = re.findall(r"%([\w.]+) = [^\n]*custom-call\([^\n]*"
+                       r'custom_call_target="tpu_custom_call"', hlo)
+    if not bare:
+        return names
+    return [re.search(r"flash_(fwd|bwd_dkv|bwd_dq)", n).group(0)
+            for n in names]
+
+
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
 def test_flash_kernel_compiles_at_gpt2_width(v5e, grad):
     one = SingleDeviceSharding(v5e[0])
@@ -86,30 +99,91 @@ def test_flash_kernel_compiles_at_gpt2_width(v5e, grad):
     if grad:
         fn = jax.grad(lambda q, k, v, f=fn: f(q, k, v).astype(
             jnp.float32).sum(), argnums=(0, 1, 2))
-    compiled = jax.jit(fn).lower(x, x, x).compile()
-    # the backward pass is the XLA blockwise path (flash_attention.py's
-    # custom VJP), and a bare grad leaves the forward kernel dead
-    assert ("tpu_custom_call" in compiled.as_text()) == (not grad)
+    calls = _flash_calls(jax.jit(fn).lower(x, x, x).compile().as_text())
+    # the backward pass is Pallas too and needs the forward's output and
+    # log-sum-exp, so a bare grad keeps the forward kernel alive
+    assert sorted(calls) == (["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+                             if grad else ["flash_fwd"])
 
 
 def test_flash_kernel_is_named_in_the_compiled_program(v5e):
     """The custom call is ``flash_fwd`` in the optimised HLO, which is the
     name its events carry in a device trace (PERF.md section 3)."""
-    import re
-
     one = SingleDeviceSharding(v5e[0])
     x = jax.ShapeDtypeStruct((8, 1024, 12, 64), jnp.bfloat16, sharding=one)
     fn = functools.partial(flash_mod.flash_attention, causal=True,
                            interpret=False)
     text = jax.jit(fn).lower(x, x, x).compile().as_text()
-    assert re.search(r"%flash_fwd[.\d]* = [^\n]*custom-call\([^\n]*"
-                     r'custom_call_target="tpu_custom_call"', text)
+    assert _flash_calls(text) == ["flash_fwd"]
+
+
+@pytest.mark.parametrize("shape", [(8, 1024, 16, 64), (2, 1024, 25, 64)],
+                         ids=["gpt2-medium", "gpt2-xl-per-chip"])
+def test_flash_grad_at_the_train_cells_shapes(v5e, shape):
+    """``grad`` at the two train cells' per-chip shapes, bf16: forward and
+    backward are Mosaic kernels, no score-shaped block (``[.., 1024, 128]``
+    and wider, or the XLA scan's stacked ``[8, 8, ...]``) reaches HBM, and
+    q/k/v enter the kernels as bf16, never converted to f32."""
+    one = SingleDeviceSharding(v5e[0])
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one)
+    fn = jax.grad(lambda q, k, v: flash_mod.flash_attention(
+        q, k, v, causal=True, interpret=False).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))
+    text = jax.jit(fn).lower(x, x, x).compile().as_text()
+    assert sorted(_flash_calls(text)) == [
+        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    assert " while(" not in text
+    B, T, H, D = shape
+    for dims in re.findall(r"(?:f32|bf16)\[([\d,]+)\]", text):
+        dims = [int(n) for n in dims.split(",")]
+        # nothing larger than an operand: a score block would be T x 128+
+        assert math.prod(dims) <= B * T * H * D, dims
+        assert dims[-2:] != [T, 128] and dims[:2] != [8, 8], dims
+    # the kernels' tensor operands are bf16 as they arrived and as they
+    # lie: medium's 16 heads two a lane block, xl's 25 as twelve pairs and
+    # one alone in a block that hangs over the edge
+    assert flash_mod.layout(H, D) == (True, 2, -(-H // 2))
+    operand = f"[{B},{T},{H * D}]"
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            operands = line.split("operand_layout_constraints={")[1]
+            operands = operands.split("}, frontend_attributes")[0]
+            assert "f32" + operand not in operands, operands
+            assert operands.count("bf16" + operand) >= 3, operands
+
+
+def test_train_step_names_its_kernels_as_the_benchmark_reads_them(
+        v5e, monkeypatch):
+    """In a scanned, rematerialised train step the instructions are
+    ``flash_fwd.N`` (forward and remat's second forward) and
+    ``flash_bwd_dkv.N`` / ``flash_bwd_dq.N``: the benchmark's
+    ``flash_fwd_device_ms`` takes every operation whose name starts with
+    ``flash_fwd`` (benchmarks/harness/scopes.py), so the backward's must
+    not, and all of them lie under the ``attn`` scope."""
+    monkeypatch.setattr(flash_mod, "_should_interpret", lambda: False)
+    one = SingleDeviceSharding(v5e[0])
+    cfg = gpt.GPTConfig(vocab_size=512, n_layers=2, d_model=256, n_heads=4,
+                        d_ff=512, max_seq_len=1024, remat=True,
+                        attention_impl="flash")
+    params = _shapes(jax.eval_shape(
+        lambda: gpt.init(jax.random.PRNGKey(0), cfg)), one)
+    tokens = jax.ShapeDtypeStruct((2, 1023), jnp.int32, sharding=one)
+    grad = jax.grad(lambda p, t: gpt.loss_fn(p, cfg, t, t))
+    text = jax.jit(grad).lower(params, tokens).compile().as_text()
+    names = sorted(n.split(".")[0] for n in _flash_calls(text, bare=False))
+    assert names == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd",
+                     "flash_fwd"], names
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            path = re.search(r'op_name="([^"]+)"', line).group(1)
+            assert "/attn/flash_" in path, path
 
 
 def test_flash_kernel_runs_per_shard_under_a_mesh(v5e, monkeypatch):
     """XLA refuses to partition a Mosaic kernel; gpt._flash shard_maps it
     over batch (dp, fsdp) and heads (tp) — the fault the four-chip compile
-    found before any chip time was spent."""
+    found before any chip time was spent. The backward kernels run inside
+    the same ``shard_map``'s transpose, per shard."""
     from jax.sharding import NamedSharding
 
     monkeypatch.setattr(flash_mod, "_should_interpret", lambda: False)
@@ -117,11 +191,19 @@ def test_flash_kernel_runs_per_shard_under_a_mesh(v5e, monkeypatch):
     x = jax.ShapeDtypeStruct((8, 1024, 12, 64), jnp.bfloat16,
                              sharding=NamedSharding(mesh, gpt.FLASH_QKV_SPEC))
     with pytest.raises(NotImplementedError, match="shard_map"):
-        jax.jit(lambda q, k, v: gpt._flash(q, k, v, 128, None)
+        jax.jit(lambda q, k, v: gpt._flash(q, k, v, None)
                 ).lower(x, x, x).compile()
-    compiled = jax.jit(lambda q, k, v: gpt._flash(q, k, v, 128, mesh)
+    compiled = jax.jit(lambda q, k, v: gpt._flash(q, k, v, mesh)
                        ).lower(x, x, x).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert _flash_calls(compiled.as_text()) == ["flash_fwd"]
+    grad = jax.grad(lambda q, k, v: gpt._flash(q, k, v, mesh).astype(
+        jnp.float32).sum(), argnums=(0, 1, 2))
+    text = jax.jit(grad).lower(x, x, x).compile().as_text()
+    assert sorted(_flash_calls(text)) == [
+        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    # each device's kernels see its own shard: 8 / 2 rows x 12 / 2 heads,
+    # read in place
+    assert "bf16[4,1024,384]" in text and "bf16[8,1024,768]" not in text
 
 
 def _compile_paged_forward(cfg, device, *, blocks, block, batch, t):

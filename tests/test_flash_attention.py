@@ -6,7 +6,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from determined_clone_tpu.ops.attention import mha
+from determined_clone_tpu.ops import flash_attention as flash_mod
+from determined_clone_tpu.ops.attention import NEG_INF, mha
 from determined_clone_tpu.ops.flash_attention import flash_attention
 
 
@@ -59,6 +60,176 @@ def test_gradients_match_reference():
     gr = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gf, gr):
         assert jnp.max(jnp.abs(a - b)) < 1e-3
+
+
+# (B, T, H, D, block_q, block_k). Read in place, heads side by side in a
+# lane block: two of D = 64 at one block whole and in pieces, four of
+# D = 32, one of D = 128; an odd head count, whose last lane block hangs
+# over the edge (five of D = 32: four and one; three of D = 64: two and
+# one), over several blocks, q blocks smaller and larger than k blocks.
+# Transposed to a head a row: D = 48
+PARITY_SHAPES = [
+    (1, 128, 2, 64, None, None),
+    (1, 512, 2, 64, None, None),
+    (1, 256, 4, 32, 128, 128),
+    (1, 256, 1, 128, 128, 128),
+    (1, 256, 5, 32, 128, 128),
+    (2, 256, 3, 64, 64, 128),
+    (1, 256, 2, 32, 128, 64),
+    (1, 128, 2, 48, None, None),
+]
+# worst element against fp32 mha on the same (rounded) inputs
+PARITY_TOL = {jnp.float32: 2e-4, jnp.bfloat16: 6e-2}
+
+
+def _parity_inputs(shape, dtype):
+    B, T, H, D, bq, bk = shape
+    q, k, v = (x.astype(dtype) for x in _qkv(B=B, T=T, H=H, D=D, seed=3))
+    as_f32 = tuple(x.astype(jnp.float32) for x in (q, k, v))
+    return (q, k, v), as_f32, dict(block_q=bq, block_k=bk)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("causal", [True, False],
+                         ids=["causal", "full"])
+@pytest.mark.parametrize("shape", PARITY_SHAPES,
+                         ids=lambda s: "x".join(str(n) for n in s))
+def test_forward_and_lse_match_mha(shape, causal, dtype):
+    (q, k, v), (qf, kf, vf), blocks = _parity_inputs(shape, dtype)
+    out = flash_attention(q, k, v, causal=causal, **blocks)
+    assert out.dtype == dtype
+    ref = mha(qf, kf, vf, causal=causal)
+    assert jnp.max(jnp.abs(ref - out.astype(jnp.float32))) \
+        < PARITY_TOL[dtype]
+
+    # the residual the backward recomputes probabilities from
+    B, T, H, D = q.shape
+    block = flash_mod.Blocks(
+        blocks["block_q"] or T, blocks["block_k"] or T,
+        flash_mod.block_sizes(T, T, D, dtype).fwd.tile)
+    lay = flash_mod.layout(H, D)
+    o, lse = flash_mod._fwd_call(
+        *(flash_mod.to_kernel_layout(x, lay) for x in (q, k, v)), lay=lay,
+        head_dim=D, causal=causal, block=block, interpret=True,
+        with_lse=True,
+        cost=flash_mod.flash_cost(B, H, T, T, D, causal, dtype)["flash_fwd"])
+    assert lse.shape == ((B * lay.groups, lay.heads, T) if lay.in_place
+                         else (B * H, 1, T))
+    assert lse.dtype == jnp.float32
+    scores = jnp.einsum("bqhd,bkhd->bhqk", qf, kf) / np.sqrt(D)
+    if causal:
+        scores = jnp.where(jnp.tril(jnp.ones((T, T), bool)), scores,
+                           NEG_INF)
+    want = jax.nn.logsumexp(scores, axis=-1)          # [B, H, T]
+    # a lane block short of heads carries rows for heads that are not there
+    assert jnp.max(jnp.abs(lse.reshape(B, -1, T)[:, :H] - want)) < 1e-3
+    assert jnp.array_equal(flash_mod.from_kernel_layout(o, lay, B, D), out)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("causal", [True, False],
+                         ids=["causal", "full"])
+@pytest.mark.parametrize("shape", PARITY_SHAPES,
+                         ids=lambda s: "x".join(str(n) for n in s))
+def test_gradients_match_mha(shape, causal, dtype):
+    (q, k, v), as_f32, blocks = _parity_inputs(shape, dtype)
+    w = jnp.asarray(np.random.RandomState(4).randn(*q.shape), jnp.float32)
+
+    def f_flash(q, k, v):
+        out = flash_attention(q, k, v, causal=causal, **blocks)
+        return (out.astype(jnp.float32) * w).sum()
+
+    def f_ref(q, k, v):
+        return (mha(q, k, v, causal=causal) * w).sum()
+
+    got = jax.grad(f_flash, argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(f_ref, argnums=(0, 1, 2))(*as_f32)
+    for name, a, b in zip("qkv", got, want):
+        assert a.dtype == dtype
+        gap = jnp.max(jnp.abs(a.astype(jnp.float32) - b))
+        assert gap < 5 * PARITY_TOL[dtype] * jnp.max(jnp.abs(b)), name
+
+
+def test_block_rule_at_the_cells_shapes_and_at_a_clamp():
+    """What ``block_sizes`` returns at the two train cells' per-chip
+    shapes (T = 1024, D = 64, bf16: a few hundred rows a side, the v5e
+    sweep of PERF.md section 6), and where a sequence or VMEM clamps it."""
+    cells = flash_mod.block_sizes(1024, 1024, 64, jnp.bfloat16)
+    for block in cells:
+        tq, tk = block.tiles
+        assert 128 <= tq <= 1024 and 128 <= tk <= 1024
+        assert block.q % tq == 0 and block.k % tk == 0
+        assert 1024 % block.q == 0 and 1024 % block.k == 0
+        assert (1024 // block.q) * (1024 // block.k) <= 16  # steps a head
+    # a sequence shorter than a block is one block, one piece
+    for block in flash_mod.block_sizes(64, 64, 32, jnp.float32):
+        assert (block.q, block.k) == (64, 64) == block.tiles
+    # a length that 1024 and 512 do not divide falls back to a multiple of
+    # 128 that does; one that is no multiple of 128 has to be padded first
+    for block in flash_mod.block_sizes(1152, 1152, 64, jnp.bfloat16):
+        assert 1152 % block.q == 0 and block.q % 128 == 0
+    with pytest.raises(ValueError, match="seq_multiple"):
+        flash_mod.block_sizes(1100, 1100, 64, jnp.bfloat16)
+    assert flash_mod.seq_multiple(1023) == 128 and -1023 % 128 == 1
+    assert flash_mod.seq_multiple(33) == 16
+    # a wide fp32 head shrinks the blocks under the VMEM budget
+    wide = flash_mod.block_sizes(8192, 8192, 1024, jnp.float32)
+    for narrow, cell in zip(wide, cells):
+        assert narrow.q * narrow.k < cell.q * cell.k
+    # heads reach the kernels side by side in lane blocks (medium: 16 of
+    # D = 64, two a block; xl: 25, twelve pairs and one alone), a head a
+    # row only where D neither divides 128 nor is a multiple of it
+    assert flash_mod.layout(16, 64) == (True, 2, 8)
+    assert flash_mod.layout(25, 64) == (True, 2, 13)
+    assert flash_mod.layout(8, 128) == (True, 1, 8)
+    assert flash_mod.layout(8, 96) == (False, 1, 1)
+    # pieces are square and only of a square block
+    assert flash_mod.Blocks(512, 512, 128).tiles == (128, 128)
+    assert flash_mod.Blocks(256, 512, 128).tiles == (256, 512)
+    assert flash_mod.Blocks(64, 64, 256).tiles == (64, 64)
+
+
+def _primitives(jaxpr, found):
+    """Names of every primitive of a jaxpr and of the jaxprs its equations
+    hold, without looking inside a kernel."""
+    for eqn in jaxpr.eqns:
+        found.append(eqn.primitive.name)
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                _primitives(sub, found)
+    return found
+
+
+def test_grad_is_three_kernels_and_no_scan():
+    """The backward is Pallas too: a jitted grad holds the forward kernel
+    and the two backward kernels and no ``scan`` / ``while`` (what
+    differentiating ``causal_blockwise_attention`` used to leave)."""
+    q, k, v = _qkv(T=256)
+    grad = jax.grad(lambda q, k, v: flash_attention(
+        q, k, v, block_q=128, block_k=128).sum(), argnums=(0, 1, 2))
+    names = _primitives(jax.make_jaxpr(grad)(q, k, v).jaxpr, [])
+    assert "scan" not in names and "while" not in names, names
+    assert names.count("pallas_call") == 3
+    assert not hasattr(flash_mod, "causal_blockwise_attention")
+
+
+def test_cost_counts_the_attended_pairs():
+    """``flash_cost``: causal attention over T keys is T (T + 1) / 2 pairs
+    a head (the benchmark's count, ``4 D (T + 1) / 2`` a token forward),
+    the backward kernels are 4 and 3 products, bytes are the operands
+    once."""
+    cost = flash_mod.flash_cost(8, 16, 1024, 1024, 64, True, jnp.bfloat16)
+    pairs = 8 * 16 * 1024 * 1025 // 2
+    assert cost["flash_fwd"].flops == 4 * 64 * pairs
+    assert cost["flash_bwd_dkv"].flops == 8 * 64 * pairs
+    assert cost["flash_bwd_dq"].flops == 6 * 64 * pairs
+    assert cost["flash_fwd"].transcendentals == pairs
+    tensor = 8 * 16 * 1024 * 64 * 2
+    assert cost["flash_fwd"].bytes_accessed == 4 * tensor + 8 * 16 * 1024 * 4
+    full = flash_mod.flash_cost(8, 16, 1024, 1024, 64, False, jnp.bfloat16)
+    assert full["flash_fwd"].flops == 4 * 64 * 8 * 16 * 1024 * 1024
 
 
 def test_bf16_inputs():
@@ -175,21 +346,33 @@ def test_flash_mha_loss_parity_over_training():
     assert curves["flash"][-1] < curves["flash"][0]
 
 
-def test_flash_pads_indivisible_seq_in_gpt():
+@pytest.mark.parametrize("seq_len", [50, 1023])
+def test_flash_pads_indivisible_seq_in_gpt(seq_len):
     """The everyday loss pattern slices tokens[:, :-1], giving T values
-    (e.g. 2047) not divisible by the kernel block. The model must pad and
-    slice transparently and still match mha numerics."""
+    (e.g. 1023) the kernel cannot tile. The model must pad to the multiple
+    the kernel asks for and slice transparently, and still match mha
+    numerics, gradients included."""
     import dataclasses
 
     from determined_clone_tpu.models import gpt
 
     cfg = gpt.GPTConfig(vocab_size=128, n_layers=2, d_model=64, n_heads=4,
-                        d_ff=128, max_seq_len=64, remat=False,
-                        attention_impl="flash", attention_block_size=32)
-    tokens = jax.random.randint(jax.random.PRNGKey(5), (2, 50), 0, 128)
+                        d_ff=128, max_seq_len=1024, remat=False,
+                        attention_impl="flash")
+    cfg_mha = dataclasses.replace(cfg, attention_impl="mha")
+    tokens = jax.random.randint(jax.random.PRNGKey(5), (1, seq_len + 1), 0,
+                                128)
     params = gpt.init(jax.random.PRNGKey(0), cfg)
-    logits_flash = gpt.apply(params, cfg, tokens)  # T=50, blk=32 -> pad 14
-    logits_mha = gpt.apply(
-        params, dataclasses.replace(cfg, attention_impl="mha"), tokens)
+    # T = 50 -> one block of 64; T = 1023 -> 1024 in several
+    logits_flash = gpt.apply(params, cfg, tokens[:, :-1])
+    logits_mha = gpt.apply(params, cfg_mha, tokens[:, :-1])
     assert logits_flash.shape == logits_mha.shape
     assert jnp.max(jnp.abs(logits_flash - logits_mha)) < 0.05
+
+    def loss(p, c):
+        return gpt.loss_fn(p, c, tokens[:, :-1], tokens[:, 1:])
+
+    g_flash = jax.grad(loss)(params, cfg)
+    g_mha = jax.grad(loss)(params, cfg_mha)
+    for a, b in zip(jax.tree.leaves(g_flash), jax.tree.leaves(g_mha)):
+        assert jnp.linalg.norm(a - b) <= 0.05 * jnp.linalg.norm(b) + 1e-6

@@ -89,6 +89,25 @@ def test_train_step_hlo_names_the_flash_kernel(train_hlo):
     assert "rematted_computation/attn/flash_fwd" in train_hlo
 
 
+@pytest.mark.parametrize("kernel", ["flash_bwd_dkv", "flash_bwd_dq"])
+def test_backward_kernels_lie_under_attn_and_are_not_named_forward(
+        train_hlo, kernel):
+    """The backward kernels' operations carry the ``attn`` scope on their
+    path, so ``train_attn_device_ms`` counts them, and their names do not
+    start with ``flash_fwd``, so ``flash_fwd_device_ms`` stays the forward
+    and remat's second forward (benchmarks/harness/scopes.py: bucket by
+    scope name on the path, the kernel part by ``startswith``)."""
+    import re
+
+    paths = set(re.findall(rf'"([^"]*/{kernel}/[^"]*)"', train_hlo))
+    assert paths, kernel
+    for path in paths:
+        names = path.split("/")
+        assert "attn" in names[:names.index(kernel)], path
+        assert "flash_fwd" not in names, path
+    assert not kernel.startswith("flash_fwd")
+
+
 def test_backward_of_a_scope_carries_its_name(train_hlo):
     assert "transpose(jvp(logits))" in train_hlo
 
