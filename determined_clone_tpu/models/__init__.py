@@ -3,10 +3,11 @@ from determined_clone_tpu.models import (
     bert,
     evabyte,
     gpt,
+    minicpm_sala,
     mlp,
     mnist_cnn,
     resnet,
     vit,
 )
 
-__all__ = ["bert", "evabyte", "gpt", "mlp", "mnist_cnn", "resnet", "vit"]
+__all__ = ["bert", "evabyte", "gpt", "minicpm_sala", "mlp", "mnist_cnn", "resnet", "vit"]

@@ -51,27 +51,42 @@ def _as_given(params: Any, cfg: Any) -> Any:
     return params
 
 
+def _kv_pools(cfg: Any, cache: Any, max_batch: int) -> Tuple[Any, ...]:
+    # imported here: serving/ imports the models at import time
+    from determined_clone_tpu.serving.kv_cache import init_kv_pools
+
+    return init_kv_pools(cfg, cache)
+
+
 @dataclasses.dataclass(frozen=True)
 class PagedModel:
     """One decoder family on the paged serving path.
 
     forward_paged(params, cfg, tokens, positions, token_mask, last_index,
-        k_pool, v_pool, block_tables) -> (logits [B, V], k_pool, v_pool):
-        a prefill slice or a decode step (``models/gpt.py:forward_paged``
-        states the contract); the engine samples ``argmax(logits)``.
+        *pools, block_tables) -> (logits [B, V], *pools): a prefill slice
+        or a decode step (``models/gpt.py:forward_paged`` states the
+        contract); the engine samples ``argmax(logits)``. ``pools`` are
+        the family's own (``init_pools``): the engine holds them as one
+        tuple, hands them over donated and keeps what comes back, and
+        looks into none of them.
     forward_paged_logits(the same minus last_index) -> (logits at every
-        position, k_pool, v_pool): the speculative verify step.
+        position, *pools): the speculative verify step.
     init(key, cfg) -> params.
+    init_pools(cfg, cache, max_batch) -> the tuple of zeroed device arrays
+        the cache lives in, one per name in ``pool_names``, for an engine
+        of ``max_batch`` rows. The default is the uniform cache's K and V
+        pools ``[L, N, block, R]`` (``kv_cache.init_kv_pools``), which is
+        GPT's and EvaByte's.
     serving_params(params, cfg) -> params: the tree the engine serves
         from, every leaf in the type the paged forward reads it in
         (:func:`cast_leaves`), so that no serving program converts a
         weight. Same structure, idempotent; the default keeps the tree.
     cache_layout(cfg, cache) -> serving/kv_cache.py:CacheLayout: block
-        kinds, reservation, table rows. Pools are
-        ``kv_cache.init_kv_pools(cfg, cache)`` for every family.
+        kinds, reservation, table rows.
     unsupported: ``ENGINE_FEATURES`` the family's cache cannot serve.
-    row_counters: one counter name per kind of ``cache_layout``'s, for the
-        cache rows a decode step attends; empty = not counted.
+    row_counters: one counter name per entry of ``cache_layout``'s
+        ``row_args``, for the cache rows a decode step attends; empty =
+        not counted.
     """
     family: str
     forward_paged: Callable[..., Any]
@@ -79,6 +94,8 @@ class PagedModel:
     init: Callable[..., Any]
     cache_layout: Callable[[Any, Any], Any]
     serving_params: Callable[[Any, Any], Any] = _as_given
+    init_pools: Callable[[Any, Any, int], Tuple[Any, ...]] = _kv_pools
+    pool_names: Tuple[str, ...] = ("k_pool", "v_pool")
     unsupported: Tuple[str, ...] = ()
     row_counters: Tuple[str, ...] = ()
 

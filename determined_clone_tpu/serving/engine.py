@@ -71,7 +71,6 @@ from determined_clone_tpu.serving.kv_cache import (
     BlockAllocator,
     KVCacheConfig,
     PrefixCache,
-    init_kv_pools,
 )
 from determined_clone_tpu.serving.kv_store import (
     PrefixInventory,
@@ -126,49 +125,51 @@ def serving_form(params: Any, model_cfg: Any, span: Any = null_span) -> Any:
 
 def forward_paged(params: Any, cfg: Any, tokens: jax.Array,
                   positions: jax.Array, token_mask: jax.Array,
-                  last_index: jax.Array, k_pool: jax.Array,
-                  v_pool: jax.Array, block_tables: jax.Array) -> Any:
+                  last_index: jax.Array, *pools_and_tables: jax.Array
+                  ) -> Any:
     """The paged forward of ``cfg``'s family: a prefill slice or a decode
-    step (``models/gpt.py:forward_paged`` states the contract). Argument
-    for argument the family's own, so the compiled program is too."""
+    step (``models/gpt.py:forward_paged`` states the contract) over the
+    family's pools, then the block tables. Argument for argument the
+    family's own, so the compiled program is too."""
     return cfg.paged_model().forward_paged(
-        params, cfg, tokens, positions, token_mask, last_index, k_pool,
-        v_pool, block_tables)
+        params, cfg, tokens, positions, token_mask, last_index,
+        *pools_and_tables)
 
 
 def forward_paged_logits(params: Any, cfg: Any, tokens: jax.Array,
                          positions: jax.Array, token_mask: jax.Array,
-                         k_pool: jax.Array, v_pool: jax.Array,
-                         block_tables: jax.Array) -> Any:
+                         *pools_and_tables: jax.Array) -> Any:
     """The family's paged forward with logits at every position."""
     return cfg.paged_model().forward_paged_logits(
-        params, cfg, tokens, positions, token_mask, k_pool, v_pool,
-        block_tables)
+        params, cfg, tokens, positions, token_mask, *pools_and_tables)
 
 
-def make_paged_forward() -> Any:
+def make_paged_forward(n_pools: int = 2) -> Any:
     """The jitted paged forward an engine runs everything through, for
     whichever family the (static) model config it is called with belongs
-    to.
+    to; ``n_pools`` (``len(PagedModel.pool_names)``) is how many pools
+    the family hands over, each donated.
     Replica fleets pass ONE of these to every engine (``fwd=``) so the
     whole fleet shares a single XLA program cache: replica N>1 warms up
     for free, and scale-up never pays a compile (all replicas serve the
     same model config and bucket ladder, so the shapes are identical)."""
     return jax.jit(forward_paged, static_argnums=(1,),
-                   donate_argnums=(6, 7))
+                   donate_argnums=tuple(range(6, 6 + n_pools)))
 
 
-def make_paged_verify() -> Any:
+def make_paged_verify(n_pools: int = 2) -> Any:
     """The jitted multi-logit forward the speculative verify step runs
     through: one [B, k+1] call scores the last committed token plus all
     k drafts; compiles one program per batch bucket."""
     return jax.jit(forward_paged_logits, static_argnums=(1,),
-                   donate_argnums=(5, 6))
+                   donate_argnums=tuple(range(5, 5 + n_pools)))
 
 
 def _block_copy(k_pool: jax.Array, v_pool: jax.Array,
                 src: jax.Array, dst: jax.Array):
-    """COW fork: duplicate one pool block (all layers) into another."""
+    """COW fork: duplicate one pool block (all layers) into another. The
+    uniform cache's two pools: only a family whose cache the prefix cache
+    can serve gets here."""
     return (k_pool.at[:, dst].set(k_pool[:, src]),
             v_pool.at[:, dst].set(v_pool[:, src]))
 
@@ -410,12 +411,20 @@ class InferenceEngine:
         self._params = serving_form(params, model_cfg, self._span)
         self._note_weight_bytes()
         self._pending_params: Any = None
-        self._allocator = BlockAllocator(cache)
-        self._k_pool, self._v_pool = init_kv_pools(model_cfg, cache)
+        # a state slot per batch row beside the blocks, where the layout
+        # has a kind of fixed count (none: an allocator of blocks alone)
+        self._allocator = BlockAllocator(
+            cache, slots=self.buckets.max_batch * self._layout.state_slots)
+        # the family's pools, as one tuple: handed to every forward
+        # donated, replaced by what it returns, never looked into
+        self._pools: Tuple[Any, ...] = tuple(
+            self._model.init_pools(model_cfg, cache,
+                                   self.buckets.max_batch))
         # fixed block-table width: every call sees the same W, so table
         # shape never causes a retrace
         self._table_width = self._layout.table_width
-        self._fwd = fwd if fwd is not None else make_paged_forward()
+        self._fwd = fwd if fwd is not None else make_paged_forward(
+            len(self._pools))
 
         # -- optional raw-speed features (module docstring) --------------
         self.chunk_prefill_len = int(chunk_prefill_len)
@@ -441,10 +450,13 @@ class InferenceEngine:
             # and the allocator) with the target's — only the per-block
             # payload shape differs — so prefix sharing and COW cover
             # the draft KV with zero extra bookkeeping
-            self._dk_pool, self._dv_pool = init_kv_pools(draft_cfg, cache)
-            self._draft_fwd = make_paged_forward()
-            self._verify_fwd = make_paged_verify()
+            self._draft_pools: Tuple[Any, ...] = tuple(
+                draft_cfg.paged_model().init_pools(
+                    draft_cfg, cache, self.buckets.max_batch))
+            self._draft_fwd = make_paged_forward(len(self._draft_pools))
+            self._verify_fwd = make_paged_verify(len(self._pools))
         else:
+            self._draft_pools = ()
             self._draft_params = None
             self.draft_cfg = None
             self._draft_fwd = None
@@ -514,11 +526,11 @@ class InferenceEngine:
                     "KV pool blocks held by running sequences, by kind",
                     labels={"kind": kind}) for kind in kinds]
         self._kind_blocks = [0] * len(kinds)
-        self._row_args = tuple(f"{kind}_rows" for kind in kinds)
+        self._row_args = self._layout.row_args
         self._c_rows = [
-            m.counter(name, f"{kind} cache rows attended by decode steps "
+            m.counter(name, f"{arg} of the cache attended by decode steps "
                             f"(at the rows' real lengths)")
-            for name, kind in zip(self._model.row_counters, kinds)]
+            for name, arg in zip(self._model.row_counters, self._row_args)]
         self._c_prefix_hit = m.counter(
             "prefix_cache_hit_blocks_total",
             "prompt blocks aliased from the prefix cache (prefill skipped)")
@@ -838,48 +850,45 @@ class InferenceEngine:
                     tables = jnp.zeros((b, self._table_width), jnp.int32)
                     for fwd, params, cfg in lanes:
                         for t in (*self.buckets.prefill_len_buckets, 1):
-                            logits, kp, vp = fwd(
+                            logits, *pools = fwd(
                                 params, cfg,
                                 jnp.zeros((b, t), jnp.int32),
                                 jnp.zeros((b, t), jnp.int32),
                                 jnp.zeros((b, t), bool),
                                 jnp.zeros((b,), jnp.int32),
                                 *self._pools_for(cfg), tables)
-                            self._set_pools_for(cfg, kp, vp)
+                            self._set_pools_for(cfg, pools)
                             # the sampling step is its own (tiny) program
                             # per batch bucket — leave it cold and the
                             # first real request pays its compile
                             jnp.argmax(logits, axis=-1).block_until_ready()
                     if self._spec_k:
                         t = self._spec_k + 1
-                        logits, self._k_pool, self._v_pool = self._verify_fwd(
+                        logits, *pools = self._verify_fwd(
                             self._params, self.model_cfg,
                             jnp.zeros((b, t), jnp.int32),
                             jnp.zeros((b, t), jnp.int32),
                             jnp.zeros((b, t), bool),
-                            self._k_pool, self._v_pool, tables)
+                            *self._pools, tables)
+                        self._pools = tuple(pools)
                         logits.block_until_ready()
                 if self._copy is not None:
-                    self._k_pool, self._v_pool = self._copy(
-                        self._k_pool, self._v_pool, 0, 0)
+                    self._pools = self._copy(*self._pools, 0, 0)
                     if self._spec_k:
-                        self._dk_pool, self._dv_pool = self._copy(
-                            self._dk_pool, self._dv_pool, 0, 0)
-                    jax.block_until_ready(self._k_pool)
+                        self._draft_pools = self._copy(
+                            *self._draft_pools, 0, 0)
+                    jax.block_until_ready(self._pools)
                 if self._write is not None:
                     # warmed by writing block 0's own contents back:
                     # materialize the slice BEFORE the donated call, so
                     # the write is bit-identical (all zeros at warmup)
-                    kb = jnp.array(self._k_pool[:, 0])
-                    vb = jnp.array(self._v_pool[:, 0])
-                    self._k_pool, self._v_pool = self._write(
-                        self._k_pool, self._v_pool, 0, kb, vb)
+                    blk = [jnp.array(p[:, 0]) for p in self._pools]
+                    self._pools = self._write(*self._pools, 0, *blk)
                     if self._spec_k:
-                        dkb = jnp.array(self._dk_pool[:, 0])
-                        dvb = jnp.array(self._dv_pool[:, 0])
-                        self._dk_pool, self._dv_pool = self._write(
-                            self._dk_pool, self._dv_pool, 0, dkb, dvb)
-                    jax.block_until_ready(self._k_pool)
+                        blk = [jnp.array(p[:, 0]) for p in self._draft_pools]
+                        self._draft_pools = self._write(
+                            *self._draft_pools, 0, *blk)
+                    jax.block_until_ready(self._pools)
         finally:
             with self._cond:
                 self._warming = False
@@ -990,8 +999,9 @@ class InferenceEngine:
         return n
 
     def kv_outstanding(self) -> int:
-        """KV blocks currently owned (active sequences + prefix-cache
-        retains). Zero on an idle engine with no prefix cache."""
+        """KV blocks and state slots currently owned (active sequences +
+        prefix-cache retains). Zero on an idle engine with no prefix
+        cache."""
         return self._allocator.outstanding()
 
     def assert_kv_balanced(self, expected_outstanding: int = 0) -> None:
@@ -1224,7 +1234,8 @@ class InferenceEngine:
             plen = len(head.req.prompt)
             total = plen + head.req.max_new_tokens
             by_kind = self._layout.blocks_by_kind(total)
-            need_total = sum(by_kind)
+            n_slots = self._layout.state_slots
+            need_total = sum(by_kind) - n_slots
             shared: List[int] = []
             fork_src: Optional[int] = None
             if self._prefix is not None:
@@ -1250,10 +1261,12 @@ class InferenceEngine:
                 skip = 0
                 kept = 0
                 need = need_total
-            if self._allocator.free_blocks() < need:
+            if (self._allocator.free_blocks() < need
+                    or self._allocator.free_slots() < n_slots):
                 if self._prefix is not None:
                     self._prefix.evict(need)
-                if self._allocator.free_blocks() < need:
+                if (self._allocator.free_blocks() < need
+                        or self._allocator.free_slots() < n_slots):
                     # defer admission; hand back the match references
                     if shared:
                         self._allocator.release(shared)
@@ -1273,7 +1286,9 @@ class InferenceEngine:
             else:
                 self._h_queue_wait.observe(now - head.submit_t)
             fresh = self._allocator.allocate_blocks(need)
-            a = _Active(head, shared + fresh, plen)
+            # the slots' ids come last, as the layout lays its table
+            a = _Active(head, shared + fresh
+                        + self._allocator.allocate_slots(n_slots), plen)
             a.by_kind = by_kind
             self._gauge_kinds(by_kind, +1)
             a.prefill_pos = skip
@@ -1301,9 +1316,9 @@ class InferenceEngine:
         pool slot shape/dtype (a config change or foreign entry must be
         a plain miss, never a bad scatter) and cover the draft pools
         when speculation is on."""
-        want = [("k", self._k_pool), ("v", self._v_pool)]
+        want = list(zip(("k", "v"), self._pools))
         if self._spec_k:
-            want += [("dk", self._dk_pool), ("dv", self._dv_pool)]
+            want += list(zip(("dk", "dv"), self._draft_pools))
         for name, pool in want:
             arr = payload.get(name) if isinstance(payload, dict) else None
             if arr is None:
@@ -1358,12 +1373,12 @@ class InferenceEngine:
         reference once its scatter lands."""
         writes, self._pending_writes = self._pending_writes, []
         for block, payload in writes:
-            self._k_pool, self._v_pool = self._write(
-                self._k_pool, self._v_pool, block,
+            self._pools = self._write(
+                *self._pools, block,
                 jnp.asarray(payload["k"]), jnp.asarray(payload["v"]))
             if self._spec_k:
-                self._dk_pool, self._dv_pool = self._write(
-                    self._dk_pool, self._dv_pool, block,
+                self._draft_pools = self._write(
+                    *self._draft_pools, block,
                     jnp.asarray(payload["dk"]), jnp.asarray(payload["dv"]))
             self._allocator.release([block])
             self._c_kv_promoted.inc()
@@ -1376,11 +1391,11 @@ class InferenceEngine:
         failed spill just means the block is gone, as before the tier
         existed."""
         try:
-            payload = {"k": np.asarray(self._k_pool[:, block]),
-                       "v": np.asarray(self._v_pool[:, block])}
+            payload = {"k": np.asarray(self._pools[0][:, block]),
+                       "v": np.asarray(self._pools[1][:, block])}
             if self._spec_k:
-                payload["dk"] = np.asarray(self._dk_pool[:, block])
-                payload["dv"] = np.asarray(self._dv_pool[:, block])
+                payload["dk"] = np.asarray(self._draft_pools[0][:, block])
+                payload["dv"] = np.asarray(self._draft_pools[1][:, block])
             self._kv_store.put(self._params_fp, key.hex(), payload)
         except Exception:  # noqa: BLE001 — demotion is best-effort
             return False
@@ -1471,11 +1486,9 @@ class InferenceEngine:
             if a.pending_copy is None:
                 continue
             src, dst = a.pending_copy
-            self._k_pool, self._v_pool = self._copy(
-                self._k_pool, self._v_pool, src, dst)
+            self._pools = self._copy(*self._pools, src, dst)
             if self._spec_k:
-                self._dk_pool, self._dv_pool = self._copy(
-                    self._dk_pool, self._dv_pool, src, dst)
+                self._draft_pools = self._copy(*self._draft_pools, src, dst)
             self._allocator.release([src])
             a.pending_copy = None
             if self._tracer is not None:
@@ -1489,17 +1502,14 @@ class InferenceEngine:
             self._kind_blocks[i] += sign * by_kind[i]
             g.set(self._kind_blocks[i])
 
-    def _pools_for(self, cfg: Any) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        if cfg is self.model_cfg:
-            return self._k_pool, self._v_pool
-        return self._dk_pool, self._dv_pool
+    def _pools_for(self, cfg: Any) -> Tuple[Any, ...]:
+        return self._pools if cfg is self.model_cfg else self._draft_pools
 
-    def _set_pools_for(self, cfg: Any, k_pool: jnp.ndarray,
-                       v_pool: jnp.ndarray) -> None:
+    def _set_pools_for(self, cfg: Any, pools: Sequence[Any]) -> None:
         if cfg is self.model_cfg:
-            self._k_pool, self._v_pool = k_pool, v_pool
+            self._pools = tuple(pools)
         else:
-            self._dk_pool, self._dv_pool = k_pool, v_pool
+            self._draft_pools = tuple(pools)
 
     def _tables_for(self, rows: Sequence[_Active], padded_b: int
                     ) -> jnp.ndarray:
@@ -1546,15 +1556,16 @@ class InferenceEngine:
         t0 = time.monotonic()
         pt0 = time.perf_counter() if self._tracer is not None else 0.0
         with self._span("serving_prefill", batch=b, length=t):
-            logits, self._k_pool, self._v_pool = self._fwd(
-                self._params, self.model_cfg, *jt,
-                self._k_pool, self._v_pool, tables)
+            logits, *pools = self._fwd(
+                self._params, self.model_cfg, *jt, *self._pools, tables)
+            self._pools = tuple(pools)
             if self._spec_k:
                 # mirror the slice into the draft pools so the proposal
                 # loop sees the same context the target does
-                dl, self._dk_pool, self._dv_pool = self._draft_fwd(
+                dl, *pools = self._draft_fwd(
                     self._draft_params, self.draft_cfg, *jt,
-                    self._dk_pool, self._dv_pool, tables)
+                    *self._draft_pools, tables)
+                self._draft_pools = tuple(pools)
                 dl.block_until_ready()
             first = np.asarray(jnp.argmax(logits, axis=-1))
         dt = time.monotonic() - t0
@@ -1615,11 +1626,11 @@ class InferenceEngine:
         t0 = time.monotonic()
         with self._span("serving_decode_step", **size):
             with self._span("decode_dispatch", **size):
-                logits, self._k_pool, self._v_pool = self._fwd(
+                logits, *pools = self._fwd(
                     self._params, self.model_cfg, jnp.asarray(tok),
                     jnp.asarray(pos), jnp.asarray(msk),
-                    jnp.zeros((b,), jnp.int32),
-                    self._k_pool, self._v_pool, tables)
+                    jnp.zeros((b,), jnp.int32), *self._pools, tables)
+                self._pools = tuple(pools)
             with self._span("decode_readback", **size):
                 nxt = np.asarray(jnp.argmax(logits, axis=-1))
         self._h_decode.observe(time.monotonic() - t0)
@@ -1678,11 +1689,12 @@ class InferenceEngine:
                 pos[:len(rows), 0] = n0 - 1 + j
                 msk[:len(rows), 0] = j < allow
                 with self._span("decode_dispatch", **size):
-                    dl, self._dk_pool, self._dv_pool = self._draft_fwd(
+                    dl, *pools = self._draft_fwd(
                         self._draft_params, self.draft_cfg,
                         jnp.asarray(tok), jnp.asarray(pos),
                         jnp.asarray(msk), zero_last,
-                        self._dk_pool, self._dv_pool, tables)
+                        *self._draft_pools, tables)
+                    self._draft_pools = tuple(pools)
                 with self._span("decode_readback", **size):
                     cur = np.asarray(jnp.argmax(dl, axis=-1))[:len(rows)]
                 drafts[:, j] = cur
@@ -1695,10 +1707,11 @@ class InferenceEngine:
                 pos[i] = np.arange(n0[i] - 1, n0[i] + k)
                 msk[i] = np.arange(k + 1) < allow[i]
             with self._span("decode_dispatch", **size):
-                logits, self._k_pool, self._v_pool = self._verify_fwd(
+                logits, *pools = self._verify_fwd(
                     self._params, self.model_cfg, jnp.asarray(tok),
                     jnp.asarray(pos), jnp.asarray(msk),
-                    self._k_pool, self._v_pool, tables)
+                    *self._pools, tables)
+                self._pools = tuple(pools)
             with self._span("decode_readback", **size):
                 target = np.asarray(jnp.argmax(logits, axis=-1))
         step_dt = time.monotonic() - t0

@@ -346,7 +346,8 @@ class ServingFleet:
         self.router = LeastLoadedRouter(self.registry,
                                         tracer=self._router_tracer)
         # the fleet-shared forward: one jit cache for every replica
-        self._fwd = make_paged_forward()
+        self._fwd = make_paged_forward(
+            len(model_cfg.paged_model().pool_names))
         # held in the serving form: every replica's engine takes these
         # leaves as they are, so the replicas share one set of buffers
         self._params = self._serving_form(params)

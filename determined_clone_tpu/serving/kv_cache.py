@@ -105,10 +105,21 @@ class CacheLayout:
     """
 
     kinds: Tuple[str, ...] = ("kv",)
+    # entries of a kind whose count a sequence is fixed (a recurrent
+    # state's slot): reserved beside the blocks, from ids of their own
+    # (``BlockAllocator(slots=)``), and counted last in ``blocks_by_kind``
+    state_slots: int = 0
 
     def __init__(self, cache: KVCacheConfig, max_seq_len: int) -> None:
         self.cache = cache
         self.max_seq_len = int(max_seq_len)
+
+    @property
+    def row_args(self) -> Tuple[str, ...]:
+        """Names of what ``attended_rows`` counts: the args a decode
+        step's span carries. A cache of one kind counts nothing."""
+        return tuple(f"{kind}_rows" for kind in self.kinds) \
+            if len(self.kinds) > 1 else ()
 
     @property
     def table_width(self) -> int:
@@ -121,7 +132,8 @@ class CacheLayout:
         return (self.cache.blocks_needed(total_len),)
 
     def blocks_needed(self, total_len: int) -> int:
-        return sum(self.blocks_by_kind(total_len))
+        """Pool blocks of all kinds (state slots are no pool blocks)."""
+        return sum(self.blocks_by_kind(total_len)) - self.state_slots
 
     def lay_table(self, row: np.ndarray, blocks: Sequence[int]) -> None:
         """Write a sequence's blocks (as ``blocks_needed`` reserved them,
@@ -209,6 +221,64 @@ class WindowSummaryLayout(CacheLayout):
                 f"{self.chunk}, so that no slice straddles a window")
 
 
+class SparseStateLayout(CacheLayout):
+    """A cache whose contents differ by layer kind (``models/
+    minicpm_sala.py``): the block-sparse attention layers keep exact K/V
+    rows, block ``w`` of the table backing positions ``[w * block, (w + 1)
+    * block)`` as in the uniform cache, and beside every block the
+    compressed keys that start in it, found by the same block id; the
+    linear-attention layers keep one recurrent state a sequence, which
+    does not grow.
+
+    Kinds ``("kv", "state")``: ``kv`` grows by ``ceil(len / block)``,
+    ``state`` is ``state_slots`` = 1 slot. The slot is the last entry of
+    the table's row, after ``blocks_needed(max_seq_len)`` block entries.
+    Past ``dense_len`` positions a query attends the rows of at most
+    ``topk`` chosen blocks, the block it lies in among them.
+    """
+
+    kinds = ("kv", "state")
+    state_slots = 1
+
+    def __init__(self, cache: KVCacheConfig, max_seq_len: int, *,
+                 topk: int, dense_len: int) -> None:
+        super().__init__(cache, max_seq_len)
+        self.topk, self.dense_len = int(topk), int(dense_len)
+
+    @property
+    def table_width(self) -> int:
+        return self.cache.blocks_needed(self.max_seq_len) + self.state_slots
+
+    @property
+    def row_args(self) -> Tuple[str, ...]:
+        return ("kv_rows", "selected_rows", "state_slots")
+
+    def blocks_by_kind(self, total_len: int) -> Tuple[int, int]:
+        return (self.cache.blocks_needed(total_len), self.state_slots)
+
+    def lay_table(self, row: np.ndarray, blocks: Sequence[int]) -> None:
+        n_kv = len(blocks) - self.state_slots
+        row[:n_kv] = blocks[:n_kv]
+        row[-1] = blocks[-1] - self.cache.num_blocks   # id -> slot
+
+    def attended_rows(self, length: int) -> Tuple[int, int, int]:
+        """(rows cached in a sparse layer, rows of them the query at
+        ``length - 1`` attends, state slots read)."""
+        bs = self.cache.block_size
+        chosen = min(self.topk, self.cache.blocks_needed(length))
+        selected = length if length <= self.dense_len \
+            else (chosen - 1) * bs + (length - 1) % bs + 1
+        return (length, selected, self.state_slots)
+
+    def check_prefill(self, max_prefill_len: int,
+                      chunk_prefill_len: int) -> None:
+        if chunk_prefill_len % self.cache.block_size:
+            raise ValueError(
+                f"chunk_prefill_len {chunk_prefill_len} must be whole "
+                f"cache blocks of {self.cache.block_size}: a prefill slice "
+                f"starts on a block boundary")
+
+
 class BlockAllocator:
     """Thread-safe per-block refcounts over the pool's block ids.
 
@@ -222,11 +292,16 @@ class BlockAllocator:
     never-freed-while-referenced invariant the COW protocol leans on.
     """
 
-    def __init__(self, cache: KVCacheConfig) -> None:
+    def __init__(self, cache: KVCacheConfig, slots: int = 0) -> None:
         self._cache = cache
         self._lock = threading.Lock()
         self._free: List[int] = list(range(cache.num_blocks - 1, -1, -1))
-        self._ref: List[int] = [0] * cache.num_blocks
+        # state slots (``CacheLayout.state_slots``) are the ids past the
+        # blocks': one discipline, one balance, a free list of their own
+        self._n_ids = cache.num_blocks + int(slots)
+        self._free_slots: List[int] = list(
+            range(self._n_ids - 1, cache.num_blocks - 1, -1))
+        self._ref: List[int] = [0] * self._n_ids
 
     @property
     def num_blocks(self) -> int:
@@ -260,27 +335,46 @@ class BlockAllocator:
                 self._ref[b] = 1
         return got
 
+    def free_slots(self) -> int:
+        with self._lock:
+            return len(self._free_slots)
+
+    def allocate_slots(self, need: int) -> List[int]:
+        """Reserve ``need`` state slots: ids ``num_blocks + slot``, held
+        and released like blocks."""
+        with self._lock:
+            if need > len(self._free_slots):
+                raise MemoryError(
+                    f"state slots exhausted: need {need}, "
+                    f"{len(self._free_slots)} free")
+            got = [self._free_slots.pop() for _ in range(need)]
+            for b in got:
+                self._ref[b] = 1
+        return got
+
     def retain(self, blocks: Sequence[int]) -> None:
         """Add one owner to each block; only live blocks can be shared."""
         with self._lock:
             for b in blocks:
-                if not 0 <= b < self._cache.num_blocks or self._ref[b] < 1:
+                if not 0 <= b < self._n_ids or self._ref[b] < 1:
                     raise ValueError(f"retain of free/bogus block {b}")
                 self._ref[b] += 1
 
     def release(self, blocks: Sequence[int]) -> None:
         with self._lock:
             for b in blocks:
-                if not 0 <= b < self._cache.num_blocks or self._ref[b] < 1:
+                if not 0 <= b < self._n_ids or self._ref[b] < 1:
                     raise ValueError(f"double/bogus free of block {b}")
                 self._ref[b] -= 1
                 if self._ref[b] == 0:
-                    self._free.append(b)
+                    (self._free if b < self._cache.num_blocks
+                     else self._free_slots).append(b)
 
     def outstanding(self) -> int:
-        """Blocks currently owned by someone (refcount > 0)."""
+        """Blocks and state slots currently owned by someone (refcount >
+        0)."""
         with self._lock:
-            return self._cache.num_blocks - len(self._free)
+            return self._n_ids - len(self._free) - len(self._free_slots)
 
     def assert_balanced(self, expected_outstanding: int = 0) -> None:
         """Audit hook: every block not on the free list must be accounted
@@ -290,7 +384,7 @@ class BlockAllocator:
         with no active sequences and no prefix cache, a nonzero balance
         is a leak (a crash path that dropped refs on the floor)."""
         with self._lock:
-            held = self._cache.num_blocks - len(self._free)
+            held = self._n_ids - len(self._free) - len(self._free_slots)
             if held != expected_outstanding:
                 owners = [i for i, r in enumerate(self._ref) if r > 0]
                 raise AssertionError(

@@ -88,18 +88,19 @@ def geometry(request):
     and ``params`` (H * head_dim = 32, padded to a 128-wide row) and the
     same model at d_model = 128, which fills the row exactly."""
     cfg = request.module.CFG
-    if request.param == "padded_rows":
-        yield cfg, request.getfixturevalue("params")
-        return
     import jax
 
     from determined_clone_tpu.models import gpt
     from determined_clone_tpu.serving.engine import make_paged_forward
 
     # the jit cache belongs to forward_paged, not to an engine, and those
-    # modules' program-budget assertions count it: one geometry at a time
+    # modules' program-budget assertions count it: one geometry at a time,
+    # and nothing of what another module's engines compiled in this worker
     shared = make_paged_forward()
     shared.clear_cache()
+    if request.param == "padded_rows":
+        yield cfg, request.getfixturevalue("params")
+        return
     aligned = dataclasses.replace(cfg, d_model=128)
     yield aligned, gpt.init(jax.random.PRNGKey(0), aligned)
     shared.clear_cache()
@@ -112,12 +113,12 @@ def assert_pool_rows():
     zero, and ``pool_bytes`` counting exactly what is allocated."""
     def check(eng, cfg):
         D = cfg.n_heads * cfg.head_dim
-        for pool in (eng._k_pool, eng._v_pool):
+        for pool in eng._pools:
             assert pool.shape == (cfg.n_layers, eng.cache.num_blocks,
                                   eng.cache.block_size, -(-D // 128) * 128)
             assert bool((pool[..., :D] != 0).any())
             assert not bool((pool[..., D:] != 0).any())
         assert eng.cache.pool_bytes(
             cfg.n_layers, cfg.n_heads, cfg.head_dim,
-            eng._k_pool.dtype.itemsize) == 2 * eng._k_pool.nbytes
+            eng._pools[0].dtype.itemsize) == 2 * eng._pools[0].nbytes
     return check

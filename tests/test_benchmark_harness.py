@@ -15,10 +15,18 @@ in a worker they share (``jax.jit``'s cache is per function, not per test).
 import pytest
 
 pytest.register_assert_rewrite(
+    "benchmarks.tests.test_minicpm_sala",
     "benchmarks.tests.test_reference", "benchmarks.tests.test_scopes",
     "benchmarks.tests.test_spec", "benchmarks.tests.test_stats",
     "benchmarks.tests.test_trace", "benchmarks.tests.test_traffic")
 
+from benchmarks.tests.test_minicpm_sala import (  # noqa: E402,F401
+    test_readers_know_the_bytes_a_step_has_to_move,
+    test_tiny_cell_lists_what_the_real_cell_lists
+    as test_sala_tiny_cell_lists_what_the_real_cell_lists,
+    test_traced_steps_are_matched_by_their_durations
+    as test_sala_traced_steps_are_matched_by_their_durations,
+)
 from benchmarks.tests.test_reference import (  # noqa: E402,F401
     setup,
     test_adamw_and_clip_match_optax,

@@ -394,6 +394,66 @@ def test_evabyte_paged_forward_compiles_at_the_cell_sizes(v5e, batch, t):
     assert [ln for ln in text.splitlines() if weight.search(ln)] == []
 
 
+@pytest.mark.parametrize("batch,t", [(8, 1), (1, 2048), (8, 2048)],
+                         ids=["decode", "slice", "slices-of-8-rows"])
+def test_minicpm_sala_paged_forward_compiles_at_the_cell_sizes(v5e, batch,
+                                                               t):
+    """``minicpm-sala-9b.serve-long-closed``: published layers 9..16 at the
+    published widths (5.6 GB of bfloat16 weights), a pool of 8 x 544 blocks
+    of 64 positions for the two sparse layers with their compressed keys,
+    and 8 x 6 recurrent states; the decode step at 8 rows and the
+    2048-token prefill slice at 34816 positions. They fit the 15.75 GB a
+    v5e offers a program, all four donated pools are updated in place, and
+    no program casts or copies a stack of weights."""
+    from determined_clone_tpu.models import minicpm_sala
+    from determined_clone_tpu.serving.engine import make_paged_forward
+    from determined_clone_tpu.serving.kv_cache import KVCacheConfig
+
+    cfg = minicpm_sala.MiniCPMSALAConfig(
+        mixer_types=minicpm_sala._PUBLISHED_MIXERS[9:17], first_layer=9,
+        max_seq_len=34816)
+    assert cfg.runs() == [("minicpm4", 0, 1), ("lightning-attn", 0, 6),
+                          ("minicpm4", 1, 2)]
+    cache = KVCacheConfig(8 * 544, 64)
+    layout = cfg.paged_model().cache_layout(cfg, cache)
+    assert layout.blocks_needed(cfg.max_seq_len) == 544
+    assert layout.table_width == 545
+    one = SingleDeviceSharding(v5e[0])
+    params = _shapes(jax.eval_shape(
+        lambda k: minicpm_sala.serving_params(minicpm_sala.init(k, cfg),
+                                              cfg),
+        jax.random.PRNGKey(0)), one)
+    pools = _shapes(jax.eval_shape(
+        lambda: minicpm_sala.init_pools(cfg, cache, 8)), one)
+    assert [p.shape for p in pools] == [
+        (2, 4352, 64, 256), (2, 4352, 64, 256), (2, 4352, 1024),
+        (6, 8, 32, 128, 128)]
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    compiled = make_paged_forward(len(pools)).lower(
+        params, cfg, arr((batch, t), jnp.int32), arr((batch, t), jnp.int32),
+        arr((batch, t), jnp.bool_), arr((batch,), jnp.int32), *pools,
+        arr((batch, layout.table_width), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(math.prod(p.shape) * p.dtype.itemsize for p in pools)
+    assert mem.alias_size_in_bytes >= pool_bytes      # every pool, in place
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 15.75 * 2 ** 30)
+    # no weight is cast (a convert of a fused operand is read as it lies),
+    # and no stack of weights is copied but into VMEM (memory space S(1));
+    # the 8-row prefill lays the lightning q, k, v stacks out anew, once a
+    # call of eight slices (1.5 ms at the memory's peak)
+    weight = r"= \w+\[(?:[26],)?(?:4096|16384),(?:4096|16384|256)\][^ ]* "
+    lines = compiled.as_text().splitlines()
+    assert [ln for ln in lines if re.search(weight + r"convert\((?!%param_)",
+                                            ln)] == []
+    copies = [ln for ln in lines if re.search(weight + r"copy\(", ln)
+              and "S(1)}" not in ln]
+    assert copies == [] or (batch, t) == (8, 2048), copies[:3]
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("n_chips", [1, 4])
 def test_gpt2_small_train_step_compiles(v5e, monkeypatch, n_chips):
