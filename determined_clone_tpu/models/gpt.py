@@ -401,7 +401,8 @@ def loss_fn(params: Params, cfg: GPTConfig, tokens: jax.Array,
 def _block_paged(cfg: GPTConfig, block_params: Params, x: jax.Array,
                  positions: jax.Array, k_rows: jax.Array,
                  v_rows: jax.Array, scatter_idx: jax.Array,
-                 gather_blocks: jax.Array, attn_mask: jax.Array):
+                 gather_blocks: jax.Array, attn_mask: jax.Array,
+                 lengths: Optional[jax.Array]):
     """One pre-LN block on the paged-KV serving path.
 
     x: [B, T, D] new tokens only (prefill: the prompt; decode: T=1).
@@ -413,7 +414,11 @@ def _block_paged(cfg: GPTConfig, block_params: Params, x: jax.Array,
     at a time, via ``gather_blocks`` ([B, W] block ids of this layer)
     under ``attn_mask`` ([B, 1, T, S], S = W * bs). Two sequences never
     share a pool block, so the scatter indices are collision-free by
-    construction.
+    construction. Where ``lengths`` ([B], the positions each row attends)
+    is given, which is a decode step on the kernel's path
+    (``_paged_backbone``), nothing is gathered: the kernel of
+    ``ops/paged_attention.py`` reads the pool through ``gather_blocks``
+    at each row's own length.
 
     Returns (x, k_rows, v_rows) — the same block math as ``_block``
     (dense or MoE FFN), minus dropout (inference) and remat.
@@ -437,13 +442,24 @@ def _block_paged(cfg: GPTConfig, block_params: Params, x: jax.Array,
                 jnp.pad(k.reshape(B * T, D), pad), mode="drop")
             v_rows = v_rows.at[scatter_idx].set(
                 jnp.pad(v.reshape(B * T, D), pad), mode="drop")
-            # gather the whole paged context: [B, S, R]; slot j of the
-            # gathered context is sequence position j (block tables map
-            # contiguously). By block, not by row: the same bytes in
-            # bs-times fewer, bs-times longer pieces (3.5 x faster on v5e)
-            ctx_k = k_rows.reshape(-1, bs, R)[gather_blocks].reshape(B, S, R)
-            ctx_v = v_rows.reshape(-1, bs, R)[gather_blocks].reshape(B, S, R)
-        if T == 1:  # decode: read the rows as they lie
+            if lengths is None:
+                # gather the whole paged context: [B, S, R]; slot j of the
+                # gathered context is sequence position j (block tables map
+                # contiguously). By block, not by row: the same bytes in
+                # bs-times fewer, bs-times longer pieces (3.5 x faster on
+                # v5e)
+                ctx_k = k_rows.reshape(-1, bs, R)[gather_blocks].reshape(
+                    B, S, R)
+                ctx_v = v_rows.reshape(-1, bs, R)[gather_blocks].reshape(
+                    B, S, R)
+        if lengths is not None:  # decode, through the table
+            from determined_clone_tpu.ops import paged_attention as paged
+
+            with jax.named_scope("paged_attn"):
+                attn = paged.paged_attention(
+                    q, k_rows.reshape(-1, bs, R), v_rows.reshape(-1, bs, R),
+                    gather_blocks, lengths)
+        elif T == 1:  # decode: read the gathered rows as they lie
             attn = decode_attention_rows(q, ctx_k, ctx_v, attn_mask)
         else:
             attn = mha(q, ctx_k[..., :D].reshape(B, S, H, hd),
@@ -484,6 +500,12 @@ def _paged_backbone(params: Params, cfg: GPTConfig, tokens: jax.Array,
     (a bitcast of ``[L, N, bs, R]``) and each layer scatters and gathers
     at its own offset, so a donated pool is updated in place: no
     per-layer slice is taken out and stacked back.
+
+    A decode step (``T == 1``) gathers nothing where the attention kernels
+    are on (``resolved_attention_impl``: the chip, or ``"flash"``) and the
+    paged kernel can take the pool's shapes: it reads each row's context
+    through the table, ``positions + 1`` rows of a live row and none of a
+    padding row. ``T > 1`` gathers whole tables and runs ``mha``.
     """
     B, T = tokens.shape
     L, N, bs, R = k_pool.shape
@@ -501,6 +523,14 @@ def _paged_backbone(params: Params, cfg: GPTConfig, tokens: jax.Array,
     attn_mask = (jnp.arange(S)[None, None, :] <= positions[:, :, None]
                  ) & token_mask[:, :, None]
     attn_mask = attn_mask[:, None]  # [B, 1, T, S] broadcast over heads
+    lengths = None
+    if T == 1 and resolved_attention_impl(cfg) == "flash":
+        # imported where a program needs it, as _block imports the
+        # training kernels: Pallas costs a second of a process's start
+        from determined_clone_tpu.ops import paged_attention as paged
+
+        if paged.fits(W, bs, cfg.n_heads, R, cfg.compute_dtype):
+            lengths = jnp.where(token_mask[:, 0], positions[:, 0] + 1, 0)
 
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"]["table"], tokens,
@@ -512,7 +542,7 @@ def _paged_backbone(params: Params, cfg: GPTConfig, tokens: jax.Array,
         x, k_rows, v_rows = _block_paged(
             cfg, layer_params, x, positions, k_rows, v_rows,
             first_block * bs + scatter_idx, first_block + block_tables,
-            attn_mask)
+            attn_mask, lengths)
         return (x, k_rows, v_rows), None
 
     (x, k_rows, v_rows), _ = jax.lax.scan(
@@ -643,7 +673,9 @@ PAGED = PagedModel(family="gpt", forward_paged=forward_paged,
                    forward_paged_logits=forward_paged_logits,
                    init=init,
                    cache_layout=_cache_layout,
-                   serving_params=serving_params)
+                   serving_params=serving_params,
+                   row_counters=("serving_kv_rows_attended_total",
+                                 "serving_kv_rows_tabled_total"))
 
 
 def param_count(params: Params) -> int:

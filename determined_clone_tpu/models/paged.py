@@ -85,7 +85,7 @@ class PagedModel:
         kinds, reservation, table rows.
     unsupported: ``ENGINE_FEATURES`` the family's cache cannot serve.
     row_counters: one counter name per entry of ``cache_layout``'s
-        ``row_args``, for the cache rows a decode step attends; empty =
+        ``row_args``, for the cache rows a decode step reads; empty =
         not counted.
     """
     family: str

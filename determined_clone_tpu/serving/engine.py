@@ -519,7 +519,7 @@ class InferenceEngine:
         self._g_free_blocks = m.gauge(
             "serving_free_kv_blocks", "unallocated KV pool blocks")
         self._g_free_blocks.set(self._allocator.free_blocks())
-        # a cache of several kinds: blocks in use and rows attended, by kind
+        # a cache of several kinds: blocks in use, by kind
         kinds = self._layout.kinds if len(self._layout.kinds) > 1 else ()
         self._g_kind_blocks = [
             m.gauge("serving_kv_blocks_in_use",
@@ -1607,13 +1607,10 @@ class InferenceEngine:
         # the host's phases of the step, each a span with the same args
         # (docs/observability.md "An engine iteration")
         size = {"batch": b, "rows": len(rows)}
-        attended = [0] * len(self._row_args)
-        if attended:  # a cache of several kinds: rows attended, by kind
-            for a in rows:
-                for i, n in enumerate(self._layout.attended_rows(
-                        a.prompt_len + len(a.out))):
-                    attended[i] += n
-            size.update(zip(self._row_args, attended))
+        # cache rows the step reads, at the rows' real lengths
+        attended = self._layout.step_rows(
+            [a.prompt_len + len(a.out) for a in rows], b)
+        size.update(zip(self._row_args, attended))
         with self._span("decode_prepare", **size):
             tok = np.zeros((b, 1), np.int32)
             pos = np.zeros((b, 1), np.int32)

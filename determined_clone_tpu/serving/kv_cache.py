@@ -116,10 +116,12 @@ class CacheLayout:
 
     @property
     def row_args(self) -> Tuple[str, ...]:
-        """Names of what ``attended_rows`` counts: the args a decode
-        step's span carries. A cache of one kind counts nothing."""
+        """Names of what ``step_rows`` counts: the args a decode step's
+        span carries. The uniform cache: the rows the step's queries
+        attend (``kv_rows``) and the rows its block tables span
+        (``table_rows``), which is what a read of whole tables moves."""
         return tuple(f"{kind}_rows" for kind in self.kinds) \
-            if len(self.kinds) > 1 else ()
+            if len(self.kinds) > 1 else ("kv_rows", "table_rows")
 
     @property
     def table_width(self) -> int:
@@ -144,6 +146,17 @@ class CacheLayout:
         """Cache rows of each kind that the query at position
         ``length - 1`` attends."""
         return (length,)
+
+    def step_rows(self, lengths: Sequence[int], batch: int
+                  ) -> Tuple[int, ...]:
+        """One decode step's ``row_args``: ``attended_rows`` summed over
+        its rows' context ``lengths`` and, for the uniform cache, the
+        whole tables of the ``batch`` rows (padding among them) the step's
+        batch bucket holds."""
+        sums = tuple(map(sum, zip(*map(self.attended_rows, lengths))))
+        if len(self.kinds) == 1:
+            sums += (batch * self.table_width * self.cache.block_size,)
+        return sums
 
     def check_prefill(self, max_prefill_len: int,
                       chunk_prefill_len: int) -> None:

@@ -15,7 +15,7 @@ in a worker they share (``jax.jit``'s cache is per function, not per test).
 import pytest
 
 pytest.register_assert_rewrite(
-    "benchmarks.tests.test_minicpm_sala",
+    "benchmarks.tests.test_minicpm_sala", "benchmarks.tests.test_paged",
     "benchmarks.tests.test_reference", "benchmarks.tests.test_scopes",
     "benchmarks.tests.test_spec", "benchmarks.tests.test_stats",
     "benchmarks.tests.test_trace", "benchmarks.tests.test_traffic")
@@ -26,6 +26,13 @@ from benchmarks.tests.test_minicpm_sala import (  # noqa: E402,F401
     as test_sala_tiny_cell_lists_what_the_real_cell_lists,
     test_traced_steps_are_matched_by_their_durations
     as test_sala_traced_steps_are_matched_by_their_durations,
+)
+from benchmarks.tests.test_paged import (  # noqa: E402,F401
+    test_a_traced_run_reads_the_scopes_time_and_the_rows_bytes,
+    test_both_gpt_serve_cells_list_the_readers_and_no_other_cell_does,
+    test_readers_find_nothing_without_the_scope_or_the_args,
+    test_traced_steps_are_matched_by_their_durations
+    as test_paged_traced_steps_are_matched_by_their_durations,
 )
 from benchmarks.tests.test_reference import (  # noqa: E402,F401
     setup,

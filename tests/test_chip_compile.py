@@ -32,6 +32,7 @@ import chip_smoke  # noqa: E402
 
 from determined_clone_tpu.models import gpt  # noqa: E402
 from determined_clone_tpu.ops import flash_attention as flash_mod  # noqa: E402
+from determined_clone_tpu.ops import paged_attention as paged_mod  # noqa: E402
 from determined_clone_tpu.parallel import MeshSpec, make_mesh  # noqa: E402
 from determined_clone_tpu.utils import compile_cache  # noqa: E402
 
@@ -286,17 +287,22 @@ def _compile_serve_cell(device, cell, t):
     """A GPT serve cell's decode step (t = 1) or prefill call, at the
     cell's real depth, which compiles in seconds: two layers of the xl pool
     are small enough for the compiler to stage them whole in fast memory,
-    which is another program than the one the chip runs. Compiled once for
-    the tests that read it. Returns (cfg, compiled, one pool's shape)."""
+    which is another program than the one the chip runs. The attention
+    kernels are forced and compiled (``"auto"`` would see the CPU here),
+    as the chip has them. Compiled once for the tests that read it.
+    Returns (cfg, compiled, one pool's shape)."""
     if (cell, t) not in _serve_cell_programs:
         n_layers, d_model, n_heads, blocks, batch = _SERVE_CELLS[cell]
         # a small vocabulary: the real table is larger than a layer of
         # xl's pool
         cfg = gpt.GPTConfig(vocab_size=2048, n_layers=n_layers,
                             d_model=d_model, n_heads=n_heads,
-                            d_ff=4 * d_model, max_seq_len=1024)
-        _serve_cell_programs[cell, t] = (cfg, *_compile_paged_forward(
-            cfg, device, blocks=blocks, block=16, batch=batch, t=t))
+                            d_ff=4 * d_model, max_seq_len=1024,
+                            attention_impl="flash")
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(paged_mod, "_should_interpret", lambda: False)
+            _serve_cell_programs[cell, t] = (cfg, *_compile_paged_forward(
+                cfg, device, blocks=blocks, block=16, batch=batch, t=t))
     return _serve_cell_programs[cell, t]
 
 
@@ -320,6 +326,55 @@ def test_paged_forward_copies_no_pool(v5e, cell, t):
     assert _pool_sized_copies(compiled.as_text(),
                               pool_elements // cfg.n_layers) == []
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * pool_elements
+
+
+@pytest.mark.parametrize("cell", list(_SERVE_CELLS))
+def test_decode_reads_the_pool_through_the_table_in_one_kernel(v5e, cell):
+    """A decode step of either GPT serve cell holds the paged-attention
+    kernel (one call, in the layer scan's body) and gathers nothing: no
+    value shaped like a batch of whole tables (``[batch * 64, 16, R]`` as
+    the gather makes it, ``[batch, 1024, ...]`` as attention reads it)
+    exists in the program, and all its temporaries together are smaller
+    than one such value. A prefill call keeps both gathers and holds no
+    kernel."""
+    cfg, compiled, pool_shape = _compile_serve_cell(v5e[0], cell, 1)
+    batch, row = _SERVE_CELLS[cell][4], pool_shape[-1]
+    tables = re.compile(rf"\w+\[(?:{batch * 64},16,{row}|{batch},1024,)")
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 1 and "paged_attn" in calls[0], calls
+    assert not tables.search(text)
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < batch * 1024 * row * 2
+    _, prefill, _ = _compile_serve_cell(v5e[0], cell, 128)
+    assert "tpu_custom_call" not in prefill.as_text()
+    assert len(set(tables.findall(prefill.as_text()))) == 2
+
+
+@pytest.mark.parametrize("batch,heads", [(32, 16), (8, 25), (1, 16)],
+                         ids=["medium", "xl", "one-row"])
+def test_paged_kernel_compiles_at_the_serve_cells_shapes(v5e, batch, heads):
+    """The kernel alone, at the rule's sizes and at the sweep's corners,
+    within the fast memory it asks for (two slots of K and V at the
+    table's whole length: 8 MiB at 1024 columns, 13 MiB at 1664)."""
+    one = SingleDeviceSharding(v5e[0])
+    row = -(-heads * 64 // 128) * 128
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    pool = arr((batch * 64 * 2, 16, row), jnp.bfloat16)
+    for sz in (None, paged_mod.Sizes(1, 128, 8),
+               paged_mod.Sizes(batch, 512, 2)):
+        text = jax.jit(functools.partial(
+            paged_mod.paged_attention, sz=sz, interpret=False)).lower(
+                arr((batch, 1, heads, 64), jnp.bfloat16), pool, pool,
+                arr((batch, 64), jnp.int32), arr((batch,), jnp.int32)
+            ).compile().as_text()
+        assert "tpu_custom_call" in text and "paged_attn" in text
+    assert paged_mod._scratch_bytes(
+        paged_mod.sizes(64, 16), paged_mod._padded_heads(
+            heads, jnp.bfloat16), row, jnp.bfloat16) < 14 * 2 ** 20
 
 
 @_each_serve_cell_program

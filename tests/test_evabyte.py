@@ -393,7 +393,10 @@ def test_gpt_through_the_interface_is_token_identical_to_uncached():
     assert layout.table_width == 8 and layout.blocks_by_kind(33) == (3,)
     prompt = _tokens(5, 12) % cfg.vocab_size
     with InferenceEngine(p, cfg) as eng:
-        assert eng._g_kind_blocks == [] and eng._c_rows == []
+        assert eng._g_kind_blocks == []  # one kind: no gauge by kind
+        assert tuple(c.name for c in eng._c_rows) == model.row_counters \
+            == ("serving_kv_rows_attended_total",
+                "serving_kv_rows_tabled_total")
         got = eng.generate(prompt.tolist(), max_new_tokens=12).tokens
     seq = prompt.tolist()
     for _ in range(12):

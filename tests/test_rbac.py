@@ -48,13 +48,19 @@ def test_admin_flag_is_cluster_admin(master):
     assert me["enforced"] is True
 
 
-def _nobody_id(admin):
-    """The unassigned user's id; made here where an earlier test of this
-    module has not (xdist's load distribution may split the module)."""
+def _user_id(admin, username):
+    """A user's id; the user is made here where an earlier test of this
+    module has not made it (xdist's load distribution may split the
+    module, and each part has a master of its own)."""
     for u in admin.list_users():
-        if u["username"] == "nobody":
+        if u["username"] == username:
             return u["id"]
-    return admin.create_user("nobody", "pw")["id"]
+    return admin.create_user(username, "pw")["id"]
+
+
+def _nobody_id(admin):
+    """The unassigned user's id."""
+    return _user_id(admin, "nobody")
 
 
 def test_unassigned_user_cannot_mutate(master):
@@ -134,6 +140,7 @@ def test_global_viewer_cannot_create(master):
 
 
 def test_only_cluster_admin_manages_assignments(master):
+    _user_id(master["session"], "alice")  # no global role either way
     alice = login_as(master, "alice", "pw")
     with pytest.raises(MasterError) as err:
         alice.assign_role("Editor", user_id=1)
@@ -148,6 +155,7 @@ def test_ntsc_tasks_are_gated(master):
     ed = admin.create_user("ed", "pw")
     admin.assign_role("Editor", user_id=ed["id"])  # global scope
 
+    _nobody_id(admin)
     nobody = login_as(master, "nobody", "pw")
     with pytest.raises(MasterError) as err:
         nobody.create_task("command", cmd=["echo", "hi"])

@@ -206,8 +206,12 @@ def test_engine_records_the_span_tree_of_every_decode_step():
         assert commit["ts_us"] >= step["ts_us"] + step["dur_us"] - 0.2
         assert dispatch["depth"] == step["depth"] + 1 == iteration["depth"] + 2
         for e in (prepare, step, dispatch, readback, commit):
-            assert set(e["args"]) == {"rows", "batch"}
+            # the uniform cache's rows: attended, and spanned by the tables
+            assert set(e["args"]) == {"rows", "batch", "kv_rows",
+                                      "table_rows"}
             assert 1 <= e["args"]["rows"] <= e["args"]["batch"]
+            assert e["args"]["rows"] <= e["args"]["kv_rows"] \
+                < e["args"]["table_rows"]
     # admission is spanned alone (the wait on the condition never is), and
     # a prefill has its prepare phase
     assert by_name["admit"] and by_name["prefill_prepare"]

@@ -116,17 +116,22 @@ def test_block_allocator():
 
 # -- the tier-1 contract: parity + compile discipline ------------------------
 
+@pytest.mark.parametrize("attention_impl", ["mha", "flash"])
 def test_paged_decode_token_identical_and_compile_budget(geometry,
-                                                         assert_pool_rows):
+                                                         assert_pool_rows,
+                                                         attention_impl):
     """Mixed-length requests through the continuous scheduler produce
     EXACTLY the tokens of the naive uncached forward (greedy), and the
     shared jitted forward never compiles more programs than the bucket
     budget — the two acceptance properties of the serving tentpole. Both
     hold whether or not a pool row is padded, and prefill and decode
-    leave the padding zero."""
+    leave the padding zero; and whether a decode step gathers its context
+    and attends it in plain XLA (``"mha"``) or reads it through the block
+    table in the paged kernel (``"flash"``, interpreted off the chip)."""
     cfg, params = geometry
     expected = {i: naive_greedy(params, p, 12, cfg)
                 for i, p in enumerate(PROMPTS)}
+    cfg = dataclasses.replace(cfg, attention_impl=attention_impl)
     with InferenceEngine(params, cfg, buckets=BUCKETS, cache=CACHE) as eng:
         handles = [eng.submit(p, 12, request_id=str(i))
                    for i, p in enumerate(PROMPTS)]
@@ -205,6 +210,40 @@ def test_telemetry_spans_and_metrics(params):
                  "serving_requests_completed_total",
                  "serving_tokens_generated_total"):
         assert name in dump, name
+
+
+def test_decode_steps_count_attended_and_tabled_rows(params):
+    """Every span of a decode step carries ``kv_rows`` (the live rows' real
+    context lengths, summed) and ``table_rows`` (batch bucket x table
+    width x block: what a read of whole tables moves), and the two
+    counters hold their sums."""
+    from determined_clone_tpu.telemetry import Tracer
+
+    class Telemetry:
+        registry = None
+        tracer = Tracer(enabled=True, process_name="t")
+
+    prompts, new = [PROMPTS[0], PROMPTS[1]], [4, 6]
+    with make_engine(params, telemetry=Telemetry()) as eng:
+        for h in [eng.submit(p, n) for p, n in zip(prompts, new)]:
+            h.result(timeout=120.0)
+        attended = eng.registry.counter(
+            "serving_kv_rows_attended_total").value
+        tabled = eng.registry.counter("serving_kv_rows_tabled_total").value
+        width = eng._layout.table_width * CACHE.block_size
+    steps = {name: [e["args"] for e in Telemetry.tracer.events()
+                    if e["name"] == name]
+             for name in ("decode_prepare", "serving_decode_step",
+                          "decode_dispatch", "decode_readback",
+                          "decode_commit")}
+    whole = steps["serving_decode_step"]
+    assert whole and all(args == whole for args in steps.values())
+    assert all(a["table_rows"] == a["batch"] * width for a in whole)
+    assert all(a["rows"] <= a["kv_rows"] <= a["rows"] * width for a in whole)
+    # a request's decode steps attend len(prompt) + 1 .. + new - 1 rows
+    assert attended == sum(a["kv_rows"] for a in whole) == sum(
+        len(p) + i for p, n in zip(prompts, new) for i in range(1, n))
+    assert tabled == sum(a["table_rows"] for a in whole)
 
 
 # -- admission control / backpressure ----------------------------------------

@@ -163,16 +163,21 @@ def test_prefix_cache_match_register_evict():
 
 # -- COW prefix sharing through the engine ------------------------------------
 
-def test_prefix_sharing_parity_counters_and_cow(geometry, assert_pool_rows):
+@pytest.mark.parametrize("attention_impl", ["mha", "flash"])
+def test_prefix_sharing_parity_counters_and_cow(geometry, assert_pool_rows,
+                                                attention_impl):
     """Repeat and prefix-sharing prompts alias cached blocks (hit/miss
     counters prove it) and still decode bit-identically — the COW fork
     of the written block is what keeps the aliased copy immutable. A fork
-    copies whole pool rows, so a row's padding stays zero through it."""
+    copies whole pool rows, so a row's padding stays zero through it.
+    ``"flash"``: the decode steps read the aliased blocks through the
+    table in the paged kernel."""
     cfg, params = geometry
     base = list(range(1, 12))            # 1 full block + 3-token tail
     fork = base[:8] + [61, 62, 63]       # shares the full block only
     expected = {tuple(p): naive_greedy(params, p, 8, cfg)
                 for p in (base, fork)}
+    cfg = dataclasses.replace(cfg, attention_impl=attention_impl)
     with make_engine(params, cfg, prefix_cache=True) as eng:
         r1 = eng.generate(base, 8)       # cold: everything misses
         assert r1.tokens == expected[tuple(base)]
@@ -199,14 +204,19 @@ def test_prefix_sharing_parity_counters_and_cow(geometry, assert_pool_rows):
 
 # -- speculative decoding -----------------------------------------------------
 
-def test_speculative_parity_with_disagreeing_draft(params, draft_params):
+@pytest.mark.parametrize("attention_impl", ["mha", "flash"])
+def test_speculative_parity_with_disagreeing_draft(params, draft_params,
+                                                   attention_impl):
     """A randomly-initialised draft disagrees with the target almost
     everywhere; the accepted-prefix rule must still emit exactly the
-    target's greedy tokens — a bad draft only costs speed."""
+    target's greedy tokens — a bad draft only costs speed. ``"flash"``:
+    the draft's proposal steps run the paged kernel, over pools that hold
+    rejected drafts' rows past a row's length."""
     expected = {i: naive_greedy(params, p, 8)
                 for i, p in enumerate(PROMPTS)}
-    with make_engine(params, speculative_k=3, draft_params=draft_params,
-                     draft_cfg=CFG) as eng:
+    cfg = dataclasses.replace(CFG, attention_impl=attention_impl)
+    with make_engine(params, cfg, speculative_k=3,
+                     draft_params=draft_params, draft_cfg=cfg) as eng:
         handles = [eng.submit(p, 8, request_id=str(i))
                    for i, p in enumerate(PROMPTS)]
         results = [h.result(timeout=120.0) for h in handles]
