@@ -4,8 +4,8 @@
 given for this (``model_cfg.paged_model()``) and runs everything through
 it: the paged forward it jits, the layout of a sequence's cache in pool
 blocks, and the engine features the family's cache cannot serve yet, which
-the engine refuses at construction. ``models/gpt.py`` and
-``models/evabyte.py`` each end in their instance of it.
+the engine refuses at construction. Every served family's module ends
+in its instance of it.
 """
 from __future__ import annotations
 
@@ -87,6 +87,21 @@ class PagedModel:
     row_counters: one counter name per entry of ``cache_layout``'s
         ``row_args``, for the cache rows a decode step reads; empty =
         not counted.
+    step_counters: names of what only the device knows of a call (how a
+        step's tokens were routed, say). A family that names some
+        returns, after its pools, one int32 vector of that length from
+        ``forward_paged`` and ``forward_paged_logits``; the engine reads
+        it in the transfer that reads the sampled tokens, puts it on the
+        call's span and adds it to counters ``serving_<name>_total``.
+        Empty (the default): the programs return logits and pools, no
+        more.
+    token_records: whether the programs note something of every token
+        (to which experts it was routed, say). A family that does returns,
+        last, one int32 array [B, T, W] from ``forward_paged`` and
+        ``forward_paged_logits``; the engine reads it in the same
+        transfer, keeps each row's real tokens' records and hands a
+        request's back, in the order of its positions, as
+        ``RequestResult.token_records``.
     """
     family: str
     forward_paged: Callable[..., Any]
@@ -98,6 +113,8 @@ class PagedModel:
     pool_names: Tuple[str, ...] = ("k_pool", "v_pool")
     unsupported: Tuple[str, ...] = ()
     row_counters: Tuple[str, ...] = ()
+    step_counters: Tuple[str, ...] = ()
+    token_records: bool = False
 
     def __post_init__(self) -> None:
         unknown = set(self.unsupported) - set(ENGINE_FEATURES)

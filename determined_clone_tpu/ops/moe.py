@@ -12,6 +12,10 @@ Capacity semantics: each expert processes at most C = ceil(tokens/E ·
 capacity_factor) tokens; overflow tokens fall through the residual connection
 (standard drop-token behavior). The router adds the load-balancing auxiliary
 loss E · Σ_e f_e·P_e from the Switch paper.
+
+Beside it, :func:`routed_experts`: a dropless expert layer that is told
+which experts it holds — one member of an expert-parallel group, without
+the exchange (the serving path of ``models/glm_moe_dsa.py``).
 """
 from __future__ import annotations
 
@@ -114,3 +118,118 @@ def moe_ffn(
     aux = E * jnp.sum(f * p)
 
     return y.reshape(B, T, D).astype(x.dtype), aux.astype(jnp.float32)
+
+
+# ---------------------------------------------------------------------------
+# A dropless expert layer that holds some of the experts
+# ---------------------------------------------------------------------------
+
+PAIR_TILE = 128  # token-expert pairs multiplied through one expert at a time
+
+
+def route(router: Params, h: jax.Array, *, k: int, scale: float
+          ) -> Tuple[jax.Array, jax.Array]:
+    """Sigmoid routing with a selection bias, for h [N, D] fp32: ``s =
+    sigmoid(h W)`` over all the router's experts (fp32, full precision);
+    the ``k`` experts of largest ``s + b`` are chosen (``b`` =
+    ``router["bias"]`` chooses and does not weigh); ``g = scale * s / sum
+    of the chosen s``. Returns ``(experts [N, k] int32, gates [N, k]
+    fp32)``."""
+    scores = jax.nn.sigmoid(jnp.matmul(
+        h.astype(jnp.float32), router["kernel"].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, experts = jax.lax.top_k(scores + router["bias"].astype(jnp.float32),
+                               k)
+    chosen = jnp.take_along_axis(scores, experts, axis=-1)
+    return experts.astype(jnp.int32), scale * chosen / jnp.sum(
+        chosen, axis=-1, keepdims=True)
+
+
+def routed_experts(params: Params, h: jax.Array, *, first_expert: int,
+                   n_held: int, n_experts: int, k: int, scale: float,
+                   token_mask: Optional[jax.Array] = None,
+                   first_row: Any = 0, compute_dtype=jnp.bfloat16
+                   ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The routed part of an expert layer, as the member of an
+    expert-parallel group that holds experts ``[first_expert, first_expert +
+    n_held)`` of ``n_experts`` computes it: h [N, D] fp32 -> ``(y [N, D]
+    fp32, counts [2] int32, experts [N, k] int32)``.
+
+    Every token is routed over all ``n_experts`` (:func:`route`:
+    ``params["router"]`` = kernel [D, n_experts] and bias [n_experts]); the
+    token-expert pairs whose expert is held are sorted by expert and
+    multiplied, ``PAIR_TILE`` pairs of one expert at a time, through that
+    expert's SwiGLU (``params["experts_gate" | "experts_up"]`` kernels
+    [n_held, D, F], ``params["experts_down"]`` [n_held, F, D], read as they
+    lie; in stacks of several layers' experts, this layer's start at row
+    ``first_row``); ``y[n] = sum over n's chosen and held experts of g *
+    Expert(h[n])``.
+    Pairs of absent experts contribute nothing (their gates still took part
+    in the normaliser); nothing stands in for the exchange. The shared
+    expert is the caller's.
+
+    **No pair is dropped, whatever the routing.** The tiles are a loop
+    whose length is the number of tiles the step's pairs fill (an expert's
+    pairs start on a tile boundary; at most ``ceil(N k / PAIR_TILE) + n_held``
+    tiles, all pairs to held experts), so the work is in proportion to the
+    pairs that fell here, and an expert with no pair is not read.
+
+    ``token_mask`` [N]: tokens that are padding route nowhere. ``counts`` =
+    (pairs that fell to held experts, held experts that got at least one);
+    ``experts`` = every token's ``k`` chosen experts of all ``n_experts``,
+    held or not, in the order of their biased scores.
+    """
+    N, D = h.shape
+    tile = PAIR_TILE
+    if params["router"]["kernel"].shape[-1] != n_experts \
+            or not 0 <= first_expert <= first_expert + n_held <= n_experts:
+        raise ValueError(
+            f"experts [{first_expert}, {first_expert + n_held}) are not "
+            f"among the router's {params['router']['kernel'].shape[-1]} "
+            f"(n_experts {n_experts})")
+    with jax.named_scope("moe_route"):
+        experts, gates = route(params["router"], h, k=k, scale=scale)
+        local = experts - first_expert
+        held = (local >= 0) & (local < n_held)
+        if token_mask is not None:
+            held &= token_mask[:, None]
+        group = jnp.where(held, local, n_held).reshape(-1)        # [N k]
+        order = jnp.argsort(group, stable=True).astype(jnp.int32)
+        sizes = jnp.sum(group[:, None] == jnp.arange(n_held)[None, :],
+                        axis=0, dtype=jnp.int32)                  # [n_held]
+        tiles = -(-sizes // tile)
+        tile_end = jnp.cumsum(tiles)
+        pair_start = jnp.cumsum(sizes) - sizes
+        counts = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0)]
+                           ).astype(jnp.int32)
+        tokens = order // k
+        weights = gates.reshape(-1)[order]
+
+    x = h.astype(compute_dtype)
+    gate_w, up_w, down_w = (params[n]["kernel"] for n in (
+        "experts_gate", "experts_up", "experts_down"))
+
+    def one_tile(t, y):
+        e = jnp.minimum(jnp.searchsorted(tile_end, t, side="right"),
+                        n_held - 1)
+        rank = (t - (tile_end[e] - tiles[e])) * tile + jnp.arange(tile)
+        real = rank < sizes[e]
+        at = jnp.where(real, pair_start[e] + rank, 0)
+        rows = x[tokens[at]]                                      # [tile, D]
+
+        def w(stack):
+            return jax.lax.dynamic_index_in_dim(stack, first_row + e,
+                                                keepdims=False)
+
+        act = jax.nn.silu(jnp.matmul(
+            rows, w(gate_w), preferred_element_type=jnp.float32)) \
+            * jnp.matmul(rows, w(up_w), preferred_element_type=jnp.float32)
+        out = jnp.matmul(act.astype(compute_dtype), w(down_w),
+                         preferred_element_type=jnp.float32)
+        out = out * jnp.where(real, weights[at], 0.0)[:, None]
+        return y.at[jnp.where(real, tokens[at], N)].add(out, mode="drop")
+
+    with jax.named_scope("moe_experts"):
+        y = jax.lax.fori_loop(0, tile_end[-1], one_tile,
+                              jnp.zeros((N, D), jnp.float32))
+    return y, counts, experts

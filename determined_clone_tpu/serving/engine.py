@@ -226,6 +226,9 @@ class RequestResult:
     spec_proposed: int = 0       # draft tokens offered for this request
     spec_accepted: int = 0       # draft tokens the target agreed with
     trace_id: Optional[str] = None  # front-door trace identity, if minted
+    # what the family's programs noted of every token run for this request
+    # (PagedModel.token_records), in the order of its positions
+    token_records: Optional[np.ndarray] = None
 
     @property
     def spec_acceptance(self) -> Optional[float]:
@@ -316,7 +319,7 @@ class _Active:
 
     __slots__ = ("handle", "blocks", "by_kind", "prompt_len", "out",
                  "last_token", "prefill_pos", "pending_copy", "hit_blocks",
-                 "miss_blocks", "spec_proposed", "spec_accepted")
+                 "miss_blocks", "spec_proposed", "spec_accepted", "records")
 
     def __init__(self, handle: _Handle, blocks: List[int],
                  prompt_len: int) -> None:
@@ -337,6 +340,8 @@ class _Active:
         self.miss_blocks = 0
         self.spec_proposed = 0
         self.spec_accepted = 0
+        # the programs' records of this row's tokens, a piece a call
+        self.records: List[np.ndarray] = []
 
 
 class InferenceEngine:
@@ -531,6 +536,12 @@ class InferenceEngine:
             m.counter(name, f"{arg} of the cache attended by decode steps "
                             f"(at the rows' real lengths)")
             for name, arg in zip(self._model.row_counters, self._row_args)]
+        # what only the device knows of a call (PagedModel.step_counters)
+        self._step_counters = tuple(self._model.step_counters)
+        self._c_steps = [
+            m.counter(f"serving_{name}_total",
+                      f"{name}, as the programs counted them")
+            for name in self._step_counters]
         self._c_prefix_hit = m.counter(
             "prefix_cache_hit_blocks_total",
             "prompt blocks aliased from the prefix cache (prefill skipped)")
@@ -850,14 +861,15 @@ class InferenceEngine:
                     tables = jnp.zeros((b, self._table_width), jnp.int32)
                     for fwd, params, cfg in lanes:
                         for t in (*self.buckets.prefill_len_buckets, 1):
+                            held = self._pools_for(cfg)
                             logits, *pools = fwd(
                                 params, cfg,
                                 jnp.zeros((b, t), jnp.int32),
                                 jnp.zeros((b, t), jnp.int32),
                                 jnp.zeros((b, t), bool),
                                 jnp.zeros((b,), jnp.int32),
-                                *self._pools_for(cfg), tables)
-                            self._set_pools_for(cfg, pools)
+                                *held, tables)
+                            self._set_pools_for(cfg, pools[:len(held)])
                             # the sampling step is its own (tiny) program
                             # per batch bucket — leave it cold and the
                             # first real request pays its compile
@@ -1502,6 +1514,30 @@ class InferenceEngine:
             self._kind_blocks[i] += sign * by_kind[i]
             g.set(self._kind_blocks[i])
 
+    def _keep_pools(self, out: Sequence[Any]) -> Tuple[Any, ...]:
+        """Keep the pools a target program handed back; what it returned
+        after them (``PagedModel.step_counters``: one device vector;
+        ``token_records``: one device array) is the caller's to read."""
+        n = len(self._pools)
+        self._pools = tuple(out[:n])
+        return tuple(out[n:])
+
+    def _read_back(self, tokens: Any, extras: Tuple[Any, ...]
+                   ) -> Tuple[np.ndarray, Dict[str, int],
+                              Optional[np.ndarray]]:
+        """The sampled tokens and what the program returned after its
+        pools, in one transfer: the tokens, the program's counts by name
+        (added to their counters) and its records of the call's tokens."""
+        if not extras:
+            return np.asarray(tokens), {}, None
+        tokens, *extras = jax.device_get((tokens, *extras))
+        counts = {name: int(n) for name, n in zip(
+            self._step_counters, extras[0] if self._step_counters else ())}
+        for counter, n in zip(self._c_steps, counts.values()):
+            counter.inc(n)
+        return tokens, counts, \
+            extras[-1] if self._model.token_records else None
+
     def _pools_for(self, cfg: Any) -> Tuple[Any, ...]:
         return self._pools if cfg is self.model_cfg else self._draft_pools
 
@@ -1555,10 +1591,10 @@ class InferenceEngine:
             tables = self._tables_for(rows, b)
         t0 = time.monotonic()
         pt0 = time.perf_counter() if self._tracer is not None else 0.0
-        with self._span("serving_prefill", batch=b, length=t):
+        with self._span("serving_prefill", batch=b, length=t) as prefill:
             logits, *pools = self._fwd(
                 self._params, self.model_cfg, *jt, *self._pools, tables)
-            self._pools = tuple(pools)
+            extras = self._keep_pools(pools)
             if self._spec_k:
                 # mirror the slice into the draft pools so the proposal
                 # loop sees the same context the target does
@@ -1567,7 +1603,9 @@ class InferenceEngine:
                     *self._draft_pools, tables)
                 self._draft_pools = tuple(pools)
                 dl.block_until_ready()
-            first = np.asarray(jnp.argmax(logits, axis=-1))
+            first, counted, records = self._read_back(
+                jnp.argmax(logits, axis=-1), extras)
+            prefill.set(**counted)
         dt = time.monotonic() - t0
         self._h_prefill.observe(dt)
         if self._tracer is not None:
@@ -1581,6 +1619,8 @@ class InferenceEngine:
         for i, a in enumerate(rows):
             a.handle.prefill_s += dt
             a.prefill_pos += cnt[i]
+            if records is not None:
+                a.records.append(records[i, :cnt[i]].copy())
             if a.prefill_pos < a.prompt_len:
                 still_prefilling.append(a)
                 continue
@@ -1627,15 +1667,18 @@ class InferenceEngine:
                     self._params, self.model_cfg, jnp.asarray(tok),
                     jnp.asarray(pos), jnp.asarray(msk),
                     jnp.zeros((b,), jnp.int32), *self._pools, tables)
-                self._pools = tuple(pools)
+                extras = self._keep_pools(pools)
             with self._span("decode_readback", **size):
-                nxt = np.asarray(jnp.argmax(logits, axis=-1))
+                nxt, counted, records = self._read_back(
+                    jnp.argmax(logits, axis=-1), extras)
         self._h_decode.observe(time.monotonic() - t0)
         for counter, n in zip(self._c_rows, attended):
             counter.inc(n)
-        with self._span("decode_commit", **size):
+        with self._span("decode_commit", **size, **counted):
             survivors: List[_Active] = []
             for i, a in enumerate(rows):
+                if records is not None:
+                    a.records.append(records[i].copy())
                 a.out.append(int(nxt[i]))
                 a.last_token = int(nxt[i])
                 if not self._maybe_finish(a):
@@ -1785,7 +1828,8 @@ class InferenceEngine:
             prefix_miss_blocks=a.miss_blocks,
             spec_proposed=a.spec_proposed,
             spec_accepted=a.spec_accepted,
-            trace_id=h.req.trace_id)
+            trace_id=h.req.trace_id,
+            token_records=np.concatenate(a.records) if a.records else None)
         if self._tracer is not None:
             self._h_total.observe(result.total_s,
                                   exemplar=h.req.request_id)

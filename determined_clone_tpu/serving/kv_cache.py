@@ -292,6 +292,43 @@ class SparseStateLayout(CacheLayout):
                 f"starts on a block boundary")
 
 
+class LatentIndexLayout(CacheLayout):
+    """A cache of one latent row a position and layer, with the keys of a
+    learned index beside it in some layers (``models/glm_moe_dsa.py``).
+    One growing kind, laid as the uniform cache is: block ``w`` of the
+    table backs positions ``[w * block, (w + 1) * block)`` in every pool,
+    so one block id finds a block's latents and its index keys. A query at
+    position ``length - 1`` is scored against all ``length`` cached index
+    keys and attends the ``min(length, topk)`` positions chosen.
+    """
+
+    def __init__(self, cache: KVCacheConfig, max_seq_len: int, *,
+                 topk: int) -> None:
+        super().__init__(cache, max_seq_len)
+        self.topk = int(topk)
+
+    @property
+    def row_args(self) -> Tuple[str, ...]:
+        return ("kv_rows", "selected_rows")
+
+    def attended_rows(self, length: int) -> Tuple[int, int]:
+        """(positions cached and scored by the index, positions of them
+        the query at ``length - 1`` attends)."""
+        return (length, min(length, self.topk))
+
+    def step_rows(self, lengths: Sequence[int], batch: int
+                  ) -> Tuple[int, ...]:
+        return tuple(map(sum, zip(*map(self.attended_rows, lengths))))
+
+    def check_prefill(self, max_prefill_len: int,
+                      chunk_prefill_len: int) -> None:
+        if chunk_prefill_len % self.cache.block_size:
+            raise ValueError(
+                f"chunk_prefill_len {chunk_prefill_len} must be whole "
+                f"cache blocks of {self.cache.block_size}: a prefill slice "
+                f"starts on a block boundary")
+
+
 class BlockAllocator:
     """Thread-safe per-block refcounts over the pool's block ids.
 

@@ -15,11 +15,19 @@ in a worker they share (``jax.jit``'s cache is per function, not per test).
 import pytest
 
 pytest.register_assert_rewrite(
-    "benchmarks.tests.test_minicpm_sala", "benchmarks.tests.test_paged",
+    "benchmarks.tests.test_glm_moe_dsa", "benchmarks.tests.test_minicpm_sala", "benchmarks.tests.test_paged",
     "benchmarks.tests.test_reference", "benchmarks.tests.test_scopes",
     "benchmarks.tests.test_spec", "benchmarks.tests.test_stats",
     "benchmarks.tests.test_trace", "benchmarks.tests.test_traffic")
 
+from benchmarks.tests.test_glm_moe_dsa import (  # noqa: E402,F401
+    test_a_steps_counts_are_those_of_the_commit_that_followed_it,
+    test_readers_know_the_bytes_a_step_has_to_read,
+    test_real_configuration_is_the_catalogs_but_for_what_reduced_names,
+    test_the_mix_is_the_issues_parameter_for_parameter,
+    test_tiny_cell_lists_what_the_real_cell_lists
+    as test_glm_tiny_cell_lists_what_the_real_cell_lists,
+)
 from benchmarks.tests.test_minicpm_sala import (  # noqa: E402,F401
     test_readers_know_the_bytes_a_step_has_to_move,
     test_tiny_cell_lists_what_the_real_cell_lists

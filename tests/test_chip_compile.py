@@ -509,6 +509,96 @@ def test_minicpm_sala_paged_forward_compiles_at_the_cell_sizes(v5e, batch,
     assert copies == [] or (batch, t) == (8, 2048), copies[:3]
 
 
+@pytest.mark.parametrize("batch,t", [(1, 1), (16, 1), (1, 512), (1, 2048),
+                                     (16, 2048)],
+                         ids=["decode-1", "decode-16", "slice-512",
+                              "slice-2048", "slices-of-16-rows"])
+def test_glm_moe_dsa_paged_forward_compiles_at_the_cell_sizes(v5e, batch, t):
+    """``glm-5.2.serve-agent-closed``: published layers 2..6 at the
+    published widths with 16 of the 256 experts held (7.8 GB of bfloat16
+    weights), 16 x 512 blocks of 64 positions of latents in five layers
+    (rows of 576 padded to 640) and of indexer keys in the two ``full``
+    ones (3.6 GB); the decode step at 1 and 16 rows, and the prefill
+    slices at 32768 positions. They fit the 15.75 GB a v5e offers a
+    program, both donated pools are updated in place, nothing as large as
+    a layer's share of a pool or a row's whole table of latents is copied
+    or gathered, no stack of weights is converted, and the five scopes the
+    benchmark reads are on the operations' paths."""
+    from determined_clone_tpu.models import glm_moe_dsa as glm
+    from determined_clone_tpu.serving.engine import make_paged_forward
+    from determined_clone_tpu.serving.kv_cache import KVCacheConfig
+
+    cfg = glm.GLMMoeDsaConfig(
+        vocab_size=19360, mlp_layer_types=glm._PUBLISHED_MLPS[2:7],
+        indexer_types=glm._PUBLISHED_INDEXERS[2:7], n_routed_experts=16,
+        max_position_embeddings=32768)
+    assert cfg.kinds == ("dense_full", "sparse_shared", "sparse_shared",
+                         "sparse_shared", "sparse_full")
+    cache = KVCacheConfig(16 * 512, 64)
+    layout = cfg.paged_model().cache_layout(cfg, cache)
+    assert layout.blocks_needed(cfg.max_seq_len) == layout.table_width == 512
+    one = SingleDeviceSharding(v5e[0])
+    params = _shapes(jax.eval_shape(
+        lambda k: glm.serving_params(glm.init(k, cfg), cfg),
+        jax.random.PRNGKey(0)), one)
+    assert 7.7e9 < sum(math.prod(x.shape) * x.dtype.itemsize
+                       for x in jax.tree.leaves(params)) < 7.8e9
+    pools = _shapes(jax.eval_shape(lambda: glm.init_pools(cfg, cache, 16)),
+                    one)
+    assert [p.shape for p in pools] == [(5, 8192, 64, 640),
+                                        (2, 8192, 64, 128)]
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    compiled = make_paged_forward(len(pools)).lower(
+        params, cfg, arr((batch, t), jnp.int32), arr((batch, t), jnp.int32),
+        arr((batch, t), jnp.bool_), arr((batch,), jnp.int32), *pools,
+        arr((batch, layout.table_width), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(math.prod(p.shape) * p.dtype.itemsize for p in pools)
+    assert mem.alias_size_in_bytes >= pool_bytes      # both pools, in place
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 15.75 * 2 ** 30)
+    text = compiled.as_text()
+    # nothing shaped like a layer of a pool, and no sequence's whole table
+    # of latents ([32768, 640] a row): a decode step gathers its 2048
+    # chosen rows, a slice reads a chunk of blocks a pass
+    N, bs, R = pools[0].shape[1:]
+    pool_like = [c for c in _pool_sized_copies(text, 32768 * 128)
+                 if re.search(rf"\[(?:\d+,)?(?:{N},{bs}|{N * bs}|32768),"
+                              rf"(?:{R}|128)\]", c)]
+    assert pool_like == [], pool_like[:3]
+    if t == 1:
+        assert mem.temp_size_in_bytes < 0.25 * 2 ** 30
+        assert not re.search(rf"bf16\[{batch},32768,{R}\]", text)
+        assert re.search(rf"bf16\[(?:{batch},)?2048,{R}\]", text)  # chosen
+    # no matrix is converted, as a stack or as a layer of one: it is read
+    # as it lies (the head alone is raised, for the one row of logits a
+    # slice returns: 0.24 GB read once a call). Shapes that a slice's
+    # activations share (those with its length among their sizes: 512 is
+    # the latent's rank too) are left to the other cases
+    shapes = set()
+    for kind in set(cfg.kinds):
+        n = cfg.kinds.count(kind)
+        for path, shape in glm.layer_shapes(cfg, kind).items():
+            if path.endswith("/kernel") and t not in shape:
+                shapes |= {shape, (n, *shape), (n * shape[0], *shape[1:])}
+    converted, fused = [], False
+    for line in text.splitlines():   # a fused convert is read as it lies
+        if line.endswith("{"):
+            fused = line.lstrip("%").startswith("fused_computation")
+        m = None if fused else _HLO_LINE.match(line)
+        if m and "convert" in (m["opcode"], *m["name"].split("_")) and any(
+                tuple(map(int, dims.split(","))) in shapes
+                for dims in re.findall(r"\w+\[([\d,]+)\]", m["result"])):
+            converted.append(m["name"])
+    assert converted == [], converted[:5]
+    for scope in ("dsa_index", "mla_attn", "kv_cache", "moe_route",
+                  "moe_experts"):
+        assert f"/{scope}/" in text, scope
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("n_chips", [1, 4])
 def test_gpt2_small_train_step_compiles(v5e, monkeypatch, n_chips):
