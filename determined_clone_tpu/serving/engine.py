@@ -8,7 +8,11 @@ for the newcomers), then runs ONE decode step for every active sequence
 their pool blocks and batch slots free up for the next iteration. No
 sequence ever waits for a stranger's completion — the property that
 makes continuous batching beat run-to-completion batching on tokens/sec
-under load.
+under load. The decode step is pipelined one deep: a step's sampled
+tokens stay on the device, the next step takes them from there and is
+dispatched before the host reads them back, so the host's turn (prepare,
+dispatch, commit, this loop) runs while the device computes
+(docs/serving.md "One step ahead").
 
 The model is whatever family the given model config belongs to: the
 engine imports none and asks the config for its
@@ -59,7 +63,7 @@ import dataclasses
 import os
 import threading
 import time
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -123,17 +127,37 @@ def serving_form(params: Any, model_cfg: Any, span: Any = null_span) -> Any:
     return served
 
 
-def forward_paged(params: Any, cfg: Any, tokens: jax.Array,
-                  positions: jax.Array, token_mask: jax.Array,
-                  last_index: jax.Array, *pools_and_tables: jax.Array
-                  ) -> Any:
-    """The paged forward of ``cfg``'s family: a prefill slice or a decode
-    step (``models/gpt.py:forward_paged`` states the contract) over the
-    family's pools, then the block tables. Argument for argument the
-    family's own, so the compiled program is too."""
-    return cfg.paged_model().forward_paged(
-        params, cfg, tokens, positions, token_mask, last_index,
-        *pools_and_tables)
+def forward_paged(params: Any, cfg: Any, rows: jax.Array, tables: jax.Array,
+                  last_tokens: jax.Array, *pools: jax.Array) -> Any:
+    """A prefill slice or a decode step of ``cfg``'s family
+    (``models/gpt.py:forward_paged`` states the family's contract), sampled
+    greedily in the same program: ``(tokens, *pools, *extras)``, ``tokens``
+    the int32 ``argmax`` of every row's logits, padded to ``last_tokens``'
+    length (the engine's largest batch bucket) so that one call's return
+    is the next one's ``last_tokens`` whatever their batch buckets.
+
+    ``rows`` is ``[B, T + 3]`` int32, everything the host knows of a row
+    in one transfer: its ``T`` tokens, the position of the first, how many
+    of them are real (0: a padding row), and ``src``. In a decode step
+    (``T == 1``, told from the shape) row ``i`` takes its token from the
+    device, ``last_tokens[src[i]]``, where ``src[i] >= 0`` (it was row
+    ``src[i]`` of the call that returned ``last_tokens``, whose tokens the
+    host may not have read yet), else from the host's column."""
+    T = rows.shape[1] - 3
+    tokens, first, count, src = (rows[:, :T], rows[:, T], rows[:, T + 1],
+                                 rows[:, T + 2])
+    if T == 1:
+        tokens = jnp.where(src >= 0, last_tokens[jnp.maximum(src, 0)],
+                           tokens[:, 0])[:, None]
+    steps = jnp.arange(T, dtype=jnp.int32)
+    token_mask = steps < count[:, None]
+    positions = jnp.where(token_mask, first[:, None] + steps, 0)
+    logits, *rest = cfg.paged_model().forward_paged(
+        params, cfg, tokens, positions, token_mask,
+        jnp.maximum(count - 1, 0), *pools, tables)
+    sampled = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return (jnp.zeros_like(last_tokens).at[:sampled.shape[0]].set(sampled),
+            *rest)
 
 
 def forward_paged_logits(params: Any, cfg: Any, tokens: jax.Array,
@@ -145,16 +169,17 @@ def forward_paged_logits(params: Any, cfg: Any, tokens: jax.Array,
 
 
 def make_paged_forward(n_pools: int = 2) -> Any:
-    """The jitted paged forward an engine runs everything through, for
-    whichever family the (static) model config it is called with belongs
-    to; ``n_pools`` (``len(PagedModel.pool_names)``) is how many pools
-    the family hands over, each donated.
+    """The jitted, sampling paged forward an engine runs every prefill
+    slice and decode step through, for whichever family the (static) model
+    config it is called with belongs to; ``n_pools``
+    (``len(PagedModel.pool_names)``) is how many pools the family hands
+    over, each donated.
     Replica fleets pass ONE of these to every engine (``fwd=``) so the
     whole fleet shares a single XLA program cache: replica N>1 warms up
     for free, and scale-up never pays a compile (all replicas serve the
     same model config and bucket ladder, so the shapes are identical)."""
     return jax.jit(forward_paged, static_argnums=(1,),
-                   donate_argnums=tuple(range(6, 6 + n_pools)))
+                   donate_argnums=tuple(range(5, 5 + n_pools)))
 
 
 def make_paged_verify(n_pools: int = 2) -> Any:
@@ -317,14 +342,18 @@ class _Handle:
 class _Active:
     """Scheduler-private state of one running sequence."""
 
-    __slots__ = ("handle", "blocks", "by_kind", "prompt_len", "out",
+    __slots__ = ("handle", "blocks", "table", "by_kind", "prompt_len", "out",
                  "last_token", "prefill_pos", "pending_copy", "hit_blocks",
-                 "miss_blocks", "spec_proposed", "spec_accepted", "records")
+                 "miss_blocks", "spec_proposed", "spec_accepted", "records",
+                 "flight", "slot", "retired")
 
     def __init__(self, handle: _Handle, blocks: List[int],
-                 prompt_len: int) -> None:
+                 table: np.ndarray, prompt_len: int) -> None:
         self.handle = handle
         self.blocks = blocks
+        # the row's line of every call's block table: its blocks as the
+        # layout lays them, fixed from admission on
+        self.table = table
         # blocks per kind of the cache layout (gauged while it runs)
         self.by_kind: Tuple[int, ...] = ()
         self.prompt_len = prompt_len
@@ -342,6 +371,35 @@ class _Active:
         self.spec_accepted = 0
         # the programs' records of this row's tokens, a piece a call
         self.records: List[np.ndarray] = []
+        # the decode step this row is part of whose tokens the host has not
+        # read yet, and the row's index in it (the next step's ``src``)
+        self.flight: Optional["_Flight"] = None
+        self.slot = -1
+        self.retired = False  # set by _retire: a step's token is dropped
+
+
+class _Flight(NamedTuple):
+    """One dispatched decode step, until its tokens are read back."""
+    rows: List[_Active]
+    size: Dict[str, int]        # the args of the step's spans
+    attended: Tuple[int, ...]   # cache rows read (the layout's row_args)
+    tokens: Any                 # [max_batch] int32, on the device
+    extras: Tuple[Any, ...]     # what the program returned after its pools
+    dispatch_s: float           # the host's seconds in the dispatch
+
+
+class _PrefillCall(NamedTuple):
+    """One dispatched prefill call, until its first tokens are read back
+    (in the same scheduler turn, after the turn's decode step went out)."""
+    rows: List[_Active]
+    counts: List[int]           # positions each row advances
+    batch: int                  # the call's buckets
+    length: int
+    tokens: Any                 # [max_batch] int32, on the device
+    extras: Tuple[Any, ...]
+    mirrored: Any               # the draft's mirror of the slice, if any
+    t0: float                   # monotonic / perf_counter at the dispatch
+    pt0: float
 
 
 class InferenceEngine:
@@ -430,6 +488,12 @@ class InferenceEngine:
         self._table_width = self._layout.table_width
         self._fwd = fwd if fwd is not None else make_paged_forward(
             len(self._pools))
+        # the decode step whose tokens are still on the device: the next
+        # step is dispatched before it is read (docs/serving.md "One step
+        # ahead"); None after a drain
+        self._flight: Optional[_Flight] = None
+        # ``last_tokens`` of a call that takes no token from the device
+        self._no_tokens = jnp.zeros((self.buckets.max_batch,), jnp.int32)
 
         # -- optional raw-speed features (module docstring) --------------
         self.chunk_prefill_len = int(chunk_prefill_len)
@@ -560,6 +624,13 @@ class InferenceEngine:
         self._h_spec_accept = m.histogram(
             "serving_spec_request_acceptance_rate",
             "per-request draft acceptance rate at retirement")
+        self._c_overlapped = m.counter(
+            "serving_decode_steps_overlapped_total",
+            "decode steps dispatched before the last one was read back")
+        self._c_overrun = m.counter(
+            "serving_decode_overrun_rows_total",
+            "rows of a decode step that had retired by its read-back (an "
+            "eos seen a step late, an abort, a deadline): token dropped")
         self._c_expired = m.counter(
             "serving_requests_expired_total",
             "requests retired at their deadline (blocks freed, not decoded)")
@@ -835,7 +906,7 @@ class InferenceEngine:
         that can dwarf the actual work. Serving stacks precompile at
         startup for exactly this reason.
 
-        The dummy inputs are fully masked (``token_mask`` all False), so
+        The dummy rows hold no real token (``token_mask`` all False), so
         nothing is written to the KV pools — warmup is invisible to
         every later request (the COW copy program is warmed by copying
         block 0 onto itself: bit-identical values). Requires an idle
@@ -858,22 +929,15 @@ class InferenceEngine:
                     lanes.append((self._draft_fwd, self._draft_params,
                                   self.draft_cfg))
                 for b in self.buckets.batch_buckets:
-                    tables = jnp.zeros((b, self._table_width), jnp.int32)
+                    tables = np.zeros((b, self._table_width), np.int32)
                     for fwd, params, cfg in lanes:
                         for t in (*self.buckets.prefill_len_buckets, 1):
                             held = self._pools_for(cfg)
-                            logits, *pools = fwd(
-                                params, cfg,
-                                jnp.zeros((b, t), jnp.int32),
-                                jnp.zeros((b, t), jnp.int32),
-                                jnp.zeros((b, t), bool),
-                                jnp.zeros((b,), jnp.int32),
-                                *held, tables)
+                            tokens, *pools = fwd(
+                                params, cfg, self._blank_rows(b, t), tables,
+                                self._no_tokens, *held)
                             self._set_pools_for(cfg, pools[:len(held)])
-                            # the sampling step is its own (tiny) program
-                            # per batch bucket — leave it cold and the
-                            # first real request pays its compile
-                            jnp.argmax(logits, axis=-1).block_until_ready()
+                            tokens.block_until_ready()
                     if self._spec_k:
                         t = self._spec_k + 1
                         logits, *pools = self._verify_fwd(
@@ -883,7 +947,10 @@ class InferenceEngine:
                             jnp.zeros((b, t), bool),
                             *self._pools, tables)
                         self._pools = tuple(pools)
-                        logits.block_until_ready()
+                        # the verify step samples in a (tiny) program of
+                        # its own per batch bucket: leave it cold and the
+                        # first real request pays its compile
+                        jnp.argmax(logits, axis=-1).block_until_ready()
                 if self._copy is not None:
                     self._pools = self._copy(*self._pools, 0, 0)
                     if self._spec_k:
@@ -1099,6 +1166,7 @@ class InferenceEngine:
                            and (self._warming
                                 or (not self._queue and not self._active
                                     and not self._prefilling
+                                    and self._flight is None
                                     and self._pending_params is None))):
                         self._cond.wait()
                     if self._stop:
@@ -1145,15 +1213,22 @@ class InferenceEngine:
                     if self._pending_writes:
                         self._do_writes()
                         worked = True
+                    # a prefill call goes out first (a first token does
+                    # not wait behind this turn's decode step) and is
+                    # read back last: the decode step is dispatched
+                    # while the device runs it
+                    prefill = None
                     if self._prefilling:
-                        self._prefill_step()
+                        prefill = self._prefill_dispatch()
                         worked = True
-                    if self._active:
-                        if self._spec_k:
-                            self._spec_step()
-                        else:
-                            self._decode_step()
+                    if self._active and self._spec_k:
+                        self._spec_step()
                         worked = True
+                    elif self._active or self._flight is not None:
+                        self._decode_step()
+                        worked = True
+                    if prefill is not None:
+                        self._prefill_settle(prefill)
                     self._beat_t = time.monotonic()
                 if worked and self.iteration_floor_s > 0.0:
                     pad = self.iteration_floor_s \
@@ -1205,6 +1280,7 @@ class InferenceEngine:
             pairs.append((a.handle, True))
         self._active.clear()
         self._prefilling.clear()
+        self._flight = None  # its rows' blocks went with the rows above
         if self._prefix is not None:
             self._prefix.flush()
         self._g_active.set(0)
@@ -1299,8 +1375,10 @@ class InferenceEngine:
                 self._h_queue_wait.observe(now - head.submit_t)
             fresh = self._allocator.allocate_blocks(need)
             # the slots' ids come last, as the layout lays its table
-            a = _Active(head, shared + fresh
-                        + self._allocator.allocate_slots(n_slots), plen)
+            blocks = shared + fresh + self._allocator.allocate_slots(n_slots)
+            table = np.zeros((self._table_width,), np.int32)
+            self._layout.lay_table(table, blocks)
+            a = _Active(head, blocks, table, plen)
             a.by_kind = by_kind
             self._gauge_kinds(by_kind, +1)
             a.prefill_pos = skip
@@ -1547,23 +1625,34 @@ class InferenceEngine:
         else:
             self._draft_pools = tuple(pools)
 
-    def _tables_for(self, rows: Sequence[_Active], padded_b: int
-                    ) -> jnp.ndarray:
-        tables = np.zeros((padded_b, self._table_width), np.int32)
-        for i, a in enumerate(rows):
-            self._layout.lay_table(tables[i], a.blocks)
-        return jnp.asarray(tables)
+    @staticmethod
+    def _blank_rows(padded_b: int, t: int) -> np.ndarray:
+        """``forward_paged``'s ``rows`` for a call of ``t`` tokens a row,
+        every row still padding: no token, none real, none from the
+        device."""
+        rows = np.zeros((padded_b, t + 3), np.int32)
+        rows[:, -1] = -1
+        return rows
 
-    def _prefill_step(self) -> None:
-        """One bucketed prefill call covering every prefilling row's
-        next slice of prompt. Without chunking a row's slice is its
+    def _tables_for(self, rows: Sequence[_Active], padded_b: int
+                    ) -> np.ndarray:
+        """The call's block table: each row's own line (laid at
+        admission), zeros for the padding rows. Handed to the program as
+        it is: the call transfers it."""
+        tables = np.zeros((padded_b, self._table_width), np.int32)
+        tables[:len(rows)] = [a.table for a in rows]
+        return tables
+
+    def _prefill_dispatch(self) -> _PrefillCall:
+        """Dispatch one bucketed prefill call covering every prefilling
+        row's next slice of prompt. Without chunking a row's slice is its
         whole remaining prompt (one call, as before); with chunking each
         row advances at most ``chunk_prefill_len`` positions per
-        iteration, so the decode step below never waits behind a long
-        prompt. Rows whose slice reaches the end of the prompt sample
-        their first token from the slice's last logits and graduate to
-        the decode set; prefix-cache rows start at ``prefill_pos > 0``
-        and their completed prompts are registered for future sharing.
+        iteration, so a decode step never waits behind a long prompt.
+        Nothing is read here: the turn's decode step is dispatched next,
+        behind this call on the device, and :meth:`_prefill_settle` reads
+        the call's first tokens after it. The rows are in no decode step
+        meanwhile, so nothing else touches their blocks.
         """
         rows = list(self._prefilling)
         with self._span("prefill_prepare", rows=len(rows)):
@@ -1576,42 +1665,56 @@ class InferenceEngine:
                 cnt.append(remaining)
             b = bucket_for(len(rows), self.buckets.batch_buckets)
             t = bucket_for(max(cnt), self.buckets.prefill_len_buckets)
-            tok = np.zeros((b, t), np.int32)
-            pos = np.zeros((b, t), np.int32)
-            msk = np.zeros((b, t), bool)
-            last = np.zeros((b,), np.int32)
+            # a row: its slice's tokens, where it starts, its length, and
+            # no token from the device (forward_paged)
+            packed = self._blank_rows(b, t)
             for i, a in enumerate(rows):
                 lo, n = a.prefill_pos, cnt[i]
-                tok[i, :n] = a.handle.req.prompt[lo:lo + n]
-                pos[i, :n] = np.arange(lo, lo + n)
-                msk[i, :n] = True
-                last[i] = n - 1
-            jt = (jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(msk),
-                  jnp.asarray(last))
+                packed[i, :n] = a.handle.req.prompt[lo:lo + n]
+                packed[i, t:t + 2] = lo, n
             tables = self._tables_for(rows, b)
         t0 = time.monotonic()
         pt0 = time.perf_counter() if self._tracer is not None else 0.0
-        with self._span("serving_prefill", batch=b, length=t) as prefill:
-            logits, *pools = self._fwd(
-                self._params, self.model_cfg, *jt, *self._pools, tables)
+        with self._span("prefill_dispatch", batch=b, length=t):
+            tokens, *pools = self._fwd(
+                self._params, self.model_cfg, packed, tables,
+                self._no_tokens, *self._pools)
             extras = self._keep_pools(pools)
+            mirrored = None
             if self._spec_k:
                 # mirror the slice into the draft pools so the proposal
                 # loop sees the same context the target does
-                dl, *pools = self._draft_fwd(
-                    self._draft_params, self.draft_cfg, *jt,
-                    *self._draft_pools, tables)
+                mirrored, *pools = self._draft_fwd(
+                    self._draft_params, self.draft_cfg, packed, tables,
+                    self._no_tokens, *self._draft_pools)
                 self._draft_pools = tuple(pools)
-                dl.block_until_ready()
-            first, counted, records = self._read_back(
-                jnp.argmax(logits, axis=-1), extras)
+            for out in (tokens, *extras):
+                out.copy_to_host_async()
+        return _PrefillCall(rows, cnt, b, t, tokens, extras, mirrored, t0,
+                            pt0)
+
+    def _prefill_settle(self, call: _PrefillCall) -> None:
+        """Read back a prefill call (``serving_prefill``: the wait for the
+        device and the one transfer) and commit it. Rows whose slice
+        reached the end of the prompt take their first token from the
+        slice's last logits and graduate to the decode set, which the next
+        turn's step picks up; prefix-cache rows started at ``prefill_pos >
+        0`` and their completed prompts are registered for future sharing.
+        """
+        rows, cnt = call.rows, call.counts
+        with self._span("serving_prefill", batch=call.batch,
+                        length=call.length) as prefill:
+            if call.mirrored is not None:
+                call.mirrored.block_until_ready()
+            first, counted, records = self._read_back(call.tokens,
+                                                      call.extras)
             prefill.set(**counted)
-        dt = time.monotonic() - t0
+        dt = time.monotonic() - call.t0
         self._h_prefill.observe(dt)
         if self._tracer is not None:
             for i, a in enumerate(rows):
                 self._tracer.record_span(
-                    "request_prefill_chunk", pt0, dt, **self._req_args(
+                    "request_prefill_chunk", call.pt0, dt, **self._req_args(
                         a.handle.req, pos=a.prefill_pos, tokens=cnt[i]))
         done_t = time.monotonic()
         still_prefilling: List[_Active] = []
@@ -1629,8 +1732,8 @@ class InferenceEngine:
                 self._prefix.register(
                     a.handle.req.prompt,
                     a.blocks[:self.cache.blocks_needed(a.prompt_len)])
-            a.out.append(int(first[i]))
             a.last_token = int(first[i])
+            a.out.append(a.last_token)
             if not self._maybe_finish(a):
                 graduated.append(a)
         with self._cond:
@@ -1640,54 +1743,104 @@ class InferenceEngine:
             self._g_free_blocks.set(self._allocator.free_blocks())
 
     def _decode_step(self) -> None:
-        """One decode iteration for every active sequence: append each
-        row's last sampled token to the pool, sample the next."""
-        rows = list(self._active)
+        """One turn of the decode pipeline, which runs one step deep:
+        dispatch a step for every active sequence that still wants a token,
+        THEN read back and commit the step dispatched a turn ago, so the
+        host's turn runs while the device computes. The step takes each
+        row's input token from the device (``last_tokens[src]``: the host
+        has not read it yet) or, for a row that was in no such step, from
+        the host. Everything else a step needs is the host's own
+        arithmetic; a finish by length is known a step ahead (such a row
+        is not dispatched again), a finish by ``eos`` a step late (its
+        extra step writes inside its own reservation and its token is
+        dropped). With no row to dispatch (every active row's last token is
+        in flight), the turn only reads that step: a drain, after which the
+        next step takes every token from the host."""
+        before = self._flight
+        rows: List[_Active] = []
+        lengths: List[int] = []  # each row's context at this step
+        for a in self._active:
+            # a row with a token in flight is one token further than ``out``
+            n = len(a.out) + (a.flight is not None)
+            if n < a.handle.req.max_new_tokens:
+                rows.append(a)
+                lengths.append(a.prompt_len + n)
+        if not rows:
+            self._flight = None
+            if before is not None:
+                self._settle(before)
+            return
         b = bucket_for(len(rows), self.buckets.batch_buckets)
         # the host's phases of the step, each a span with the same args
         # (docs/observability.md "An engine iteration")
         size = {"batch": b, "rows": len(rows)}
         # cache rows the step reads, at the rows' real lengths
-        attended = self._layout.step_rows(
-            [a.prompt_len + len(a.out) for a in rows], b)
+        attended = self._layout.step_rows(lengths, b)
         size.update(zip(self._row_args, attended))
-        with self._span("decode_prepare", **size):
-            tok = np.zeros((b, 1), np.int32)
-            pos = np.zeros((b, 1), np.int32)
-            msk = np.zeros((b, 1), bool)
-            for i, a in enumerate(rows):
-                tok[i, 0] = a.last_token
-                pos[i, 0] = a.prompt_len + len(a.out) - 1
-                msk[i, 0] = True
-            tables = self._tables_for(rows, b)
-        t0 = time.monotonic()
-        with self._span("serving_decode_step", **size):
+        with self._span("serving_decode_step", **size,
+                        overlapped=int(before is not None)):
+            with self._span("decode_prepare", **size):
+                # a row: its token as the host knows it, its position, one
+                # real token, and where the device holds its token
+                packed = self._blank_rows(b, 1)
+                packed[:len(rows)] = [
+                    (a.last_token, n - 1, 1,
+                     a.slot if a.flight is not None else -1)
+                    for a, n in zip(rows, lengths)]
+                tables = self._tables_for(rows, b)
             with self._span("decode_dispatch", **size):
-                logits, *pools = self._fwd(
-                    self._params, self.model_cfg, jnp.asarray(tok),
-                    jnp.asarray(pos), jnp.asarray(msk),
-                    jnp.zeros((b,), jnp.int32), *self._pools, tables)
+                t0 = time.monotonic()
+                tokens, *pools = self._fwd(
+                    self._params, self.model_cfg, packed, tables,
+                    self._no_tokens if before is None else before.tokens,
+                    *self._pools)
                 extras = self._keep_pools(pools)
-            with self._span("decode_readback", **size):
-                nxt, counted, records = self._read_back(
-                    jnp.argmax(logits, axis=-1), extras)
-        self._h_decode.observe(time.monotonic() - t0)
-        for counter, n in zip(self._c_rows, attended):
+                for out in (tokens, *extras):
+                    out.copy_to_host_async()
+                self._flight = _Flight(rows, size, attended, tokens, extras,
+                                       time.monotonic() - t0)
+                for i, a in enumerate(rows):
+                    a.flight, a.slot = self._flight, i
+            if before is not None:
+                self._c_overlapped.inc()
+                self._settle(before)
+
+    def _settle(self, flight: _Flight) -> None:
+        """Read back one dispatched step (tokens, the program's counts and
+        records, in one transfer) and commit it: append each row's token,
+        retire the rows that finish. A row retired since the dispatch (an
+        ``eos`` in the step before, an abort, a deadline) drops its token."""
+        t0 = time.monotonic()
+        with self._span("decode_readback", **flight.size):
+            nxt, counted, records = self._read_back(flight.tokens,
+                                                    flight.extras)
+        # one observation a dispatched step: the host's time round its
+        # dispatch and round its read-back
+        self._h_decode.observe(flight.dispatch_s + time.monotonic() - t0)
+        for counter, n in zip(self._c_rows, flight.attended):
             counter.inc(n)
-        with self._span("decode_commit", **size, **counted):
-            survivors: List[_Active] = []
-            for i, a in enumerate(rows):
+        with self._span("decode_commit", **flight.size, **counted):
+            finished = overrun = 0
+            for i, (a, token) in enumerate(zip(flight.rows, nxt.tolist())):
+                if a.flight is flight:
+                    a.flight = None
+                if a.retired:
+                    overrun += 1
+                    continue
                 if records is not None:
                     a.records.append(records[i].copy())
-                a.out.append(int(nxt[i]))
-                a.last_token = int(nxt[i])
-                if not self._maybe_finish(a):
-                    survivors.append(a)
-            with self._cond:
-                self._active = survivors
-                self._g_active.set(len(self._active)
-                                   + len(self._prefilling))
-                self._g_free_blocks.set(self._allocator.free_blocks())
+                a.last_token = token
+                a.out.append(token)
+                finished += self._maybe_finish(a)
+            if overrun:
+                self._c_overrun.inc(overrun)
+            if finished:
+                with self._cond:
+                    self._active = [a for a in self._active
+                                    if not a.retired]
+                    self._g_active.set(len(self._active)
+                                       + len(self._prefilling))
+                    self._g_free_blocks.set(self._allocator.free_blocks())
 
     def _spec_step(self) -> None:
         """One speculative iteration for every active sequence: the
@@ -1720,23 +1873,20 @@ class InferenceEngine:
         with self._span("serving_spec_step", k=k, **size):
             drafts = np.zeros((len(rows), k), np.int64)
             cur = np.array([a.last_token for a in rows])
-            zero_last = jnp.zeros((b,), jnp.int32)
             for j in range(k):
-                tok = np.zeros((b, 1), np.int32)
-                pos = np.zeros((b, 1), np.int32)
-                msk = np.zeros((b, 1), bool)
-                tok[:len(rows), 0] = cur
-                pos[:len(rows), 0] = n0 - 1 + j
-                msk[:len(rows), 0] = j < allow
+                # a draft row: its token, its position, whether the slot
+                # is inside its allowance, none from the device
+                packed = self._blank_rows(b, 1)
+                packed[:len(rows), 0] = cur
+                packed[:len(rows), 1] = n0 - 1 + j
+                packed[:len(rows), 2] = j < allow
                 with self._span("decode_dispatch", **size):
-                    dl, *pools = self._draft_fwd(
-                        self._draft_params, self.draft_cfg,
-                        jnp.asarray(tok), jnp.asarray(pos),
-                        jnp.asarray(msk), zero_last,
-                        *self._draft_pools, tables)
+                    sampled, *pools = self._draft_fwd(
+                        self._draft_params, self.draft_cfg, packed, tables,
+                        self._no_tokens, *self._draft_pools)
                     self._draft_pools = tuple(pools)
                 with self._span("decode_readback", **size):
-                    cur = np.asarray(jnp.argmax(dl, axis=-1))[:len(rows)]
+                    cur = np.asarray(sampled)[:len(rows)]
                 drafts[:, j] = cur
             tok = np.zeros((b, k + 1), np.int32)
             pos = np.zeros((b, k + 1), np.int32)
@@ -1812,6 +1962,7 @@ class InferenceEngine:
 
     def _retire(self, a: _Active, reason: str) -> None:
         now = time.monotonic()
+        a.retired = True
         self._allocator.release(a.blocks)
         self._gauge_kinds(a.by_kind, -1)
         h = a.handle

@@ -15,7 +15,8 @@ in a worker they share (``jax.jit``'s cache is per function, not per test).
 import pytest
 
 pytest.register_assert_rewrite(
-    "benchmarks.tests.test_glm_moe_dsa", "benchmarks.tests.test_minicpm_sala", "benchmarks.tests.test_paged",
+    "benchmarks.tests.test_glm_moe_dsa", "benchmarks.tests.test_minicpm_sala", "benchmarks.tests.test_overlap",
+    "benchmarks.tests.test_paged",
     "benchmarks.tests.test_reference", "benchmarks.tests.test_scopes",
     "benchmarks.tests.test_spec", "benchmarks.tests.test_stats",
     "benchmarks.tests.test_trace", "benchmarks.tests.test_traffic")
@@ -34,6 +35,12 @@ from benchmarks.tests.test_minicpm_sala import (  # noqa: E402,F401
     as test_sala_tiny_cell_lists_what_the_real_cell_lists,
     test_traced_steps_are_matched_by_their_durations
     as test_sala_traced_steps_are_matched_by_their_durations,
+)
+from benchmarks.tests.test_overlap import (  # noqa: E402,F401
+    test_nothing_to_read_is_none_not_an_error
+    as test_overlap_nothing_to_read_is_none_not_an_error,
+    test_share_of_the_steps_dispatched_ahead,
+    test_the_manifest_lists_the_reader_as_it_describes_itself,
 )
 from benchmarks.tests.test_paged import (  # noqa: E402,F401
     test_a_traced_run_reads_the_scopes_time_and_the_rows_bytes,

@@ -207,6 +207,16 @@ def test_flash_kernel_runs_per_shard_under_a_mesh(v5e, monkeypatch):
     assert "bf16[4,1024,384]" in text and "bf16[8,1024,768]" not in text
 
 
+def _step_inputs(arr, batch, t, table_width):
+    """What the engine's jitted forward takes between the model config
+    and the pools (``serving/engine.py:forward_paged``): a row's ``t``
+    tokens with its first position, its count and ``src``; the block
+    table; the last call's sampled tokens, as wide as the largest batch
+    bucket (here: this batch)."""
+    return (arr((batch, t + 3), jnp.int32),
+            arr((batch, table_width), jnp.int32), arr((batch,), jnp.int32))
+
+
 def _compile_paged_forward(cfg, device, *, blocks, block, batch, t):
     """The engine's jitted forward for a described chip, the weights in
     the form the engine holds them in (``serving_params``), pools as
@@ -228,10 +238,9 @@ def _compile_paged_forward(cfg, device, *, blocks, block, batch, t):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
     compiled = make_paged_forward().lower(
-        _shapes(params, one), cfg, arr((batch, t), jnp.int32),
-        arr((batch, t), jnp.int32), arr((batch, t), jnp.bool_),
-        arr((batch,), jnp.int32), k_pool, v_pool,
-        arr((batch, cfg.max_seq_len // block), jnp.int32)).compile()
+        _shapes(params, one), cfg,
+        *_step_inputs(arr, batch, t, cfg.max_seq_len // block),
+        k_pool, v_pool).compile()
     return compiled, k_pool.shape
 
 
@@ -429,9 +438,8 @@ def test_evabyte_paged_forward_compiles_at_the_cell_sizes(v5e, batch, t):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
     compiled = make_paged_forward().lower(
-        params, cfg, arr((batch, t), jnp.int32), arr((batch, t), jnp.int32),
-        arr((batch, t), jnp.bool_), arr((batch,), jnp.int32), k_pool, v_pool,
-        arr((batch, layout.table_width), jnp.int32)).compile()
+        params, cfg, *_step_inputs(arr, batch, t, layout.table_width),
+        k_pool, v_pool).compile()
     mem = compiled.memory_analysis()
     pool_bytes = 2 * math.prod(k_pool.shape)
     assert mem.alias_size_in_bytes >= 2 * pool_bytes  # both pools, in place
@@ -488,9 +496,8 @@ def test_minicpm_sala_paged_forward_compiles_at_the_cell_sizes(v5e, batch,
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
     compiled = make_paged_forward(len(pools)).lower(
-        params, cfg, arr((batch, t), jnp.int32), arr((batch, t), jnp.int32),
-        arr((batch, t), jnp.bool_), arr((batch,), jnp.int32), *pools,
-        arr((batch, layout.table_width), jnp.int32)).compile()
+        params, cfg, *_step_inputs(arr, batch, t, layout.table_width),
+        *pools).compile()
     mem = compiled.memory_analysis()
     pool_bytes = sum(math.prod(p.shape) * p.dtype.itemsize for p in pools)
     assert mem.alias_size_in_bytes >= pool_bytes      # every pool, in place
@@ -552,9 +559,8 @@ def test_glm_moe_dsa_paged_forward_compiles_at_the_cell_sizes(v5e, batch, t):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
     compiled = make_paged_forward(len(pools)).lower(
-        params, cfg, arr((batch, t), jnp.int32), arr((batch, t), jnp.int32),
-        arr((batch, t), jnp.bool_), arr((batch,), jnp.int32), *pools,
-        arr((batch, layout.table_width), jnp.int32)).compile()
+        params, cfg, *_step_inputs(arr, batch, t, layout.table_width),
+        *pools).compile()
     mem = compiled.memory_analysis()
     pool_bytes = sum(math.prod(p.shape) * p.dtype.itemsize for p in pools)
     assert mem.alias_size_in_bytes >= pool_bytes      # both pools, in place

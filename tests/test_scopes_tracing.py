@@ -178,6 +178,15 @@ def _inside(inner, outer):
 
 
 def test_engine_records_the_span_tree_of_every_decode_step():
+    """A step's spans, now that the engine runs one step ahead
+    (docs/observability.md "An engine iteration"): ``serving_decode_step``
+    holds the turn, ``decode_prepare`` then ``decode_dispatch`` of this
+    step and, where the step before it was still unread (``overlapped``),
+    that step's ``decode_readback`` then ``decode_commit``. A step that no
+    other follows (every row's last token is in flight) is read back
+    outside every step's span, in a turn with nothing left to dispatch.
+    A prefill call is dispatched before the turn's step and read after it
+    (``serving_prefill``), outside the step's span."""
     tel = Telemetry(enabled=True)
     tokens = _serve(tel)
     events = [e for e in tel.tracer.events() if e.get("ph") != "i"]
@@ -187,34 +196,71 @@ def test_engine_records_the_span_tree_of_every_decode_step():
     steps = by_name["serving_decode_step"]
     assert len(steps) >= 3  # 4 tokens a request, the first from prefill
     for name in PHASES:
+        # one of each a dispatched step, a step's own in the step's order
         assert len(by_name[name]) == len(steps), name
     iterations = by_name["engine_iteration"]
+
+    def end(e):
+        return e["ts_us"] + e["dur_us"]
+
+    assert steps[0]["args"]["overlapped"] == 0
+    assert any(s["args"]["overlapped"] for s in steps)
     for i, step in enumerate(steps):
         (iteration,) = [it for it in iterations if _inside(step, it)]
         prepare, dispatch, readback, commit = (
             by_name[n][i] for n in ("decode_prepare", "decode_dispatch",
                                     "decode_readback", "decode_commit"))
-        # in that order inside the iteration; dispatch then read-back
-        # inside the step
-        for phase in (prepare, step, commit):
-            assert _inside(phase, iteration)
-        assert _inside(dispatch, step) and _inside(readback, step)
-        order = [prepare, dispatch, readback, commit]
-        assert [e["ts_us"] for e in order] == sorted(
-            e["ts_us"] for e in order)
-        assert prepare["ts_us"] + prepare["dur_us"] <= step["ts_us"] + 0.2
-        assert commit["ts_us"] >= step["ts_us"] + step["dur_us"] - 0.2
-        assert dispatch["depth"] == step["depth"] + 1 == iteration["depth"] + 2
+        # this step's prepare then dispatch inside its span; its read-back
+        # and commit after them, in that order, a turn late or in a drain
+        assert _inside(prepare, step) and _inside(dispatch, step)
+        assert end(prepare) <= dispatch["ts_us"] + 0.2
+        assert end(dispatch) <= readback["ts_us"] + 0.2
+        assert end(readback) <= commit["ts_us"] + 0.2
+        assert prepare["depth"] == dispatch["depth"] == step["depth"] + 1 \
+            == iteration["depth"] + 2
+        (held,) = [it for it in iterations if _inside(readback, it)]
+        assert _inside(commit, held)
+        later = steps[i + 1] if i + 1 < len(steps) else None
+        if later is not None and later["args"]["overlapped"]:
+            # read inside the next step's span, after that step's dispatch
+            assert _inside(readback, later) and _inside(commit, later)
+            assert end(by_name["decode_dispatch"][i + 1]) \
+                <= readback["ts_us"] + 0.2
+        else:
+            # a drain: outside every step's span, before the next step
+            assert not any(_inside(readback, s) for s in steps)
+            assert not any(_inside(commit, s) for s in steps)
+            assert later is None or end(commit) <= later["ts_us"] + 0.2
+        assert step["args"].pop("overlapped") in (0, 1)
         for e in (prepare, step, dispatch, readback, commit):
             # the uniform cache's rows: attended, and spanned by the tables
+            assert e["args"] == step["args"]
             assert set(e["args"]) == {"rows", "batch", "kv_rows",
                                       "table_rows"}
             assert 1 <= e["args"]["rows"] <= e["args"]["batch"]
             assert e["args"]["rows"] <= e["args"]["kv_rows"] \
                 < e["args"]["table_rows"]
+    # a prefill call: prepare then dispatch, the turn's decode step if
+    # there is one, then the read-back, inside no step's span
+    calls = by_name["serving_prefill"]
+    assert len(by_name["prefill_prepare"]) == len(
+        by_name["prefill_dispatch"]) == len(calls)
+    for prepare, dispatch, call in zip(by_name["prefill_prepare"],
+                                       by_name["prefill_dispatch"], calls):
+        (iteration,) = [it for it in iterations if _inside(call, it)]
+        assert _inside(prepare, iteration) and _inside(dispatch, iteration)
+        assert end(prepare) <= dispatch["ts_us"] + 0.2
+        assert end(dispatch) <= call["ts_us"] + 0.2
+        assert dispatch["args"] == {k: call["args"][k]
+                                    for k in ("batch", "length")}
+        for step in steps:
+            assert not _inside(call, step) and not _inside(step, call)
+            if _inside(step, iteration):  # dispatched between the two
+                assert end(dispatch) <= step["ts_us"] + 0.2
+                assert end(step) <= call["ts_us"] + 0.2
     # admission is spanned alone (the wait on the condition never is), and
     # a prefill has its prepare phase
-    assert by_name["admit"] and by_name["prefill_prepare"]
+    assert by_name["admit"]
     assert all(not _inside(a, it) for a in by_name["admit"]
                for it in iterations)
     assert all(len(t) == 4 for t in tokens)

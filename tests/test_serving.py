@@ -176,6 +176,10 @@ def test_warmup_precompiles_full_ladder(params):
         for h in hs:
             h.result(timeout=120.0)
         assert eng.programs_compiled() == compiled  # nothing new to compile
+        # ... and steps dispatched a step ahead (their token taken from
+        # the device) were among that traffic
+        assert eng.registry.counter(
+            "serving_decode_steps_overlapped_total").value >= 6
     with make_engine(params) as eng:
         # white-box: an un-notified queue entry keeps the scheduler
         # parked, so the busy engine is observed deterministically
@@ -236,6 +240,10 @@ def test_decode_steps_count_attended_and_tabled_rows(params):
              for name in ("decode_prepare", "serving_decode_step",
                           "decode_dispatch", "decode_readback",
                           "decode_commit")}
+    # a step's read-back and commit come a turn late, with the step's own
+    # args; the step's span alone says whether it was dispatched ahead
+    assert {a.pop("overlapped") for a in steps["serving_decode_step"]} \
+        == {0, 1}
     whole = steps["serving_decode_step"]
     assert whole and all(args == whole for args in steps.values())
     assert all(a["table_rows"] == a["batch"] * width for a in whole)
@@ -244,6 +252,207 @@ def test_decode_steps_count_attended_and_tabled_rows(params):
     assert attended == sum(a["kv_rows"] for a in whole) == sum(
         len(p) + i for p, n in zip(prompts, new) for i in range(1, n))
     assert tabled == sum(a["table_rows"] for a in whole)
+
+
+# -- one step ahead: the pipelined decode step --------------------------------
+
+class _BeforeDecodeCall:
+    """``eng._fwd`` with ``then()`` run on the scheduler thread just before
+    the ``at``-th decode call (``rows`` is ``[b, 4]``) is dispatched: the
+    step before it, if any, is then in flight and unread."""
+
+    def __init__(self, eng, at, then):
+        self.real, self.at, self.then, self.calls = eng._fwd, at, then, 0
+        eng._fwd = self
+
+    def __call__(self, params, cfg, rows, *rest):
+        if rows.shape[1] == 4:
+            self.calls += 1
+            if self.calls == self.at:
+                self.then()
+        return self.real(params, cfg, rows, *rest)
+
+
+def _counter(eng, name):
+    return eng.registry.counter(name).value
+
+
+@pytest.fixture(scope="module")
+def varied(params):
+    """Weights whose continuations vary from token to token: the seeded
+    tree repeats a prompt's last token for ever, which a step fed the
+    wrong row's token, or a stale one, would repeat just as well."""
+    leaves, tree = jax.tree.flatten(params)
+    keys = jax.random.split(jax.random.PRNGKey(7), len(leaves))
+    noisy = jax.tree.unflatten(tree, [
+        x + jax.random.normal(k, x.shape, x.dtype)
+        for x, k in zip(leaves, keys)])
+    assert len(set(naive_greedy(noisy, PROMPTS[2], 8))) >= 4
+    return noisy
+
+
+def test_pipelined_batch_matches_one_request_at_a_time(varied):
+    """Requests that finish by length at different steps, with rows joining
+    from prefill while a step is in flight, get token for token what each
+    gets alone."""
+    wave1 = [(PROMPTS[0], 3), (PROMPTS[1], 9), (PROMPTS[2], 6)]
+    wave2 = [([7, 7, 2, 90], 5), ([33] * 9, 2), ([4, 8, 15, 16, 23, 42], 7)]
+    params, late = varied, []
+    with make_engine(params) as eng:
+        _BeforeDecodeCall(eng, 2, lambda: late.extend(
+            eng.submit(p, n) for p, n in wave2))
+        with eng._cond:  # admitted together: one prefill call
+            first = [eng.submit(p, n) for p, n in wave1]
+        got = [h.result(timeout=120.0) for h in first]
+        got += [h.result(timeout=120.0) for h in late]
+        eng.wait_idle(timeout=60.0)
+        assert _counter(eng, "serving_decode_steps_overlapped_total") > 0
+        assert _counter(eng, "serving_decode_overrun_rows_total") == 0
+        eng.assert_kv_balanced()
+        alone = [eng.generate(p, n) for p, n in wave1 + wave2]
+    assert len(late) == 3
+    for r, ref, (p, n) in zip(got, alone, wave1 + wave2):
+        assert r.tokens == ref.tokens == naive_greedy(params, p, n)
+        assert r.finish_reason == "length" and len(r.tokens) == n
+
+
+def test_eos_a_step_late_drops_the_extra_token(varied):
+    """A finish by ``eos`` is seen when its step is read, a turn after the
+    next step was dispatched with the row in it: the result still ends at
+    the ``eos`` token, the extra step's token is dropped and counted, and
+    every block comes back."""
+    params, new = varied, 10
+    prompts = [PROMPTS[0], PROMPTS[1], PROMPTS[2], [7, 7, 2, 90]]
+    refs = [naive_greedy(params, p, new) for p in prompts]
+    # eos: the first token a request emits that is not its first one (a
+    # later step is in flight when it is read), except for the last
+    # request, which stops on its first token, out of prefill
+    eos = [next(t for t in ref if t != ref[0]) for ref in refs[:-1]] \
+        + [refs[-1][0]]
+    stops = [ref.index(e) + 1 for ref, e in zip(refs, eos)]
+    overrun = sum(1 for stop in stops if 1 < stop < new)
+    assert overrun == 3 and stops[-1] == 1
+    with make_engine(params) as eng:
+        with eng._cond:  # one prefill call, then decode steps alone
+            hs = [eng.submit(p, new, eos_token_id=e)
+                  for p, e in zip(prompts, eos)]
+        results = [h.result(timeout=120.0) for h in hs]
+        eng.wait_idle(timeout=60.0)
+        assert _counter(eng, "serving_decode_overrun_rows_total") == overrun
+        eng.assert_kv_balanced()
+        assert eng.stats().free_blocks == CACHE.num_blocks
+    for r, ref, stop in zip(results, refs, stops):
+        assert r.finish_reason == "eos" and r.tokens == ref[:stop]
+
+
+@pytest.mark.parametrize("how", ["abort", "deadline"])
+def test_abort_and_deadline_while_a_step_is_in_flight(varied, how):
+    """The row is retired at the next iteration boundary with what was
+    committed; the token of the step in flight is dropped, its blocks are
+    released once."""
+    import threading
+    import time
+
+    reached, release = threading.Event(), threading.Event()
+    with make_engine(varied) as eng:
+        ref = eng.generate(PROMPTS[0], 12).tokens  # and the programs warm
+        assert len(set(ref[:3])) > 1
+        _BeforeDecodeCall(eng, 2, lambda: (reached.set(),
+                                           release.wait(60.0)))
+        deadline = time.monotonic() + 0.5 if how == "deadline" else None
+        h = eng.submit(PROMPTS[0], 12, deadline_t=deadline)
+        assert reached.wait(60.0)  # step 1 in flight, step 2 at the gate
+        if how == "abort":
+            assert eng.abort(h)
+        else:
+            time.sleep(max(0.0, deadline - time.monotonic()) + 0.01)
+        release.set()
+        r = h.result(timeout=120.0)
+        eng.wait_idle(timeout=60.0)
+        assert r.finish_reason == ("aborted" if how == "abort"
+                                   else "expired")
+        # the prefill's token and step 1's; step 2's was dropped
+        assert r.tokens == ref[:2]
+        assert _counter(eng, "serving_decode_overrun_rows_total") == 1
+        assert eng._flight is None
+        eng.assert_kv_balanced()
+        assert eng.generate(PROMPTS[0], 12).tokens == ref  # and serves on
+
+
+def test_a_forward_that_raises_fails_every_waiter_and_frees_every_block(
+        params):
+    """An error of a step in flight surfaces a turn late at the latest;
+    whenever it does, running and queued requests all fail (none hangs)
+    and the allocator is balanced."""
+    from determined_clone_tpu.serving.engine import ReplicaFailed
+
+    def boom():
+        raise RuntimeError("device fell over")
+
+    with make_engine(params) as eng:
+        _BeforeDecodeCall(eng, 3, boom)
+        with eng._cond:  # four running (max_batch), two queued behind them
+            hs = [eng.submit(p, 12) for p in PROMPTS * 2]
+        for h in hs:
+            with pytest.raises(ReplicaFailed, match="device fell over"):
+                h.result(timeout=120.0)
+        assert eng._flight is None and not eng._active
+        eng.assert_kv_balanced()
+        with pytest.raises(ReplicaFailed):
+            eng.submit(PROMPTS[0], 2)
+
+
+def test_overlapped_is_one_on_steady_steps_and_zero_after_a_drain(varied):
+    """``overlapped`` on ``serving_decode_step``: 0 on the first step of a
+    busy stretch (nothing was in flight), 1 on every step dispatched while
+    the one before it was unread. A prefill call does not drain the
+    pipeline: it is dispatched before the turn's step and read after it, so
+    the steps round it stay overlapped and its ``serving_prefill`` span
+    lies outside every step's. The counter adds the flags up."""
+    from determined_clone_tpu.telemetry import Tracer
+
+    class Telemetry:
+        registry = None
+        tracer = Tracer(enabled=True, process_name="t")
+
+    params, late = varied, []
+    with make_engine(params, telemetry=Telemetry()) as eng:
+        _BeforeDecodeCall(eng, 4, lambda: late.append(
+            eng.submit(PROMPTS[1], 4)))
+        first = eng.submit(PROMPTS[0], 9)
+        assert first.result(timeout=120.0).tokens == naive_greedy(
+            params, PROMPTS[0], 9)
+        assert late[0].result(timeout=120.0).tokens == naive_greedy(
+            params, PROMPTS[1], 4)
+        eng.wait_idle(timeout=60.0)
+        busy = _counter(eng, "serving_decode_steps_overlapped_total")
+        eng.generate(PROMPTS[2], 3)  # after an idle engine: a first step
+        total = _counter(eng, "serving_decode_steps_overlapped_total")
+    events = sorted((e for e in Telemetry.tracer.events()
+                     if e["name"] in ("serving_decode_step",
+                                      "serving_prefill")),
+                    key=lambda e: e["ts_us"])
+    names = [e["name"] for e in events]
+    steps = [e for e in events if e["name"] == "serving_decode_step"]
+    flags = [e["args"]["overlapped"] for e in steps]
+    # 8 steps of the first request, the late one's beside and after them,
+    # then the third request's two
+    assert flags[0] == 0 and flags[-2:] == [0, 1]
+    assert all(flags[1:-2]) and len(flags) >= 8 + 2
+    assert busy == sum(flags[:-2]) and total == sum(flags)
+    # the late request's prefill call: between two overlapped steps, inside
+    # neither's span
+    assert names.count("serving_prefill") == 3
+    late_call = events[[i for i, n in enumerate(names)
+                        if n == "serving_prefill"][1]]
+    i = events.index(late_call)
+    assert names[i - 1] == names[i + 1] == "serving_decode_step"
+    assert events[i - 1]["args"]["overlapped"] == 1 \
+        and events[i + 1]["args"]["overlapped"] == 1
+    assert events[i - 1]["ts_us"] + events[i - 1]["dur_us"] \
+        <= late_call["ts_us"] + 0.2
+    assert late_call["ts_us"] + late_call["dur_us"] \
+        <= events[i + 1]["ts_us"] + 0.2
 
 
 # -- admission control / backpressure ----------------------------------------
