@@ -20,7 +20,6 @@ from __future__ import annotations
 
 import dataclasses
 import gc
-import itertools
 import os
 import threading
 import time
@@ -51,7 +50,7 @@ class _ClosedLoop:
                  clients: int) -> None:
         self.engine = engine
         self.requests = requests
-        self.next_index = itertools.count()
+        self.taken = 0  # requests handed to a client; read under ``lock``
         self.lock = threading.Lock()
         self.served: List[_Served] = []
         self.in_flight: Dict[int, float] = {}  # request index -> submit time
@@ -65,9 +64,10 @@ class _ClosedLoop:
 
         while not self.stop.is_set():
             with self.lock:
-                i = next(self.next_index)
-            if i >= len(self.requests):
-                return
+                i = self.taken
+                if i >= len(self.requests):
+                    return  # the list ran dry: ``requests_outlast_window``
+                self.taken = i + 1
             req = self.requests[i]
             rec = _Served(req, time.monotonic())
             with self.lock:
@@ -186,11 +186,14 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool,
     names a lower precision whose first tokens are scored on the same
     sample (``tools/readings.py``; never in a benchmark run)."""
 
+    t_run = time.monotonic()
     adapter = cell.adapter()
     compiles = device.CompileCounter()
     mix, config = cell.traffic, cell.config
     d = adapter.dims(config)
+    t_adapter = time.monotonic()
     requests = traffic.serve_requests(mix, d["vocab"], seed)
+    t_requests = time.monotonic()
     clients = int(config["serving"]["max_batch"]) \
         if mix["clients"] == "max_batch" else int(mix["clients"])
     telemetry = adapter.new_telemetry() if traced else None
@@ -203,7 +206,9 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool,
     sync_t = None
     loop = _ClosedLoop(engine, requests, clients)
     try:
+        t_built = time.monotonic()
         programs = engine.warmup()
+        t_warm = time.monotonic()
         if telemetry is not None:
             sync_t = time.monotonic()
             telemetry.tracer.instant("bench_clock_sync")
@@ -221,6 +226,8 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool,
             tracer.stop()
         time.sleep(max(0.0, t0 + seconds - time.monotonic()))
         t1 = time.monotonic()
+        with loop.lock:
+            taken_at_t1 = loop.taken
         registry_after = _registry_reading(engine)
         compiled_inside = compiles.requests - requests_before
         # -- closed: the sample is fixed; the load stays until it is done -----
@@ -249,9 +256,16 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool,
     ttft = [(r.prefill_done_t - r.submit_t) * 1e3 for r in ok]
     tpot = [(r.done_t - r.prefill_done_t) * 1e3 / (len(r.tokens) - 1)
             for r in ok if len(r.tokens) > 1]
+    print(f"# set-up {setup_s:.2f} s: {t_run - t_process:.2f} to this call "
+          f"(jax's import, the chip's runtime), {t_adapter - t_run:.2f} the "
+          f"adapter's imports, {t_requests - t_adapter:.2f} the list "
+          f"of {len(requests)} requests, {t_built - t_requests:.2f} weights "
+          f"and engine, {t_warm - t_built:.2f} warm-up of {programs} "
+          f"programs, {t0 - t_warm:.2f} ramp", flush=True)
     print(f"# window: {len(sample)} requests submitted, {len(ok)} sound, "
           f"{len(completed_in)} completed inside, {out_tokens:.1f} tokens "
-          f"generated inside; "
+          f"generated inside; {taken_at_t1} of {len(requests)} offered were "
+          f"taken by its close; "
           f"ttft over {len(ttft)} samples, tpot over {len(tpot)}; "
           f"{programs} programs; {compiles.snapshot()}", flush=True)
 
@@ -260,6 +274,11 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool,
                     and programs_after == programs,
                     detail=[compiled_inside, programs, programs_after])
     verdict.require("no_kv_block_leaked", leaked == 0, detail=leaked)
+    # a list that runs dry thins the batch before the window closes: the rate
+    # then reads lower the faster the engine is, and the tails swing (PR 39)
+    verdict.require("requests_outlast_window",
+                    taken_at_t1 + clients <= len(requests),
+                    detail=[taken_at_t1, clients, len(requests)])
     verdict.require("every_request_sound", bool(sample)
                     and len(ok) == len(sample),
                     detail=[r.error or r.finish_reason

@@ -10,9 +10,12 @@ Kinds:
   the pool in a seeded order, every row of a batch another sequence.
 - ``serve``: requests. ``size_set`` (prompt, output) lengths are drawn once
   from ``size_seed``, so that every run seed offers the same set of sizes;
-  the run seed orders the set, which the requests then walk round and
-  round, and fills in the prompt tokens (uniform over the vocabulary). Outputs are fixed by ``max_new_tokens`` (no EOS), decoding
-  is greedy.
+  the requests walk the set round and round, every walk in an order of its
+  own drawn from the run seed, which also fills in the prompt tokens
+  (uniform over the vocabulary). Under one order a seed the same prompts
+  arrived together walk after walk, and that order, not the program,
+  decided ``gpt2-medium.serve-closed``'s tails (PERF.md section 6, PR 39).
+  Outputs are fixed by ``max_new_tokens`` (no EOS), decoding is greedy.
 """
 from __future__ import annotations
 
@@ -90,9 +93,10 @@ def serve_requests(mix: Dict[str, Any], vocab: int, seed: int
     prompt_len = _lengths(mix["prompt_len"], k, sizes)
     output_len = _lengths(mix["output_len"], k, sizes)
     rng = np.random.default_rng(seed)
-    order = rng.permutation(k)
+    # the orders first: a longer list then walks the same orders further
+    size = np.concatenate([rng.permutation(k) for _ in range(-(-n // k))])
     tokens = rng.integers(0, vocab, size=(n, int(prompt_len.max())),
                           dtype=np.int32)
-    return [ServeRequest(i, tokens[i, :prompt_len[order[i % k]]].tolist(),
-                         int(output_len[order[i % k]]))
+    return [ServeRequest(i, tokens[i, :prompt_len[size[i]]].tolist(),
+                         int(output_len[size[i]]))
             for i in range(n)]

@@ -1,7 +1,10 @@
 """Device time of one decode step under the scope ``kv_cache`` (inside
-``attn``): the scatter of the new tokens' K and V into the paged pool and
-the gather of every row's whole context from it (ROADMAP A3's full-length
-gathers).
+``attn``): the writes of the new tokens' cache rows into the paged pool
+(GPT: the two scatters of K and V; the other families: their own cache
+kinds' writes). Since PR 32 a GPT decode step gathers nothing here: it reads
+its context through the block table inside ``paged_attn``
+(``decode_paged_attn_device_ms``); the whole-table gathers remain in
+``T > 1`` programs, which no decode metric reads (ROADMAP A3(2)).
 """
 from benchmarks.harness import scopes
 

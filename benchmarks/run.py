@@ -8,7 +8,9 @@ result line, when JAX reports no TPU, another number of chips than the cell
 asks for, or a device kind that the benchmark's peak table lacks. Otherwise
 the last line of its output is the result: ``correct``, ``attempted``,
 ``failed``, ``metrics`` (``--trace 0``: end-to-end; ``--trace 1``:
-per-layer), ``device`` and, traced, ``breakdown``.
+per-layer), ``device``, traced ``breakdown``, and last ``checks``: each
+number that decided ``correct`` beside its limit (also the last lines of
+standard error).
 """
 from __future__ import annotations
 
@@ -19,6 +21,7 @@ T_PROCESS = time.monotonic()  # set-up counts from here, imports included
 import argparse  # noqa: E402
 import importlib  # noqa: E402
 import json  # noqa: E402
+import math  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
 
@@ -86,6 +89,18 @@ def main(argv=None) -> int:
     if args.trace:
         line["breakdown"] = {"device_ops": tr["device_ops"],
                              "idle_gaps": tr["idle_gaps"]}
+    # every number that decided ``correct`` beside its limit: last in the
+    # line and last on standard error, which is what the driver keeps of a
+    # run that is not correct
+    line["checks"] = {
+        row["check"]: {"value": row["value"] if math.isfinite(row["value"])
+                       else str(row["value"]),  # NaN is not JSON
+                       "limit": row["limit"]}
+        for row in result["checks"]}
+    for name, row in line["checks"].items():
+        print(f"check {name}: {row['value']} (limit {row['limit']})",
+              file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(line), flush=True)
     return 0
 

@@ -10,7 +10,9 @@ import pytest
 from benchmarks.harness import spec
 
 NAME = "decode_overlap_pct"
-CELLS = ("gpt2-medium.serve-closed", "gpt2-xl.serve-closed")
+CELLS = ("gpt2-medium.serve-closed", "gpt2-xl.serve-closed",
+         "evabyte-6.5b.serve-doc-closed", "minicpm-sala-9b.serve-long-closed",
+         "glm-5.2.serve-agent-closed")  # every serve cell (PR 39)
 
 
 def _read(spans, kind="serve"):

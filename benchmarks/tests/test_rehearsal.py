@@ -107,28 +107,39 @@ def test_train_step_that_leaves_out_half_the_batch_is_not_correct(
 
 def test_served_token_altered_where_it_is_produced_is_not_correct(
         monkeypatch):
-    import jax.numpy as jnp
-
     from determined_clone_tpu.serving import engine as engine_mod
 
-    real = engine_mod.make_paged_forward
+    real = engine_mod.forward_paged  # the entry ``make_paged_forward`` jits
 
-    def altered(*a, **kw):
-        fwd = real(*a, **kw)
+    def altered(params, cfg, rows, tables, last_tokens, *pools):
+        # every token the program samples, as the host reads it and as the
+        # next step takes it from the device, is its neighbour instead
+        tokens, *rest = real(params, cfg, rows, tables, last_tokens, *pools)
+        return (tokens ^ 1, *rest)
 
-        def call(*args):
-            logits, k_pool, v_pool = fwd(*args)
-            # the second-best token is served in place of the best
-            best = jnp.argmax(logits, axis=-1)
-            logits = logits.at[jnp.arange(logits.shape[0]), best].set(-1e9)
-            return logits, k_pool, v_pool
-
-        return call
-
-    monkeypatch.setattr(engine_mod, "make_paged_forward", altered)
+    monkeypatch.setattr(engine_mod, "forward_paged", altered)
     _, r = _run(serve, "tiny.serve")
     assert not r["correct"]
-    assert "served_token_logit_gap" in _failed(r)
+    assert _failed(r) == ["served_token_logit_gap"]
+
+
+@pytest.mark.parametrize("n_requests,outlasts", [(128, False), (4096, True)],
+                         ids=["short_list", "ample_list"])
+def test_a_list_that_runs_dry_inside_the_window_is_not_correct(
+        n_requests, outlasts):
+    """A closed loop whose list is used up sends its clients home: the batch
+    thins before the window closes and the rate reads lower the faster the
+    engine is (``gpt2-medium.serve-closed``, PRs 34-38). Such a run says so."""
+    cell = spec.load_cell("tiny.serve", roots=ROOTS)
+    cell.traffic = dict(cell.traffic, n_requests=n_requests)
+    r = serve.run(cell, 2 ** 31 + 23, 2.0, False, time.monotonic(),
+                  dict(FAKE_DEVICE))
+    (row,) = [c for c in r["checks"]
+              if c["check"] == "requests_outlast_window"]
+    taken, clients, offered = row["detail"]
+    assert offered == n_requests and clients == 4
+    assert row["ok"] == outlasts == (taken + clients <= offered)
+    assert r["correct"] == outlasts
 
 
 @pytest.mark.parametrize("seed", [1, 2, 4])
