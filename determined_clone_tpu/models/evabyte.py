@@ -27,13 +27,14 @@ one summary row per finished chunk, so a sequence holds and a step reads
 ``window + T / chunk`` rows, not ``T``. ``forward_paged`` covers a prefill
 slice (inside one window, starting on a chunk boundary) and a decode step:
 scatter the new rows into the ring; pool and scatter the summary of every
-chunk the call completes (the chunk is one block of the ring); gather the
-ring and the summary blocks, by block; one masked joint softmax. A decode
-step over a pool no larger than its batch's tables (an engine's pool is
-``max_batch`` full tables) gathers nothing: every query is multiplied with
-the layer's whole share of the pool where it lies, under a mask of who owns
-which block (``_pool_mask``), which reads each row once instead of copying
-it and reading the copy.
+chunk the call completes (the chunk is one block of the ring); then one
+joint softmax over what the row attends. A slice gathers the ring and the
+summary blocks, by block, and masks (``eva_attention``). A decode step
+gathers nothing: it reads the pool through the table at each row's real
+window and summary lengths, in one Pallas kernel
+(``ops/eva_paged_attention.py``) that streams only the blocks those rows
+fill, K and V each once; shapes the kernel cannot take (``fits``) fall back
+to the slice's gathered form.
 
 **Weights** are held in ``param_dtype`` (bfloat16) and read as they lie: no
 program casts a matrix. The norm scales, ``phi``, ``mu`` and the head are
@@ -247,7 +248,7 @@ def _block_paged(cfg: EvaByteConfig, bp: Params, x: jax.Array,
                  positions: jax.Array, k_rows: jax.Array, v_rows: jax.Array,
                  scatter_idx: jax.Array, chunk_blocks: jax.Array,
                  summary_idx: jax.Array, gather_blocks: jax.Array,
-                 attn_mask: jax.Array):
+                 attend: Any):
     """One block over the two-kind cache. x: [B, T, D] fp32, the new tokens
     only. k_rows/v_rows: the whole pool as rows [L*N*bs, R]. All indices
     are this layer's: ``scatter_idx`` where the new K/V go, [B*T] rows or,
@@ -256,9 +257,8 @@ def _block_paged(cfg: EvaByteConfig, bp: Params, x: jax.Array,
     chunks this call may complete and ``summary_idx`` [B*nC] the rows
     their summaries go to (past the pool where the chunk is not completed
     or not reserved); ``gather_blocks`` [B, Wt] the ring then the summary
-    blocks, with ``attn_mask`` [B, T, Wt*bs] over what they gather, or, for
-    a decode step that reads the pool in place, the layer's first block (a
-    scalar) with ``attn_mask`` [B, 1, N*bs] over its whole share."""
+    blocks, which ``attend(q, k blocks, v blocks, gather_blocks)`` reads
+    (``_paged_backbone`` says how: the kernel, or gather and mask)."""
     B, T, D = x.shape
     R = k_rows.shape[1]
     bs = cfg.chunk_size
@@ -287,51 +287,24 @@ def _block_paged(cfg: EvaByteConfig, bp: Params, x: jax.Array,
                     sum_k.reshape(-1, R), mode="drop")
                 v_rows = v_rows.at[summary_idx].set(
                     sum_v.reshape(-1, R), mode="drop")
-        with jax.named_scope("kv_cache"):
-            if gather_blocks.ndim == 0:
-                # the layer's whole share of the pool, read where it lies
-                # (gather_blocks is its first block): see _pool_mask
-                n = attn_mask.shape[-1]
-                ctx_k = jax.lax.dynamic_slice_in_dim(
-                    k_rows, gather_blocks * bs, n)
-                ctx_v = jax.lax.dynamic_slice_in_dim(
-                    v_rows, gather_blocks * bs, n)
-            else:
-                # by block, as models/gpt.py gathers: the ring's slot j is
-                # position j of the window, summary slot c is chunk c
-                ctx_k = k_rows.reshape(-1, bs, R)[gather_blocks].reshape(
-                    B, -1, R)
-                ctx_v = v_rows.reshape(-1, bs, R)[gather_blocks].reshape(
-                    B, -1, R)
-        with jax.named_scope("eva_attn"):
-            attn = eva_attention(q, ctx_k, ctx_v, attn_mask)
+        attn = attend(q, k_rows.reshape(-1, bs, R),
+                      v_rows.reshape(-1, bs, R), gather_blocks)
         x = x + _matmul(attn.reshape(B, T, D), bp["attn_out"])
     return _mlp(cfg, bp, x), k_rows, v_rows
 
 
-def _pool_mask(attn_mask: jax.Array, block_tables: jax.Array,
-               token_mask: jax.Array, n_blocks: int, bs: int) -> jax.Array:
-    """A decode step's mask over a layer's whole share of the pool.
-
-    Where the pool is no larger than the batch's tables (``N <= B * Wt``:
-    an engine's pool is ``max_batch`` full tables), gathering every row's
-    blocks copies as many bytes as the pool holds, and attention reads the
-    copy again. Each row's query is multiplied with every row of the pool
-    instead, which reads them once where they lie, and the mask keeps, for
-    row b, the slots of its own blocks that ``attn_mask`` [B, 1, Wt*bs]
-    admits. A block belongs to the row whose table names it; entries of
-    rows with no real token, and -1 entries, name nothing.
-    """
-    B, Wt = block_tables.shape
-    named = (block_tables >= 0) & jnp.any(token_mask, axis=1)[:, None]
-    ids = jnp.where(named, block_tables, n_blocks).reshape(-1)  # N: dropped
-    owner = jnp.full((n_blocks,), -1, jnp.int32).at[ids].set(
-        jnp.repeat(jnp.arange(B, dtype=jnp.int32), Wt), mode="drop")
-    entry = jnp.zeros((n_blocks,), jnp.int32).at[ids].set(
-        jnp.tile(jnp.arange(Wt, dtype=jnp.int32), B), mode="drop")
-    slot = (entry[:, None] * bs + jnp.arange(bs)[None]).reshape(-1)
-    own = jnp.repeat(owner, bs)[None] == jnp.arange(B)[:, None]  # [B, N*bs]
-    return (jnp.take(attn_mask[:, 0], slot, axis=1) & own)[:, None]
+def decode_rows(cfg: EvaByteConfig, positions: jax.Array,
+                token_mask: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """What a decode step's queries attend, from its ``positions`` and
+    ``token_mask`` [B, 1]: int32 [B] rows of the ring (the step's own row
+    among them: it is written before it is read) and summary rows, each
+    from its table's first; both 0 for a row with no real token. The
+    numbers ``WindowSummaryLayout.attended_rows`` gives the step's span."""
+    W = cfg.window_size
+    pos, live = positions[:, 0], token_mask[:, 0]
+    return (jnp.where(live, pos % W + 1, 0).astype(jnp.int32),
+            jnp.where(live, pos // W * (W // cfg.chunk_size),
+                      0).astype(jnp.int32))
 
 
 def _paged_backbone(params: Params, cfg: EvaByteConfig, tokens: jax.Array,
@@ -380,10 +353,33 @@ def _paged_backbone(params: Params, cfg: EvaByteConfig, tokens: jax.Array,
                             nowhere).reshape(B * nC)
 
     gather_blocks = jnp.maximum(block_tables, 0)
-    attn_mask = eva_mask(cfg, positions, W, SB * bs) & token_mask[:, :, None]
-    in_place = T == 1 and N <= B * block_tables.shape[1]
-    if in_place:
-        attn_mask = _pool_mask(attn_mask, block_tables, token_mask, N, bs)
+    attend = None
+    if T == 1:
+        # imported where a decode step is traced, as models/gpt.py imports
+        # its kernels: a module with Pallas kernels costs a process a second
+        from determined_clone_tpu.ops import eva_paged_attention as eva_paged
+
+        if eva_paged.fits(block_tables.shape[1], bs, cfg.n_heads, R,
+                          k_pool.dtype):
+            rows = decode_rows(cfg, positions, token_mask)
+
+            def attend(q, k_blocks, v_blocks, blocks):
+                # through the table, only the blocks the lengths reach
+                with jax.named_scope("eva_attn"):
+                    return eva_paged.eva_paged_attention(
+                        q, k_blocks, v_blocks, blocks, *rows,
+                        window_blocks=WB)
+    if attend is None:
+        mask = eva_mask(cfg, positions, W, SB * bs) & token_mask[:, :, None]
+
+        def attend(q, k_blocks, v_blocks, blocks):
+            with jax.named_scope("kv_cache"):
+                # by block, as models/gpt.py gathers: the ring's slot j is
+                # position j of the window, summary slot c is chunk c
+                ctx_k = k_blocks[blocks].reshape(B, -1, R)
+                ctx_v = v_blocks[blocks].reshape(B, -1, R)
+            with jax.named_scope("eva_attn"):
+                return eva_attention(q, ctx_k, ctx_v, mask)
 
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"]["table"], tokens,
@@ -402,8 +398,7 @@ def _paged_backbone(params: Params, cfg: EvaByteConfig, tokens: jax.Array,
             jnp.where(scatter_idx < L * N, first_block + scatter_idx, L * N)
             if by_block else here(scatter_idx),
             first_block + chunk_blocks, here(summary_idx),
-            first_block if in_place else first_block + gather_blocks,
-            attn_mask)
+            first_block + gather_blocks, attend)
         return (x, k_rows, v_rows), None
 
     (x, k_rows, v_rows), _ = jax.lax.scan(

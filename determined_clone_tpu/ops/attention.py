@@ -133,8 +133,9 @@ def eva_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     and softmax in fp32, the probabilities rounded to q's dtype for the
     product with v (fp32 sums).
 
-    ``T == 1`` (a decode step) reads the rows as they lie, as
-    ``decode_attention_rows`` does. ``T > 1`` (a prefill slice) runs row
+    ``T == 1`` (a decode step whose shapes ``ops/eva_paged_attention.py``
+    cannot take, and that kernel's oracle in the tests) reads the rows as
+    they lie, as ``decode_attention_rows`` does. ``T > 1`` (a prefill slice) runs row
     by row and ``q_block`` queries at a time, so that the fp32 scores
     ``[H, q_block, S]`` are what is live, not ``[B, H, T, S]``.
     """
@@ -143,8 +144,7 @@ def eva_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     scale = D ** -0.5
     if T == 1:
         own, q_diag = _query_over_rows(q, R)
-        ctx = "sr" if k.ndim == 2 else "bsr"  # one context for all rows
-        scores = jnp.einsum(f"bhr,{ctx}->bhs", q_diag, k,
+        scores = jnp.einsum("bhr,bsr->bhs", q_diag, k,
                             preferred_element_type=jnp.float32) * scale
         # kept as they are computed: left to itself the TPU compiler
         # recomputes the product, and reads k again, for the softmax's
@@ -152,7 +152,7 @@ def eva_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         scores = jax.lax.optimization_barrier(scores)
         scores = jnp.where(mask, scores, NEG_INF)       # [B, 1, S] -> heads
         probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        out = jnp.einsum(f"bhs,{ctx}->bhr", probs, v,
+        out = jnp.einsum("bhs,bsr->bhr", probs, v,
                          preferred_element_type=jnp.float32)
         out = jnp.sum(jnp.where(own[None], out, 0.0), axis=1)       # [B, R]
         return out[:, :H * D].astype(q.dtype).reshape(B, 1, H, D)

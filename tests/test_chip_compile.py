@@ -31,6 +31,9 @@ sys.path.insert(0, REPO)
 import chip_smoke  # noqa: E402
 
 from determined_clone_tpu.models import gpt  # noqa: E402
+from determined_clone_tpu.ops import (  # noqa: E402
+    eva_paged_attention as eva_paged_mod,
+)
 from determined_clone_tpu.ops import flash_attention as flash_mod  # noqa: E402
 from determined_clone_tpu.ops import paged_attention as paged_mod  # noqa: E402
 from determined_clone_tpu.parallel import MeshSpec, make_mesh  # noqa: E402
@@ -409,6 +412,45 @@ def test_paged_forward_converts_and_copies_no_weight_stack(v5e, cell, t):
     assert _pool_sized_copies(text, L * D * D) == []
 
 
+_evabyte_programs = {}
+
+
+def _compile_evabyte(device, batch, t):
+    """A program of ``evabyte-6.5b.serve-doc-closed`` (16 layers at the
+    published widths, a pool of 8 x (128 window + 64 summary) blocks), the
+    decode kernel compiled as the chip has it (``fits`` would see the CPU
+    here and hand every shape to the interpreter). Compiled once for the
+    tests that read it. Returns (compiled, one pool's shape)."""
+    from determined_clone_tpu.models import evabyte
+    from determined_clone_tpu.serving.engine import make_paged_forward
+    from determined_clone_tpu.serving.kv_cache import (
+        KVCacheConfig,
+        init_kv_pools,
+    )
+
+    if (batch, t) not in _evabyte_programs:
+        cfg = evabyte.EvaByteConfig(n_layers=16, max_seq_len=16384)
+        cache = KVCacheConfig(8 * 192, 16)
+        layout = cfg.paged_model().cache_layout(cfg, cache)
+        assert layout.blocks_needed(cfg.max_seq_len) == layout.table_width \
+            == 192
+        one = SingleDeviceSharding(device)
+        params = _shapes(jax.eval_shape(lambda k: evabyte.init(k, cfg),
+                                        jax.random.PRNGKey(0)), one)
+        k_pool, v_pool = _shapes(jax.eval_shape(
+            lambda: init_kv_pools(cfg, cache)), one)
+
+        def arr(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(eva_paged_mod, "_should_interpret", lambda: False)
+            _evabyte_programs[batch, t] = (make_paged_forward().lower(
+                params, cfg, *_step_inputs(arr, batch, t, layout.table_width),
+                k_pool, v_pool).compile(), k_pool.shape)
+    return _evabyte_programs[batch, t]
+
+
 @pytest.mark.parametrize("batch,t", [(8, 1), (1, 2048), (8, 2048)],
                          ids=["decode", "slice", "slices-of-8-rows"])
 def test_evabyte_paged_forward_compiles_at_the_cell_sizes(v5e, batch, t):
@@ -417,44 +459,68 @@ def test_evabyte_paged_forward_compiles_at_the_cell_sizes(v5e, batch, t):
     blocks (6.4 GB), the decode step and the 2048-token prefill slice. They
     fit the 15.75 GB a v5e offers a program, the donated pools are updated
     in place, and no program casts a weight: a matrix is read as it lies."""
-    from determined_clone_tpu.models import evabyte
-    from determined_clone_tpu.serving.engine import make_paged_forward
-    from determined_clone_tpu.serving.kv_cache import (
-        KVCacheConfig,
-        init_kv_pools,
-    )
-
-    cfg = evabyte.EvaByteConfig(n_layers=16, max_seq_len=16384)
-    cache = KVCacheConfig(8 * 192, 16)
-    layout = cfg.paged_model().cache_layout(cfg, cache)
-    assert layout.blocks_needed(cfg.max_seq_len) == layout.table_width == 192
-    one = SingleDeviceSharding(v5e[0])
-    params = _shapes(jax.eval_shape(lambda k: evabyte.init(k, cfg),
-                                    jax.random.PRNGKey(0)), one)
-    k_pool, v_pool = _shapes(jax.eval_shape(
-        lambda: init_kv_pools(cfg, cache)), one)
-
-    def arr(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
-
-    compiled = make_paged_forward().lower(
-        params, cfg, *_step_inputs(arr, batch, t, layout.table_width),
-        k_pool, v_pool).compile()
+    compiled, pool_shape = _compile_evabyte(v5e[0], batch, t)
     mem = compiled.memory_analysis()
-    pool_bytes = 2 * math.prod(k_pool.shape)
+    pool_bytes = 2 * math.prod(pool_shape)
     assert mem.alias_size_in_bytes >= 2 * pool_bytes  # both pools, in place
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             < 15.75 * 2 ** 30)
     text = compiled.as_text()
     # of the pool, that is: the 8-row prefill also lays one [16, D, D]
     # weight out anew, once a call (a third of a millisecond)
-    L, N, bs, R = k_pool.shape
+    L, N, bs, R = pool_shape
     pool_dims = (f"{N},{bs},{R}]", f"[{L * N * bs},{R}]", f"[{N * bs},{R}]")
     assert [c for c in _pool_sized_copies(text, N * bs * R)
             if any(d in c for d in pool_dims)] == []
     weight = re.compile(r"= \w+\[(?:16,)?(?:4096|11008),(?:4096|11008)\]"
                         r"[^ ]* convert\(")
     assert [ln for ln in text.splitlines() if weight.search(ln)] == []
+
+
+def test_evabyte_decode_reads_the_pool_through_the_table_in_one_kernel(v5e):
+    """The cell's decode step holds the EVA paged-attention kernel (one
+    call, in the layer scan's body, under ``attn/eva_attn``: the scope the
+    benchmark's ``decode_eva_attn_device_ms`` reads) and neither multiplies
+    a query with a layer's whole share of the pool (scores ``[8, 32,
+    24576]``, a context ``[24576, 4096]``) nor gathers a batch of whole
+    tables (``[8, 3072, 4096]``); all its temporaries together are smaller
+    than one table's K rows. A slice keeps the gather and holds no
+    kernel."""
+    compiled, pool_shape = _compile_evabyte(v5e[0], 8, 1)
+    L, N, bs, R = pool_shape
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 1 and "eva_paged_attn" in calls[0], calls
+    assert "attn/eva_attn" in calls[0]
+    whole = re.compile(rf"\w+\[(?:8,32,{N * bs}|{N * bs},{R}|8,{N * bs // 8},"
+                       rf"{R}|{N},{bs},{R})\]")
+    assert not whole.search(text), whole.search(text).group(0)
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < N * bs // 8 * R * 2
+    slice_text = _compile_evabyte(v5e[0], 1, 2048)[0].as_text()
+    assert "tpu_custom_call" not in slice_text
+
+
+@pytest.mark.parametrize("batch", [1, 2, 4, 8])
+def test_evabyte_paged_kernel_compiles_at_the_decode_ladder(v5e, batch):
+    """The kernel alone at every batch bucket of the cell's decode ladder,
+    within the fast memory it asks for (two buffers each of K and V, 2 MB a
+    piece, and a whole table's scores)."""
+    one = SingleDeviceSharding(v5e[0])
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    pool = arr((16 * 8 * 192, 16, 4096), jnp.bfloat16)
+    rows = arr((batch,), jnp.int32)
+    text = jax.jit(functools.partial(
+        eva_paged_mod.eva_paged_attention, window_blocks=128,
+        interpret=False)).lower(
+            arr((batch, 1, 32, 128), jnp.bfloat16), pool, pool,
+            arr((batch, 192), jnp.int32), rows, rows).compile().as_text()
+    assert "tpu_custom_call" in text and "eva_paged_attn" in text
+    assert eva_paged_mod._scratch_bytes(
+        eva_paged_mod.sizes(192, 16), 32, 4096, jnp.bfloat16) < 10 * 2 ** 20
 
 
 @pytest.mark.parametrize("batch,t", [(8, 1), (1, 2048), (8, 2048)],

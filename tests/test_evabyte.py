@@ -144,15 +144,17 @@ def test_uncached_forward_is_the_reference(params):
 
 
 @pytest.mark.parametrize("num_blocks", [96, 36],
-                         ids=["gathered", "pool-read-in-place"])
+                         ids=["roomy-pool", "pool-of-the-batchs-tables"])
 def test_slices_then_decode_across_window_ends_is_the_reference(params,
                                                                 num_blocks):
     """Three rows of one batch, each in another window at every step:
     prompts of 40, 100 and 150 (slices of 32, the last partial), decoded to
     88, 168 and 200 positions, so every row crosses at least one window end
-    while decoding and two in all. All 8 heads, every position. With a pool
-    of 36 blocks (3 rows x 12 table entries) a decode step reads the pool
-    in place under an ownership mask instead of gathering."""
+    while decoding and two in all. All 8 heads, every position. A decode
+    step reads the pool through the table in the kernel
+    (``ops/eva_paged_attention.py``, interpreted here), whether the pool is
+    roomy or just the batch's tables (36 blocks = 3 rows x 12 entries,
+    every block somebody's neighbour)."""
     cfg = _config(jnp.float32)
     prompt_lens, totals = [40, 100, 150], [88, 168, 200]
     seqs = [_tokens(10 + i, n) for i, n in enumerate(totals)]
@@ -256,15 +258,16 @@ def _engine(cfg, params, **kw):
 
 
 @pytest.mark.parametrize("num_blocks", [64, 48],
-                         ids=["gathered", "full-batches-read-in-place"])
+                         ids=["roomy-pool", "pool-of-the-batchs-tables"])
 def test_engine_serves_the_reference_tokens_and_leaks_no_block(params,
                                                                num_blocks):
     """Through ``submit``: scheduler, allocator, buckets and chunked
     prefill as the GPT cells use them. Greedy bytes are the reference's
     argmax of head 0 wherever its margin is wider than the tolerance. One
     request is shorter than a window (a ring with unreserved entries), and
-    with a pool of 48 blocks (4 rows x 12 entries) batches of 3-4 rows read
-    the pool in place, padding rows and block 0's owner among them."""
+    the decode batches of 1-4 rows hold padding rows (length 0 to the
+    kernel) and, with a pool of 48 blocks (4 rows x 12 entries), block 0's
+    owner, whose block every -1 entry names."""
     cfg = _config(jnp.float32)
     prompts = [_tokens(20 + i, n).tolist() for i, n in
                enumerate([40, 100, 70, 130, 9, 5])]
