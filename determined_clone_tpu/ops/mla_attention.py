@@ -13,16 +13,21 @@ neither is ever formed. With ``q~_i = W_UK_i^T q_N_i`` the score is ``q~_i
 sum is taken over whole rows and its first ``rank`` columns go through
 ``W_UV`` (:func:`expand_values`); the rows are read as they lie.
 
-Two paths over the same rows:
+Three paths over the same rows:
 
-- :func:`mla_decode` — one query a sequence: the rows of the chosen
-  positions (at most ``index_topk``) are gathered by position through the
-  block table, and nothing else of the table is read;
+- :func:`mla_decode` — one query a sequence that attends a chosen few: the
+  rows of the chosen positions (at most ``index_topk``) are gathered by
+  position through the block table, and nothing else of the table is read;
+- :func:`mla_decode_dense` — one query a sequence that attends every
+  cached position (no selection): a row's blocks are read through its
+  table a chunk of blocks at a time, as far as the row's real length and
+  no further, under an online softmax;
 - :func:`mla_slice` — a prefill slice: a block of queries at a time over
   the sequence's blocks a chunk of positions at a time, as far as the
   block's last real query reaches, an online softmax under the mask of
-  allowed positions (``ops/dsa_index.py``). The cost is the dense one
-  (ROADMAP B-M keeps the gathered form).
+  allowed positions (``ops/dsa_index.py``) or, with none, under the causal
+  mask alone. The cost is the dense one (ROADMAP B-M keeps the gathered
+  form).
 
 Products take bfloat16 operands and sum in fp32; scores, softmax and the
 running sums are fp32; the probabilities are rounded to the rows' dtype
@@ -105,13 +110,66 @@ def mla_decode(q: jax.Array, rows: jax.Array, row_ids: jax.Array,
     return (out / jnp.maximum(jnp.sum(p, axis=-1), 1e-30)[..., None])[:, None]
 
 
+def mla_decode_dense(q: jax.Array, blocks: jax.Array, tables: jax.Array,
+                     lengths: jax.Array, *, scale: float,
+                     key_blocks: int = 32) -> jax.Array:
+    """q [B, 1, H, R] (:func:`absorbed_query`); ``blocks`` [n, block, R]
+    the pool as blocks; ``tables`` [B, W] each sequence's blocks in order;
+    ``lengths`` [B] how many cached positions each query attends, its own
+    among them (0: none, the row is padding). Returns the
+    probability-weighted sums of rows ``[0, length)``, [B, 1, H, R] fp32;
+    zeros for a row of length 0. A row at a time, ``key_blocks`` blocks of
+    positions a pass, ``ceil(length / (key_blocks * block))`` passes: what
+    is read is the row's own blocks up to its length, not the table."""
+    B, _, H, R = q.shape
+    bs, W = blocks.shape[1], tables.shape[1]
+    nb = min(key_blocks, W)
+    S = nb * bs
+    tables = jnp.pad(tables, ((0, 0), (0, -W % nb)))
+    lengths = lengths.astype(jnp.int32)
+    queries = q[:, 0]
+
+    def one_row(r, out):
+        q_r = jax.lax.dynamic_index_in_dim(queries, r, keepdims=False)
+        table = jax.lax.dynamic_index_in_dim(tables, r, keepdims=False)
+        n = lengths[r]
+
+        def step(s, carry):
+            m_run, l_run, acc = carry
+            phys = jax.lax.dynamic_slice_in_dim(table, s * nb, nb)
+            chunk = blocks[phys].reshape(S, R)
+            scores = jnp.einsum("hr,sr->hs", q_r, chunk,
+                                preferred_element_type=jnp.float32) * scale
+            seen = (s * S + jnp.arange(S) < n)[None, :]
+            m_new = jnp.maximum(m_run, jnp.max(
+                jnp.where(seen, scores, NEG_INF), axis=-1))
+            p = jnp.where(seen, jnp.exp(scores - m_new[:, None]), 0.0)
+            fade = jnp.exp(m_run - m_new)
+            acc = acc * fade[:, None] + jnp.einsum(
+                "hs,sr->hr", p.astype(blocks.dtype), chunk,
+                preferred_element_type=jnp.float32)
+            return m_new, l_run * fade + jnp.sum(p, axis=-1), acc
+
+        stat = jnp.full((H,), NEG_INF, jnp.float32)
+        _, l_run, acc = jax.lax.fori_loop(
+            0, (n + S - 1) // S, step,
+            (stat, jnp.zeros_like(stat), jnp.zeros((H, R), jnp.float32)))
+        return jax.lax.dynamic_update_index_in_dim(
+            out, acc / jnp.maximum(l_run, 1e-30)[:, None], r, axis=0)
+
+    out = jax.lax.fori_loop(0, B, one_row,
+                            jnp.zeros((B, H, R), jnp.float32))
+    return out[:, None]
+
+
 def mla_slice(q: jax.Array, blocks: jax.Array, tables: jax.Array,
-              allowed: jax.Array, positions: jax.Array,
+              allowed: Optional[jax.Array], positions: jax.Array,
               token_mask: jax.Array, *, scale: float,
               key_blocks: int = 32, query_block: int = 512) -> jax.Array:
     """q [B, T, H, R]; ``blocks`` [n, block, R] the pool as blocks;
     ``tables`` [B, W] each sequence's blocks in order; ``allowed`` [B, T,
-    W * block] the positions each query attends (causality included).
+    W * block] the positions each query attends (causality included), or
+    None: every real query attends the positions up to its own.
     Returns [B, T, H, R] fp32 as :func:`mla_decode`. ``query_block``
     queries at a time attend ``key_blocks`` blocks of positions a pass, as
     far as the last real query among them reaches: the scores of one pass,
@@ -124,11 +182,12 @@ def mla_slice(q: jax.Array, blocks: jax.Array, tables: jax.Array,
     S = nb * bs
     pad = -W % nb
     tables = jnp.pad(tables, ((0, 0), (0, pad)))
-    allowed = jnp.pad(allowed, ((0, 0), (0, 0), (0, pad * bs)))
+    if allowed is not None:
+        allowed = jnp.pad(allowed, ((0, 0), (0, 0), (0, pad * bs)))
     qb = math.gcd(T, query_block)
 
     def queries(_, block):
-        q, allowed, positions, token_mask = block
+        q, *selection, positions, token_mask = block
 
         def step(s, carry):
             m_run, l_run, acc = carry
@@ -136,8 +195,12 @@ def mla_slice(q: jax.Array, blocks: jax.Array, tables: jax.Array,
             chunk = blocks[phys].reshape(B, S, R)
             scores = jnp.einsum("bthr,bsr->bths", q, chunk,
                                 preferred_element_type=jnp.float32) * scale
-            seen = jax.lax.dynamic_slice_in_dim(allowed, s * S, S,
-                                                axis=2)[:, :, None, :]
+            if selection:
+                seen = jax.lax.dynamic_slice_in_dim(
+                    selection[0], s * S, S, axis=2)[:, :, None, :]
+            else:
+                seen = ((s * S + jnp.arange(S) <= positions[:, :, None])
+                        & token_mask[:, :, None])[:, :, None, :]
             m_new = jnp.maximum(m_run, jnp.max(
                 jnp.where(seen, scores, NEG_INF), axis=-1))
             p = jnp.where(seen, jnp.exp(scores - m_new[..., None]), 0.0)
@@ -159,6 +222,7 @@ def mla_slice(q: jax.Array, blocks: jax.Array, tables: jax.Array,
     def by_block(a):
         return jnp.moveaxis(a.reshape(B, T // qb, qb, *a.shape[2:]), 1, 0)
 
-    _, out = jax.lax.scan(queries, None, tuple(map(
-        by_block, (q, allowed, positions, token_mask))))
+    inputs = (q, positions, token_mask) if allowed is None \
+        else (q, allowed, positions, token_mask)
+    _, out = jax.lax.scan(queries, None, tuple(map(by_block, inputs)))
     return jnp.moveaxis(out, 0, 1).reshape(B, T, H, R)

@@ -1703,7 +1703,7 @@ class InferenceEngine:
         """
         rows, cnt = call.rows, call.counts
         with self._span("serving_prefill", batch=call.batch,
-                        length=call.length) as prefill:
+                        length=call.length, tokens=sum(cnt)) as prefill:
             if call.mirrored is not None:
                 call.mirrored.block_until_ready()
             first, counted, records = self._read_back(call.tokens,

@@ -234,29 +234,24 @@ class WindowSummaryLayout(CacheLayout):
                 f"{self.chunk}, so that no slice straddles a window")
 
 
-class SparseStateLayout(CacheLayout):
-    """A cache whose contents differ by layer kind (``models/
-    minicpm_sala.py``): the block-sparse attention layers keep exact K/V
-    rows, block ``w`` of the table backing positions ``[w * block, (w + 1)
-    * block)`` as in the uniform cache, and beside every block the
-    compressed keys that start in it, found by the same block id; the
-    linear-attention layers keep one recurrent state a sequence, which
-    does not grow.
+class StateSlotLayout(CacheLayout):
+    """Blocks that grow and one slot that does not: a cache whose contents
+    differ by layer kind. Some layers keep rows a position, block ``w`` of
+    the table backing positions ``[w * block, (w + 1) * block)`` as in the
+    uniform cache; the others keep what a sequence holds once, whatever
+    its length (a recurrent state, the tail of a short convolution), in
+    pools of their own that one slot id names.
 
     Kinds ``("kv", "state")``: ``kv`` grows by ``ceil(len / block)``,
     ``state`` is ``state_slots`` = 1 slot. The slot is the last entry of
     the table's row, after ``blocks_needed(max_seq_len)`` block entries.
-    Past ``dense_len`` positions a query attends the rows of at most
-    ``topk`` chosen blocks, the block it lies in among them.
+    This one reads every cached row (``models/kimi_linear.py``: latent
+    attention with no selection beside delta-rule states); a family whose
+    growing layers read a chosen few brings a subclass.
     """
 
     kinds = ("kv", "state")
     state_slots = 1
-
-    def __init__(self, cache: KVCacheConfig, max_seq_len: int, *,
-                 topk: int, dense_len: int) -> None:
-        super().__init__(cache, max_seq_len)
-        self.topk, self.dense_len = int(topk), int(dense_len)
 
     @property
     def table_width(self) -> int:
@@ -275,13 +270,9 @@ class SparseStateLayout(CacheLayout):
         row[-1] = blocks[-1] - self.cache.num_blocks   # id -> slot
 
     def attended_rows(self, length: int) -> Tuple[int, int, int]:
-        """(rows cached in a sparse layer, rows of them the query at
+        """(rows cached in a growing layer, rows of them the query at
         ``length - 1`` attends, state slots read)."""
-        bs = self.cache.block_size
-        chosen = min(self.topk, self.cache.blocks_needed(length))
-        selected = length if length <= self.dense_len \
-            else (chosen - 1) * bs + (length - 1) % bs + 1
-        return (length, selected, self.state_slots)
+        return (length, length, self.state_slots)
 
     def check_prefill(self, max_prefill_len: int,
                       chunk_prefill_len: int) -> None:
@@ -290,6 +281,29 @@ class SparseStateLayout(CacheLayout):
                 f"chunk_prefill_len {chunk_prefill_len} must be whole "
                 f"cache blocks of {self.cache.block_size}: a prefill slice "
                 f"starts on a block boundary")
+
+
+class SparseStateLayout(StateSlotLayout):
+    """:class:`StateSlotLayout` where the growing layers are block-sparse
+    (``models/minicpm_sala.py``): the block-sparse attention layers keep
+    exact K/V rows and beside every block the compressed keys that start
+    in it, found by the same block id; the linear-attention layers keep one
+    recurrent state a sequence. Past ``dense_len`` positions a query
+    attends the rows of at most ``topk`` chosen blocks, the block it lies
+    in among them.
+    """
+
+    def __init__(self, cache: KVCacheConfig, max_seq_len: int, *,
+                 topk: int, dense_len: int) -> None:
+        super().__init__(cache, max_seq_len)
+        self.topk, self.dense_len = int(topk), int(dense_len)
+
+    def attended_rows(self, length: int) -> Tuple[int, int, int]:
+        bs = self.cache.block_size
+        chosen = min(self.topk, self.cache.blocks_needed(length))
+        selected = length if length <= self.dense_len \
+            else (chosen - 1) * bs + (length - 1) % bs + 1
+        return (length, selected, self.state_slots)
 
 
 class LatentIndexLayout(CacheLayout):

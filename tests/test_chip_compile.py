@@ -671,6 +671,68 @@ def test_glm_moe_dsa_paged_forward_compiles_at_the_cell_sizes(v5e, batch, t):
         assert f"/{scope}/" in text, scope
 
 
+@pytest.mark.parametrize("batch,t", [(32, 1), (1, 2048), (32, 2048)],
+                         ids=["decode-32", "slice-2048",
+                              "slices-of-32-rows"])
+def test_kimi_linear_paged_forward_compiles_at_the_cell_sizes(v5e, batch, t):
+    """``kimi-linear-48b-a3b.serve-longdoc-closed``: published layers 1..5
+    at the published widths with 128 of the 256 experts held and half the
+    vocabulary (8.57 GB of bfloat16 weights), 32 x 800 blocks of 64
+    positions of latents in the one MLA layer (2.1 GB), 32 slots of four
+    states and tails (0.28 GB); the decode step at 32 rows and the prefill
+    slices at 51200 positions. They fit the 15.75 GB a v5e offers a
+    program, all three donated pools are updated in place, the decode step
+    makes nothing as large as one row's table of latents, a slice nothing
+    as large as a chunk's decays for every chunk at once, and the six
+    scopes the benchmark reads are on the operations' paths."""
+    from determined_clone_tpu.models import kimi_linear as kl
+    from determined_clone_tpu.serving.engine import make_paged_forward
+    from determined_clone_tpu.serving.kv_cache import KVCacheConfig
+
+    cfg = kl.KimiLinearConfig(
+        vocab_size=81920, num_hidden_layers=5, kda_layers=(1, 2, 3, 5),
+        full_attn_layers=(4,), num_experts=128, model_max_length=51200)
+    cache = KVCacheConfig(32 * 800, 64)
+    layout = cfg.paged_model().cache_layout(cfg, cache)
+    assert layout.blocks_needed(cfg.max_seq_len) == 800 \
+        == layout.table_width - 1
+    one = SingleDeviceSharding(v5e[0])
+    params = _shapes(jax.eval_shape(
+        lambda k: kl.serving_params(kl.init(k, cfg), cfg),
+        jax.random.PRNGKey(0)), one)
+    assert 8.5e9 < sum(math.prod(x.shape) * x.dtype.itemsize
+                       for x in jax.tree.leaves(params)) < 8.6e9
+    pools = _shapes(jax.eval_shape(lambda: kl.init_pools(cfg, cache, 32)),
+                    one)
+    assert [p.shape for p in pools] == [(1, 25600, 64, 640),
+                                        (4, 32, 32, 128, 128),
+                                        (4, 32, 3, 12288)]
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    compiled = make_paged_forward(len(pools)).lower(
+        params, cfg, *_step_inputs(arr, batch, t, layout.table_width),
+        *pools).compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(math.prod(p.shape) * p.dtype.itemsize for p in pools)
+    assert mem.alias_size_in_bytes >= pool_bytes      # all three, in place
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 15.75 * 2 ** 30)
+    text = compiled.as_text()
+    if t == 1:
+        # a pass of 32 blocks a row, not a row's table (51200 x 640)
+        assert mem.temp_size_in_bytes < 0.25 * 2 ** 30
+        assert not re.search(r"bf16\[(?:\d+,)?51200,640\]", text)
+    else:
+        # one chunk's [64, 64, 128] decays a head, never every chunk's
+        assert mem.temp_size_in_bytes < 1.0 * 2 ** 30
+        assert not re.search(r"f32\[(?:1,)?32,32,64,64,128\]", text)
+    for scope in ("kda", "kda_conv", "mla_attn", "kv_cache", "moe_route",
+                  "moe_experts"):
+        assert f"/{scope}/" in text, scope
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("n_chips", [1, 4])
 def test_gpt2_small_train_step_compiles(v5e, monkeypatch, n_chips):
