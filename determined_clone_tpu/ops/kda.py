@@ -28,15 +28,46 @@ bucket leaves the state where its last real token left it.
       O   = (Q exp(G)) S_0 + B U
       S_C = Diag(exp(G_C)) S_0 + (K exp(G_C - G))^T U
 
-  ``X = (I + A)^-1`` is a forward substitution over the chunk's rows, made
-  for all chunks at once (it does not depend on the state); the chunks are
-  then walked in order with three products a chunk. The decay between two
-  positions is formed per channel from the difference ``G_i - G_j`` (the
-  ``[C, C, d]`` factor, one chunk at a time): **every exponent is a
-  non-positive difference**, so the smallest decays underflow to 0 and
-  nothing overflows. The lightning chunk form (``lam ** (a_i - a_j)``, one
-  scalar a head) does not carry over: the decay is a vector and the delta
-  rule couples a chunk's writes.
+  in one Pallas TPU kernel, ``kda_chunks`` (compiled on the chip,
+  interpreted off it, as ``ops/flash_attention.py``'s are): a grid step is
+  one chunk of ``heads_a_step`` heads, every value ``[heads, ...]`` and
+  every product one batched product over them, so the program is as long
+  for eight heads as for one and the heads' independent chains of small
+  products share the matrix unit. XLA keeps only ``G`` (the running sum)
+  before the call; nothing the size of a chunk's pairs reaches HBM.
+
+  1. *The pairs by sub-chunks of* ``SUB`` *= 16.* A sub-chunk ``I``
+     against an earlier one goes through ``G_ref``, the running sum at the
+     last row before ``I``: ``(k_I exp(G_I - G_ref)) (k_j exp(G_ref -
+     G_j))^T``, one ``[2 SUB, d] x [d, C]`` product a sub-chunk for ``A``
+     and ``B`` together. The diagonal ``SUB x SUB`` blocks are formed
+     exactly, per channel from the difference ``G_i - G_j``, a head and
+     sub-chunk at a time with its rows held in registers: ``SUB`` turns,
+     turn ``s`` every position against the one ``s`` before it (the rows
+     rolled down by one a turn), a lane reduction a turn. (The same turns
+     over all heads' values at once go through fast memory for every
+     operand and are bound by that; a loop over turns that is not unrolled
+     is bound by a turn's latency: ``tools/kda_sweep.py``, PERF.md.)
+     **Every exponent is a non-positive difference** (``G`` only falls),
+     so the smallest decays underflow to 0 and nothing overflows; a factor
+     through ``G_ref`` underflows only where the true decay is under
+     1e-38.
+  2. *``X = (I + A)^-1`` by blocks:* the inverses of the diagonal blocks
+     of ``m`` rows give those of ``2 m`` rows by two products (``X_21 =
+     -X_22 A_21 X_11``, every pair of blocks at once under a mask), from
+     ``m = 2`` (``I - A``) to the chunk: block forward substitution, no
+     loop over rows.
+  3. *The walk over a slice's chunks with the state resident:* the chunk
+     is the innermost, sequential grid axis, and the heads' states (held
+     transposed, ``[d_v, d_k]``, so a decay a key channel scales columns)
+     stay in the output's block in fast memory from the slice's first
+     chunk to its last: ``U = X b (V - (K exp G) S)`` (the state is at
+     hand, so the solve is applied once, to the corrected values), ``O =
+     (Q exp G) S + B U``, ``S' = exp(G_C) S + (K exp(G_C - G))^T U``.
+
+  The lightning chunk form (``lam ** (a_i - a_j)``, one scalar a head)
+  does not carry over: the decay is a vector and the delta rule couples a
+  chunk's writes.
 
 Everything here is fp32: the state, the decays, the solve, and the
 products (``Precision.HIGHEST``: a read of the state feeds the next write,
@@ -47,12 +78,19 @@ last ``K - 1`` rows carried between calls beside the state.
 """
 from __future__ import annotations
 
-from typing import Tuple
+import functools
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from determined_clone_tpu.ops.flash_attention import _should_interpret
 
 CHUNK = 64
+SUB = 16           # a sub-chunk: the pairs within one are formed exactly
+HEADS_A_STEP = 8
 _HI = jax.lax.Precision.HIGHEST
 
 
@@ -87,24 +125,154 @@ def _step(q, k, v, g, b, state, token_mask):
     return out[:, None], state
 
 
-def _matmul(a: jax.Array, b: jax.Array) -> jax.Array:
-    return jnp.matmul(a, b, precision=_HI,
-                      preferred_element_type=jnp.float32)
+def heads_a_step(heads: int) -> int:
+    """Heads a grid step takes: the most that divide ``heads``, up to
+    ``HEADS_A_STEP`` (independent chains of small products keep the matrix
+    unit fed; ``tools/kda_sweep.py`` holds the sweep)."""
+    return max(n for n in range(1, min(heads, HEADS_A_STEP) + 1)
+               if heads % n == 0)
 
 
-def _inverse_unit_lower(a: jax.Array) -> jax.Array:
-    """``(I + a)^-1`` for ``a`` [..., C, C] strictly lower triangular, by
-    forward substitution over the rows: row ``i`` of the inverse is ``e_i -
-    sum_{j < i} a_ij row_j``."""
-    C = a.shape[-1]
-    eye = jnp.eye(C, dtype=a.dtype)
+# batched products over a leading axis of heads: A·Bᵀ, A·B and Aᵀ·B
+_NT = (((2,), (2,)), ((0,), (0,)))
+_NN = (((2,), (1,)), ((0,), (0,)))
+_TN = (((1,), (1,)), ((0,), (0,)))
 
-    def row(i, x):
-        a_i = jax.lax.dynamic_index_in_dim(a, i, axis=-2, keepdims=False)
-        new = eye[i] - jnp.sum(a_i[..., :, None] * x, axis=-2)
-        return jax.lax.dynamic_update_index_in_dim(x, new, i, axis=-2)
 
-    return jax.lax.fori_loop(0, C, row, jnp.zeros_like(a))
+def _dot(a: jax.Array, b: jax.Array, dims) -> jax.Array:
+    return jax.lax.dot_general(a, b, dims, precision=_HI,
+                               preferred_element_type=jnp.float32)
+
+
+def _kernel(q_ref, k_ref, v_ref, G_ref, b_ref, s0_ref, o_ref, s_ref,
+            q_rows, k_rows, G_rows, A_rows, B_rows, *,
+            heads: int, d: int, C: int):
+    """One chunk of ``heads`` heads, every value ``[heads, ...]`` so that a
+    product is one batched operation whatever the group: ``q_ref`` ..
+    ``G_ref`` and ``o_ref`` [1, C, heads * d] (a head its own columns),
+    ``b_ref`` [1, 1, C, heads], ``s0_ref`` / ``s_ref`` [1, heads, d_v, d_k]
+    the states *transposed*, so a decay a key channel scales columns;
+    ``s_ref`` stays in fast memory over the slice's chunks, the innermost
+    grid axis. Scratch: ``q_rows``, ``k_rows``, ``G_rows`` [heads, C, d] the
+    inputs a head its own rows, ``A_rows``, ``B_rows`` [heads, C, C] the
+    diagonal sub-blocks."""
+    f32 = jnp.float32
+    n_sub = C // SUB
+    row = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    sub_first = row // SUB * SUB      # the first column of a row's sub-chunk
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        s_ref[...] = s0_ref[...]
+
+    for h in range(heads):       # a head's columns as its own [C, d] rows
+        for ref, rows in zip((q_ref, k_ref, G_ref), (q_rows, k_rows, G_rows)):
+            rows[h] = ref[0, :, h * d:(h + 1) * d]
+
+    at = jax.lax.broadcasted_iota(jnp.int32, (SUB, C), 1) \
+        - jax.lax.broadcasted_iota(jnp.int32, (SUB, C), 0)
+    turn = jax.lax.broadcasted_iota(jnp.int32, (SUB, C), 0)
+
+    def exact(n, _):
+        """The diagonal sub-block of ``A / b`` and of ``B`` of one head and
+        sub-chunk, exactly, all of it in registers: turn ``s`` every
+        position against the one ``s`` before it (the rows rolled down by
+        one a turn), the decay between the two from their difference
+        ``G_i - G_{i-s}``, a lane reduction a turn."""
+        h, first = n // n_sub, pl.multiple_of(n % n_sub * SUB, SUB)
+        mine = pl.ds(first, SUB)
+        q, k, G = q_rows[h, mine], k_rows[h, mine], G_rows[h, mine]
+        A = Bm = jnp.zeros((SUB, C), f32)
+        k_s, G_s = k, G                      # k_s[i] = k[i - s], G_s alike
+        for s in range(SUB):
+            with_s = jnp.exp(jnp.minimum(G - G_s, 0.0)) * k_s
+            here = (at == first - s) & (turn >= s)
+            if s:
+                A = jnp.where(here, jnp.sum(k * with_s, axis=1,
+                                            keepdims=True), A)
+            Bm = jnp.where(here, jnp.sum(q * with_s, axis=1, keepdims=True),
+                           Bm)
+            k_s, G_s = pltpu.roll(k_s, 1, 0), pltpu.roll(G_s, 1, 0)
+        A_rows[h, mine], B_rows[h, mine] = A, Bm
+
+    jax.lax.fori_loop(0, heads * n_sub, exact, None)
+    q, k, G = q_rows[...], k_rows[...], G_rows[...]        # [heads, C, d]
+    v = jnp.stack([v_ref[0, :, h * d:(h + 1) * d] for h in range(heads)])
+    b = jnp.stack([b_ref[0, 0, :, h:h + 1] for h in range(heads)])
+    A, Bm = A_rows[...], B_rows[...]
+
+    # the sub-blocks under the diagonal, as products: sub-chunk ``i``
+    # against every earlier position through ``G_ref``, the running sum at
+    # the last row before ``i``; both exponents are non-positive
+    under = [jnp.zeros((heads, 2 * SUB, C), f32)]
+    for i in range(1, n_sub):
+        mine = slice(i * SUB, (i + 1) * SUB)
+        ref = G[:, i * SUB - 1:i * SUB]
+        since = jnp.exp(G[:, mine] - ref)
+        until = k * jnp.exp(jnp.minimum(ref - G, 0.0))
+        under.append(_dot(
+            jnp.concatenate([k[:, mine] * since, q[:, mine] * since],
+                            axis=1), until, _NT))
+    earlier = col < sub_first
+    A, Bm = (m + jnp.where(earlier, jnp.concatenate(
+        [u[:, x * SUB:(x + 1) * SUB] for u in under], axis=1), 0.0)
+        for x, m in enumerate((A, Bm)))
+
+    # ``(I + A)^-1``, ``A`` strictly lower: the inverses of the diagonal
+    # blocks of ``m`` rows give those of ``2 m`` by two products, ``X_21 =
+    # -X_22 A_21 X_11`` for every pair at once
+    A = b * A
+    X, m = jnp.where(row == col, 1.0, 0.0) \
+        - jnp.where(row // 2 == col // 2, A, 0.0), 2
+    while m < C:
+        pair = (row // (2 * m) == col // (2 * m)) & (row // m != col // m)
+        X, m = X - _dot(X, _dot(jnp.where(pair, A, 0.0), X, _NN), _NN), 2 * m
+
+    decay = jnp.exp(G)
+    S = s_ref[0]                                      # [heads, d_v, d_k]
+    U = _dot(X, b * (v - _dot(k * decay, S, _NT)), _NN)
+    out = _dot(q * decay, S, _NT) + _dot(Bm, U, _NN)
+    for h in range(heads):
+        o_ref[0, :, h * d:(h + 1) * d] = out[h]
+    last = G[:, C - 1:]
+    s_ref[0] = jnp.exp(last) * S + _dot(U, k * jnp.exp(last - G), _TN)
+
+
+@functools.partial(jax.jit, static_argnames=("C", "heads"))
+def _chunked(q, k, v, g, b, state, C: int, heads: Optional[int] = None):
+    """The slice form over whole chunks: q, k, v, g [B, N * C, H, d] fp32
+    (``g`` and ``b`` [B, N * C, H] zero at masked positions), state [B, H,
+    d_k, d_v]. ``heads`` a grid step, ``heads_a_step``'s unless given."""
+    B, T, H, d = q.shape
+    heads = heads or heads_a_step(H)
+    N, f32 = T // C, jnp.float32
+    G = jnp.cumsum(g.reshape(B, N, C, H * d), axis=2).reshape(B, T, H * d)
+    q, k, v = (x.reshape(B, T, H * d) for x in (q, k, v))
+    b = jnp.swapaxes(b.reshape(B, T, H // heads, heads), 1, 2)
+    wide = pl.BlockSpec((1, C, heads * d), lambda i, j, n: (i, n, j))
+    held = pl.BlockSpec((1, heads, d, d), lambda i, j, n: (i, j, 0, 0))
+    ops = 2 * (5 * C * C * d + 3 * C * d * d) * N * H * B
+    out, state = pl.pallas_call(
+        functools.partial(_kernel, heads=heads, d=d, C=C),
+        grid=(B, H // heads, N),
+        in_specs=[wide, wide, wide, wide,
+                  pl.BlockSpec((1, 1, C, heads), lambda i, j, n: (i, j, n, 0)),
+                  held],
+        out_specs=[wide, held],
+        out_shape=[jax.ShapeDtypeStruct((B, T, H * d), f32),
+                   jax.ShapeDtypeStruct((B, H, d, d), f32)],
+        scratch_shapes=[pltpu.VMEM((heads, C, d), f32)] * 3
+        + [pltpu.VMEM((heads, C, C), f32)] * 2,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        cost_estimate=pl.CostEstimate(
+            flops=ops, transcendentals=(SUB + 6) * C * d * N * H * B,
+            bytes_accessed=4 * (5 * B * T * H * d + 2 * B * H * d * d)),
+        interpret=_should_interpret(),
+        name="kda_chunks",
+    )(q, k, v, G, b, jnp.swapaxes(state, -1, -2))
+    return out.reshape(B, T, H, d), jnp.swapaxes(state, -1, -2)
 
 
 def kda(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
@@ -117,58 +285,15 @@ def kda(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     fp32, state' [B, H, d_k, d_v] fp32)``: the outputs at every position
     (those of masked positions mean nothing) and the state after the last
     real token."""
-    B, T, H, dk = q.shape
+    T = q.shape[1]
     f32 = jnp.float32
     state = state.astype(f32)
     if T == 1:
         return _step(q, k, v, g, b, state, token_mask)
-    C = chunk
-    pad = -T % C
-    if pad:
-        q, k, v, g = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
-                      for x in (q, k, v, g))
-        b = jnp.pad(b, ((0, 0), (0, pad), (0, 0)))
-        token_mask = jnp.pad(token_mask, ((0, 0), (0, pad)))
-    N = (T + pad) // C
     real = token_mask[:, :, None]
     g = jnp.where(real[..., None], g.astype(f32), 0.0)
     b = jnp.where(real, b.astype(f32), 0.0)
-
-    def chunks(x):  # [B, N * C, H, ...] -> [N, B, H, C, ...]
-        x = x.astype(f32).reshape(B, N, C, H, *x.shape[3:])
-        return jnp.moveaxis(jnp.moveaxis(x, 3, 2), 1, 0)
-
-    q, k, v, g, b = map(chunks, (q, k, v, g, b))
-    G = jnp.cumsum(g, axis=-2)                             # [N, B, H, C, d]
-    lower = jnp.tril(jnp.ones((C, C), bool))
-    strict = jnp.tril(jnp.ones((C, C), bool), -1)
-
-    def pairs(xs):
-        """A, B of one chunk: the [C, C, d] factor is this chunk's."""
-        q_c, k_c, G_c, b_c = xs
-        between = jnp.exp(jnp.where(
-            lower[..., None], G_c[..., :, None, :] - G_c[..., None, :, :],
-            -jnp.inf)) * k_c[..., None, :, :]              # [B, H, C, C, d]
-        a = jnp.sum(k_c[..., :, None, :] * between, axis=-1)
-        return (jnp.where(strict, a * b_c[..., None], 0.0),
-                jnp.sum(q_c[..., :, None, :] * between, axis=-1))
-
-    A, Bm = jax.lax.map(pairs, (q, k, G, b))               # [N, B, H, C, C]
-    X = _inverse_unit_lower(A)
-    decay = jnp.exp(G)
-    W = _matmul(X, b[..., None] * k * decay)               # [N, B, H, C, dk]
-    U0 = _matmul(X, b[..., None] * v)                      # [N, B, H, C, dv]
-    to_end = jnp.exp(G[..., -1:, :] - G)                   # exp(G_C - G_j)
-
-    def one_chunk(S, xs):
-        q_d, k_e, W_c, U0_c, B_c, last = xs
-        U = U0_c - _matmul(W_c, S)
-        out = _matmul(q_d, S) + _matmul(B_c, U)
-        S = last[..., None] * S + _matmul(jnp.swapaxes(k_e, -1, -2), U)
-        return S, out
-
-    state, out = jax.lax.scan(
-        one_chunk, state,
-        (q * decay, k * to_end, W, U0, Bm, decay[..., -1, :]))
-    out = jnp.moveaxis(jnp.moveaxis(out, 0, 1), 2, 3)      # [B, N, C, H, dv]
-    return out.reshape(B, N * C, H, -1)[:, :T], state
+    pad = ((0, 0), (0, -T % chunk), (0, 0), (0, 0))
+    q, k, v, g = (jnp.pad(x.astype(f32), pad) for x in (q, k, v, g))
+    out, state = _chunked(q, k, v, g, jnp.pad(b, pad[:3]), state, chunk)
+    return out[:, :T], state

@@ -674,7 +674,8 @@ def test_glm_moe_dsa_paged_forward_compiles_at_the_cell_sizes(v5e, batch, t):
 @pytest.mark.parametrize("batch,t", [(32, 1), (1, 2048), (32, 2048)],
                          ids=["decode-32", "slice-2048",
                               "slices-of-32-rows"])
-def test_kimi_linear_paged_forward_compiles_at_the_cell_sizes(v5e, batch, t):
+def test_kimi_linear_paged_forward_compiles_at_the_cell_sizes(
+        v5e, monkeypatch, batch, t):
     """``kimi-linear-48b-a3b.serve-longdoc-closed``: published layers 1..5
     at the published widths with 128 of the 256 experts held and half the
     vocabulary (8.57 GB of bfloat16 weights), 32 x 800 blocks of 64
@@ -682,13 +683,16 @@ def test_kimi_linear_paged_forward_compiles_at_the_cell_sizes(v5e, batch, t):
     states and tails (0.28 GB); the decode step at 32 rows and the prefill
     slices at 51200 positions. They fit the 15.75 GB a v5e offers a
     program, all three donated pools are updated in place, the decode step
-    makes nothing as large as one row's table of latents, a slice nothing
-    as large as a chunk's decays for every chunk at once, and the six
+    makes nothing as large as one row's table of latents, a slice runs the
+    chunked delta rule as a Mosaic call under ``kda`` (no chunk's ``[64,
+    64, 128]`` decays and no loop of 64 rows in the program), and the six
     scopes the benchmark reads are on the operations' paths."""
     from determined_clone_tpu.models import kimi_linear as kl
+    from determined_clone_tpu.ops import kda as kda_mod
     from determined_clone_tpu.serving.engine import make_paged_forward
     from determined_clone_tpu.serving.kv_cache import KVCacheConfig
 
+    monkeypatch.setattr(kda_mod, "_should_interpret", lambda: False)
     cfg = kl.KimiLinearConfig(
         vocab_size=81920, num_hidden_layers=5, kda_layers=(1, 2, 3, 5),
         full_attn_layers=(4,), num_experts=128, model_max_length=51200)
@@ -725,9 +729,14 @@ def test_kimi_linear_paged_forward_compiles_at_the_cell_sizes(v5e, batch, t):
         assert mem.temp_size_in_bytes < 0.25 * 2 ** 30
         assert not re.search(r"bf16\[(?:\d+,)?51200,640\]", text)
     else:
-        # one chunk's [64, 64, 128] decays a head, never every chunk's
+        # the chunked delta rule is the kernel: no [64, 64, 128] decays of
+        # a chunk anywhere in the program, no loop (of a chunk's 64 rows, or
+        # over chunks) left to XLA under the scope
         assert mem.temp_size_in_bytes < 1.0 * 2 ** 30
-        assert not re.search(r"f32\[(?:1,)?32,32,64,64,128\]", text)
+        kda_lines = [line for line in text.splitlines() if "/kda/" in line]
+        assert any("tpu_custom_call" in line for line in kda_lines)
+        assert not re.search(r"f32\[(?:\d+,)*64,64,128\]", text)
+        assert not any("/kda/while" in line for line in kda_lines)
     for scope in ("kda", "kda_conv", "mla_attn", "kv_cache", "moe_route",
                   "moe_experts"):
         assert f"/{scope}/" in text, scope

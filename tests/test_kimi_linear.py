@@ -169,16 +169,21 @@ def _delta_inputs(seed, B, T, H, d):
 
 
 @pytest.mark.parametrize("T,real", [(128, (128, 128)), (150, (150, 77)),
-                                    (64, (64, 1)), (70, (5, 70))],
+                                    (64, (64, 1)), (70, (5, 70)),
+                                    (144, (79, 80)), (144, (81, 127)),
+                                    (136, (129, 63))],
                          ids=["whole-chunks", "ragged", "one-chunk",
-                              "under-a-chunk"])
+                              "under-a-chunk", "ends-at-15-and-16",
+                              "ends-at-17-and-63", "ends-at-65-and-63"])
 def test_chunk_form_is_the_one_token_form_is_the_references_scan(T, real):
-    """``ops/kda.py``'s chunk form against its one-token form applied
-    position by position against ``reference.delta_rule``, outputs at the
-    real positions and the state after them, from a state that is not
-    zero, for lengths that are and are not whole chunks of 64, decays from
-    1 - 1e-3 down to exp(-20). Float32; 2e-5 of outputs of size 1 is the
-    order of the sums (the forward substitution of 64 rows among them)."""
+    """``ops/kda.py``'s chunk form (the Pallas kernel, interpreted here)
+    against its one-token form applied position by position against
+    ``reference.delta_rule``, outputs at the real positions and the state
+    after them, from a state that is not zero, for lengths that are and are
+    not whole chunks of 64 and that end on either side of a sub-chunk's
+    edge (15, 16, 17, 63 and 65 of a chunk), decays from 1 - 1e-3 down to
+    exp(-20). Float32; 2e-5 of outputs of size 1 is the order of the sums
+    (the solve of 64 rows among them)."""
     q, k, v, g, b, state = _delta_inputs(T, 2, T, 3, 16)
     mask = jnp.arange(T)[None, :] < jnp.asarray(real)[:, None]
     o_chunk, s_chunk = jax.jit(ops_kda.kda)(q, k, v, g, b, state, mask)
@@ -215,6 +220,66 @@ def test_every_exponent_is_a_non_positive_difference():
                                         state=state[0])
     assert np.abs(o[0] - o_ref).max() < 2e-5
     assert np.abs(s[0] - s_ref).max() < 2e-5
+
+
+@pytest.mark.parametrize("first", [16, 17, 31, 48, 70],
+                         ids=lambda n: f"forgets-from-{n}")
+def test_a_decay_that_underflows_after_a_sub_chunks_first_row(first):
+    """Half the channels keep everything up to position ``first`` (g = 0)
+    and forget at exp(-80) a token from there on: for a later position of
+    the same sub-chunk or chunk the decay back to the sub-chunk's
+    reference row underflows while the reference row's decay back to the
+    earlier positions is 1. The product of the two is the reference's
+    value (0 where it underflows there, finite everywhere)."""
+    q, k, v, g, b, state = _delta_inputs(first, 1, 128, 2, 16)
+    g = jnp.where((jnp.arange(16) < 8)[None, None, None, :],
+                  jnp.where(jnp.arange(128) < first, 0.0,
+                            -80.0)[None, :, None, None], g)
+    o, s = jax.jit(ops_kda.kda)(q, k, v, g, b, state,
+                                jnp.ones((1, 128), bool))
+    assert np.isfinite(np.asarray(o)).all() and np.isfinite(np.asarray(s)
+                                                            ).all()
+    o_ref, s_ref = reference.delta_rule(q[0], k[0], v[0], g[0], b[0],
+                                        state=state[0])
+    assert np.abs(o[0] - o_ref).max() < 2e-5
+    assert np.abs(s[0] - s_ref).max() < 2e-5
+
+
+@pytest.mark.parametrize("cuts", [(64, 128), (48, 113), (17, 81)],
+                         ids=["whole-chunks", "ragged", "sub-chunk-edges"])
+def test_a_state_handed_through_three_slices_is_one_pass_over_them(cuts):
+    """192 tokens in three calls, each padded to 128 with the state of the
+    one before, against one call over all of them."""
+    q, k, v, g, b, state = _delta_inputs(7, 2, 192, 3, 16)
+    slice_form = jax.jit(ops_kda.kda)
+    o_one, s_one = slice_form(q, k, v, g, b, state, jnp.ones((2, 192), bool))
+    outs, s = [], state
+    for lo, hi in zip((0, *cuts), (*cuts, 192)):
+        pad = ((0, 0), (0, 128 - (hi - lo)), (0, 0), (0, 0))
+        o, s = slice_form(*(jnp.pad(x[:, lo:hi], pad) for x in (q, k, v, g)),
+                          jnp.pad(b[:, lo:hi], pad[:3]), s,
+                          jnp.arange(128)[None, :] < jnp.full((2, 1),
+                                                              hi - lo))
+        outs.append(o[:, :hi - lo])
+    assert np.abs(jnp.concatenate(outs, axis=1) - o_one).max() < 2e-5
+    assert np.abs(s - s_one).max() < 2e-5
+
+
+@pytest.mark.parametrize("H,group", [(12, 6), (16, 8), (5, 5), (11, 1)])
+def test_more_heads_than_a_grid_step_takes_are_each_their_own(H, group):
+    """A grid step takes ``heads_a_step`` heads; with more, every head of
+    every group reads its own columns, write strengths and state: each
+    head alone gives what it gives among the others."""
+    assert ops_kda.heads_a_step(H) == group
+    q, k, v, g, b, state = _delta_inputs(H, 2, 80, H, 16)
+    mask = jnp.arange(80)[None, :] < jnp.asarray([80, 33])[:, None]
+    o, s = jax.jit(ops_kda.kda)(q, k, v, g, b, state, mask)
+    for h in (0, group - 1, H - group, H - 1):
+        at = slice(h, h + 1)
+        o_h, s_h = ops_kda.kda(q[:, :, at], k[:, :, at], v[:, :, at],
+                               g[:, :, at], b[:, :, at], state[:, at], mask)
+        assert np.abs(o[:, :, at] - o_h).max() < 1e-6
+        assert np.abs(s[:, at] - s_h).max() < 1e-6
 
 
 def test_short_conv_carries_its_tail_over_real_rows_only():
