@@ -16,7 +16,6 @@ FSDP-style sharding") re-designed TPU-first:
 from __future__ import annotations
 
 import dataclasses
-import functools
 import re
 from typing import Any, Dict, Optional
 
@@ -190,24 +189,14 @@ def init(key: jax.Array, cfg: GPTConfig) -> Params:
 
 def _flash(q: jax.Array, k: jax.Array, v: jax.Array,
            mesh: Optional[Any]) -> jax.Array:
-    """Causal flash attention, per shard when ``mesh`` spans devices.
+    """Causal flash attention, each device over its own slice of the batch
+    (dp, fsdp) and heads (tp) when ``mesh`` spans devices
+    (``ops/flash_attention.py:flash_attention_per_shard`` says why)."""
+    from determined_clone_tpu.ops.flash_attention import (
+        flash_attention_per_shard,
+    )
 
-    XLA cannot partition a Mosaic kernel ("Mosaic kernels cannot be
-    automatically partitioned"), so under a multi-device mesh the kernel
-    runs inside ``shard_map``: each device attends over its own slice of
-    the batch (dp, fsdp) and heads (tp). No collective is needed — rows
-    and heads never interact inside attention. The kernel's custom VJP is
-    differentiated inside the ``shard_map``, so the backward kernels run
-    per shard too. Blocks are the kernel's own choice, from the shard's
-    shapes."""
-    from determined_clone_tpu.ops.flash_attention import flash_attention
-
-    attend = functools.partial(flash_attention, causal=True)
-    if mesh is None or mesh.size == 1:
-        return attend(q, k, v)
-    return jax.shard_map(
-        attend, mesh=mesh, in_specs=(FLASH_QKV_SPEC,) * 3,
-        out_specs=FLASH_QKV_SPEC, check_vma=False)(q, k, v)
+    return flash_attention_per_shard(q, k, v, mesh, FLASH_QKV_SPEC)
 
 
 def _block(cfg: GPTConfig, block_params: Params, x: jax.Array,

@@ -769,3 +769,23 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if interpret is None:
         interpret = _should_interpret()
     return _flash_attention_cvjp(q, k, v, causal, blocks, interpret)
+
+
+def flash_attention_per_shard(q: jax.Array, k: jax.Array, v: jax.Array,
+                              mesh: Optional[Any], spec: Any) -> jax.Array:
+    """Causal flash attention, per shard when ``mesh`` spans devices.
+
+    XLA cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned"), so under a multi-device mesh the kernel
+    runs inside ``shard_map``: each device attends over its own slice of
+    ``spec`` (q, k, v ``[B, T, H, D]``: rows and heads, which never
+    interact inside attention, so no collective is needed; the sequence
+    stays whole). The kernel's custom VJP is differentiated inside the
+    ``shard_map``, so the backward kernels run per shard too. Blocks are
+    the kernel's own choice, from the shard's shapes."""
+    attend = functools.partial(flash_attention, causal=True)
+    if mesh is None or mesh.size == 1:
+        return attend(q, k, v)
+    return jax.shard_map(
+        attend, mesh=mesh, in_specs=(spec,) * 3, out_specs=spec,
+        check_vma=False)(q, k, v)

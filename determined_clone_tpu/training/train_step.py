@@ -19,7 +19,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from determined_clone_tpu.parallel.sharding import ShardingRules
 
-LossFn = Callable[..., Any]  # (params, batch, rng) -> loss | (loss, metrics)
+# (params, batch, rng) -> loss | (loss, metrics) | (loss, metrics, statistics)
+LossFn = Callable[..., Any]
 
 
 @dataclasses.dataclass
@@ -92,6 +93,7 @@ def make_train_step(
     batch_sharding: Optional[Any] = None,
     donate: bool = True,
     steps_per_dispatch: int = 1,
+    apply_statistics: Optional[Callable[[Any, Any], Any]] = None,
 ) -> Callable[..., Tuple[TrainState, Dict[str, jax.Array]]]:
     """Build the jitted train step.
 
@@ -99,6 +101,13 @@ def make_train_step(
     ``(loss, metrics_dict)``. Gradient reduction across dp/fsdp is implicit:
     the batch is sharded over those axes, so XLA emits the reduce-scatter /
     all-reduce the specs imply.
+
+    A loss that returns a third value, ``(loss, metrics, statistics)``,
+    has state that no gradient trains (an expert layer's selection bias,
+    moved by the forward pass's own counts): after the optimizer's update
+    the step sets ``params = apply_statistics(params, statistics)``
+    (``JaxTrial.apply_statistics``). ``statistics`` is any pytree of
+    arrays; it never leaves the device.
 
     With ``steps_per_dispatch=k > 1`` the returned callable takes
     ``(state, batch_0, ..., batch_{k-1})`` and runs all k optimizer steps
@@ -116,19 +125,27 @@ def make_train_step(
 
         def wrapped(params):
             out = loss_fn(params, batch, step_rng)
-            if isinstance(out, tuple):
+            statistics = None
+            if isinstance(out, tuple) and len(out) == 3:
+                loss, metrics, statistics = out
+            elif isinstance(out, tuple):
                 loss, metrics = out
             else:
                 loss, metrics = out, {}
-            return loss, metrics
+            return loss, (metrics, statistics)
 
-        (loss, metrics), grads = jax.value_and_grad(wrapped, has_aux=True)(
-            state.params
-        )
+        (loss, (metrics, statistics)), grads = jax.value_and_grad(
+            wrapped, has_aux=True)(state.params)
         with jax.named_scope("optimizer"):
             updates, opt_state = tx.update(grads, state.opt_state,
                                            state.params)
             params = optax.apply_updates(state.params, updates)
+        if statistics is not None:
+            if apply_statistics is None:
+                raise ValueError(
+                    "the loss returned statistics and no apply_statistics "
+                    "was given to take them")
+            params = apply_statistics(params, statistics)
         new_state = TrainState(
             params=params, opt_state=opt_state, step=state.step + 1, rng=rng
         )

@@ -261,6 +261,7 @@ class Trainer:
         train_step = make_train_step(
             trial.loss, tx, mesh=mesh, state_sharding=shardings,
             batch_sharding=batch_sharding,
+            apply_statistics=trial.apply_statistics,
         )
         # k batches through one jitted lax.scan program; remainders smaller
         # than k use the single-step program above, so batch order and the
@@ -270,6 +271,7 @@ class Trainer:
             fused_step = make_train_step(
                 trial.loss, tx, mesh=mesh, state_sharding=shardings,
                 batch_sharding=batch_sharding, steps_per_dispatch=k,
+                apply_statistics=trial.apply_statistics,
             )
         eval_step = make_eval_step(
             trial.eval_metrics, state_sharding=shardings,
@@ -571,8 +573,12 @@ class Trainer:
                             "device_memory_peak_bytes",
                             "peak summed device bytes_in_use since the "
                             "previous chunk boundary").set(memmon.take_peak())
-                    self.core.train.report_training_metrics(batches_trained,
-                                                            train_metrics)
+                    # the span carries the report: a trace reader finds the
+                    # loss's own metrics (an expert layer's counts) here
+                    with span("training_report") as report:
+                        report.set(**train_metrics)
+                        self.core.train.report_training_metrics(
+                            batches_trained, train_metrics)
                     if profiler is not None:
                         # chunk-level split of the hot loop: input stall vs the
                         # rest (dispatch + device compute up to the acc sync)

@@ -9,6 +9,7 @@ JaxTrial therefore declares pure functions over pytrees:
   optimizer()                    ≈ wrap_optimizer (an optax transformation —
                                     LR schedules are optax schedules, ≈ wrap_lr_scheduler)
   loss(params, batch, rng)       ≈ train_batch (traced; returns loss, metrics)
+  apply_statistics(params, s)    state no gradient trains, moved after each step
   eval_metrics(params, batch[, rng])  ≈ evaluate_batch (traced)
   sharding_rules()               parallelism layout (≈ DeepSpeed config / MPU)
   training_data()/validation_data()  ≈ build_training_data_loader
@@ -81,7 +82,8 @@ class JaxTrial(abc.ABC):
     @abc.abstractmethod
     def loss(self, params: Any, batch: Any, rng: jax.Array
              ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-        """Traced. Returns (scalar loss, metrics dict of device scalars)."""
+        """Traced. Returns (scalar loss, metrics dict of device scalars),
+        or with a third value what ``apply_statistics`` takes."""
 
     @abc.abstractmethod
     def training_data(self) -> Iterable[Any]:
@@ -102,8 +104,18 @@ class JaxTrial(abc.ABC):
         signature keep working; declare ``rng`` to receive the key."""
         if rng is None:
             rng = jax.random.PRNGKey(self.context.config.experiment_seed)
-        loss, metrics = self.loss(params, batch, rng)
+        loss, metrics = self.loss(params, batch, rng)[:2]
         return {"loss": loss, **metrics}
+
+    def apply_statistics(self, params: Any, statistics: Any) -> Any:
+        """Traced, after the optimizer's update of every step, for a
+        ``loss`` that returns ``(loss, metrics, statistics)``: the new
+        ``params``, with the state that no gradient trains moved by what
+        the forward pass counted (an expert layer's selection bias by its
+        experts' loads). ``statistics`` is any pytree of device arrays;
+        ``metrics`` stays scalars for the report."""
+        raise NotImplementedError(
+            "loss() returned statistics: override apply_statistics()")
 
     def validation_data(self) -> Optional[Iterable[Any]]:
         return None

@@ -15,6 +15,7 @@ in a worker they share (``jax.jit``'s cache is per function, not per test).
 import pytest
 
 pytest.register_assert_rewrite(
+    "benchmarks.tests.test_glm4_moe_lite",
     "benchmarks.tests.test_glm_moe_dsa", "benchmarks.tests.test_kimi_linear",
     "benchmarks.tests.test_minicpm_sala", "benchmarks.tests.test_overlap",
     "benchmarks.tests.test_paged",
@@ -22,6 +23,24 @@ pytest.register_assert_rewrite(
     "benchmarks.tests.test_spec", "benchmarks.tests.test_stats",
     "benchmarks.tests.test_trace", "benchmarks.tests.test_traffic")
 
+from benchmarks.tests.test_glm4_moe_lite import (  # noqa: E402,F401
+    compared,
+    test_a_program_without_the_bias_in_its_selection_is_not_the_references,
+    test_control_is_not_correct as test_glm_lite_control_is_not_correct,
+    test_loss_and_every_leafs_gradient_are_the_references,
+    test_program_with_bfloat16_parameters_is_not_correct,
+    test_readers_find_nothing_where_nothing_is_theirs,
+    test_readers_know_the_operations_a_step_has_to_do,
+    test_readers_read_a_made_up_trace_of_the_real_cell,
+    test_real_configuration_is_the_catalogs_but_for_what_reduced_names
+    as test_glm_lite_real_configuration_is_the_catalogs_but_for_what_reduced_names,
+    test_the_mix_is_the_issues_parameter_for_parameter
+    as test_glm_lite_the_mix_is_the_issues_parameter_for_parameter,
+    test_tiny_cell_lists_what_the_real_cell_lists
+    as test_glm_lite_tiny_cell_lists_what_the_real_cell_lists,
+    test_tiny_cell_runs_and_is_correct_and_the_bias_moved_by_the_loads,
+    work_dir,
+)
 from benchmarks.tests.test_glm_moe_dsa import (  # noqa: E402,F401
     test_a_steps_counts_are_those_of_the_commit_that_followed_it,
     test_readers_know_the_bytes_a_step_has_to_read,
@@ -112,8 +131,8 @@ def test_the_manifest_lists_the_reader_as_it_describes_itself():
     and a later PR's entries go after it (the contract: new entries at the
     end of their lists). What it guarded stands: the entry is as its reader
     describes itself, lists the five serve cells it listed, and everything
-    after it is a later PR's, appended (here: PR 41's, the new cell's
-    alone). The file under ``benchmarks/`` may not be edited by a PR that
+    after it is a later PR's, appended (PR 41's and PR 43's, each its new
+    cell's alone). The file under ``benchmarks/`` may not be edited by a PR that
     is no benchmark PR, so by hand that one assertion now fails
     (``CHANGES.md``, PR 41)."""
     import json
@@ -131,8 +150,9 @@ def test_the_manifest_lists_the_reader_as_it_describes_itself():
     assert entry["better"] == "higher"
     assert tuple(entry["workloads"]) == test_overlap.CELLS
     later = manifest["per_layer"][manifest["per_layer"].index(entry) + 1:]
-    assert all(m["workloads"] == ["kimi-linear-48b-a3b.serve-longdoc-closed"]
-               for m in later)
+    assert all(m["workloads"] in (
+        ["kimi-linear-48b-a3b.serve-longdoc-closed"],
+        ["glm-4.7-flash.train-8k"]) for m in later)
     for cell in entry["workloads"]:
         assert test_overlap.NAME in spec.load_cell(
             cell, manifest=manifest).per_layer

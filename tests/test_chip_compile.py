@@ -156,6 +156,117 @@ def test_flash_grad_at_the_train_cells_shapes(v5e, shape):
             assert operands.count("bf16" + operand) >= 3, operands
 
 
+def test_flash_grad_at_the_expert_cells_shape(v5e):
+    """``grad`` at ``glm-4.7-flash.train-8k``'s shape, one row of 8192
+    positions and 20 heads of 256, bf16: three Mosaic kernels, one head a
+    grid step as it lies, bf16 operands never converted, no score-shaped
+    block in HBM, and blocks whose fast memory stays within what the
+    kernels ask for."""
+    shape = (1, 8192, 20, 256)
+    one = SingleDeviceSharding(v5e[0])
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one)
+    fn = jax.grad(lambda q, k, v: flash_mod.flash_attention(
+        q, k, v, causal=True, interpret=False).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))
+    text = jax.jit(fn).lower(x, x, x).compile().as_text()
+    assert sorted(_flash_calls(text)) == [
+        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    B, T, H, D = shape
+    for dims in re.findall(r"(?:f32|bf16)\[([\d,]+)\]", text):
+        dims = [int(n) for n in dims.split(",")]
+        assert math.prod(dims) <= B * T * H * D, dims
+        assert dims[-2:] != [T, T], dims
+    assert flash_mod.layout(H, D) == (True, 1, H)
+    operand = f"[{B},{T},{H * D}]"
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            operands = line.split("operand_layout_constraints={")[1]
+            operands = operands.split("}, frontend_attributes")[0]
+            assert "f32" + operand not in operands, operands
+            assert operands.count("bf16" + operand) >= 3, operands
+    blocks = flash_mod.block_sizes(T, T, D, jnp.bfloat16)
+    assert blocks.fwd == (1024, 1024, 1024)
+    for block in blocks:
+        tq, tk = block.tiles
+        used = 2 * 2 * (block.q + block.k) * D * 2 \
+            + 2 * max(block.q, block.k) * D * 4 + 4 * tq * tk * 4
+        assert used <= flash_mod._VMEM_LIMIT, (block, used)
+
+
+def test_expert_cells_train_step_compiles_and_fits(v5e, monkeypatch):
+    """``glm-4.7-flash.train-8k``'s step as the trainer builds it (the
+    example trial's loss and ``apply_statistics``, clip + AdamW with the
+    bias masked, state donated) at ``[1, 8193]`` tokens for one described
+    v5e: the compiler's memory analysis is under the chip's 15.75 GB; the
+    three flash kernels are custom calls at head size 256 on the
+    ``mla_attn`` path and the held experts' grouped products
+    (``grouped_matmul``, ``grouped_outer`` for the weights' gradients and
+    ``grouped_add_rows`` for the sums into the tokens' rows:
+    ``ops/grouped_matmul.py``) on the ``moe_experts`` path, no
+    other kernel anywhere; nothing is shaped by all the pairs (``[32768,
+    2048]``), by experts x capacity (``[8192, 64, C]``) or by positions
+    squared (``f32[8192, 8192]``).
+
+    80 s, and in tier-1 all the same: the cell leaves 0.5 GB of the chip
+    free, so a later change's few hundred MB decide whether it runs."""
+    import json
+
+    import optax
+
+    from benchmarks.adapters import glm4_moe_lite as adapter
+    from determined_clone_tpu.models import glm_moe_lite
+    from determined_clone_tpu.training.train_step import (
+        create_train_state,
+        make_train_step,
+    )
+
+    from determined_clone_tpu.ops import grouped_matmul as grouped_mod
+
+    monkeypatch.setattr(flash_mod, "_should_interpret", lambda: False)
+    monkeypatch.setattr(grouped_mod, "_should_interpret", lambda: False)
+    one = SingleDeviceSharding(v5e[0])
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "glm-4.7-flash.json")) as f:
+        config = json.load(f)
+    cfg = adapter.model_config(config)
+    opt = config["training"]["optimizer"]
+    tx = optax.chain(
+        optax.clip_by_global_norm(opt["clip_global_norm"]),
+        optax.adamw(opt["lr"], b1=opt["b1"], b2=opt["b2"], eps=opt["eps"],
+                    weight_decay=opt["weight_decay"],
+                    mask=glm_moe_lite.trained_mask))
+    state = _shapes(jax.eval_shape(lambda: create_train_state(
+        adapter._weights(jax.random.PRNGKey(0), adapter.dims(config)), tx,
+        jax.random.PRNGKey(1))), one)
+    batch = jax.ShapeDtypeStruct((1, 8193), jnp.int32, sharding=one)
+    step = make_train_step(
+        lambda p, b, r: glm_moe_lite.loss_fn(p, cfg, b[:, :-1], b[:, 1:]),
+        tx, apply_statistics=lambda p, s:
+        glm_moe_lite.update_selection_bias(p, cfg, s))
+    compiled = step.lower(state, batch).compile()
+    mem = compiled.memory_analysis()
+    peak = mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes
+    assert 0.25 * 16e9 < peak < 15.75e9, peak
+    text = compiled.as_text()
+    names = {n.split(".")[0] for n in _flash_calls(text, bare=False)}
+    assert names == {"flash_fwd", "flash_bwd_dkv", "flash_bwd_dq",
+                     "grouped_matmul", "grouped_outer", "grouped_add_rows"}
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            name = re.search(r"%([\w.]+) = ", line).group(1).split(".")[0]
+            path = re.search(r'op_name="([^"]+)"', line).group(1)
+            if name.startswith("grouped_"):
+                assert "/moe_experts/" in path, path
+            else:
+                assert "/mla_attn/" in path and "[1,8192,5120]" in line, path
+    for dims in re.findall(r"(?:f32|bf16|s32|pred)\[([\d,]+)\]", text):
+        dims = [int(n) for n in dims.split(",")]
+        assert dims[:2] != [32768, 2048], dims
+        assert dims[:2] != [8192, 64] or len(dims) == 2, dims
+        assert dims[-2:] != [8192, 8192], dims
+
+
 def test_train_step_names_its_kernels_as_the_benchmark_reads_them(
         v5e, monkeypatch):
     """In a scanned, rematerialised train step the instructions are

@@ -112,18 +112,26 @@ def test_workspace_scoped_editor_via_group(master):
     admin.update_group_members(group["id"], add=[alice["id"]])
 
 
+def _workspace_id(admin, name):
+    """A workspace's id; made here where an earlier test of this module
+    has not made it (as ``_user_id``: xdist may split the module)."""
+    for w in admin.list_workspaces():
+        if w["name"] == name:
+            return w["id"]
+    return admin.create_workspace(name)["id"]
+
+
 def test_editor_cannot_admin_workspace(master):
     admin = master["session"]
-    ws_id = next(w["id"] for w in admin.list_workspaces()
-                 if w["name"] == "ml-team")
+    ws_id = _workspace_id(admin, "ml-team")
+    alice_id = _user_id(admin, "alice")
     alice = login_as(master, "alice", "pw")
     # archive needs WorkspaceAdmin
     with pytest.raises(MasterError) as err:
         alice.post(f"/api/v1/workspaces/{ws_id}/archive")
     assert err.value.status == 403
-    admin.assign_role("WorkspaceAdmin", user_id=[
-        u["id"] for u in admin.list_users() if u["username"] == "alice"][0],
-        workspace_id=ws_id)
+    admin.assign_role("WorkspaceAdmin", user_id=alice_id,
+                      workspace_id=ws_id)
     alice.post(f"/api/v1/workspaces/{ws_id}/archive")
     alice.post(f"/api/v1/workspaces/{ws_id}/unarchive")
 
