@@ -50,7 +50,9 @@ the head is untied.
 
 **Stacks by FFN kind**: ``params["dense"]`` and ``params["sparse"]`` hold
 their layers stacked, in order; the forward is one ``lax.scan`` a run, each
-layer under ``jax.checkpoint`` (``remat``). The two heads' fp32 logits are
+layer under ``jax.checkpoint`` (``remat``: the backward pass makes all of a
+layer again but the flash kernel's output and log-sum-exp, which are kept,
+so the kernel's forward runs once). The two heads' fp32 logits are
 made one after the other, each under ``jax.checkpoint``, so that one
 ``[B T, V]`` block and its gradient are alive at a time.
 
@@ -340,7 +342,11 @@ def _run(cfg: GLMMoeLiteConfig, kind: str, stack: Params, x: jax.Array,
         return _ffn(cfg, kind, lp, x)
 
     if cfg.remat:
-        layer = jax.checkpoint(layer)
+        from determined_clone_tpu.ops.flash_attention import (
+            save_flash_residuals,
+        )
+
+        layer = jax.checkpoint(layer, policy=save_flash_residuals)
     return jax.lax.scan(layer, x, stack)
 
 

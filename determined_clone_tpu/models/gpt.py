@@ -11,7 +11,9 @@ FSDP-style sharding") re-designed TPU-first:
  - megatron TP sharding expressed as regex→PartitionSpec rules
    (parallel/sharding.py), fsdp fallback = ZeRO-3;
  - sequence axis ready for ring attention over the ``sp`` mesh axis;
- - ``jax.checkpoint`` (remat) around each block to trade FLOPs for HBM.
+ - ``jax.checkpoint`` (remat) around each block to trade FLOPs for HBM: the
+   backward pass makes a block's activations again but for the results of
+   its weight products and the flash kernel's output and log-sum-exp.
 """
 from __future__ import annotations
 
@@ -300,9 +302,18 @@ def _forward(params: Params, cfg: GPTConfig, tokens: jax.Array, *,
         return _block(cfg, layer_params, x, positions, key,
                       None if pipelined else mesh)
     if cfg.remat:
-        block_fn = jax.checkpoint(
-            block_fn, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+        from determined_clone_tpu.ops.flash_attention import (
+            save_flash_residuals,
         )
+
+        # the block's products, and the flash kernel's output and
+        # log-sum-exp (a custom call, not a dot): the backward pass reads
+        # them and does not run the kernel's forward again
+        policies = jax.checkpoint_policies
+        block_fn = jax.checkpoint(
+            block_fn, policy=policies.save_from_both_policies(
+                policies.dots_with_no_batch_dims_saveable,
+                save_flash_residuals))
 
     if pipelined:
         from determined_clone_tpu.parallel.pipeline import pipeline_apply

@@ -78,6 +78,7 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -715,8 +716,21 @@ def _flash_attention_cvjp(q, k, v, causal, blocks, interpret):
     return _forward(q, k, v, causal, blocks, interpret, with_lse=False)[0]
 
 
+# The differentiated forward names its two outputs, so that a remat policy
+# can keep them: both come out of a custom call, which no policy over dots
+# sees, and without them the backward pass runs ``flash_fwd`` a second time.
+# ``save_flash_residuals`` is the ``jax.checkpoint`` policy that keeps those
+# two and nothing else (``save_from_both_policies`` adds it to another); the
+# names are inert where no policy asks for them.
+_RESIDUAL_NAMES = ("flash_o", "flash_lse")
+save_flash_residuals = jax.checkpoint_policies.save_only_these_names(
+    *_RESIDUAL_NAMES)
+
+
 def _vjp_fwd(q, k, v, causal, blocks, interpret):
     o, qkv, lse = _forward(q, k, v, causal, blocks, interpret, with_lse=True)
+    o, lse = (checkpoint_name(x, name)
+              for x, name in zip((o, lse), _RESIDUAL_NAMES))
     return o, (qkv, o, lse)
 
 

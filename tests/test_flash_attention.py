@@ -376,3 +376,96 @@ def test_flash_pads_indivisible_seq_in_gpt(seq_len):
     g_mha = jax.grad(loss)(params, cfg_mha)
     for a, b in zip(jax.tree.leaves(g_flash), jax.tree.leaves(g_mha)):
         assert jnp.linalg.norm(a - b) <= 0.05 * jnp.linalg.norm(b) + 1e-6
+
+
+def _kernel_names(jaxpr):
+    """The Pallas kernels of a jaxpr and of the jaxprs its equations hold,
+    by name, in order."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn.params["name"])
+        else:
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                found.extend(_kernel_names(sub))
+    return found
+
+
+@pytest.mark.parametrize("other", ["no_remat", "dots_alone"])
+def test_remat_keeps_the_kernels_output_and_log_sum_exp(monkeypatch, other):
+    """GPT's rematerialised block keeps ``flash_fwd``'s two outputs
+    (``save_flash_residuals``), so the gradient program of a two-layer
+    scanned loss holds the forward kernel once, as without remat, where the
+    policy over dots alone holds it twice; and since what is kept is what
+    the second forward would have made, the gradients are the same to the
+    last bit."""
+    import dataclasses
+
+    from determined_clone_tpu.models import gpt
+
+    cfg = gpt.GPTConfig(vocab_size=128, n_layers=2, d_model=64, n_heads=4,
+                        d_ff=128, max_seq_len=64, remat=True,
+                        attention_impl="flash")
+    params = gpt.init(jax.random.PRNGKey(0), cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(3), (2, 65), 0, 128)
+
+    def grad(c):
+        fn = jax.grad(lambda p: gpt.loss_fn(p, c, tokens[:, :-1],
+                                            tokens[:, 1:]))
+        return fn(params), _kernel_names(jax.make_jaxpr(fn)(params).jaxpr)
+
+    kept, kernels = grad(cfg)
+    assert sorted(kernels) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    if other == "no_remat":
+        theirs, kernels = grad(dataclasses.replace(cfg, remat=False))
+        assert sorted(kernels) == ["flash_bwd_dkv", "flash_bwd_dq",
+                                   "flash_fwd"]
+    else:
+        monkeypatch.setattr(flash_mod, "save_flash_residuals",
+                            jax.checkpoint_policies.nothing_saveable)
+        theirs, kernels = grad(cfg)
+        assert kernels.count("flash_fwd") == 2, kernels
+    for a, b in zip(jax.tree.leaves(kept), jax.tree.leaves(theirs)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_a_fully_rematerialised_layer_keeps_them_too(monkeypatch):
+    """``glm_moe_lite``'s layer keeps nothing else: its gradient program
+    holds the forward kernel once a run of layers (dense, sparse, the
+    prediction module) where a layer that keeps nothing holds it twice, and
+    the gradients are the same to the last bit."""
+    from determined_clone_tpu.models import glm_moe_lite as glm
+
+    cfg = glm.GLMMoeLiteConfig.tiny()
+    assert cfg.remat
+    params = glm.init(jax.random.PRNGKey(0), cfg)
+    batch = jax.random.randint(jax.random.PRNGKey(1), (2, 33), 0,
+                               cfg.vocab_size)
+
+    def grad():
+        fn = jax.grad(lambda p: glm.loss_fn(p, cfg, batch[:, :-1],
+                                            batch[:, 1:])[0])
+        return fn(params), _kernel_names(jax.make_jaxpr(fn)(params).jaxpr)
+
+    kept, kernels = grad()
+    assert [kernels.count(k) for k in (
+        "flash_fwd", "flash_bwd_dkv", "flash_bwd_dq")] == [3, 3, 3], kernels
+    monkeypatch.setattr(flash_mod, "save_flash_residuals",
+                        jax.checkpoint_policies.nothing_saveable)
+    nothing, kernels = grad()
+    assert kernels.count("flash_fwd") == 6, kernels
+    for a, b in zip(jax.tree.leaves(kept), jax.tree.leaves(nothing)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_forward_alone_names_nothing():
+    """The names are the differentiated forward's: a forward-only program
+    (serving, evaluation) keeps the jaxpr it had."""
+    q, k, v = _qkv(T=128)
+    names = _primitives(jax.make_jaxpr(flash_attention)(q, k, v).jaxpr, [])
+    assert "name" not in names, names
+    assert names.count("pallas_call") == 1
+    grad = jax.grad(lambda q, k, v: flash_attention(q, k, v).sum(),
+                    argnums=(0, 1, 2))
+    assert _primitives(jax.make_jaxpr(grad)(q, k, v).jaxpr,
+                       []).count("name") == 2

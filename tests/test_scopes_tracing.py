@@ -84,9 +84,11 @@ def test_train_step_hlo_carries_every_scope(train_hlo, scope):
 
 
 def test_train_step_hlo_names_the_flash_kernel(train_hlo):
-    # forward and remat's second forward both run the named kernel
+    # the forward runs the named kernel; remat's second forward does not
+    # (the block's policy keeps the kernel's output and log-sum-exp)
     assert _has_scope(train_hlo, "flash_fwd")
-    assert "rematted_computation/attn/flash_fwd" in train_hlo
+    assert "rematted_computation/attn/" in train_hlo
+    assert "rematted_computation/attn/flash_fwd" not in train_hlo
 
 
 @pytest.mark.parametrize("kernel", ["flash_bwd_dkv", "flash_bwd_dq"])
@@ -95,8 +97,8 @@ def test_backward_kernels_lie_under_attn_and_are_not_named_forward(
     """The backward kernels' operations carry the ``attn`` scope on their
     path, so ``train_attn_device_ms`` counts them, and their names do not
     start with ``flash_fwd``, so ``flash_fwd_device_ms`` stays the forward
-    and remat's second forward (benchmarks/harness/scopes.py: bucket by
-    scope name on the path, the kernel part by ``startswith``)."""
+    alone (benchmarks/harness/scopes.py: bucket by scope name on the path,
+    the kernel part by ``startswith``)."""
     import re
 
     paths = set(re.findall(rf'"([^"]*/{kernel}/[^"]*)"', train_hlo))
