@@ -72,7 +72,7 @@ from __future__ import annotations
 
 import dataclasses
 import re
-from typing import Any, Dict, List, Tuple
+from typing import Any, Dict, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -452,7 +452,7 @@ def _attention(cfg: GLMMoeDsaConfig, kind: str, lp: Params, x: jax.Array,
             else:
                 o = mla.mla_slice(q, latent_rows.reshape(-1, bs, R), here,
                                   selection, positions, token_mask,
-                                  scale=scale)
+                                  scale=scale, rank=rank)
             o = mla.expand_values(o, lp["uv"]["kernel"])
         x = x + _matmul(o.reshape(B, T, -1).astype(dt), lp["attn_out"])
     return x, latent_rows, index_rows, selection
@@ -609,6 +609,16 @@ def _cache_layout(cfg: GLMMoeDsaConfig, cache: Any) -> Any:
     return LatentIndexLayout(cache, cfg.max_seq_len, topk=cfg.index_topk)
 
 
+def _prefill_counts(cfg: GLMMoeDsaConfig, layout: Any,
+                    starts: Sequence[int], counts: Sequence[int],
+                    length: int) -> Dict[str, int]:
+    """The key tiles a call's slices multiply in every layer's latent
+    attention, beside those whole tables would cost."""
+    return mla.key_tiles(starts, counts, length, cfg.num_attention_heads,
+                         layout.table_width, layout.cache.block_size,
+                         layers=len(cfg.kinds))
+
+
 PAGED = PagedModel(
     family="glm_moe_dsa", forward_paged=forward_paged,
     forward_paged_logits=forward_paged_logits, init=init,
@@ -621,4 +631,4 @@ PAGED = PagedModel(
     row_counters=("serving_dsa_scored_rows_total",
                   "serving_dsa_selected_rows_total"),
     step_counters=("expert_pairs", "expert_hits"),
-    token_records=True)
+    token_records=True, prefill_counts=_prefill_counts)

@@ -44,8 +44,8 @@ holds three things of two lifetimes:
   position in the ``mla`` layers, ``R`` = rank + rope width rounded up to
   whole 128-lane tiles (576 -> 640); it grows a position at a time. A
   decode step reads a row's blocks through its table up to its real length
-  (``mla_decode_dense``), a slice a chunk of blocks a pass under the
-  causal mask (``mla_slice``);
+  (``mla_decode_dense``), a slice through the same table in one Pallas
+  kernel under the causal mask (``mla_slice``);
 - ``state_pool`` ``[L_kda, slots, H, d, d]`` fp32: a ``kda`` layer's state
   of each sequence;
 - ``tail_pool`` ``[L_kda, slots, K - 1, 3 H d]``: the last ``K - 1`` rows
@@ -70,7 +70,7 @@ from __future__ import annotations
 
 import dataclasses
 import re
-from typing import Any, Dict, List, Tuple
+from typing import Any, Dict, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -482,7 +482,7 @@ def _mla_attention(cfg: KimiLinearConfig, lp: Params, x: jax.Array,
                                          scale=scale)
             else:
                 o = mla.mla_slice(q, blocks, first + tables, None, positions,
-                                  token_mask, scale=scale)
+                                  token_mask, scale=scale, rank=rank)
             o = mla.expand_values(o, lp["uv"]["kernel"])
         x = x + _matmul(o.reshape(B, T, -1).astype(dt), lp["attn_out"])
     return x, latent_rows
@@ -642,6 +642,17 @@ def _cache_layout(cfg: KimiLinearConfig, cache: Any) -> Any:
     return StateSlotLayout(cache, cfg.max_seq_len)
 
 
+def _prefill_counts(cfg: KimiLinearConfig, layout: Any,
+                    starts: Sequence[int], counts: Sequence[int],
+                    length: int) -> Dict[str, int]:
+    """The key tiles a call's slices multiply in the MLA layers' latent
+    attention, beside those whole tables would cost (the table's last
+    entry is the slot)."""
+    return mla.key_tiles(starts, counts, length, cfg.num_attention_heads,
+                         layout.table_width - 1, layout.cache.block_size,
+                         layers=len(cfg.full_attn_layers))
+
+
 PAGED = PagedModel(
     family="kimi_linear", forward_paged=forward_paged,
     forward_paged_logits=forward_paged_logits, init=init,
@@ -657,4 +668,4 @@ PAGED = PagedModel(
                   "serving_latent_rows_read_total",
                   "serving_state_slots_total"),
     step_counters=("expert_pairs", "expert_hits"),
-    token_records=True)
+    token_records=True, prefill_counts=_prefill_counts)

@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -102,6 +102,11 @@ class PagedModel:
         transfer, keeps each row's real tokens' records and hands a
         request's back, in the order of its positions, as
         ``RequestResult.token_records``.
+    prefill_counts(cfg, layout, starts, counts, length) -> {name: int}:
+        what the host can say of a prefill call's work from where its
+        rows' slices start, how many real tokens each holds (padding rows
+        0) and the call's bucketed ``length``; the engine puts it on the
+        call's ``serving_prefill`` span. None (the default): nothing.
     """
     family: str
     forward_paged: Callable[..., Any]
@@ -115,6 +120,7 @@ class PagedModel:
     row_counters: Tuple[str, ...] = ()
     step_counters: Tuple[str, ...] = ()
     token_records: bool = False
+    prefill_counts: Optional[Callable[..., Dict[str, int]]] = None
 
     def __post_init__(self) -> None:
         unknown = set(self.unsupported) - set(ENGINE_FEATURES)

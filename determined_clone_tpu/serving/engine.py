@@ -400,6 +400,7 @@ class _PrefillCall(NamedTuple):
     mirrored: Any               # the draft's mirror of the slice, if any
     t0: float                   # monotonic / perf_counter at the dispatch
     pt0: float
+    reckoned: Dict[str, int]    # PagedModel.prefill_counts of the call
 
 
 class InferenceEngine:
@@ -1673,6 +1674,12 @@ class InferenceEngine:
                 packed[i, :n] = a.handle.req.prompt[lo:lo + n]
                 packed[i, t:t + 2] = lo, n
             tables = self._tables_for(rows, b)
+            reckoned = {}
+            if self._tracer is not None and self._model.prefill_counts:
+                pad = [0] * (b - len(rows))
+                reckoned = self._model.prefill_counts(
+                    self.model_cfg, self._layout,
+                    [a.prefill_pos for a in rows] + pad, cnt + pad, t)
         t0 = time.monotonic()
         pt0 = time.perf_counter() if self._tracer is not None else 0.0
         with self._span("prefill_dispatch", batch=b, length=t):
@@ -1691,7 +1698,7 @@ class InferenceEngine:
             for out in (tokens, *extras):
                 out.copy_to_host_async()
         return _PrefillCall(rows, cnt, b, t, tokens, extras, mirrored, t0,
-                            pt0)
+                            pt0, reckoned)
 
     def _prefill_settle(self, call: _PrefillCall) -> None:
         """Read back a prefill call (``serving_prefill``: the wait for the
@@ -1703,7 +1710,8 @@ class InferenceEngine:
         """
         rows, cnt = call.rows, call.counts
         with self._span("serving_prefill", batch=call.batch,
-                        length=call.length, tokens=sum(cnt)) as prefill:
+                        length=call.length, tokens=sum(cnt),
+                        **call.reckoned) as prefill:
             if call.mirrored is not None:
                 call.mirrored.block_until_ready()
             first, counted, records = self._read_back(call.tokens,

@@ -712,11 +712,55 @@ def test_minicpm_sala_paged_forward_compiles_at_the_cell_sizes(v5e, batch,
     assert copies == [] or (batch, t) == (8, 2048), copies[:3]
 
 
+def _mla_slice_calls(hlo_text: str) -> int:
+    """Mosaic calls of the kernel ``mla_slice`` under the scope
+    ``mla_attn``."""
+    return sum("tpu_custom_call" in line and bool(re.search(
+        r"/mla_attn/(?:jit\(_slice_call\)/)?mla_slice/", line))
+        for line in hlo_text.splitlines())
+
+
+@pytest.mark.parametrize("heads,table", [(64, 512), (32, 800)],
+                         ids=["glm-5.2", "kimi-linear"])
+@pytest.mark.parametrize("t", [512, 1024, 2048])
+def test_mla_slice_kernel_compiles_at_the_serve_cells_shapes(
+        v5e, monkeypatch, t, heads, table):
+    """The prefill buckets of both latent-attention serve cells, alone:
+    rows of 640 in blocks of 64, 64 heads under a mask of chosen positions
+    over a table of 512 blocks, 32 heads under the causal mask over 800."""
+    from determined_clone_tpu.ops import mla_attention as mla_mod
+
+    monkeypatch.setattr(mla_mod, "_should_interpret", lambda: False)
+    one = SingleDeviceSharding(v5e[0])
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    chosen = heads == 64
+    args = [arr((1, t, heads, 640), jnp.bfloat16),
+            arr((4 * table, 64, 640), jnp.bfloat16),
+            arr((1, table), jnp.int32), arr((1, t), jnp.int32),
+            arr((1, t), jnp.bool_)]
+    if chosen:
+        args.append(arr((1, t, table * 64), jnp.bool_))
+
+    def call(q, blocks, tables, positions, mask, allowed=None):
+        return mla_mod.mla_slice(q, blocks, tables, allowed, positions, mask,
+                                 scale=0.07, rank=512)
+
+    compiled = jax.jit(call).lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 1
+    # the latents are read where they lie: no row's table of them is made
+    assert not re.search(rf"bf16\[(?:1,)?{table * 64},640\]",
+                         compiled.as_text())
+
+
 @pytest.mark.parametrize("batch,t", [(1, 1), (16, 1), (1, 512), (1, 2048),
                                      (16, 2048)],
                          ids=["decode-1", "decode-16", "slice-512",
                               "slice-2048", "slices-of-16-rows"])
-def test_glm_moe_dsa_paged_forward_compiles_at_the_cell_sizes(v5e, batch, t):
+def test_glm_moe_dsa_paged_forward_compiles_at_the_cell_sizes(
+        v5e, monkeypatch, batch, t):
     """``glm-5.2.serve-agent-closed``: published layers 2..6 at the
     published widths with 16 of the 256 experts held (7.8 GB of bfloat16
     weights), 16 x 512 blocks of 64 positions of latents in five layers
@@ -725,12 +769,16 @@ def test_glm_moe_dsa_paged_forward_compiles_at_the_cell_sizes(v5e, batch, t):
     slices at 32768 positions. They fit the 15.75 GB a v5e offers a
     program, both donated pools are updated in place, nothing as large as
     a layer's share of a pool or a row's whole table of latents is copied
-    or gathered, no stack of weights is converted, and the five scopes the
+    or gathered, no stack of weights is converted, a slice's latent
+    attention is one Mosaic call a layer under ``mla_attn`` (no pass's
+    ``[512, 64, 2048]`` scores in the program), and the five scopes the
     benchmark reads are on the operations' paths."""
     from determined_clone_tpu.models import glm_moe_dsa as glm
+    from determined_clone_tpu.ops import mla_attention as mla_mod
     from determined_clone_tpu.serving.engine import make_paged_forward
     from determined_clone_tpu.serving.kv_cache import KVCacheConfig
 
+    monkeypatch.setattr(mla_mod, "_should_interpret", lambda: False)
     cfg = glm.GLMMoeDsaConfig(
         vocab_size=19360, mlp_layer_types=glm._PUBLISHED_MLPS[2:7],
         indexer_types=glm._PUBLISHED_INDEXERS[2:7], n_routed_experts=16,
@@ -775,6 +823,10 @@ def test_glm_moe_dsa_paged_forward_compiles_at_the_cell_sizes(v5e, batch, t):
         assert mem.temp_size_in_bytes < 0.25 * 2 ** 30
         assert not re.search(rf"bf16\[{batch},32768,{R}\]", text)
         assert re.search(rf"bf16\[(?:{batch},)?2048,{R}\]", text)  # chosen
+    else:
+        # one a layer: a run of layers of one kind is one loop's body
+        assert _mla_slice_calls(text) == len(cfg.runs()) == 3
+        assert not re.search(r"f32\[(?:\d+,)*512,64,2048\]", text)
     # no matrix is converted, as a stack or as a layer of one: it is read
     # as it lies (the head alone is raised, for the one row of logits a
     # slice returns: 0.24 GB read once a call). Shapes that a slice's
@@ -815,14 +867,18 @@ def test_kimi_linear_paged_forward_compiles_at_the_cell_sizes(
     program, all three donated pools are updated in place, the decode step
     makes nothing as large as one row's table of latents, a slice runs the
     chunked delta rule as a Mosaic call under ``kda`` (no chunk's ``[64,
-    64, 128]`` decays and no loop of 64 rows in the program), and the six
-    scopes the benchmark reads are on the operations' paths."""
+    64, 128]`` decays and no loop of 64 rows in the program) and the MLA
+    layer's attention as one under ``mla_attn`` (no pass's ``[512, 32,
+    2048]`` scores), and the six scopes the benchmark reads are on the
+    operations' paths."""
     from determined_clone_tpu.models import kimi_linear as kl
     from determined_clone_tpu.ops import kda as kda_mod
+    from determined_clone_tpu.ops import mla_attention as mla_mod
     from determined_clone_tpu.serving.engine import make_paged_forward
     from determined_clone_tpu.serving.kv_cache import KVCacheConfig
 
     monkeypatch.setattr(kda_mod, "_should_interpret", lambda: False)
+    monkeypatch.setattr(mla_mod, "_should_interpret", lambda: False)
     cfg = kl.KimiLinearConfig(
         vocab_size=81920, num_hidden_layers=5, kda_layers=(1, 2, 3, 5),
         full_attn_layers=(4,), num_experts=128, model_max_length=51200)
@@ -867,6 +923,8 @@ def test_kimi_linear_paged_forward_compiles_at_the_cell_sizes(
         assert any("tpu_custom_call" in line for line in kda_lines)
         assert not re.search(r"f32\[(?:\d+,)*64,64,128\]", text)
         assert not any("/kda/while" in line for line in kda_lines)
+        assert _mla_slice_calls(text) == len(cfg.full_attn_layers)
+        assert not re.search(r"f32\[(?:\d+,)*512,32,2048\]", text)
     for scope in ("kda", "kda_conv", "mla_attn", "kv_cache", "moe_route",
                   "moe_experts"):
         assert f"/{scope}/" in text, scope
