@@ -51,7 +51,7 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from determined_clone_tpu.models.paged import PagedModel
+from determined_clone_tpu.models.paged import PagedModel, run_rows
 from determined_clone_tpu.ops.attention import (
     eva_attention,
     eva_chunk_summary,
@@ -418,8 +418,6 @@ def _paged_logits(params: Params, cfg: EvaByteConfig, tokens: jax.Array,
     """All heads' logits at ``last_index`` [B] of each row ([B, P, V]) or,
     with None, at every position ([B, T, P, V]); the batch in one pass or,
     over ``PREFILL_TOKENS_PER_PASS`` tokens, a row at a time."""
-    B, T = tokens.shape
-
     def run(tokens, positions, token_mask, tables, last, k_pool, v_pool):
         x, k_pool, v_pool = _paged_backbone(
             params, cfg, tokens, positions, token_mask, k_pool, v_pool,
@@ -430,18 +428,9 @@ def _paged_logits(params: Params, cfg: EvaByteConfig, tokens: jax.Array,
         with jax.named_scope("logits"):
             return _heads(cfg, params, x), k_pool, v_pool
 
-    rows = (tokens, positions, token_mask, block_tables, last_index)
-    if B == 1 or B * T <= PREFILL_TOKENS_PER_PASS:
-        return run(*rows, k_pool, v_pool)
-
-    def one_row(pools, row):
-        logits, *pools = run(*(None if a is None else a[None] for a in row),
-                             *pools)
-        return tuple(pools), logits[0]
-
-    (k_pool, v_pool), logits = jax.lax.scan(
-        one_row, (k_pool, v_pool), rows)
-    return logits, k_pool, v_pool
+    return run_rows(run, tokens, positions, token_mask, block_tables,
+                    last_index, (k_pool, v_pool),
+                    tokens_per_pass=PREFILL_TOKENS_PER_PASS)
 
 
 def forward_paged(params: Params, cfg: EvaByteConfig, tokens: jax.Array,

@@ -77,7 +77,11 @@ from typing import Any, Dict, List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from determined_clone_tpu.models.paged import PagedModel, cast_leaves
+from determined_clone_tpu.models.paged import (
+    PagedModel,
+    cast_leaves,
+    run_rows,
+)
 from determined_clone_tpu.ops import dsa_index, mla_attention as mla
 from determined_clone_tpu.ops.layers import layernorm, rmsnorm
 from determined_clone_tpu.ops.moe import routed_experts
@@ -537,12 +541,7 @@ def _paged_logits(params: Params, cfg: GLMMoeDsaConfig, tokens: jax.Array,
     ``PREFILL_TOKENS_PER_PASS`` tokens, a row at a time. A slice is padded
     to whole cache blocks. Returns ``(logits, latent_pool, index_pool,
     counts, routing [B, T, L_sparse * k])``."""
-    B, T = tokens.shape
-    bs = pools[0].shape[2]
-    if T > 1 and T % bs:
-        pad = ((0, 0), (0, -T % bs))
-        tokens, positions, token_mask = (
-            jnp.pad(a, pad) for a in (tokens, positions, token_mask))
+    T = tokens.shape[1]
 
     def run(tokens, positions, token_mask, tables, last, *pools):
         x, latent_pool, index_pool, counts, routing, _ = _paged_backbone(
@@ -554,19 +553,9 @@ def _paged_logits(params: Params, cfg: GLMMoeDsaConfig, tokens: jax.Array,
             return (_matmul(h, params["lm_head"]), latent_pool, index_pool,
                     counts, routing[:, :T])
 
-    rows = (tokens, positions, token_mask, block_tables, last_index)
-    if B == 1 or B * tokens.shape[1] <= PREFILL_TOKENS_PER_PASS:
-        return run(*rows, *pools)
-
-    def one_row(carry, row):
-        *pools, counts = carry
-        logits, *pools, hit, routing = run(
-            *(None if a is None else a[None] for a in row), *pools)
-        return (*pools, counts + hit), (logits[0], routing[0])
-
-    (*pools, counts), (logits, routing) = jax.lax.scan(
-        one_row, (*pools, jnp.zeros((2,), jnp.int32)), rows)
-    return (logits, *pools, counts, routing)
+    return run_rows(run, tokens, positions, token_mask, block_tables,
+                    last_index, pools, block=pools[0].shape[2],
+                    tokens_per_pass=PREFILL_TOKENS_PER_PASS, counters=2)
 
 
 def forward_paged(params: Params, cfg: GLMMoeDsaConfig, tokens: jax.Array,

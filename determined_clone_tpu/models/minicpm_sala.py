@@ -58,7 +58,11 @@ from typing import Any, Dict, List, Tuple
 import jax
 import jax.numpy as jnp
 
-from determined_clone_tpu.models.paged import PagedModel, cast_leaves
+from determined_clone_tpu.models.paged import (
+    PagedModel,
+    cast_leaves,
+    run_rows,
+)
 from determined_clone_tpu.ops.attention import rotary_embedding
 from determined_clone_tpu.ops.layers import rmsnorm
 from determined_clone_tpu.ops.lightning_attention import (
@@ -476,12 +480,7 @@ def _paged_logits(params: Params, cfg: MiniCPMSALAConfig, tokens: jax.Array,
     every position ([B, T, V]); the batch in one pass or, over
     ``PREFILL_TOKENS_PER_PASS`` tokens, a row at a time. A slice is
     padded to whole cache blocks."""
-    B, T = tokens.shape
-    bs = cfg.sparse.block
-    if T > 1 and T % bs:
-        pad = ((0, 0), (0, -T % bs))
-        tokens, positions, token_mask = (
-            jnp.pad(a, pad) for a in (tokens, positions, token_mask))
+    T = tokens.shape[1]
 
     def run(tokens, positions, token_mask, tables, last, *pools):
         x, *pools = _paged_backbone(params, cfg, tokens, positions,
@@ -494,17 +493,9 @@ def _paged_logits(params: Params, cfg: MiniCPMSALAConfig, tokens: jax.Array,
             return (_matmul(h.astype(cfg.compute_dtype), params["lm_head"]),
                     *pools)
 
-    rows = (tokens, positions, token_mask, block_tables, last_index)
-    if B == 1 or B * tokens.shape[1] <= PREFILL_TOKENS_PER_PASS:
-        return run(*rows, *pools)
-
-    def one_row(pools, row):
-        logits, *pools = run(*(None if a is None else a[None] for a in row),
-                             *pools)
-        return tuple(pools), logits[0]
-
-    pools, logits = jax.lax.scan(one_row, tuple(pools), rows)
-    return (logits, *pools)
+    return run_rows(run, tokens, positions, token_mask, block_tables,
+                    last_index, pools, block=cfg.sparse.block,
+                    tokens_per_pass=PREFILL_TOKENS_PER_PASS)
 
 
 def forward_paged(params: Params, cfg: MiniCPMSALAConfig, tokens: jax.Array,

@@ -47,6 +47,48 @@ def cast_leaves(params: Any,
     return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
+def run_rows(run: Callable[..., Tuple[Any, ...]], tokens: jax.Array,
+             positions: jax.Array, token_mask: jax.Array,
+             block_tables: jax.Array, last_index: Optional[jax.Array],
+             pools: Tuple[jax.Array, ...], *, tokens_per_pass: int,
+             block: Optional[int] = None,
+             counters: int = 0) -> Tuple[Any, ...]:
+    """A call's packed rows through a family's ``run(tokens, positions,
+    token_mask, tables, last, *pools) -> (logits, *pools)``: the whole
+    batch in one pass when there is one row or ``B x T`` is within
+    ``tokens_per_pass``, else a row at a time (``lax.scan``), the pools
+    carried from row to row and the logits stacked. With ``block``, a
+    slice (``T > 1``) is first padded to whole cache blocks of that many
+    positions; ``run`` trims what it returns. With ``counters``, ``run``
+    returns two more values, an int32 vector of that length that is summed
+    over the rows and something of every token that is stacked beside the
+    logits. ``last`` is None where ``last_index`` is."""
+    if block and tokens.shape[1] > 1 and tokens.shape[1] % block:
+        pad = ((0, 0), (0, -tokens.shape[1] % block))
+        tokens, positions, token_mask = (
+            jnp.pad(a, pad) for a in (tokens, positions, token_mask))
+    rows = (tokens, positions, token_mask, block_tables, last_index)
+    B, T = tokens.shape
+    if B == 1 or B * T <= tokens_per_pass:
+        return run(*rows, *pools)
+
+    n = len(pools)
+
+    def one_row(carry, row):
+        logits, *out = run(*(None if a is None else a[None] for a in row),
+                           *carry[:n])
+        if not counters:
+            return tuple(out), logits[0]
+        hit, noted = out[n:]
+        return (*out[:n], carry[n] + hit), (logits[0], noted[0])
+
+    zeros = (jnp.zeros((counters,), jnp.int32),) if counters else ()
+    carry, stacked = jax.lax.scan(one_row, (*pools, *zeros), rows)
+    if not counters:
+        return (stacked, *carry)
+    return (stacked[0], *carry, stacked[1])
+
+
 def _as_given(params: Any, cfg: Any) -> Any:
     return params
 

@@ -8,6 +8,7 @@ The steering itself (env + jax.config) lives in
 determined_clone_tpu.utils.host_steering, shared with __graft_entry__.
 """
 import dataclasses
+import functools
 import os
 import sys
 import threading
@@ -20,6 +21,18 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from determined_clone_tpu.utils.host_steering import steer_to_host_cpu  # noqa: E402
 
 steer_to_host_cpu(8)
+
+# XLA aborts the process when the participants of a CPU collective (the
+# eight virtual devices' threads) have not all arrived within 40 s of the
+# first. Beside five other workers on a crowded host that clock reads the
+# machine (``test_trainer.py::TestTrainerMnist::test_mnist_mlp_learns_sharded``
+# and ``test_examples_e2e.py::test_mnist_distributed_dp8`` died of it now
+# and then): the rendezvous waits for its participants, four minutes at
+# most so that a real deadlock still ends well inside the run's limit. The
+# trial processes the e2e tests start inherit it with the environment.
+os.environ["XLA_FLAGS"] += (
+    " --xla_cpu_collective_call_warn_stuck_timeout_seconds=60"
+    " --xla_cpu_collective_call_terminate_timeout_seconds=240")
 
 
 def pytest_configure(config):
@@ -88,22 +101,20 @@ def geometry(request):
     and ``params`` (H * head_dim = 32, padded to a 128-wide row) and the
     same model at d_model = 128, which fills the row exactly."""
     cfg = request.module.CFG
+    if request.param == "padded_rows":
+        return cfg, request.getfixturevalue("params")
+    return _aligned(cfg)
+
+
+@functools.lru_cache(maxsize=None)
+def _aligned(cfg):
+    """One tree a configuration and worker: the cases only read it."""
     import jax
 
     from determined_clone_tpu.models import gpt
-    from determined_clone_tpu.serving.engine import make_paged_forward
 
-    # the jit cache belongs to forward_paged, not to an engine, and those
-    # modules' program-budget assertions count it: one geometry at a time,
-    # and nothing of what another module's engines compiled in this worker
-    shared = make_paged_forward()
-    shared.clear_cache()
-    if request.param == "padded_rows":
-        yield cfg, request.getfixturevalue("params")
-        return
     aligned = dataclasses.replace(cfg, d_model=128)
-    yield aligned, gpt.init(jax.random.PRNGKey(0), aligned)
-    shared.clear_cache()
+    return aligned, gpt.init(jax.random.PRNGKey(0), aligned)
 
 
 @pytest.fixture

@@ -167,7 +167,16 @@ def test_single_experiment_trains_to_completion(cluster):
     metrics = session.trial_metrics(t["id"])
     groups = {m["group"] for m in metrics}
     assert "training" in groups and "validation" in groups
-    # checkpoint was reported and linked
+    # checkpoint was reported and linked. The master publishes COMPLETED
+    # from the report of the last validation (the searcher closes the trial
+    # and shuts the experiment down in that request, master.cc
+    # apply_search_ops); the harness reports the checkpoint it saves on its
+    # way out in a later request, so the link is waited for like the state
+    t = wait_for(
+        lambda: (lambda tr: tr if tr["latest_checkpoint"] else None)(
+            session.get_experiment(exp["id"])["trials"][0]),
+        desc="checkpoint linked", timeout=60,
+    )
     assert t["latest_checkpoint"]
     ckpts = session.get(f"/api/v1/experiments/{exp['id']}/checkpoints")[
         "checkpoints"]

@@ -11,6 +11,7 @@ diagnostic, and the clean variants must stay clean.
 """
 import sys
 import textwrap
+import time
 from pathlib import Path
 
 REPO = Path(__file__).resolve().parent.parent
@@ -747,14 +748,23 @@ def test_changed_only_filters_reporting_not_analysis(tmp_path):
 
 def test_perf_budget_cold_full_tree():
     """A cold serial run over the whole tree (per-file pass + facts +
-    every project checker) stays under the documented budget."""
+    every project checker) stays under the documented budget: every file
+    analysed, none from a cache, in this thread, within ``PERF_BUDGET_S``
+    of this thread's own CPU time (``docs/static_analysis.md``: "on one
+    CPU core"). Not of the wall clock: tier-1 runs beside five other
+    workers, and their load is not dctlint's cost."""
     stats = {}
+    cpu0 = time.thread_time()
     lint_core.run([str(REPO / p) for p in TIER1_LINT_PATHS],
                   relative_to=REPO, jobs=1, stats=stats)
+    cpu_s = time.thread_time() - cpu0
     assert stats["files"] >= 100
-    assert stats["wall_s"] < PERF_BUDGET_S, (
-        f"cold dctlint run took {stats['wall_s']:.2f}s over "
-        f"{stats['files']} files (budget {PERF_BUDGET_S}s) — profile "
+    assert stats["jobs"] == 1 and stats["cache_hits"] == 0
+    assert stats["analyzed"] == stats["files"]
+    assert cpu_s < PERF_BUDGET_S, (
+        f"cold dctlint run took {cpu_s:.2f}s of CPU over "
+        f"{stats['files']} files (budget {PERF_BUDGET_S}s; "
+        f"{stats['wall_s']:.2f}s of wall clock) — profile "
         f"the per-file pass before raising the budget")
 
 

@@ -12,6 +12,7 @@ whole softmax) and NaN in V (it must reach no sum, not even times a
 probability of zero); summary entries a row did not reserve are -1.
 """
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -80,13 +81,15 @@ def _plain(q, k_pool, v_pool, tables, window_rows, summary_rows, *,
 
 def _check(case, rtol, atol, geometry=TINY, **kw):
     q, *_, w, s = case
-    out = epa.eva_paged_attention(*case, window_blocks=geometry[1], **kw)
+    out = jax.jit(functools.partial(   # not one dispatch an operation
+        epa.eva_paged_attention, window_blocks=geometry[1], **kw))(*case)
     assert out.shape == q.shape and out.dtype == q.dtype
     out = np.asarray(out, np.float32)
     assert np.isfinite(out).all()
     live = np.asarray(w + s) > 0
     assert (out[~live] == 0).all()
-    want = np.asarray(_plain(*case, geometry=geometry), np.float32)
+    want = np.asarray(jax.jit(functools.partial(_plain, geometry=geometry))(
+        *case), np.float32)
     np.testing.assert_allclose(out[live], want[live], rtol=rtol, atol=atol)
     return out
 
@@ -176,7 +179,8 @@ def test_decode_takes_the_kernel_and_slices_the_plain_form(monkeypatch):
     cfg = dataclasses.replace(evabyte.EvaByteConfig.tiny(),
                               compute_dtype=jnp.float32,
                               param_dtype=jnp.float32)
-    params = evabyte.init(jax.random.PRNGKey(0), cfg)
+    params = jax.jit(evabyte.init, static_argnums=1)(
+        jax.random.PRNGKey(0), cfg)
     cache = KVCacheConfig(40, cfg.chunk_size)
     layout = cfg.paged_model().cache_layout(cfg, cache)
     tables = np.zeros((2, layout.table_width), np.int32)
@@ -193,8 +197,10 @@ def test_decode_takes_the_kernel_and_slices_the_plain_form(monkeypatch):
         rng = np.random.default_rng(0)
         pools = [jnp.asarray(rng.standard_normal(p.shape), p.dtype).at[
             ..., cfg.d_model:].set(0) for p in init_kv_pools(cfg, cache)]
-        logits, _, _ = evabyte.forward_paged(
-            params, cfg, jnp.ones((2, t), jnp.int32),
+        # a jit of its own every time: the path is chosen while tracing
+        logits, _, _ = jax.jit(
+            lambda p, *rest: evabyte.forward_paged(p, cfg, *rest))(
+            params, jnp.ones((2, t), jnp.int32),
             jnp.asarray([[72 + i for i in range(t)], [3] * t], jnp.int32),
             jnp.asarray([[True] * t, [t == 1] * t]),
             jnp.zeros((2,), jnp.int32), *pools, jnp.asarray(tables))

@@ -5,6 +5,7 @@ at a small size on the CPU: window 64, chunk 8, 2 layers, 4 heads of 16,
 8 prediction heads.
 """
 import dataclasses
+import functools
 import time
 
 import jax
@@ -44,7 +45,8 @@ def params():
     initial value (norm scales 0, phi and mu tiny), matrices 8 x init_std so
     that attention is far from uniform."""
     cfg = _config(jnp.float32)
-    p = evabyte.init(jax.random.PRNGKey(0), cfg)
+    p = jax.jit(functools.partial(evabyte.init, cfg=cfg))(
+        jax.random.PRNGKey(0))
     keys = iter(jax.random.split(jax.random.PRNGKey(1), 8))
     blocks = p["blocks"]
     for name in ("ln1", "ln2"):
@@ -136,8 +138,8 @@ class _Paged:
 
 def test_uncached_forward_is_the_reference(params):
     tokens = _tokens(0, 200)
-    got = np.asarray(evabyte.apply(params, _config(jnp.float32),
-                                   jnp.asarray(tokens[None])))[0]
+    got = np.asarray(jax.jit(evabyte.apply, static_argnums=1)(
+        params, _config(jnp.float32), jnp.asarray(tokens[None])))[0]
     want = _reference(params, tokens)
     assert got.shape == want.shape == (200, 8, 320)
     assert np.abs(got - want).max() < TOLERANCE
@@ -401,8 +403,12 @@ def test_gpt_through_the_interface_is_token_identical_to_uncached():
             == ("serving_kv_rows_attended_total",
                 "serving_kv_rows_tabled_total")
         got = eng.generate(prompt.tolist(), max_new_tokens=12).tokens
+    # one program for every length: the growing sequence padded on the
+    # right, which no causal position on the left can see
+    apply = jax.jit(gpt.apply, static_argnums=1)
     seq = prompt.tolist()
     for _ in range(12):
-        logits = gpt.apply(p, cfg, jnp.asarray([seq]))
-        seq.append(int(jnp.argmax(logits[0, -1])))
+        padded = seq + [0] * (len(prompt) + 12 - len(seq))
+        logits = apply(p, cfg, jnp.asarray([padded]))
+        seq.append(int(jnp.argmax(logits[0, len(seq) - 1])))
     assert got == seq[len(prompt):]

@@ -49,8 +49,9 @@ def cluster(tmp_path_factory):
         "JAX_PLATFORMS": "cpu",
         # the distributed examples want 8 chips; give the trial processes
         # a virtual 8-device host (the conftest trick, but for the agent's
-        # children)
-        "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
+        # children: conftest's flags, its wait for a CPU collective's
+        # participants among them)
+        "XLA_FLAGS": os.environ["XLA_FLAGS"],
         "PYTHONPATH": str(REPO),
         "DCT_AGENT_SLOTS": "8",
         "DCT_AGENT_TOPOLOGY": "v5e-8",
@@ -127,6 +128,21 @@ def _submit(cluster, det, config_path, model_dir, overrides, name):
     return rc, detail
 
 
+def _linked_checkpoint(cluster, trial, timeout=60):
+    """The trial's ``latest_checkpoint`` once the master holds it. The
+    master publishes COMPLETED from the report of the last validation; the
+    harness reports the checkpoint it saves on its way out in a later
+    request, so the link is waited for, not read at once."""
+    deadline = time.time() + timeout
+    while time.time() < deadline:
+        for t in cluster["session"].get_experiment(
+                trial["experiment_id"])["trials"]:
+            if t["id"] == trial["id"] and t["latest_checkpoint"]:
+                return t["latest_checkpoint"]
+        time.sleep(0.5)
+    return None
+
+
 TINY_COMMON = [
     "scheduling_unit=2",
     "min_validation_period.batches=4",
@@ -149,7 +165,7 @@ def test_mnist_const(cluster, det):
     metrics = cluster["session"].trial_metrics(trial["id"])
     val = [m for m in metrics if m["group"] == "validation"]
     assert val and "accuracy" in val[-1]["metrics"]
-    assert trial["latest_checkpoint"]
+    assert _linked_checkpoint(cluster, trial)
 
 
 def test_mnist_distributed_dp8(cluster, det):
@@ -202,7 +218,7 @@ def test_bert_core_api(cluster, det):
     val = [m for m in metrics if m["group"] == "validation"]
     assert val and "accuracy" in val[-1]["metrics"]
     # and uploaded a checkpoint through core_context.checkpoint
-    assert trial["latest_checkpoint"]
+    assert _linked_checkpoint(cluster, trial)
 
 
 def test_bert_core_api_resume_local(tmp_path):

@@ -1,6 +1,12 @@
 """Pallas flash attention: numerics vs the XLA reference, gradients,
 shape guards, and GPT integration (interpret mode on CPU — same kernel
-code path the TPU compiles)."""
+code path the TPU compiles).
+
+Every run of a kernel here is under ``jax.jit``: un-jitted, the
+interpreter dispatches a grid step's operations one by one. The shapes are
+the smallest that cross the branch a case names; the train cells' own
+(T = 1024, D = 64, bf16) are compiled for a described v5e in
+``tests/test_chip_compile.py`` and run on the chip."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,6 +15,10 @@ import pytest
 from determined_clone_tpu.ops import flash_attention as flash_mod
 from determined_clone_tpu.ops.attention import NEG_INF, mha
 from determined_clone_tpu.ops.flash_attention import flash_attention
+
+
+flash = jax.jit(flash_attention,
+                static_argnames=("causal", "block_q", "block_k"))
 
 
 def _qkv(B=2, T=128, H=2, D=32, seed=0):
@@ -21,7 +31,7 @@ def _qkv(B=2, T=128, H=2, D=32, seed=0):
 def test_matches_mha(causal):
     q, k, v = _qkv()
     ref = mha(q, k, v, causal=causal)
-    out = flash_attention(q, k, v, causal=causal, block_q=64, block_k=64)
+    out = flash(q, k, v, causal=causal, block_q=64, block_k=64)
     assert jnp.max(jnp.abs(ref - out)) < 1e-4
 
 
@@ -30,14 +40,14 @@ def test_uneven_q_k_blocks():
     q, k, v = _qkv(T=128)
     ref = mha(q, k, v, causal=True)
     for bq, bk in [(32, 64), (64, 32), (128, 128)]:
-        out = flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk)
+        out = flash(q, k, v, causal=True, block_q=bq, block_k=bk)
         assert jnp.max(jnp.abs(ref - out)) < 1e-4, (bq, bk)
 
 
 def test_block_clamps_to_seq():
     # seq shorter than the default blocks: clamp instead of error
     q, k, v = _qkv(T=64)
-    out = flash_attention(q, k, v)  # default block 128 > 64
+    out = flash(q, k, v)  # default block 128 > 64
     assert jnp.max(jnp.abs(mha(q, k, v) - out)) < 1e-4
 
 
@@ -56,8 +66,8 @@ def test_gradients_match_reference():
     def f_ref(q, k, v):
         return (mha(q, k, v) ** 2).sum()
 
-    gf = jax.grad(f_flash, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
+    gf = jax.jit(jax.grad(f_flash, argnums=(0, 1, 2)))(q, k, v)
+    gr = jax.jit(jax.grad(f_ref, argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(gf, gr):
         assert jnp.max(jnp.abs(a - b)) < 1e-3
 
@@ -96,35 +106,40 @@ def _parity_inputs(shape, dtype):
 @pytest.mark.parametrize("shape", PARITY_SHAPES,
                          ids=lambda s: "x".join(str(n) for n in s))
 def test_forward_and_lse_match_mha(shape, causal, dtype):
-    (q, k, v), (qf, kf, vf), blocks = _parity_inputs(shape, dtype)
-    out = flash_attention(q, k, v, causal=causal, **blocks)
-    assert out.dtype == dtype
-    ref = mha(qf, kf, vf, causal=causal)
-    assert jnp.max(jnp.abs(ref - out.astype(jnp.float32))) \
-        < PARITY_TOL[dtype]
-
-    # the residual the backward recomputes probabilities from
+    (q, k, v), as_f32, blocks = _parity_inputs(shape, dtype)
     B, T, H, D = q.shape
     block = flash_mod.Blocks(
         blocks["block_q"] or T, blocks["block_k"] or T,
         flash_mod.block_sizes(T, T, D, dtype).fwd.tile)
     lay = flash_mod.layout(H, D)
-    o, lse = flash_mod._fwd_call(
-        *(flash_mod.to_kernel_layout(x, lay) for x in (q, k, v)), lay=lay,
-        head_dim=D, causal=causal, block=block, interpret=True,
-        with_lse=True,
-        cost=flash_mod.flash_cost(B, H, T, T, D, causal, dtype)["flash_fwd"])
+
+    @jax.jit   # the kernel, its reference and the residual's: one program
+    def run(q, k, v, qf, kf, vf):
+        out = flash_attention(q, k, v, causal=causal, **blocks)
+        # the residual the backward recomputes probabilities from
+        o, lse = flash_mod._fwd_call(
+            *(flash_mod.to_kernel_layout(x, lay) for x in (q, k, v)),
+            lay=lay, head_dim=D, causal=causal, block=block, interpret=True,
+            with_lse=True, cost=flash_mod.flash_cost(
+                B, H, T, T, D, causal, dtype)["flash_fwd"])
+        scores = jnp.einsum("bqhd,bkhd->bhqk", qf, kf) / np.sqrt(D)
+        if causal:
+            scores = jnp.where(jnp.tril(jnp.ones((T, T), bool)), scores,
+                               NEG_INF)
+        return (out, mha(qf, kf, vf, causal=causal),
+                flash_mod.from_kernel_layout(o, lay, B, D), lse,
+                jax.nn.logsumexp(scores, axis=-1))          # [B, H, T]
+
+    out, ref, o, lse, want = run(q, k, v, *as_f32)
+    assert out.dtype == dtype
+    assert jnp.max(jnp.abs(ref - out.astype(jnp.float32))) \
+        < PARITY_TOL[dtype]
     assert lse.shape == ((B * lay.groups, lay.heads, T) if lay.in_place
                          else (B * H, 1, T))
     assert lse.dtype == jnp.float32
-    scores = jnp.einsum("bqhd,bkhd->bhqk", qf, kf) / np.sqrt(D)
-    if causal:
-        scores = jnp.where(jnp.tril(jnp.ones((T, T), bool)), scores,
-                           NEG_INF)
-    want = jax.nn.logsumexp(scores, axis=-1)          # [B, H, T]
     # a lane block short of heads carries rows for heads that are not there
     assert jnp.max(jnp.abs(lse.reshape(B, -1, T)[:, :H] - want)) < 1e-3
-    assert jnp.array_equal(flash_mod.from_kernel_layout(o, lay, B, D), out)
+    assert jnp.array_equal(o, out)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
@@ -144,8 +159,9 @@ def test_gradients_match_mha(shape, causal, dtype):
     def f_ref(q, k, v):
         return (mha(q, k, v, causal=causal) * w).sum()
 
-    got = jax.grad(f_flash, argnums=(0, 1, 2))(q, k, v)
-    want = jax.grad(f_ref, argnums=(0, 1, 2))(*as_f32)
+    got, want = jax.jit(lambda x, xf: (   # one program for both
+        jax.grad(f_flash, argnums=(0, 1, 2))(*x),
+        jax.grad(f_ref, argnums=(0, 1, 2))(*xf)))((q, k, v), as_f32)
     for name, a, b in zip("qkv", got, want):
         assert a.dtype == dtype
         gap = jnp.max(jnp.abs(a.astype(jnp.float32) - b))
@@ -234,7 +250,7 @@ def test_cost_counts_the_attended_pairs():
 
 def test_bf16_inputs():
     q, k, v = (x.astype(jnp.bfloat16) for x in _qkv(T=128))
-    out = flash_attention(q, k, v, block_q=64, block_k=64)
+    out = flash(q, k, v, block_q=64, block_k=64)
     assert out.dtype == jnp.bfloat16
     ref = mha(q, k, v)
     assert jnp.max(jnp.abs(ref.astype(jnp.float32) -
@@ -263,8 +279,9 @@ def test_gpt_with_flash_attention_trains():
     cfg_ref = gpt.GPTConfig(vocab_size=128, n_layers=2, d_model=64, n_heads=4,
                             d_ff=128, max_seq_len=64, remat=False,
                             attention_impl="mha")
-    logits_ref = gpt.apply(params, cfg_ref, tokens[:, :-1])
-    logits_flash = gpt.apply(params, cfg, tokens[:, :-1])
+    apply = jax.jit(gpt.apply, static_argnums=1)
+    logits_ref = apply(params, cfg_ref, tokens[:, :-1])
+    logits_flash = apply(params, cfg, tokens[:, :-1])
     assert jnp.max(jnp.abs(logits_ref - logits_flash)) < 0.05
 
     tx = optax.sgd(0.1)
@@ -346,12 +363,45 @@ def test_flash_mha_loss_parity_over_training():
     assert curves["flash"][-1] < curves["flash"][0]
 
 
-@pytest.mark.parametrize("seq_len", [50, 1023])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_every_kernel_works_a_square_block_through_in_pieces(causal):
+    """Blocks of 256 x 256 in pieces of 128 in all three kernels (the block
+    rule gives the backward kernels pieces from T = 256 and 1024 on, the
+    forward none: ``_CAPS``): four pieces a block, under the causal mask
+    the one above the diagonal skipped and the two on it masked. Output and
+    the three gradients against ``mha``. T = 256 is the smallest length
+    with two pieces a side (a piece is a lane tile)."""
+    q, k, v = _qkv(B=1, T=256, H=2, D=64, seed=6)
+    w = jnp.asarray(np.random.RandomState(7).randn(*q.shape), jnp.float32)
+    pieces = flash_mod.Blocks(256, 256, 128)
+    assert pieces.tiles == (128, 128)
+
+    def both(attend):
+        return jax.jit(jax.value_and_grad(
+            lambda q, k, v: (attend(q, k, v) * w).sum(), argnums=(0, 1, 2)))
+
+    got, got_grads = both(lambda q, k, v: flash_mod._flash_attention_cvjp(
+        q, k, v, causal, flash_mod.BlockSizes(pieces, pieces, pieces),
+        True))(q, k, v)
+    want, want_grads = both(lambda q, k, v: mha(q, k, v, causal=causal))(
+        q, k, v)
+    assert abs(got - want) < 1e-3 * abs(want)
+    for name, a, b in zip("qkv", got_grads, want_grads):
+        assert jnp.max(jnp.abs(a - b)) < 1e-3 * jnp.max(jnp.abs(b)), name
+
+
+@pytest.mark.parametrize("seq_len", [50, 255])
 def test_flash_pads_indivisible_seq_in_gpt(seq_len):
     """The everyday loss pattern slices tokens[:, :-1], giving T values
     (e.g. 1023) the kernel cannot tile. The model must pad to the multiple
     the kernel asks for and slice transparently, and still match mha
-    numerics, gradients included."""
+    numerics, gradients included. Two lengths, one on each side of
+    ``seq_multiple``'s branch: 50 is under a lane tile and is padded to a
+    sublane multiple (64, one block); 255 is past one and is padded to
+    whole lane tiles (256, which ``flash_bwd_dkv`` works through in four
+    pieces). 1023 -> 1024 crosses the same branch at sixteen times the
+    pieces to trace; the train cells' T = 1024 is compiled for the chip in
+    ``tests/test_chip_compile.py``."""
     import dataclasses
 
     from determined_clone_tpu.models import gpt
@@ -362,18 +412,20 @@ def test_flash_pads_indivisible_seq_in_gpt(seq_len):
     cfg_mha = dataclasses.replace(cfg, attention_impl="mha")
     tokens = jax.random.randint(jax.random.PRNGKey(5), (1, seq_len + 1), 0,
                                 128)
-    params = gpt.init(jax.random.PRNGKey(0), cfg)
-    # T = 50 -> one block of 64; T = 1023 -> 1024 in several
-    logits_flash = gpt.apply(params, cfg, tokens[:, :-1])
-    logits_mha = gpt.apply(params, cfg_mha, tokens[:, :-1])
+    params = jax.jit(gpt.init, static_argnums=1)(jax.random.PRNGKey(0), cfg)
+    assert flash_mod.seq_multiple(seq_len) == (16 if seq_len == 50 else 128)
+    apply = jax.jit(gpt.apply, static_argnums=1)
+    logits_flash = apply(params, cfg, tokens[:, :-1])
+    logits_mha = apply(params, cfg_mha, tokens[:, :-1])
     assert logits_flash.shape == logits_mha.shape
     assert jnp.max(jnp.abs(logits_flash - logits_mha)) < 0.05
 
     def loss(p, c):
         return gpt.loss_fn(p, c, tokens[:, :-1], tokens[:, 1:])
 
-    g_flash = jax.grad(loss)(params, cfg)
-    g_mha = jax.grad(loss)(params, cfg_mha)
+    grad = jax.jit(jax.grad(loss), static_argnums=1)
+    g_flash = grad(params, cfg)
+    g_mha = grad(params, cfg_mha)
     for a, b in zip(jax.tree.leaves(g_flash), jax.tree.leaves(g_mha)):
         assert jnp.linalg.norm(a - b) <= 0.05 * jnp.linalg.norm(b) + 1e-6
 
@@ -410,9 +462,10 @@ def test_remat_keeps_the_kernels_output_and_log_sum_exp(monkeypatch, other):
     tokens = jax.random.randint(jax.random.PRNGKey(3), (2, 65), 0, 128)
 
     def grad(c):
-        fn = jax.grad(lambda p: gpt.loss_fn(p, c, tokens[:, :-1],
-                                            tokens[:, 1:]))
-        return fn(params), _kernel_names(jax.make_jaxpr(fn)(params).jaxpr)
+        traced = jax.jit(jax.grad(lambda p: gpt.loss_fn(
+            p, c, tokens[:, :-1], tokens[:, 1:]))).trace(params)
+        return (traced.lower().compile()(params),
+                _kernel_names(traced.jaxpr.jaxpr))
 
     kept, kernels = grad(cfg)
     assert sorted(kernels) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
@@ -438,14 +491,15 @@ def test_a_fully_rematerialised_layer_keeps_them_too(monkeypatch):
 
     cfg = glm.GLMMoeLiteConfig.tiny()
     assert cfg.remat
-    params = glm.init(jax.random.PRNGKey(0), cfg)
+    params = jax.jit(glm.init, static_argnums=1)(jax.random.PRNGKey(0), cfg)
     batch = jax.random.randint(jax.random.PRNGKey(1), (2, 33), 0,
                                cfg.vocab_size)
 
     def grad():
-        fn = jax.grad(lambda p: glm.loss_fn(p, cfg, batch[:, :-1],
-                                            batch[:, 1:])[0])
-        return fn(params), _kernel_names(jax.make_jaxpr(fn)(params).jaxpr)
+        traced = jax.jit(jax.grad(lambda p: glm.loss_fn(
+            p, cfg, batch[:, :-1], batch[:, 1:])[0])).trace(params)
+        return (traced.lower().compile()(params),
+                _kernel_names(traced.jaxpr.jaxpr))
 
     kept, kernels = grad()
     assert [kernels.count(k) for k in (

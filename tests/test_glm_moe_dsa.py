@@ -10,6 +10,7 @@ layers in the published pattern ``full, shared, shared, shared, full``,
 seeded weights and a selection bias of size 0.1.
 """
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -62,7 +63,8 @@ def params():
     """Seeded weights with every learned vector away from its initial
     value (norm scales 1, LayerNorm bias 0), and a selection bias large
     enough to change choices."""
-    p = glm.init(jax.random.PRNGKey(0), CFG, bias_std=0.1)
+    p = jax.jit(functools.partial(glm.init, cfg=CFG, bias_std=0.1))(
+        jax.random.PRNGKey(0))
     keys = iter(jax.random.split(jax.random.PRNGKey(1), 64))
     for kind in set(CFG.kinds):
         for name, leaves in p[kind].items():

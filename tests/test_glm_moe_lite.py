@@ -7,6 +7,8 @@ The tiny cell through the benchmark's harness, the configuration against
 the catalog and the readers are ``benchmarks/tests/test_glm4_moe_lite.py``
 (collected by ``tests/test_benchmark_harness.py``).
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -84,11 +86,12 @@ def test_expert_layers_backward_is_jax_grads_of_the_dense_form(routing):
         return jnp.sum(y * ct), (y, stats)
 
     with jax.default_matmul_precision("highest"):
-        (_, (y, stats)), got = jax.value_and_grad(
-            program, argnums=(0, 1), has_aux=True)(p, h)
-        want_y = _dense_masked(p, h, 4)
-        want = jax.grad(lambda p, h: jnp.sum(_dense_masked(p, h, 4) * ct),
-                        argnums=(0, 1))(p, h)
+        (_, (y, stats)), got = jax.jit(jax.value_and_grad(
+            program, argnums=(0, 1), has_aux=True))(p, h)
+        want_y, want = jax.jit(lambda p, h: (
+            _dense_masked(p, h, 4),
+            jax.grad(lambda p, h: jnp.sum(_dense_masked(p, h, 4) * ct),
+                     argnums=(0, 1))(p, h)))(p, h)
     np.testing.assert_allclose(y, want_y, atol=1e-5 * float(
         jnp.max(jnp.abs(want_y))))
     for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got)[0],
@@ -138,13 +141,15 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
     def uncut(h):
         return ref.expert_layer(p, h, first_expert=0)[0]
 
+    def both(layer):
+        return jax.jit(lambda h: (layer(h), jax.value_and_grad(
+            lambda h: jnp.sum(layer(h) * ct))(h)))
+
     with jax.default_matmul_precision("highest"):
-        got, got_grad = jax.value_and_grad(
-            lambda h: jnp.sum(shares(h) * ct))(h)
-        want, want_grad = jax.value_and_grad(
-            lambda h: jnp.sum(uncut(h) * ct))(h)
-        np.testing.assert_allclose(shares(h), uncut(h), atol=1e-5 * float(
-            jnp.max(jnp.abs(uncut(h)))))
+        got_y, (got, got_grad) = both(shares)(h)
+        want_y, (want, want_grad) = both(uncut)(h)
+    np.testing.assert_allclose(got_y, want_y, atol=1e-5 * float(
+        jnp.max(jnp.abs(want_y))))
     np.testing.assert_allclose(got, want, rtol=1e-5)
     np.testing.assert_allclose(got_grad, want_grad, atol=1e-5 * float(
         jnp.max(jnp.abs(want_grad))))
@@ -160,12 +165,14 @@ def test_served_layer_and_trained_layer_are_one_function_of_the_rows():
     4e-3)."""
     p = _layer(jax.random.PRNGKey(7))
     h = jax.random.normal(jax.random.PRNGKey(8), (40, D))
-    served, counts, experts = moe.routed_experts(
+    served, counts, experts = jax.jit(functools.partial(
+        moe.routed_experts, first_expert=4, n_held=HELD, n_experts=E, k=K,
+        scale=1.8))(
         {k: ({"kernel": v["kernel"].astype(jnp.bfloat16)}
-             if k.startswith("experts_") else v) for k, v in p.items()},
-        h, first_expert=4, n_held=HELD, n_experts=E, k=K, scale=1.8)
-    trained, stats = moe.routed_experts_trained(
-        p, h, first_expert=4, n_experts=E, k=K, scale=1.8, rows=16)
+             if k.startswith("experts_") else v) for k, v in p.items()}, h)
+    trained, stats = jax.jit(functools.partial(
+        moe.routed_experts_trained, first_expert=4, n_experts=E, k=K,
+        scale=1.8, rows=16))(p, h)
     np.testing.assert_allclose(served, trained, atol=1e-5 * float(
         jnp.max(jnp.abs(served))))
     np.testing.assert_array_equal(experts, stats["experts"])
@@ -176,7 +183,8 @@ def test_served_layer_and_trained_layer_are_one_function_of_the_rows():
 @pytest.fixture(scope="module")
 def tiny():
     cfg = glm.GLMMoeLiteConfig.tiny()
-    params = glm.init(jax.random.PRNGKey(0), cfg)
+    params = jax.jit(functools.partial(glm.init, cfg=cfg))(
+        jax.random.PRNGKey(0))
     batch = jax.random.randint(jax.random.PRNGKey(1), (2, 33), 0,
                                cfg.vocab_size)
     return cfg, params, batch
@@ -184,9 +192,9 @@ def tiny():
 
 def _reference_losses(cfg, params, batch, **kw):
     with jax.default_matmul_precision("highest"):
-        nxt, mtp, _ = ref.summed_losses(
-            params, batch, n_heads=cfg.num_attention_heads,
-            first_expert=cfg.first_expert, **kw)
+        nxt, mtp, _ = jax.jit(functools.partial(
+            ref.summed_losses, n_heads=cfg.num_attention_heads,
+            first_expert=cfg.first_expert, **kw))(params, batch)
     rows, width = batch.shape
     return float(nxt) / (rows * (width - 1)), \
         float(mtp) / (rows * (width - 2))
@@ -205,8 +213,8 @@ def test_prediction_loss_reads_the_second_next_token(tiny):
         **{f.name: getattr(cfg, f.name) for f in
            __import__("dataclasses").fields(cfg)},
         "num_experts_per_tok": ref.TOP_K})
-    loss, metrics, stats = glm.loss_fn(params, cfg, batch[:, :-1],
-                                       batch[:, 1:])
+    loss_fn = jax.jit(glm.loss_fn, static_argnums=1)
+    loss, metrics, stats = loss_fn(params, cfg, batch[:, :-1], batch[:, 1:])
     right = _reference_losses(cfg, params, batch)
     wrong = _reference_losses(cfg, params, batch, mtp_inputs_shift=0)
     assert abs(float(metrics["loss_next"]) - right[0]) < 1e-3 * right[0]
@@ -218,7 +226,7 @@ def test_prediction_loss_reads_the_second_next_token(tiny):
     assert set(stats) == {"sparse", "mtp"}
     assert stats["sparse"].shape == (cfg.n_sparse, 16)
     other = batch.at[:, -1].set((batch[:, -1] + 1) % cfg.vocab_size)
-    _, moved, _ = glm.loss_fn(params, cfg, other[:, :-1], other[:, 1:])
+    _, moved, _ = loss_fn(params, cfg, other[:, :-1], other[:, 1:])
     assert float(moved["loss_mtp"]) != float(metrics["loss_mtp"])
     # ... while the token before the last two is an input of neither loss's
     # last position alone: both losses move
@@ -240,9 +248,10 @@ def test_selection_bias_moves_by_the_loads_and_by_nothing_else(tiny):
         apply_statistics=lambda p, s: glm.update_selection_bias(p, cfg, s),
         donate=False)
     state = create_train_state(params, tx, jax.random.PRNGKey(2))
+    loss_fn = jax.jit(glm.loss_fn, static_argnums=1)
     for _ in range(3):
         before = state.params
-        _, _, loads = glm.loss_fn(before, cfg, batch[:, :-1], batch[:, 1:])
+        _, _, loads = loss_fn(before, cfg, batch[:, :-1], batch[:, 1:])
         state, metrics = step(state, batch)
         for name, router_of in (("sparse", lambda p: p["sparse"]["router"]),
                                 ("mtp", lambda p: p["mtp"]["layer"]["router"])):

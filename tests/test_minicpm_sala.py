@@ -8,6 +8,7 @@ dense_len 256, 4 query heads of 16 over 2 KV groups, layers sparse,
 lightning, lightning, sparse.
 """
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -52,7 +53,7 @@ def params():
     """Seeded weights with every learned vector away from its initial
     value (norm scales 1) and a decay table that is not the convention's,
     so that the table the op is handed is the one that counts."""
-    p = sala.init(jax.random.PRNGKey(0), CFG)
+    p = jax.jit(functools.partial(sala.init, cfg=CFG))(jax.random.PRNGKey(0))
     keys = iter(jax.random.split(jax.random.PRNGKey(1), 16))
     for stack in (p["sparse"], p["lightning"]):
         for name in ("ln1", "ln2", "q_norm", "k_norm"):

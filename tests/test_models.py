@@ -1,5 +1,7 @@
 """Model-family tests — tiny deterministic models, the reference's fixture
 strategy (harness/tests/experiment/fixtures/pytorch_onevar_model.py etc.)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -101,14 +103,28 @@ class TestMnistCNN:
         assert not np.allclose(np.asarray(tr1), np.asarray(tr2))
 
 
+@functools.lru_cache(maxsize=None)
+def jitted(fn):
+    """``fn(params, cfg, ...)`` under ``jax.jit`` with the configuration
+    static: one trace a shape, not one dispatch (and one small program to
+    compile) an operation."""
+    return jax.jit(fn, static_argnums=1)
+
+
+@functools.lru_cache(maxsize=None)
+def _seeded(model, cfg):
+    """``model.init`` of key 0, once a process: no case writes to it."""
+    return jitted(model.init)(jax.random.PRNGKey(0), cfg)
+
+
 class TestResNet:
     def setup_method(self):
         self.cfg = resnet.ResNetConfig.tiny()
-        self.params = resnet.init(jax.random.PRNGKey(0), self.cfg)
+        self.params = _seeded(resnet, self.cfg)
 
     def test_forward_shape_and_dtype(self):
         x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 32, 3))
-        logits = resnet.apply(self.params, self.cfg, x)
+        logits = jitted(resnet.apply)(self.params, self.cfg, x)
         assert logits.shape == (2, self.cfg.n_classes)
         assert logits.dtype == jnp.float32
 
@@ -124,7 +140,7 @@ class TestResNet:
     def test_grad_structure(self):
         x = jax.random.normal(jax.random.PRNGKey(2), (2, 32, 32, 3))
         y = jnp.array([0, 1])
-        g = jax.grad(resnet.loss_fn)(self.params, self.cfg, x, y)
+        g = jitted(jax.grad(resnet.loss_fn))(self.params, self.cfg, x, y)
         assert jax.tree.structure(g) == jax.tree.structure(self.params)
         # every leaf receives gradient signal (no dead branches): a
         # disconnected block would produce exactly-zero grads
@@ -151,7 +167,7 @@ class TestResNet:
         # dp+fsdp data parallelism with the auto-ZeRO-3 fallback rules
         mesh = make_mesh(MeshSpec(dp=4, fsdp=2))
         x = jax.random.normal(jax.random.PRNGKey(5), (8, 32, 32, 3))
-        expect = resnet.apply(self.params, self.cfg, x)
+        expect = jitted(resnet.apply)(self.params, self.cfg, x)
         from determined_clone_tpu.parallel.sharding import ShardingRules
 
         shardings = ShardingRules().shardings_for(self.params, mesh)
@@ -165,22 +181,22 @@ class TestResNet:
 class TestBert:
     def setup_method(self):
         self.cfg = bert.BertConfig.tiny()
-        self.params = bert.init(jax.random.PRNGKey(0), self.cfg)
+        self.params = _seeded(bert, self.cfg)
 
     def test_classify_shape_and_dtype(self):
         tokens = jnp.zeros((2, 16), jnp.int32)
-        logits = bert.classify(self.params, self.cfg, tokens)
+        logits = jitted(bert.classify)(self.params, self.cfg, tokens)
         assert logits.shape == (2, self.cfg.n_classes)
         assert logits.dtype == jnp.float32
 
     def test_mlm_logits_tied_to_embedding(self):
         tokens = jnp.zeros((1, 8), jnp.int32)
-        logits = bert.mlm_logits(self.params, self.cfg, tokens)
+        logits = jitted(bert.mlm_logits)(self.params, self.cfg, tokens)
         assert logits.shape == (1, 8, self.cfg.vocab_size)
         # perturbing the embedding table must move the MLM projection too
         p2 = jax.tree.map(lambda x: x, self.params)
         p2["embed"] = {"table": self.params["embed"]["table"] + 0.1}
-        logits2 = bert.mlm_logits(p2, self.cfg, tokens)
+        logits2 = jitted(bert.mlm_logits)(p2, self.cfg, tokens)
         assert not np.allclose(np.asarray(logits), np.asarray(logits2))
 
     def test_bidirectional_not_causal(self):
@@ -188,8 +204,8 @@ class TestBert:
         # unlike the GPT causality test)
         t1 = jax.random.randint(jax.random.PRNGKey(1), (1, 16), 0, 256)
         t2 = t1.at[0, -1].set((t1[0, -1] + 1) % 256)
-        e1 = bert.encode(self.params, self.cfg, t1)
-        e2 = bert.encode(self.params, self.cfg, t2)
+        e1 = jitted(bert.encode)(self.params, self.cfg, t1)
+        e2 = jitted(bert.encode)(self.params, self.cfg, t2)
         assert not np.allclose(np.asarray(e1[:, 0]), np.asarray(e2[:, 0]),
                                atol=1e-6)
 
@@ -199,8 +215,9 @@ class TestBert:
         mask = jnp.concatenate(
             [jnp.ones((1, 8), jnp.float32), jnp.zeros((1, 8), jnp.float32)], 1)
         garbage = tokens.at[0, 8:].set(255)
-        e1 = bert.encode(self.params, self.cfg, tokens, pad_mask=mask)
-        e2 = bert.encode(self.params, self.cfg, garbage, pad_mask=mask)
+        encode = jitted(bert.encode)
+        e1 = encode(self.params, self.cfg, tokens, pad_mask=mask)
+        e2 = encode(self.params, self.cfg, garbage, pad_mask=mask)
         np.testing.assert_allclose(np.asarray(e1[:, :8]),
                                    np.asarray(e2[:, :8]), atol=1e-5)
 
@@ -224,16 +241,17 @@ class TestBert:
         tokens = jax.random.randint(jax.random.PRNGKey(5), (2, 16), 0, 256)
         targets = jax.random.randint(jax.random.PRNGKey(6), (2, 16), 0, 256)
         mask = jnp.zeros((2, 16)).at[:, :4].set(1.0)
-        loss = bert.mlm_loss(self.params, self.cfg, tokens, targets, mask)
+        mlm_loss = jitted(bert.mlm_loss)
+        loss = mlm_loss(self.params, self.cfg, tokens, targets, mask)
         # changing targets at UNMASKED positions must not move the loss
         targets2 = targets.at[:, 8:].set(0)
-        loss2 = bert.mlm_loss(self.params, self.cfg, tokens, targets2, mask)
+        loss2 = mlm_loss(self.params, self.cfg, tokens, targets2, mask)
         np.testing.assert_allclose(float(loss), float(loss2), rtol=1e-6)
 
     def test_sharded_forward_matches_single(self):
         mesh = make_mesh(MeshSpec(dp=2, fsdp=2, tp=2))
         tokens = jax.random.randint(jax.random.PRNGKey(7), (8, 16), 0, 256)
-        expect = bert.classify(self.params, self.cfg, tokens)
+        expect = jitted(bert.classify)(self.params, self.cfg, tokens)
         shardings = bert.BERT_SHARDING_RULES.shardings_for(self.params, mesh)
         sp = shard_put(self.params, shardings)
         st = shard_put(tokens, NamedSharding(mesh, batch_spec(extra_dims=1)))
@@ -245,7 +263,7 @@ class TestBert:
 class TestGPT:
     def setup_method(self):
         self.cfg = gpt.GPTConfig.tiny()
-        self.params = gpt.init(jax.random.PRNGKey(0), self.cfg)
+        self.params = _seeded(gpt, self.cfg)
 
     def test_stacked_blocks_shape(self):
         qkv = self.params["blocks"]["attn_qkv"]["kernel"]
@@ -253,15 +271,15 @@ class TestGPT:
 
     def test_forward_shape_and_dtype(self):
         tokens = jnp.zeros((2, 16), jnp.int32)
-        logits = gpt.apply(self.params, self.cfg, tokens)
+        logits = jitted(gpt.apply)(self.params, self.cfg, tokens)
         assert logits.shape == (2, 16, self.cfg.vocab_size)
         assert logits.dtype == jnp.float32
 
     def test_causality(self):
         t1 = jax.random.randint(jax.random.PRNGKey(1), (1, 16), 0, 256)
         t2 = t1.at[0, -1].set((t1[0, -1] + 1) % 256)
-        l1 = gpt.apply(self.params, self.cfg, t1)
-        l2 = gpt.apply(self.params, self.cfg, t2)
+        l1 = jitted(gpt.apply)(self.params, self.cfg, t1)
+        l2 = jitted(gpt.apply)(self.params, self.cfg, t2)
         np.testing.assert_allclose(np.asarray(l1[:, :-1]), np.asarray(l2[:, :-1]),
                                    atol=1e-4)
 
@@ -283,7 +301,7 @@ class TestGPT:
     def test_sharded_forward_matches_single(self):
         mesh = make_mesh(MeshSpec(dp=2, fsdp=2, tp=2))
         tokens = jax.random.randint(jax.random.PRNGKey(4), (8, 16), 0, 256)
-        expect = gpt.apply(self.params, self.cfg, tokens)
+        expect = jitted(gpt.apply)(self.params, self.cfg, tokens)
 
         shardings = gpt.GPT_SHARDING_RULES.shardings_for(self.params, mesh)
         sharded_params = shard_put(self.params, shardings)
@@ -304,8 +322,8 @@ class TestGPT:
                             d_ff=128, max_seq_len=128, remat=False,
                             blockwise_attention=True, attention_block_size=16)
         tokens = jax.random.randint(jax.random.PRNGKey(5), (2, 32), 0, 256)
-        base = gpt.apply(self.params, self.cfg, tokens)
-        blocked = gpt.apply(self.params, cfg, tokens)
+        base = jitted(gpt.apply)(self.params, self.cfg, tokens)
+        blocked = jitted(gpt.apply)(self.params, cfg, tokens)
         # bf16 compute: different summation order → small noise
         np.testing.assert_allclose(np.asarray(base), np.asarray(blocked),
                                    atol=1e-2, rtol=1e-2)

@@ -8,6 +8,8 @@ row's context fills is NaN (a block read past a row's length poisons the
 result); the table's entries past a row's length name *another row's* live
 blocks (a copy too many would go unnoticed by the NaNs, not by the sums).
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -76,13 +78,14 @@ def test_kernel_matches_the_plain_form_over_the_gathered_context(
     whole block, a block and one, the whole table, and a batch of them."""
     q, k_pool, v_pool, tables, n = _case(heads, 64, lengths)
     assert k_pool.shape[-1] == row_width
-    out = pa.paged_attention(q, k_pool, v_pool, tables, n)
+    out = jax.jit(pa.paged_attention)(q, k_pool, v_pool, tables, n)
     assert out.shape == q.shape and out.dtype == q.dtype
     out = np.asarray(out, np.float32)
     assert np.isfinite(out).all()
     live = np.asarray(n) > 0
     assert (out[~live] == 0).all()
-    want = np.asarray(_plain(q, k_pool, v_pool, tables, n), np.float32)
+    want = np.asarray(jax.jit(_plain)(q, k_pool, v_pool, tables, n),
+                      np.float32)
     # the same rounding points; the fp32 sums run in another order, which
     # can move a result by one bf16 step
     np.testing.assert_allclose(out[live], want[live], rtol=2 ** -7,
@@ -95,9 +98,10 @@ def test_any_sizes_give_the_rules_numbers(rows, chunk):
     they move no number (a chunk is a whole product either way; only the
     fp32 sum over chunks is ordered by it)."""
     q, k_pool, v_pool, tables, n = _case(16, 64, (700, 0, 18, 1024, 64, 3))
-    rule = pa.paged_attention(q, k_pool, v_pool, tables, n)
+    rule = jax.jit(pa.paged_attention)(q, k_pool, v_pool, tables, n)
     sz = pa.Sizes(rows, chunk, WIDTH * BLOCK // chunk)
-    out = pa.paged_attention(q, k_pool, v_pool, tables, n, sz=sz)
+    out = jax.jit(functools.partial(pa.paged_attention, sz=sz))(
+        q, k_pool, v_pool, tables, n)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(rule, np.float32),
                                rtol=2 ** -7, atol=2 ** -9)
@@ -116,7 +120,7 @@ def test_float32_and_a_small_block_run_interpreted():
     tables = jnp.asarray(rng.permutation(24)[:B * width].reshape(B, width),
                          jnp.int32)
     n = jnp.asarray([48, 9, 0], jnp.int32)
-    out = pa.paged_attention(q, k_pool, v_pool, tables, n)
+    out = jax.jit(pa.paged_attention)(q, k_pool, v_pool, tables, n)
     mask = jnp.arange(width * block)[None, :] < n[:, None]
     want = decode_attention_rows(
         q, k_pool[tables].reshape(B, -1, R), v_pool[tables].reshape(B, -1, R),
@@ -146,7 +150,7 @@ def test_decode_takes_the_kernel_where_the_training_path_takes_flash(
     gather. Compiled, the kernel also has to fit: a block of whole tiles."""
     cfg = gpt.GPTConfig(vocab_size=64, n_layers=2, d_model=32, n_heads=4,
                         d_ff=64, max_seq_len=32, remat=False)
-    params = gpt.init(jax.random.PRNGKey(0), cfg)
+    params = jax.jit(gpt.init, static_argnums=1)(jax.random.PRNGKey(0), cfg)
     cache = KVCacheConfig(num_blocks=8, block_size=8)
     called = []
     real = pa.paged_attention
@@ -158,8 +162,10 @@ def test_decode_takes_the_kernel_where_the_training_path_takes_flash(
 
         k_pool, v_pool = init_kv_pools(cfg, cache)
         del called[:]
-        logits, _, _ = gpt.forward_paged(
-            params, cfg, jnp.ones((2, t), jnp.int32),
+        # a jit of its own every time: the path is chosen while tracing
+        logits, _, _ = jax.jit(
+            lambda p, *rest: gpt.forward_paged(p, cfg, *rest))(
+            params, jnp.ones((2, t), jnp.int32),
             jnp.tile(jnp.arange(t, dtype=jnp.int32), (2, 1)),
             jnp.asarray([[True] * t, [False] * t]),
             jnp.zeros((2,), jnp.int32), k_pool, v_pool,

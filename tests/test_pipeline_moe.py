@@ -3,6 +3,8 @@
 Runs on the 8-device virtual CPU mesh from conftest.py — the same trick as
 the reference's artificial slots (agent/internal/detect/detect.go:39-56).
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -140,7 +142,8 @@ def test_moe_ffn_shapes_and_aux():
     key = jax.random.PRNGKey(0)
     params = moe_init(key, n_experts=4, d_model=16, d_ff=32)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, 16))
-    y, aux = moe_ffn(params, x, k=2, compute_dtype=jnp.float32)
+    y, aux = jax.jit(functools.partial(
+        moe_ffn, k=2, compute_dtype=jnp.float32))(params, x)
     assert y.shape == x.shape
     assert y.dtype == x.dtype
     assert jnp.isfinite(aux)
@@ -159,8 +162,9 @@ def test_moe_capacity_drops_overflow():
     x = jnp.ones((1, N, D))
     cap = expert_capacity(N, E, 0.1)
     assert cap == 1
-    y, _ = moe_ffn(params, x, k=1, capacity_factor=0.1,
-                   compute_dtype=jnp.float32)
+    y, _ = jax.jit(functools.partial(
+        moe_ffn, k=1, capacity_factor=0.1, compute_dtype=jnp.float32))(
+        params, x)
     # exactly `cap` tokens routed to expert 0 produce nonzero output
     nonzero_rows = int(jnp.sum(jnp.any(jnp.abs(y[0]) > 1e-6, axis=-1)))
     assert nonzero_rows == cap
@@ -175,7 +179,7 @@ def test_moe_grads_flow():
         y, aux = moe_ffn(p, x, compute_dtype=jnp.float32)
         return jnp.sum(y ** 2) + 0.01 * aux
 
-    grads = jax.grad(loss)(params)
+    grads = jax.jit(jax.grad(loss))(params)
     gnorm = sum(float(jnp.sum(jnp.abs(g))) for g in jax.tree.leaves(grads))
     assert gnorm > 0
 

@@ -4,6 +4,7 @@ budget, admission control/backpressure, checkpoint hot-load, the static
 run-to-completion baseline, the HTTP front-end, and the KV-cached decode
 FLOPs accounting that makes serving MFU honest."""
 import dataclasses
+import functools
 import json
 import urllib.error
 import urllib.request
@@ -52,12 +53,20 @@ def params():
     return gpt.init(jax.random.PRNGKey(0), CFG)
 
 
+@functools.partial(jax.jit, static_argnums=1)
+def _next_token(params, cfg, toks, n):
+    return jnp.argmax(gpt.apply(params, cfg, toks)[0, n - 1])
+
+
 def naive_greedy(params, prompt, max_new, cfg=CFG):
-    """Reference decode: full-context uncached forward every step."""
+    """Reference decode: full-context uncached forward every step, one
+    program for every length: the context is padded on the right to
+    ``max_seq_len``, which no causal position on the left can see."""
     toks = list(prompt)
     for _ in range(max_new):
-        logits = gpt.apply(params, cfg, jnp.asarray([toks], jnp.int32))
-        toks.append(int(jnp.argmax(logits[0, -1])))
+        padded = toks + [0] * (cfg.max_seq_len - len(toks))
+        toks.append(int(_next_token(
+            params, cfg, jnp.asarray([padded], jnp.int32), len(toks))))
     return toks[len(prompt):]
 
 
@@ -121,9 +130,11 @@ def test_paged_decode_token_identical_and_compile_budget(geometry,
                                                          assert_pool_rows,
                                                          attention_impl):
     """Mixed-length requests through the continuous scheduler produce
-    EXACTLY the tokens of the naive uncached forward (greedy), and the
-    shared jitted forward never compiles more programs than the bucket
-    budget — the two acceptance properties of the serving tentpole. Both
+    EXACTLY the tokens of the naive uncached forward (greedy), and this
+    engine never adds more programs to the shared jitted forward than the
+    bucket budget (the jit's cache is the process's: what an engine
+    compiled is the growth of ``programs_compiled()`` over its traffic) —
+    the two acceptance properties of the serving tentpole. Both
     hold whether or not a pool row is padded, and prefill and decode
     leave the padding zero; and whether a decode step gathers its context
     and attends it in plain XLA (``"mha"``) or reads it through the block
@@ -133,6 +144,7 @@ def test_paged_decode_token_identical_and_compile_budget(geometry,
                 for i, p in enumerate(PROMPTS)}
     cfg = dataclasses.replace(cfg, attention_impl=attention_impl)
     with InferenceEngine(params, cfg, buckets=BUCKETS, cache=CACHE) as eng:
+        before = eng.programs_compiled()
         handles = [eng.submit(p, 12, request_id=str(i))
                    for i, p in enumerate(PROMPTS)]
         results = {int(h.result(timeout=120.0).request_id):
@@ -149,7 +161,8 @@ def test_paged_decode_token_identical_and_compile_budget(geometry,
         assert results[i].tokens == expected[i], f"request {i} diverged"
         assert results[i].finish_reason == "length"
         assert results[i].prompt_len == len(PROMPTS[i])
-    assert 0 < compiled <= budget, (compiled, budget)
+    assert compiled > 0 and 0 <= compiled - before <= budget, (
+        before, compiled, budget)
     assert stats.completed == 5
     assert stats.tokens_generated == 3 * 12 + 2 * 5
     assert stats.free_blocks == CACHE.num_blocks  # everything released
@@ -163,9 +176,13 @@ def test_warmup_precompiles_full_ladder(params):
     single program. The mid-traffic compile stall this prevents is what
     collapsed the bench's top load point ~10x before warmup existed."""
     expected = naive_greedy(params, PROMPTS[0], 8)
-    with make_engine(params) as eng:
+    # the jit's cache is the process's, so the count is the growth over
+    # the warm-up; a pool of 17 blocks is this test's alone in the
+    # process, so that growth is exactly this engine's ladder
+    with make_engine(params, cache=KVCacheConfig(17, 8)) as eng:
+        before = eng.programs_compiled()
         compiled = eng.warmup()
-        assert compiled == eng.buckets.program_budget
+        assert compiled - before == eng.buckets.program_budget
         # trickle: each request admitted alone → batch-bucket-1 prefill,
         # the shape a warm burst at full batch never compiles
         for _ in range(2):
@@ -493,7 +510,8 @@ def test_closed_engine_refuses(params):
 def test_hot_load_from_cas_swaps_params(params, tmp_path):
     """Serve under params A, hot-load params B from a CAS-backed store,
     and the very next generation must match the naive forward under B —
-    no restart, no re-jit (program count stays bounded)."""
+    no restart, no re-jit (the programs this engine added stay within the
+    budget, and B's tree added none to A's)."""
     params_b = gpt.init(jax.random.PRNGKey(7), CFG)
     store = CASStorageManager(
         SharedFSStorageManager(str(tmp_path / "store")))
@@ -503,11 +521,14 @@ def test_hot_load_from_cas_swaps_params(params, tmp_path):
 
     ref_a = naive_greedy(params, PROMPTS[0], 6)
     with make_engine(params) as eng:
+        before = eng.programs_compiled()  # the jit is shared in a process
         assert eng.generate(PROMPTS[0], 6).tokens == ref_a
+        under_a = eng.programs_compiled()
         dt = eng.hot_load(store, "ck-b", base_tmp=str(tmp_path))
         assert dt >= 0.0
         got = eng.generate(PROMPTS[0], 6).tokens
-        compiled = eng.programs_compiled()
+        assert eng.programs_compiled() == under_a  # no re-jit
+        compiled = eng.programs_compiled() - before
         # the swap installed the restored tree (greedy token streams of
         # two untrained models can coincide — check the params, not the
         # sampled tokens, to prove the swap happened)

@@ -58,12 +58,20 @@ def params():
     return gpt.init(jax.random.PRNGKey(0), CFG)
 
 
+@jax.jit
+def _next_token(params, toks, n):
+    return jnp.argmax(gpt.apply(params, CFG, toks)[0, n - 1])
+
+
 def naive_greedy(params, prompt, max_new):
-    """Reference decode: full-context uncached forward every step."""
+    """Reference decode: full-context uncached forward every step, one
+    program for every length: the context is padded on the right to
+    ``max_seq_len``, which no causal position on the left can see."""
     toks = list(prompt)
     for _ in range(max_new):
-        logits = gpt.apply(params, CFG, jnp.asarray([toks], jnp.int32))
-        toks.append(int(jnp.argmax(logits[0, -1])))
+        padded = toks + [0] * (CFG.max_seq_len - len(toks))
+        toks.append(int(_next_token(
+            params, jnp.asarray([padded], jnp.int32), len(toks))))
     return toks[len(prompt):]
 
 

@@ -12,6 +12,7 @@ program ladder — draft, k+1 verify, and block-copy included — so
 traffic never compiles.
 """
 import dataclasses
+import functools
 import json
 import time
 import urllib.request
@@ -64,12 +65,20 @@ def draft_params():
     return gpt.init(jax.random.PRNGKey(7), CFG)
 
 
+@functools.partial(jax.jit, static_argnums=1)
+def _next_token(params, cfg, toks, n):
+    return jnp.argmax(gpt.apply(params, cfg, toks)[0, n - 1])
+
+
 def naive_greedy(params, prompt, max_new, cfg=CFG):
-    """Reference decode: full-context uncached forward every step."""
+    """Reference decode: full-context uncached forward every step, one
+    program for every length: the context is padded on the right to
+    ``max_seq_len``, which no causal position on the left can see."""
     toks = list(prompt)
     for _ in range(max_new):
-        logits = gpt.apply(params, cfg, jnp.asarray([toks], jnp.int32))
-        toks.append(int(jnp.argmax(logits[0, -1])))
+        padded = toks + [0] * (cfg.max_seq_len - len(toks))
+        toks.append(int(_next_token(
+            params, cfg, jnp.asarray([padded], jnp.int32), len(toks))))
     return toks[len(prompt):]
 
 

@@ -2,11 +2,18 @@
 
 ≈ the reference's nightly k6 run (performance/src/api_performance_tests.ts:
 336-374 — 25 ramping VUs, 20 min, ~40 endpoint groups, p95 < 1 s). Scaled
-to CI wall-clock: DCT_SOAK_SECONDS (default 120) of sustained load from
-25 VUs across every GET endpoint group, WHILE 12 log followers long-poll a
-live stream being appended to and a WebSocket relay shuttles frames
-through the reverse proxy. The same p95 < 1 s / <5% failure gates apply
-throughout — not just at the end.
+to CI wall-clock: DCT_SOAK_SECONDS of sustained load from 25 VUs across
+every GET endpoint group, WHILE 12 log followers long-poll a live stream
+being appended to and a WebSocket relay shuttles frames through the
+reverse proxy. The same p95 < 1 s / <5% failure gates apply throughout —
+in every window of a quarter of the run (30 s at most), not just at the
+end.
+
+Which length runs where: 20 s by default, which is what tier-1 runs (the
+gates are the same and every path is driven within the first seconds; a
+longer sleep beside five other test workers measures the machine);
+``DCT_SOAK_SECONDS=120`` by hand for the comparison with the reference's
+k6 run above, four windows of 30 s.
 """
 import base64
 import hashlib
@@ -27,7 +34,8 @@ REPO = Path(__file__).resolve().parent.parent
 MASTER_DIR = REPO / "determined_clone_tpu" / "master"
 MASTER_BIN = MASTER_DIR / "build" / "dct-master"
 
-SOAK_SECONDS = float(os.environ.get("DCT_SOAK_SECONDS", "120"))
+SOAK_SECONDS = float(os.environ.get("DCT_SOAK_SECONDS", "20"))
+WINDOW_SECONDS = min(30.0, SOAK_SECONDS / 4)
 VUS = 25
 FOLLOWERS = 12
 P95_BUDGET_S = 1.0
@@ -343,7 +351,8 @@ def test_sustained_soak_p95_with_followers_and_ws(master):
     # per-window p95: the gate must hold THROUGHOUT, not just on average
     windows = {}
     for t_end, lat in window_latencies:
-        windows.setdefault(int((t_end - t_start) // 30), []).append(lat)
+        windows.setdefault(int((t_end - t_start) // WINDOW_SECONDS),
+                           []).append(lat)
     window_p95 = {}
     for w, lats in sorted(windows.items()):
         lats.sort()
@@ -354,7 +363,7 @@ def test_sustained_soak_p95_with_followers_and_ws(master):
           f" + WS relay: {len(all_lat)} reqs, p50={p50 * 1000:.1f}ms "
           f"p95={p95 * 1000:.1f}ms, follower_rounds={follower_rounds[0]}, "
           f"ws_frames={ws_rounds[0]}, errors={len(errs)}")
-    print(f"[soak] per-30s-window p95: "
+    print(f"[soak] per-{WINDOW_SECONDS:.0f}s-window p95: "
           f"{[f'{v * 1000:.0f}ms' for _, v in sorted(window_p95.items())]}")
 
     assert fail_rate < 0.05, (fail_rate, errs[:5])
