@@ -306,6 +306,50 @@ class SparseStateLayout(StateSlotLayout):
         return (length, selected, self.state_slots)
 
 
+class WindowSlotLayout(StateSlotLayout):
+    """:class:`StateSlotLayout` where the slot is a ring of K/V rows
+    (``models/afmoe.py``): the full-attention layers keep one exact row a
+    position in blocks that grow, and every sliding-window layer keeps the
+    last ``ring = window + slice_len`` positions of a sequence in the
+    sequence's slot of a pool of its own, position ``p`` in row ``p %
+    ring``. A prefill slice of at most ``slice_len`` tokens is written
+    first and attended after, so the ring still holds the ``window - 1``
+    positions before the slice's first when its last row is in; what a
+    sequence holds in those layers stops growing at ``ring`` positions. A
+    query at position ``length - 1`` attends ``min(length, window)`` rows
+    of a sliding layer and all ``length`` of a full one.
+    """
+
+    def __init__(self, cache: KVCacheConfig, max_seq_len: int, *,
+                 window: int, slice_len: int) -> None:
+        super().__init__(cache, max_seq_len)
+        self.window, self.slice_len = int(window), int(slice_len)
+        self.ring = self.window + self.slice_len
+        if self.slice_len < cache.block_size or self.ring % cache.block_size:
+            raise ValueError(
+                f"the ring of {self.window} + {self.slice_len} positions is "
+                f"not whole cache blocks of {cache.block_size} with a block "
+                f"at least to spare")
+
+    @property
+    def row_args(self) -> Tuple[str, ...]:
+        return ("kv_rows", "window_rows")
+
+    def attended_rows(self, length: int) -> Tuple[int, int]:
+        """(rows cached and read in a full layer, rows read in one sliding
+        layer)."""
+        return (length, min(length, self.window))
+
+    def check_prefill(self, max_prefill_len: int,
+                      chunk_prefill_len: int) -> None:
+        super().check_prefill(max_prefill_len, chunk_prefill_len)
+        if max(max_prefill_len, chunk_prefill_len) > self.slice_len:
+            raise ValueError(
+                f"a prefill slice of {max(max_prefill_len, chunk_prefill_len)}"
+                f" tokens exceeds the {self.slice_len} positions the ring "
+                f"keeps beside its window of {self.window}")
+
+
 class LatentIndexLayout(CacheLayout):
     """A cache of one latent row a position and layer, with the keys of a
     learned index beside it in some layers (``models/glm_moe_dsa.py``).

@@ -15,7 +15,7 @@ in a worker they share (``jax.jit``'s cache is per function, not per test).
 import pytest
 
 pytest.register_assert_rewrite(
-    "benchmarks.tests.test_glm4_moe_lite",
+    "benchmarks.tests.test_afmoe", "benchmarks.tests.test_glm4_moe_lite",
     "benchmarks.tests.test_glm_moe_dsa", "benchmarks.tests.test_kimi_linear",
     "benchmarks.tests.test_minicpm_sala", "benchmarks.tests.test_overlap",
     "benchmarks.tests.test_paged",
@@ -41,6 +41,15 @@ from benchmarks.tests.test_glm4_moe_lite import (  # noqa: E402,F401
     test_tiny_cell_lists_what_the_real_cell_lists
     as test_glm_lite_tiny_cell_lists_what_the_real_cell_lists,
     work_dir,
+)
+from benchmarks.tests.test_afmoe import (  # noqa: E402,F401
+    test_readers_know_the_bytes_a_step_has_to_read,
+    test_real_configuration_is_the_catalogs_but_for_what_reduced_names
+    as test_afmoe_real_configuration_is_the_catalogs_but_for_what_reduced_names,
+    test_the_mix_is_the_issues_parameter_for_parameter
+    as test_afmoe_the_mix_is_the_issues_parameter_for_parameter,
+    test_tiny_cell_lists_what_the_real_cell_lists
+    as test_afmoe_tiny_cell_lists_what_the_real_cell_lists,
 )
 from benchmarks.tests.test_glm_moe_dsa import (  # noqa: E402,F401
     test_a_steps_counts_are_those_of_the_commit_that_followed_it,
@@ -132,8 +141,8 @@ def test_the_manifest_lists_the_reader_as_it_describes_itself():
     and a later PR's entries go after it (the contract: new entries at the
     end of their lists). What it guarded stands: the entry is as its reader
     describes itself, lists the five serve cells it listed, and everything
-    after it is a later PR's, appended (PR 41's and PR 43's, each its new
-    cell's alone). The file under ``benchmarks/`` may not be edited by a PR that
+    after it is a later PR's, appended (PR 41's, PR 43's and PR 47's, each
+    its new cell's alone). The file under ``benchmarks/`` may not be edited by a PR that
     is no benchmark PR, so by hand that one assertion now fails
     (``CHANGES.md``, PR 41)."""
     import json
@@ -153,7 +162,8 @@ def test_the_manifest_lists_the_reader_as_it_describes_itself():
     later = manifest["per_layer"][manifest["per_layer"].index(entry) + 1:]
     assert all(m["workloads"] in (
         ["kimi-linear-48b-a3b.serve-longdoc-closed"],
-        ["glm-4.7-flash.train-8k"]) for m in later)
+        ["glm-4.7-flash.train-8k"],
+        ["trinity-large-preview.serve-mixed-closed"]) for m in later)
     for cell in entry["workloads"]:
         assert test_overlap.NAME in spec.load_cell(
             cell, manifest=manifest).per_layer

@@ -930,6 +930,71 @@ def test_kimi_linear_paged_forward_compiles_at_the_cell_sizes(
         assert f"/{scope}/" in text, scope
 
 
+@pytest.mark.parametrize("batch,t", [(16, 1), (1, 2048), (16, 2048)],
+                         ids=["decode-16", "slice-2048",
+                              "slices-of-16-rows"])
+def test_afmoe_paged_forward_compiles_at_the_cell_sizes(v5e, batch, t):
+    """``trinity-large-preview.serve-mixed-closed``: published layers 6..10
+    at the published widths with 32 of the 256 experts held and an eighth
+    of the vocabulary (8.65 GB of bfloat16 weights), 16 x 544 blocks of 64
+    positions of K and V rows of 1024 in the one full layer (2.28 GB), 16
+    slots of four rings of 6144 positions (1.61 GB); the decode step at 16
+    rows and the prefill slices at 34816 positions. They fit the 15.75 GB
+    a v5e offers a program, all four donated pools are updated in place,
+    neither form makes anything as large as one row's table of K or V rows
+    (a pass of blocks a row, under an online softmax) or a slice's scores
+    over a whole context, and the six scopes the benchmark reads are on
+    the operations' paths."""
+    from determined_clone_tpu.models import afmoe
+    from determined_clone_tpu.serving.engine import make_paged_forward
+    from determined_clone_tpu.serving.kv_cache import KVCacheConfig
+
+    cfg = afmoe.AfmoeConfig(
+        vocab_size=25024, num_hidden_layers=5, num_dense_layers=1,
+        layer_types=("sliding_attention",) * 2 + ("full_attention",)
+        + ("sliding_attention",) * 2, num_experts=32,
+        max_position_embeddings=34816)
+    cache = KVCacheConfig(16 * 544, 64)
+    layout = cfg.paged_model().cache_layout(cfg, cache)
+    assert layout.blocks_needed(cfg.max_seq_len) == 544 \
+        == layout.table_width - 1
+    assert (layout.window, layout.ring) == (4096, 6144)
+    one = SingleDeviceSharding(v5e[0])
+    params = _shapes(jax.eval_shape(
+        lambda k: afmoe.serving_params(afmoe.init(k, cfg), cfg),
+        jax.random.PRNGKey(0)), one)
+    assert 8.6e9 < sum(math.prod(x.shape) * x.dtype.itemsize
+                       for x in jax.tree.leaves(params)) < 8.7e9
+    pools = _shapes(jax.eval_shape(lambda: afmoe.init_pools(cfg, cache, 16)),
+                    one)
+    assert [p.shape for p in pools] == [(1, 8704, 64, 1024)] * 2 \
+        + [(4, 16, 6144, 1024)] * 2
+    pool_bytes = sum(math.prod(p.shape) * p.dtype.itemsize for p in pools)
+    assert 3.89e9 < pool_bytes < 3.90e9
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    compiled = make_paged_forward(len(pools)).lower(
+        params, cfg, *_step_inputs(arr, batch, t, layout.table_width),
+        *pools).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes      # all four, in place
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 15.75 * 2 ** 30)
+    text = compiled.as_text()
+    # no row's ring or table of rows, no scores over a ring or a context
+    assert not re.search(r"bf16\[(?:\d+,)?(?:6144|34816),1024\]", text)
+    assert not re.search(r"f32\[(?:\d+,)*34816\]", text)
+    if t == 1:
+        assert mem.temp_size_in_bytes < 0.25 * 2 ** 30
+    else:
+        assert mem.temp_size_in_bytes < 2.0 * 2 ** 30
+    for scope in ("window_attn", "full_attn", "kv_cache", "moe_route",
+                  "moe_experts"):
+        assert f"/{scope}/" in text, scope
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("n_chips", [1, 4])
 def test_gpt2_small_train_step_compiles(v5e, monkeypatch, n_chips):
